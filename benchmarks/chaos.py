@@ -5,9 +5,10 @@ PERFORMANCE.md.  Each scenario runs the full wordcount engine over the
 same synthetic corpus with one fault rule armed (``resilience/faults.py``
 grammar) and asserts the resilience tentpole's two contracts:
 
-* **byte identity** — every recovered OR degraded run produces
-  ``word_counts.csv`` byte-identical to the clean run (the golden
-  contracts hold under injected failure);
+* **byte identity** — every recovered run produces ``word_counts.csv``
+  byte-identical to the clean run (the golden contracts hold under
+  injected failure; a *persistent* device fault fails the run —
+  ``tests/test_resilience.py`` — there is no host-side count path);
 * **visible recovery** — the injected trips and the retries/failovers
   that absorbed them appear in the run's telemetry counters.
 
@@ -29,12 +30,11 @@ import time
 from benchmarks import suite
 from benchmarks._util import device_info, smoke
 
-# (scenario, fault spec, expect_degraded) — specs use the public grammar.
+# (scenario, fault spec) — specs use the public grammar.
 _SCENARIOS = (
-    ("ingest_transient", "ingest.read:error@1", False),
-    ("prefetch_transient", "prefetch.stage:error@1", False),
-    ("psum_transient", "collective.psum:error@1", False),
-    ("psum_persistent_degrade", "collective.psum:error", True),
+    ("ingest_transient", "ingest.read:error@1"),
+    ("prefetch_transient", "prefetch.stage:error@1"),
+    ("psum_transient", "collective.psum:error@1"),
 )
 
 _WORDS = (
@@ -1153,7 +1153,7 @@ def run() -> dict:
         print(f"[chaos] clean baseline: {clean_s:.3f}s "
               f"({n_rows} rows)", file=sys.stderr)
 
-        for name, spec, expect_degraded in _SCENARIOS:
+        for name, spec in _SCENARIOS:
             reset_retry_stats()
             configure_faults(spec)
             try:
@@ -1164,11 +1164,6 @@ def run() -> dict:
             finally:
                 configure_faults(None)
             identical = got == clean_bytes
-            degraded = False
-            manifest_path = os.path.join(tmp, name, "run_manifest.json")
-            if os.path.exists(manifest_path):
-                with open(manifest_path, "r", encoding="utf-8") as fh:
-                    degraded = bool(json.load(fh).get("degraded"))
             trips = sum(
                 int(info.get("trips", 0)) for info in faults.values()
             )
@@ -1181,8 +1176,6 @@ def run() -> dict:
                 "scenario": name,
                 "spec": spec,
                 "bytes_identical": identical,
-                "expect_degraded": expect_degraded,
-                "degraded": degraded,
                 "trips": trips,
                 "retries": retries,
                 "wall_s": round(wall_s, 4),
@@ -1361,9 +1354,7 @@ def run() -> dict:
         and compile_first["bytes_identical"]
         and checkpoint_stream["bytes_identical"],
         "all_recovered": all(
-            s["trips"] > 0
-            and (s["degraded"] if s["expect_degraded"] else True)
-            for s in scenarios
+            s["trips"] > 0 for s in scenarios
         ) and serving["all_answered"] and decode["all_answered"]
         and router["all_answered"] and prefix["all_fell_back"]
         and spec_draft["all_fell_back"]

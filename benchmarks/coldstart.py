@@ -2,7 +2,7 @@
 
 Backs the "Cold starts" section in PERFORMANCE.md.  Each measurement is a
 REAL fresh Python process (subprocess) running a DistilBERT sentiment
-batch end-to-end; the only variable is whether ``MUSICAAL_XLA_CACHE``
+batch end-to-end; the only variable is whether ``JAX_COMPILATION_CACHE_DIR``
 points at an empty directory or one populated by the previous run.  The
 delta is what the persistent compilation cache (``utils/cache.py``) buys
 every CLI invocation after the first.
@@ -42,7 +42,9 @@ print(json.dumps({"seconds": time.perf_counter() - start,
 
 
 def _fresh_run(cache_dir: str, tiny: bool) -> float:
-    env = dict(os.environ, MUSICAAL_XLA_CACHE=cache_dir)
+    # The deliberate per-run directory goes through JAX's own variable:
+    # with it set, utils/cache.py sets no directory in code.
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=cache_dir)
     args = [sys.executable, "-c", _CHILD] + (["tiny"] if tiny else [])
     proc = subprocess.run(
         args, capture_output=True, text=True, env=env,

@@ -5,7 +5,7 @@ Answers two questions the ISSUE-3 data plane raised:
 * **depth** — how many batches should the bounded pipeline
   (``runtime/prefetch.py``) stage ahead of the device?  An ingest-bound
   source (emulated here with a metered per-chunk delay, the shape a
-  ~10 MB/s tunnel or a cold page cache produces) serializes the whole
+  slow disk or a cold page cache produces) serializes the whole
   run at depth 0; depth ≥ 2 should hide the source behind compute.  The
   per-depth ``pipeline.*`` stall columns show *where* the remaining wall
   time lives — ``compute_stall_s`` high means the device starves

@@ -27,7 +27,7 @@ import tempfile
 import time
 
 from benchmarks import suite
-from benchmarks._util import device_info, smoke
+from benchmarks._util import CPU_CHILDREN, smoke
 
 _LYRICS = (
     "I love the sunshine and the happy days we share",
@@ -137,7 +137,7 @@ def run() -> dict:
 
     return {
         "suite": "router",
-        **device_info(),
+        "workers": CPU_CHILDREN,
         "smoke": smoke(),
         "rows": rows,
         "failover_drill": drill,

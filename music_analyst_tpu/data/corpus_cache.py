@@ -100,7 +100,7 @@ def resolve_cache_dir(
     ``use_cache=False`` (the ``--no-corpus-cache`` flag) always wins;
     then an explicit ``cache_dir`` (``--corpus-cache-dir``), then
     ``$MUSICAAL_CORPUS_CACHE`` (``0``/``off``/``false`` disables), then
-    the user-level default next to the XLA cache.
+    the user-level default (``~/.cache/musicaal_corpus``).
     """
     if use_cache is False:
         return None

@@ -28,7 +28,7 @@ from music_analyst_tpu.data.vocab import Vocab
 from music_analyst_tpu.resilience.faults import fault_point
 from music_analyst_tpu.resilience.policy import RetryPolicy
 
-# Transient read failures (tunnel-mounted corpus, injected ingest.read
+# Transient read failures (network-mounted corpus, injected ingest.read
 # faults) get re-attempted; the whole ingest is idempotent, so the retry
 # wraps the full backend dispatch rather than just the open().
 _INGEST_RETRY = RetryPolicy(base_s=0.05, cap_s=1.0)

@@ -5,12 +5,12 @@ Three pieces (see each module's docstring):
 * :mod:`flight` — bounded telemetry ring dumped as ``flight_record.json``
   (thread stacks + vitals) on crash/signal/watchdog/deadline,
 * :mod:`watchdog` — heartbeat monitor classifying hangs into the
-  structured taxonomy (``tunnel_dead``, ``compile_hang``, ``stage_stall``,
+  structured taxonomy (``backend_lost``, ``compile_hang``, ``stage_stall``,
   ``host_oom``, …),
 * :mod:`report` — ``telemetry-report`` run-over-run aggregation.
 
 Jax-free at import: safe before ``tests/conftest.py`` pins the platform
-and inside ``bench.py --probe``.
+and in jax-free tools (``chip_smoke.py``'s parent).
 """
 
 from music_analyst_tpu.observability.flight import (

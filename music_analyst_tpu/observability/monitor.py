@@ -14,8 +14,8 @@ the house 0/1/2 gate semantics: 0 = healthy reply, 1 = the server
 answered but reported itself draining/unhealthy, 2 = no usable reply
 (dead socket, bad payload).
 
-Jax-free by design — a monitor must attach while the device is busy or
-the tunnel is dead.
+Jax-free by design — a monitor must attach while the device is busy,
+and must never claim a chip of its own.
 """
 
 from __future__ import annotations

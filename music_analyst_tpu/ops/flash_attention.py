@@ -34,12 +34,18 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.6 renamed TPUCompilerParams -> CompilerParams; same fields.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 NEG_INF = -1e30
+
+
+def interpret_default() -> bool:
+    """Whether this package's Pallas kernels run under the interpreter.
+
+    The one place that decides: on a TPU backend every kernel is compiled
+    by Mosaic; anywhere else (the CPU test mesh) it is interpreted.  An
+    explicit ``interpret=`` argument is a test facility, not a fallback —
+    a kernel Mosaic refuses fails the run.
+    """
+    return jax.default_backend() != "tpu"
 
 
 def _flash_kernel(
@@ -55,8 +61,12 @@ def _flash_kernel(
     segmented: bool,
 ):
     if segmented:
-        # VMEM [1, bq] / [1, bkv] — per-token segment ids (block-diagonal
-        # attention for packed batches, models/distilbert.py).
+        # VMEM [1, bq, 1] / [1, 1, bkv] — per-token segment ids (block-
+        # diagonal attention for packed batches, models/distilbert.py).
+        # Query ids ride the sublane axis and key ids the lane axis, so
+        # the [bq, bkv] comparison is a plain broadcast: Mosaic accepts
+        # neither a (1, bq) block of a [B, S] array with B > 1 nor the
+        # lane→sublane transpose a 1-D id vector would need.
         qseg_ref, kvseg_ref, *refs = refs
     q_ref, k_ref, v_ref, o_ref, *rest = refs
     if residuals:
@@ -85,12 +95,17 @@ def _flash_kernel(
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale
+        q = q_ref[0, 0].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
+        # Scale the scores, not the queries: the MXU takes its operands
+        # in bf16 passes, so bf16 inputs contract exactly — a pre-scaled
+        # q no longer fits bf16 and costs ~0.4% of every logit (measured
+        # on a v5e against the f32 reference, chip_smoke.py; the CPU
+        # interpreter's exact f32 matmul cannot show it).
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [bq, bkv]
+        ) * scale  # [bq, bkv]
 
         kv_pos = kv_off + ki * block_kv + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, dimension=1
@@ -102,9 +117,7 @@ def _flash_kernel(
             )
             valid = valid & (kv_pos <= q_pos)
         if segmented:
-            qs = qseg_ref[0]                                    # [bq]
-            ks = kvseg_ref[0]                                   # [bkv]
-            valid = valid & (qs[:, None] == ks[None, :])
+            valid = valid & (qseg_ref[0] == kvseg_ref[0])  # [bq,1]==[1,bkv]
         s = jnp.where(valid, s, NEG_INF)
 
         m_prev = m_ref[:, :1]                                  # [bq, 1]
@@ -208,14 +221,14 @@ def _flash_call(
     inputs = [lengths, offsets]
     if segmented:
         in_specs.append(pl.BlockSpec(
-            (1, block_q), lambda b, h, qi, ki: (b, qi),
+            (1, block_q, 1), lambda b, h, qi, ki: (b, qi, 0),
             memory_space=pltpu.VMEM,
         ))
         in_specs.append(pl.BlockSpec(
-            (1, block_kv), lambda b, h, qi, ki: (b, ki),
+            (1, 1, block_kv), lambda b, h, qi, ki: (b, 0, ki),
             memory_space=pltpu.VMEM,
         ))
-        inputs += [q_seg, kv_seg]
+        inputs += [q_seg[:, :, None], kv_seg[:, None, :]]
     in_specs += [qblock_spec, kvblock_spec, kvblock_spec]
     inputs += [q, k, v]
     out = pl.pallas_call(
@@ -229,7 +242,7 @@ def _flash_call(
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -288,7 +301,8 @@ def flash_attention(
     (tile-aligned when possible), so non-power-of-two shards (e.g. ring
     attention's per-device slices) pick a legal block instead of raising.
     Off-TPU the kernel runs in interpreter mode
-    so CPU test meshes exercise the same code path.
+    so CPU test meshes exercise the same code path
+    (:func:`interpret_default`).
 
     ``q_segment_ids`` ``[B, S]`` / ``kv_segment_ids`` ``[B, KV]`` add
     block-diagonal masking: a query attends only to keys with the SAME
@@ -319,7 +333,7 @@ def flash_attention(
             kv_offset, jnp.int32
         )
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     offsets = jnp.stack(
         [jnp.asarray(q_offset, jnp.int32), jnp.asarray(kv_offset, jnp.int32)]
     )

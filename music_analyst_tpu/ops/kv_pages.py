@@ -63,7 +63,11 @@ import jax
 import jax.numpy as jnp
 
 from music_analyst_tpu.models.layers import KVCache
-from music_analyst_tpu.ops.paged_attention import PagedAttnView
+from music_analyst_tpu.ops.flash_attention import interpret_default
+from music_analyst_tpu.ops.paged_attention import (
+    PagedAttnView,
+    check_stream_geometry,
+)
 from music_analyst_tpu.ops.quant import quantize_kv_page
 from music_analyst_tpu.profiling.compile import profiled_jit
 
@@ -203,6 +207,13 @@ class PagedDecodeRuntime:
             )
         self.kv_quant = kv_quant
         quantized = kv_quant == "int8"
+        if not interpret_default():
+            # Decode attends through the Mosaic-compiled streaming kernel;
+            # a geometry it cannot lower is refused here, not at the
+            # first dispatch.
+            check_stream_geometry(
+                config.n_kv_heads, config.dim // config.n_heads
+            )
         # The dtype KV rows dequantize to (and the unquantized pool's
         # storage dtype): the model's activation dtype.
         compute_dtype = jnp.bfloat16

@@ -4,15 +4,14 @@
 ``[n_slots, max_total]`` copy of every slot's KV through the page table
 (gather), running dense attention over the copy, and scattering the
 touched pages back — three extra HBM passes over the whole resident KV
-per decode dispatch, measured at ~25% decode overhead vs the monolithic
-slot runtime on decode-heavy no-prefix workloads (PERFORMANCE.md).  This
-module removes the copy: one fused kernel walks the ``(n_slots,
-pages_per_slot)`` int32 page table *inside* the program, streams KV
-pages through VMEM, and reduces — gather + QK + softmax + V in a single
-``pallas_call``, with the page pool bound as an ``ANY``-space operand so
-no contiguous view is ever materialized.
+per decode dispatch.  This module removes the copy: one fused kernel
+reads the ``(n_slots, pages_per_slot)`` int32 page table *inside* the
+program, streams KV pages through VMEM, and reduces — gather + QK +
+softmax + V in a single ``pallas_call``, so no contiguous view is ever
+materialized.
 
-Two kernel bodies, chosen statically by backend:
+Two kernel bodies, chosen statically by backend
+(``ops/flash_attention.interpret_default`` is the one place that decides):
 
 * **exact batched body** (interpret mode / the CPU-emulated test mesh):
   one program over the whole batch; the in-kernel take-gather feeds the
@@ -22,20 +21,32 @@ Two kernel bodies, chosen statically by backend:
   order — so interpret-mode lowering is **bitwise** identical to the
   retired gather path.  (A no-repeat grouped contraction is
   mathematically equal but reassociates the head broadcast, and a
-  1-ulp logit difference flips greedy argmax near-ties; the streaming
-  TPU body keeps the grouped form since on-chip it IS the lowering.)
-* **streaming body** (real TPU): grid over slots; each program walks its
-  table row, DMAs one page at a time into VMEM scratch
-  (``pltpu.make_async_copy``), and folds it into an online-softmax
-  accumulator (running max / normalizer / weighted-V, masked lanes
-  contribute exact zeros) — O(page) VMEM regardless of context length.
+  1-ulp logit difference flips greedy argmax near-ties.)
+* **streaming body** (real TPU, compiled by Mosaic): grid ``(n_slots,
+  pages_per_slot)``; the table rides in SMEM as a scalar-prefetch
+  operand and the K/V page BlockSpecs index the pool *through* it, so
+  the Pallas pipeline DMAs (and double-buffers) one physical page per
+  grid step.  Each step folds its page into an online-softmax
+  accumulator held in VMEM scratch across the page axis (running max /
+  normalizer / weighted-V, masked lanes contribute exact zeros) —
+  O(page) VMEM regardless of context length.  Everything in the body is
+  a lane-dense 2-D tile: the page ``[P, n_kv, D]`` is viewed as
+  ``[P * n_kv, D]`` and contracted against *all* ``H`` query heads at
+  once; the ``[H, P * n_kv]`` score tile then keeps only the columns
+  whose KV head matches the row's query group (an iota compare), which
+  is GQA without the mid-axis batched einsum, strided head slices or
+  sub-128 lane slices Mosaic refuses.  The extra ``n_kv``× MXU work is
+  free where decode attention is bound by page bytes.
 
 int8 KV pages (``ops/quant.quantize_kv_page``): both bodies accept
-optional per-(page, row) f32 scale pools and fuse the dequant into the
-KV-load epilogue — codes go ``int8 → f32 × scale → bf16`` right after
-the gather/DMA, before QK.  The fp16/bf16 path stays byte-identical to
-the retired gather runtime; int8 carries a bounded-error contract
-instead (``tests/test_paged_attention.py``).
+optional per-(page, row) f32 scale pools.  The exact body dequantizes
+right after the gather (``int8 → f32 × scale → bf16``); the streaming
+body applies the K scale to the score columns and the V scale to the
+probabilities (the scale is constant over ``(n_kv, D)``, so this is the
+same product with the codes contracted exactly).  The fp16/bf16 path
+under interpret stays byte-identical to the retired gather runtime;
+int8 and the streaming body carry a bounded-error contract instead
+(``tests/test_paged_attention.py``; on the chip, ``chip_smoke.py``).
 
 :class:`PagedAttnView` is the cache-shaped adapter: a registered
 dataclass carrying (pool, scales, table, write offsets) that duck-types
@@ -56,9 +67,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from music_analyst_tpu.ops.flash_attention import interpret_default
+
 # Masked logit value.  The exact body uses finfo.min to match the dense
 # reference bitwise; the streaming body's running max starts here and
-# masked lanes are zeroed explicitly, so the sentinel never reaches exp.
+# masked lanes are zeroed explicitly after the exp.
 _NEG_INF = -1e30
 
 
@@ -135,69 +148,79 @@ def _exact_body(n, H, n_kv, D, P, pps, total, quantized, dtype):
     return body
 
 
-def _stream_body(n, H, n_kv, D, P, pps, total, quantized, dtype):
-    """Per-slot program: DMA one page at a time, online-softmax fold.
+def check_stream_geometry(n_kv: int, head_dim: int) -> None:
+    """Raise unless Mosaic can lower the streaming body at this geometry.
 
-    The page walk is a static unroll over the slot's table row; each
-    page is copied pool → VMEM scratch with ``make_async_copy`` (the
-    dequant epilogue runs on the scratch block for int8), contributes a
-    ``[H, P]`` logit tile, and folds into the running (max, normalizer,
-    weighted-V) accumulator.  Masked lanes are zeroed *after* the exp,
-    so fully-masked pages (the slack tail past ``total``, a free slot's
-    trash pages) contribute exactly nothing.
+    The body views a ``[P, n_kv, D]`` page as ``[P * n_kv, D]``; Mosaic
+    (libtpu 0.0.34) folds the KV-head axis into sublanes only when it is
+    a power of two or the minor dim fills whole 128-lane tiles.
+    """
+    if n_kv & (n_kv - 1) and head_dim % 128:
+        raise ValueError(
+            f"paged attention cannot be compiled for n_kv_heads={n_kv}, "
+            f"head_dim={head_dim}: the TPU kernel needs a power-of-two "
+            "KV-head count or a head_dim that is a multiple of 128"
+        )
+
+
+def _stream_body(H, n_kv, D, P, pps, quantized, dtype):
+    """Per-(slot, page) program: fold one page into the online softmax.
+
+    The grid's page axis is sequential; (max, normalizer, weighted-V)
+    live in VMEM scratch across it and the output block is written on
+    the slot's last page.  The ``[H, P * n_kv]`` score tile holds every
+    (query head, KV head) pair; columns of a foreign KV head are masked
+    exactly like invalid positions.  Masked lanes are zeroed *after* the
+    exp, so fully-masked pages (the slack tail past ``total``, a free
+    slot's trash pages) contribute exactly nothing.
     """
     G = H // n_kv
+    W = P * n_kv
     att_scale = D ** -0.5
 
-    def body(table_ref, mask_ref, q_ref, kp_ref, vp_ref, *rest):
+    def body(table_ref, mask_ref, *rest):
+        del table_ref  # consumed by the page BlockSpecs' index maps
         if quantized:
-            (ks_ref, vs_ref, o_ref,
-             kbuf, vbuf, ksbuf, vsbuf, sem) = rest
-        else:
-            o_ref, kbuf, vbuf, sem = rest
+            ks_ref, vs_ref, *rest = rest
+        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = rest
+        lp = pl.program_id(1)
+
+        @pl.when(lp == 0)
+        def _init():
+            m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+            l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+            acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
         q = q_ref[0, 0]                                    # [H, D]
-        qg = q.reshape(n_kv, G, D)
-        m = jnp.full((H, 1), _NEG_INF, jnp.float32)        # running max
-        l = jnp.zeros((H, 1), jnp.float32)                 # normalizer
-        acc = jnp.zeros((H, D), jnp.float32)               # weighted V
-        for lp in range(pps):
-            phys = table_ref[0, lp]
-            cp = pltpu.make_async_copy(kp_ref.at[phys], kbuf, sem)
-            cp.start()
-            cp.wait()
-            cp = pltpu.make_async_copy(vp_ref.at[phys], vbuf, sem)
-            cp.start()
-            cp.wait()
-            if quantized:
-                cp = pltpu.make_async_copy(ks_ref.at[phys], ksbuf, sem)
-                cp.start()
-                cp.wait()
-                cp = pltpu.make_async_copy(vs_ref.at[phys], vsbuf, sem)
-                cp.start()
-                cp.wait()
-                k = _dequant(kbuf[:], ksbuf[:], dtype)     # [P, n_kv, D]
-                v = _dequant(vbuf[:], vsbuf[:], dtype)
-            else:
-                k = kbuf[:]
-                v = vbuf[:]
-            valid = mask_ref[0, lp * P:(lp + 1) * P]       # [P]
-            s = jnp.einsum("hgd,phd->hgp", qg, k).astype(jnp.float32)
-            s = s.reshape(H, P) * att_scale
-            s = jnp.where(valid[None, :], s, _NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            p = jnp.where(valid[None, :], p, 0.0)          # exact zeros
-            corr = jnp.exp(m - m_new)
-            l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-            pv = jnp.einsum(
-                "hgp,phd->hgd",
-                p.reshape(n_kv, G, P),
-                v.astype(jnp.float32),
-            )
-            acc = acc * corr + pv.reshape(H, D)
-            m = m_new
-        l = jnp.where(l == 0.0, 1.0, l)                    # all-masked rows
-        o_ref[0, 0] = (acc / l).astype(dtype)
+        k = k_ref[0].astype(dtype).reshape(W, D)           # int8 → bf16 exact
+        v = v_ref[0].astype(dtype).reshape(W, D)
+        row_kv = jax.lax.broadcasted_iota(jnp.int32, (H, W), 0) // G
+        col_kv = jax.lax.broadcasted_iota(jnp.int32, (H, W), 1) % n_kv
+        valid = (mask_ref[0, 0] > 0) & (row_kv == col_kv)  # [H, W]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * att_scale
+        if quantized:
+            s = s * ks_ref[0, 0]
+        s = jnp.where(valid, s, _NEG_INF)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)      # exact zeros
+        corr = jnp.exp(m - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        if quantized:
+            p = p * vs_ref[0, 0]
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+            p.astype(dtype), v, preferred_element_type=jnp.float32
+        )
+        m_ref[...] = m_new
+
+        @pl.when(lp == pps - 1)
+        def _finalize():
+            l = l_ref[...]
+            l = jnp.where(l == 0.0, 1.0, l)                # all-masked rows
+            o_ref[0, 0] = (acc_ref[...] / l).astype(dtype)
 
     return body
 
@@ -229,7 +252,8 @@ def paged_attention(
         per-(page, row) symmetric dequant scales; passing them selects
         the int8 path with dequant fused after the KV load.
       interpret: run the Pallas interpreter (defaults to "not on TPU" —
-        the CPU-emulated test mesh always interprets).
+        the CPU-emulated test mesh always interprets; on a TPU the
+        kernel is compiled by Mosaic).
       stream: pick the page-streaming online-softmax body (defaults to
         the exact batched body under interpret, streaming on TPU; tests
         force ``stream=True`` under interpret to cover the TPU body).
@@ -237,25 +261,20 @@ def paged_attention(
     Returns ``[n_slots, 1, n_heads, head_dim]`` in ``q.dtype``.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     if stream is None:
         stream = not interpret
     quantized = key_scale is not None
     if quantized != (value_scale is not None):
         raise ValueError("key_scale and value_scale must be passed together")
     n, H, n_kv, D, P, pps, total = _geometry(q, key_pages, table, mask)
-    span = pps * P
-    if stream and total < span:
-        # The streaming body walks whole pages; pad the mask so the
-        # slack tail past ``total`` is just more masked lanes.
-        mask = jnp.pad(mask, ((0, 0), (0, span - total)))
     dtype = q.dtype
-    operands = [table, mask, q, key_pages, value_pages]
-    pool_specs = [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
-    if quantized:
-        operands += [key_scale, value_scale]
-        pool_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
     if not stream:
+        operands = [table, mask, q, key_pages, value_pages]
+        pool_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        if quantized:
+            operands += [key_scale, value_scale]
+            pool_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
         body = _exact_body(n, H, n_kv, D, P, pps, total, quantized, dtype)
         return pl.pallas_call(
             body,
@@ -269,38 +288,61 @@ def paged_attention(
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             interpret=interpret,
         )(*operands)
-    body = _stream_body(n, H, n_kv, D, P, pps, total, quantized, dtype)
-    mask_w = mask.shape[-1]
-    scratch = [
-        pltpu.VMEM((P, n_kv, D), key_pages.dtype),
-        pltpu.VMEM((P, n_kv, D), value_pages.dtype),
+    check_stream_geometry(n_kv, D)
+    span = pps * P
+    W = P * n_kv
+
+    def per_column(plane):
+        """``[n, span]`` per-token plane → ``[n, pps, 1, P * n_kv]``: one
+        lane-dense row per page, each token repeated over the KV heads
+        its page rows fan out to in the ``[H, P * n_kv]`` score tile."""
+        return jnp.repeat(plane, n_kv, axis=-1).reshape(n, pps, 1, W)
+
+    column_spec = pl.BlockSpec(
+        (1, 1, 1, W), lambda i, lp, tbl: (i, lp, 0, 0)
+    )
+    head_spec = pl.BlockSpec((1, 1, H, D), lambda i, lp, tbl: (i, 0, 0, 0))
+    page_spec = pl.BlockSpec(
+        (1, P, n_kv, D), lambda i, lp, tbl: (tbl[i * pps + lp], 0, 0, 0)
+    )
+
+    # The body walks whole pages; the slack tail past ``total`` is just
+    # more masked lanes.  An integer mask: Mosaic has no bool VMEM blocks.
+    operands = [
+        per_column(
+            jnp.pad(mask, ((0, 0), (0, span - total))).astype(jnp.int32)
+        )
     ]
+    in_specs = [column_spec]
     if quantized:
-        scratch += [
-            pltpu.VMEM((P,), key_scale.dtype),
-            pltpu.VMEM((P,), value_scale.dtype),
-        ]
-    scratch.append(pltpu.SemaphoreType.DMA)
+        # Scales are 1/(n_kv * D) of the page bytes: gather the slot's
+        # rows here and hand them to the kernel as score-column planes.
+        for scale in (key_scale, value_scale):
+            operands.append(
+                per_column(jnp.take(scale, table, axis=0).reshape(n, span))
+            )
+            in_specs.append(column_spec)
+    operands += [q, key_pages, value_pages]
+    in_specs += [head_spec, page_spec, page_spec]
     return pl.pallas_call(
-        body,
+        _stream_body(H, n_kv, D, P, pps, quantized, dtype),
         out_shape=jax.ShapeDtypeStruct((n, 1, H, D), dtype),
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, pps), lambda i: (i, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec(
-                (1, mask_w), lambda i: (i, 0), memory_space=pltpu.VMEM
-            ),
-            pl.BlockSpec(
-                (1, 1, H, D), lambda i: (i, 0, 0, 0), memory_space=pltpu.VMEM
-            ),
-            *pool_specs,
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, H, D), lambda i: (i, 0, 0, 0), memory_space=pltpu.VMEM
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n, pps),
+            in_specs=in_specs,
+            out_specs=head_spec,
+            scratch_shapes=[
+                pltpu.VMEM((H, 1), jnp.float32),     # running max
+                pltpu.VMEM((H, 1), jnp.float32),     # normalizer
+                pltpu.VMEM((H, D), jnp.float32),     # weighted V
+            ],
         ),
-        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
         interpret=interpret,
-    )(*operands)
+    )(table.reshape(-1), *operands)
 
 
 def paged_attention_reference(

@@ -20,9 +20,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import pcast
 from jax.sharding import Mesh, PartitionSpec as P
-
-from music_analyst_tpu.utils.jax_compat import pcast, shard_map
 
 _NEG_INF = -1e30
 

@@ -21,10 +21,11 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.lax import pcast
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from music_analyst_tpu.profiling.collectives import record_collective
-from music_analyst_tpu.utils.jax_compat import pcast, shard_map
 
 
 def stack_layer_params(params: dict, n_stages: int, prefix: str = "layer_"):

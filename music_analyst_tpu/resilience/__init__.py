@@ -11,8 +11,8 @@ backend:
 - :mod:`.policy` — the one :class:`RetryPolicy` (exponential backoff,
   full jitter, cap, deadline-aware budget) shared by Ollama HTTP,
   prefetch stages, cache I/O, and serving dispatch.
-- :mod:`.failover` — structured re-init-and-retry of a dead backend,
-  then degrade-to-CPU with a ``degraded: true`` manifest stamp.
+- :mod:`.failover` — one structured re-init-and-retry of a backend
+  lost mid-run; a second failure fails the run.
 """
 
 from music_analyst_tpu.resilience.faults import (

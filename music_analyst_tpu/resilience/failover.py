@@ -1,33 +1,31 @@
-"""Backend failover: one structured re-init-and-retry, then degrade.
+"""Backend failover: one structured re-init-and-retry, then fail.
 
-The watchdog taxonomy (PR 4) can *name* a dead tunnel or a stalled
-device; this module is what finally *acts* on the name.  An engine wraps
-its device-dependent block in :func:`run_with_failover`:
+The watchdog taxonomy (PR 4) can *name* a lost backend or a stalled
+device; this module is what *acts* on the name.  An engine wraps its
+device-dependent block in :func:`run_with_failover`:
 
 1. the block runs; on success nothing else happens;
-2. a failure classified as backend loss (``tunnel_dead`` /
+2. a failure classified as mid-run backend loss (``backend_lost`` /
    ``device_stall`` / a transient injected fault) triggers ONE re-init of
    the backend (caller-supplied ``reinit``) and one retry;
-3. if that also fails and the caller supplied a ``degrade`` path (the
-   CPU/numpy equivalent), the run finishes there — stamped
-   ``degraded: true`` in the run manifest via ``tel.annotate`` — instead
-   of dying minutes into a corpus pass.
+3. if that also fails the error propagates and the run exits non-zero.
 
-Degrade paths must be bit-compatible: the golden contracts (byte-stable
-``word_counts.csv``) hold on the degraded path too, which is what the
-chaos suite asserts.
+There is no host-side compute path to finish on: a run that exits 0 did
+its device work on the device.  A backend that cannot *initialise* is
+not backend loss — it is never classified transient and never retried
+(``observability/report.py``).
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Optional
 
 from music_analyst_tpu.resilience.policy import classify_retryable
 from music_analyst_tpu.telemetry import get_telemetry
 
 # Kinds that mean "the backend, not the program": worth a re-init.
 FAILOVER_KINDS = frozenset(
-    {"tunnel_dead", "device_stall", "fault_injected"}
+    {"backend_lost", "device_stall", "fault_injected"}
 )
 
 
@@ -42,17 +40,16 @@ def run_with_failover(
     *,
     site: str,
     reinit: Optional[Callable[[], None]] = None,
-    degrade: Optional[Callable[[], Any]] = None,
-) -> Tuple[Any, bool]:
-    """Run ``fn``; on classified backend loss re-init + retry, then degrade.
+) -> Any:
+    """Run ``fn``; on classified backend loss re-init and retry once.
 
-    Returns ``(result, degraded)``.  Anything not classified as backend
-    loss — and any :class:`InjectedFatal` — propagates unchanged so
-    logic errors keep failing fast.
+    Anything not classified as backend loss — and any
+    :class:`InjectedFatal` — propagates unchanged so logic errors keep
+    failing fast; so does a second failure after the re-init.
     """
     tel = get_telemetry()
     try:
-        return fn(), False
+        return fn()
     except Exception as exc:
         if not should_failover(exc):
             raise
@@ -73,25 +70,7 @@ def run_with_failover(
                     site=site,
                     error=str(reinit_exc)[:200],
                 )
-        try:
-            result = fn()
-        except Exception as retry_exc:
-            if degrade is None or not should_failover(retry_exc):
-                raise
-            _, retry_kind = classify_retryable(retry_exc)
-            tel.count(f"failover.{site}.degraded")
-            tel.event(
-                "failover_degraded",
-                site=site,
-                kind=retry_kind,
-                error=str(retry_exc)[:200],
-            )
-            tel.annotate(
-                degraded=True,
-                degraded_site=site,
-                degraded_reason=retry_kind or "backend_loss",
-            )
-            return degrade(), True
+        result = fn()
         tel.count(f"failover.{site}.recoveries")
         tel.event("failover_recovered", site=site)
-        return result, False
+        return result

@@ -16,9 +16,10 @@ retrying now goes through :class:`RetryPolicy`:
 - **Watchdog-aware**: a retry sleep inside a watched scope counts as
   silence, so sleeps are clamped below the active watchdog timeout.
 - **Classified**: retryability reuses ``observability/report.py``'s
-  error taxonomy; only transiently-classified failures (tunnel drops,
-  device loss, timeouts, injected transient faults, OS-level I/O
-  hiccups) are retried.  Logic errors propagate on the first throw.
+  error taxonomy; only transiently-classified failures (a backend
+  lost mid-run, device stalls, timeouts, injected transient faults,
+  OS-level I/O hiccups) are retried.  Logic errors — and a backend
+  that cannot initialise — propagate on the first throw.
 """
 
 from __future__ import annotations
@@ -34,7 +35,7 @@ from music_analyst_tpu.telemetry import get_telemetry
 # Taxonomy kinds worth another attempt: the failure is in the transport /
 # device layer, not the program.
 _TRANSIENT_KINDS = frozenset(
-    {"tunnel_dead", "device_stall", "attempt_timeout", "fault_injected"}
+    {"backend_lost", "device_stall", "attempt_timeout", "fault_injected"}
 )
 
 # OSError subtypes that are verdicts about the input, not the transport.

@@ -751,7 +751,7 @@ class DynamicBatcher:
         t0_w = time.time() if rt.enabled else None
         t0 = time.perf_counter()
         try:
-            # The dispatch edge is where a wedged device/tunnel would hang
+            # The dispatch edge is where a wedged device would hang
             # a resident server silently — the watchdog classifies that as
             # serve_stall instead of a mute socket.
             with watchdog.watch("serve.dispatch", kind="serve"):

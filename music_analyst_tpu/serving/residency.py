@@ -8,8 +8,7 @@ amortize it over a whole dataset; a server amortizes it over its
   ``engines/sentiment.get_backend`` dispatch the CLI uses, so
   ``--weight-quant`` streams the checkpoint through
   ``engines/checkpoint.load_quantized_params`` + the persistent
-  ``wq_cache`` exactly like a batch run, and the persistent XLA
-  compilation cache is enabled before the first compile;
+  ``wq_cache`` exactly like a batch run;
 * **pin for the server lifetime** — the classifier (and its on-device
   params) is held by this object until :meth:`release`; nothing about
   the request path can drop it;
@@ -23,8 +22,8 @@ lands in the run manifest's ``serving.residency`` section.
 
 This object is the single owner of a resident backend *everywhere*, not
 just under the server: the batch sentiment engine and the weight
-validator acquire through it too, so backend construction (persistent
-compile cache, mesh placement, weight-quant streaming, length buckets)
+validator acquire through it too, so backend construction (mesh
+placement, weight-quant streaming, length buckets)
 is written once and reload-on-poisoned-device is one code path
 (:meth:`reload`) whichever surface hit the failure.
 """
@@ -91,11 +90,7 @@ class ModelResidency:
                 return self._backend
             tel = get_telemetry()
             from music_analyst_tpu.engines.sentiment import get_backend
-            from music_analyst_tpu.utils.cache import (
-                enable_persistent_compilation_cache,
-            )
 
-            enable_persistent_compilation_cache()
             t0 = time.perf_counter()
             with tel.span("serve.load", model=self.model,
                           weight_quant=self.weight_quant or "none"):

@@ -72,6 +72,7 @@ from music_analyst_tpu.serving.response_cache import (
     resolve_response_cache_dir,
 )
 from music_analyst_tpu.telemetry import get_telemetry
+from music_analyst_tpu.telemetry.introspect import device_summary
 from music_analyst_tpu.observability.metrics_plane import (
     configure_metrics,
     get_metrics_plane,
@@ -518,6 +519,11 @@ class SentimentServer:
             "drain_reason": self.drain_reason,
             "requests": self.batcher.stats(),
         }
+        # What this process's backend is, once it has one: a
+        # replica-router parent holds none and reads its workers' here.
+        device = device_summary()
+        if device is not None:
+            out["device"] = device
         if self.decode is not None:
             out["decode"] = self.decode.stats()
         if self.residency is not None:

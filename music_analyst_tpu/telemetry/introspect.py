@@ -86,6 +86,30 @@ def peak_rss_bytes() -> Optional[int]:
         return None
 
 
+def device_summary() -> Optional[Dict[str, Any]]:
+    """Platform / kinds / count of this process's devices, or None when
+    no backend is live here (an HTTP-passthrough server must not claim a
+    chip just to describe it)."""
+    import jax
+    # Private API: jax 0.9.0 has no public way to ask without
+    # initialising (`jax.extend.backend.backends()` starts them).  If a
+    # JAX upgrade moves it, this import fails loudly and
+    # tests/test_router.py's backend-free-parent test says so.
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return None
+    return _describe(jax.devices())
+
+
+def _describe(devices) -> Dict[str, Any]:
+    return {
+        "platform": devices[0].platform if devices else "unknown",
+        "count": len(devices),
+        "kinds": sorted({d.device_kind for d in devices}),
+    }
+
+
 def collect_device_info() -> Dict[str, Any]:
     """Platform, device count, and per-device ``memory_stats()`` where the
     plugin exposes them (TPU does; CPU-emulated meshes return None)."""
@@ -100,12 +124,7 @@ def collect_device_info() -> Dict[str, Any]:
         except Exception:
             stats = None
         per_device.append(stats)
-    return {
-        "platform": devices[0].platform if devices else "unknown",
-        "count": len(devices),
-        "kinds": sorted({d.device_kind for d in devices}),
-        "memory_stats": per_device,
-    }
+    return {**_describe(devices), "memory_stats": per_device}
 
 
 def write_run_manifest(
@@ -143,7 +162,9 @@ def write_run_manifest(
         "jax_version": jax.__version__,
         "jaxlib_version": jaxlib.__version__,
         "git_describe": git_describe(),
-        "device": collect_device_info(),
+        # A process that must not initialise a backend (the replica
+        # router) annotates the section itself, from its workers' stats.
+        "device": context.pop("device", None) or collect_device_info(),
         "peak_rss_bytes": peak_rss_bytes(),
         "compile": tel.compile_stats(),
         "jax_events": jax_events,
@@ -156,15 +177,6 @@ def write_run_manifest(
         "event_count": events,
         "telemetry_log": tel.sink_path,
     }
-    # Failover degradation is a headline fact about the run — hoist it
-    # out of the annotation context so readers (and telemetry-report)
-    # never dig for it.  Only present when a failover actually degraded,
-    # so healthy runs keep the original key set.
-    if context.get("degraded"):
-        manifest["degraded"] = True
-        for key in ("degraded_site", "degraded_reason"):
-            if key in context:
-                manifest[key] = context[key]
     # An unclean previous shutdown (journal without its clean marker, or
     # a stale non-drain flight record) is the same class of headline
     # fact: hoisted so telemetry-report and operators see it at a glance,

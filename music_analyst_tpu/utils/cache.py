@@ -5,42 +5,43 @@ binary) but also re-does its column-split preprocessing on every run
 (``src/parallel_spotify.c:821``); here the expensive per-run artifact is
 the XLA program, and it persists.
 
-First-compile latency (~1-2 s per program on v5e, more for big models)
-would otherwise be paid by every fresh process; with the persistent cache
-a cold CLI invocation reuses programs compiled by any earlier run.
-Combined with the power-of-two shape bucketing in ``ops/histogram.py``,
-repeat analyses skip compilation entirely.
+One rule, applied once per process at the entry point (``cli/main.py``,
+``bench.py``, ``chip_smoke.py``'s kernel child):
+
+* ``JAX_COMPILATION_CACHE_DIR`` set — JAX reads it itself; this module
+  sets **no** directory in code, so whoever launched the process owns
+  where the cache lives.
+* unset — one fixed directory inside the checkout, ``<repo>/.jax_cache``
+  (git-ignored).  Never the home directory, a temp name, a pid or a
+  time: the path is part of the cache key, so a directory that moves
+  never hits.
+
+A cache that cannot be enabled raises; it is not an optimization the run
+quietly goes without.
 """
 
 from __future__ import annotations
 
 import os
 
-_enabled = False
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    ))),
+    ".jax_cache",
+)
 
 
-def enable_persistent_compilation_cache(path: str | None = None) -> None:
-    global _enabled
-    if _enabled:
-        return
+def enable_persistent_compilation_cache() -> str:
+    """Apply the rule above; returns the directory in effect."""
     import jax
 
-    cache_dir = path or os.environ.get(
-        "MUSICAAL_XLA_CACHE", os.path.expanduser("~/.cache/musicaal_xla")
-    )
-    try:
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = REPO_CACHE_DIR
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
-        _enabled = True
-    except Exception:
-        # Cache is an optimization only; never fail a run over it.  But
-        # leave _enabled False: a transient failure (unwritable dir, full
-        # disk) must stay retryable on the next call, not silently pin
-        # the process to cold compiles — and the failure is observable.
-        try:
-            from music_analyst_tpu.telemetry import get_telemetry
-
-            get_telemetry().count("xla_cache.enable_failed")
-        except Exception:
-            pass
+    # Programs here are many and small (bucketed shapes); JAX's default
+    # 1 s floor would leave most of them out of the cache.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+    return cache_dir

@@ -300,35 +300,46 @@ def test_streaming_empty_and_bad_args(fixture_csv):
 # ------------------------------------------------------------- satellites
 
 
-def test_xla_cache_enable_failure_stays_retryable(monkeypatch, tmp_path):
-    """A transient enable failure must not permanently pin the process to
-    cold compiles (the old bug set _enabled=True in the except path)."""
+def test_compile_cache_rule(monkeypatch, tmp_path):
+    """$JAX_COMPILATION_CACHE_DIR set ⇒ the code sets no directory;
+    unset ⇒ the one fixed in-checkout path; a cache that cannot be
+    enabled raises (no counter, no cold-compile run)."""
     import jax
 
-    from music_analyst_tpu.telemetry import get_telemetry
     from music_analyst_tpu.utils import cache as xla_cache
 
-    prev_enabled = xla_cache._enabled
     prev_dir = jax.config.jax_compilation_cache_dir
+    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert xla_cache.REPO_CACHE_DIR == os.path.join(repo, ".jax_cache")
     try:
-        xla_cache._enabled = False
+        sentinel = str(tmp_path / "untouched")
+        jax.config.update("jax_compilation_cache_dir", sentinel)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+        assert xla_cache.enable_persistent_compilation_cache() == str(
+            tmp_path / "env"
+        )
+        assert jax.config.jax_compilation_cache_dir == sentinel
+        assert not (tmp_path / "env").exists()  # JAX makes it, not us
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = tmp_path / "checkout" / ".jax_cache"
+        monkeypatch.setattr(xla_cache, "REPO_CACHE_DIR", str(fixed))
+        assert xla_cache.enable_persistent_compilation_cache() == str(fixed)
+        assert jax.config.jax_compilation_cache_dir == str(fixed)
+        assert fixed.is_dir()
 
         def boom(*args, **kwargs):
-            raise OSError("disk full")
+            raise OSError("read-only checkout")
 
         monkeypatch.setattr(os, "makedirs", boom)
-        before = get_telemetry().counters.get("xla_cache.enable_failed", 0)
-        xla_cache.enable_persistent_compilation_cache(str(tmp_path / "x"))
-        assert xla_cache._enabled is False  # retryable, not latched
-        after = get_telemetry().counters.get("xla_cache.enable_failed", 0)
-        assert after == before + 1
-
-        monkeypatch.undo()
-        xla_cache.enable_persistent_compilation_cache(str(tmp_path / "x"))
-        assert xla_cache._enabled is True  # the retry succeeded
+        with pytest.raises(OSError):
+            xla_cache.enable_persistent_compilation_cache()
     finally:
-        xla_cache._enabled = prev_enabled
         jax.config.update("jax_compilation_cache_dir", prev_dir)
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", prev_min
+        )
 
 
 def test_bench_child_timeout_clamps_to_parent_budget():

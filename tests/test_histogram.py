@@ -75,7 +75,7 @@ def test_multi_axis_mesh_histogram():
 
     import jax.numpy as jnp
     from music_analyst_tpu.ops.histogram import token_histogram
-    from music_analyst_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     fn = jax.jit(
         shard_map(

@@ -250,6 +250,43 @@ def test_profile_run_writes_chrome_trace(tmp_path):
     assert any(e["ph"] == "M" and e["name"] == "thread_name" for e in events)
 
 
+def test_profile_run_raises_when_the_profiler_cannot_start(
+    tmp_path, monkeypatch
+):
+    """A device trace that was asked for and silently not taken is worse
+    than no run: the failure propagates, nothing is swallowed."""
+    import jax
+
+    from music_analyst_tpu.profiling.trace import profile_run
+
+    def refuse(*args, **kwargs):
+        raise RuntimeError("profiler service unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    with pytest.raises(RuntimeError, match="profiler service unavailable"):
+        with profile_run(str(tmp_path / "prof")):
+            raise AssertionError("the body must not run unprofiled")
+
+
+def test_profile_run_without_device_trace_stays_off_the_profiler(
+    tmp_path, monkeypatch
+):
+    """The replica-router parent holds no chip: it gets the span trace
+    without ever starting jax.profiler (which initialises the backend)."""
+    import jax
+
+    from music_analyst_tpu.profiling.trace import profile_run
+
+    def forbidden(*args, **kwargs):
+        raise AssertionError("jax.profiler touched with device_trace=False")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", forbidden)
+    monkeypatch.setattr(jax.profiler, "stop_trace", forbidden)
+    with profile_run(str(tmp_path / "prof"), device_trace=False):
+        pass
+    assert (tmp_path / "prof" / "trace_spans.json").exists()
+
+
 def test_cli_profile_dir_flag(fixture_csv, tmp_path, capsys):
     from music_analyst_tpu.cli.main import main
 

@@ -272,21 +272,25 @@ def test_failover_reinit_then_recover():
         state["reinits"] += 1
         state["healthy"] = True
 
-    result, degraded = run_with_failover(
+    result = run_with_failover(
         compute, site="unit.failover", reinit=reinit
     )
-    assert (result, degraded) == (42, False)
+    assert result == 42
     assert state["reinits"] == 1
 
 
-def test_failover_degrades_after_second_failure():
-    def compute():
-        raise RuntimeError("tunnel dead: lease lost")
+def test_failover_second_failure_propagates():
+    """One re-init-and-retry, then the error — there is no host path to
+    finish on."""
+    calls = {"n": 0}
 
-    result, degraded = run_with_failover(
-        compute, site="unit.degrade", degrade=lambda: "host-path"
-    )
-    assert (result, degraded) == ("host-path", True)
+    def compute():
+        calls["n"] += 1
+        raise RuntimeError("backend lost: device went away")
+
+    with pytest.raises(RuntimeError, match="backend lost"):
+        run_with_failover(compute, site="unit.lost")
+    assert calls["n"] == 2
 
 
 def test_failover_ignores_logic_errors():
@@ -294,8 +298,7 @@ def test_failover_ignores_logic_errors():
         raise KeyError("missing column")
 
     with pytest.raises(KeyError):
-        run_with_failover(compute, site="unit.logic",
-                          degrade=lambda: "never")
+        run_with_failover(compute, site="unit.logic")
     assert not should_failover(KeyError("x"))
     assert should_failover(InjectedFault("collective.psum", 1))
 
@@ -340,26 +343,28 @@ def test_wordcount_transient_ingest_fault_byte_identical(tmp_path):
     assert manifest["counters"]["retry.ingest.read.recovered"] == 1
 
 
-def test_wordcount_persistent_fault_degrades_byte_identical(tmp_path):
-    """Persistent device-path failure → one failover retry, then the CPU
-    degrade path — stamped in the manifest, bytes unchanged."""
-    from music_analyst_tpu.engines.wordcount import run_analysis
+def test_wordcount_persistent_device_fault_fails_the_run(tmp_path):
+    """Persistent device-path failure → one failover retry, then the run
+    FAILS (non-zero from the CLI): no host-side count path, no
+    ``degraded`` stamp, no torn artifacts."""
+    from music_analyst_tpu.cli.main import main
 
-    clean = tmp_path / "clean"
-    degraded = tmp_path / "degraded"
-    run_analysis(FIXTURE, output_dir=str(clean), write_split=False,
-                 quiet=True, use_corpus_cache=False)
-    configure_faults("collective.psum:error")
-    run_analysis(FIXTURE, output_dir=str(degraded), write_split=False,
-                 quiet=True, use_corpus_cache=False)
-    assert _word_counts_bytes(clean) == _word_counts_bytes(degraded)
-    manifest = json.loads((degraded / "run_manifest.json").read_text())
-    assert manifest["degraded"] is True
-    assert manifest["degraded_site"] == "wordcount.device_compute"
-    assert manifest["degraded_reason"] == "fault_injected"
+    out = tmp_path / "lost"
+    with pytest.raises(InjectedFault):
+        main([
+            "analyze", FIXTURE, "--output-dir", str(out), "--no-split",
+            "--no-corpus-cache", "--inject-faults", "collective.psum:error",
+        ])
+    leftovers = [
+        name for name in os.listdir(out)
+        if name.endswith(".csv") or ".tmp-" in name
+    ]
+    assert leftovers == []
+    manifest = json.loads((out / "run_manifest.json").read_text())
+    assert "degraded" not in manifest
     counters = manifest["counters"]
     assert counters["failover.wordcount.device_compute.retries"] == 1
-    assert counters["failover.wordcount.device_compute.degraded"] == 1
+    assert "failover.wordcount.device_compute.recoveries" not in counters
 
 
 def test_fatal_injection_dies_structurally_no_torn_files(tmp_path):
@@ -414,7 +419,7 @@ def test_serving_dispatch_retry_answers_everyone():
 
 def test_residency_reload_swaps_poisoned_backend_mid_session():
     """Reload-on-poisoned-device: a backend that dies with a classified
-    tunnel error is replaced under the live batcher; the request that hit
+    backend-loss error is replaced under the live batcher; the request that hit
     it still gets an answer from the fresh backend."""
     from music_analyst_tpu.serving.batcher import DynamicBatcher
     from music_analyst_tpu.serving.residency import ModelResidency
@@ -424,7 +429,7 @@ def test_residency_reload_swaps_poisoned_backend_mid_session():
         name = "poisoned"
 
         def classify_batch(self, texts):
-            raise ConnectionError("tunnel dead: device lease lost")
+            raise ConnectionError("backend lost: device went away")
 
     residency = ModelResidency(model="mock", mock=True,
                                backend=PoisonedBackend())

@@ -314,25 +314,6 @@ def run_sentiment(
         )
 
 
-def _timed_source(tel, source):
-    """Yield rows from ``source`` while accumulating pure read time; the
-    total lands as ONE ``ingest`` span (per-row spans would swamp the log
-    on million-row datasets)."""
-    read_s = 0.0
-    n = 0
-    it = iter(source)
-    while True:
-        t0 = time.perf_counter()
-        try:
-            item = next(it)
-        except StopIteration:
-            break
-        read_s += time.perf_counter() - t0
-        n += 1
-        yield item
-    tel.record_span("ingest", read_s, rows=n)
-
-
 def _run_sentiment_impl(
     tel, dataset_path, model, mock, limit, output_dir, batch_size,
     backend, quiet, resume, songs, mesh, length_buckets,
@@ -383,8 +364,8 @@ def _run_sentiment_impl(
     if not skip:
         writer.writeheader()
 
-    def finish(rows_batch, handle, t_submit, measured) -> None:
-        with tel.span("compute", rows=len(rows_batch)):
+    def finish(index, rows_batch, handle, t_submit, measured) -> None:
+        with tel.span("compute", rows=len(rows_batch), batch=index):
             # collect() is the device-blocking edge — a wedged device
             # hangs here without erroring; let the watchdog classify
             # that as device_stall instead of silence.  On a
@@ -419,7 +400,7 @@ def _run_sentiment_impl(
         per_song = (
             elapsed / max(1, len(rows_batch)) if clf.reports_latency else 0.0
         )
-        with tel.span("write", rows=len(rows_batch)):
+        with tel.span("write", rows=len(rows_batch), batch=index):
             for i, ((artist, song, text), label) in enumerate(
                 zip(rows_batch, labels)
             ):
@@ -488,21 +469,21 @@ def _run_sentiment_impl(
         name="pipeline",
         sink_name="compute",
     )
-    source = _timed_source(
-        tel,
-        songs if songs is not None else iter_songs(dataset_path, limit=limit),
+    # The pipeline records one ``read`` span per batch around the source.
+    source = songs if songs is not None else iter_songs(
+        dataset_path, limit=limit
     )
     try:
         # closing(): a collect()/write error below must cancel and join the
         # pipeline threads, not leave them prefetching into a dead run.
         with contextlib.closing(pipe.run(batches(source))) as results:
-            for rows_batch, handle, t_submit, measured in results:
-                finish(rows_batch, handle, t_submit, measured)
+            for index, item in enumerate(results):
+                finish(index, *item)
     finally:
         details_fh.close()
     wall = time.perf_counter() - start
 
-    with atomic_write(totals_path) as fh:
+    with tel.span("write_totals"), atomic_write(totals_path) as fh:
         json.dump(counts, fh, indent=2)
 
     if not quiet:

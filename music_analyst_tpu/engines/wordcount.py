@@ -295,26 +295,28 @@ def _run_analysis_instrumented(
     )
     metrics_path = os.path.join(output_dir, "performance_metrics.json")
     devices = mesh.devices.flatten().tolist()
-    write_performance_metrics(
-        metrics_path,
-        processes=len(devices),
-        total_songs=total_songs,
-        total_words=total_words,
-        compute_time=compute_time,
-        total_time=total_time,
-        per_chip=[
-            {
-                "device": str(d),
-                "platform": d.platform,
-                # 9 decimals: the per-shard spread is microseconds on small
-                # corpora; 6 would round distinct measurements together.
-                "compute_seconds": round(seconds, 9),
-            }
-            for d, seconds in zip(devices, per_chip_compute)
-        ],
-        stages=dict(timer.seconds),
-        device_platform=devices[0].platform if devices else "unknown",
-    )
+    with tel.span("write_metrics"):
+        write_performance_metrics(
+            metrics_path,
+            processes=len(devices),
+            total_songs=total_songs,
+            total_words=total_words,
+            compute_time=compute_time,
+            total_time=total_time,
+            per_chip=[
+                {
+                    "device": str(d),
+                    "platform": d.platform,
+                    # 9 decimals: the per-shard spread is microseconds on
+                    # small corpora; 6 would round distinct measurements
+                    # together.
+                    "compute_seconds": round(seconds, 9),
+                }
+                for d, seconds in zip(devices, per_chip_compute)
+            ],
+            stages=dict(timer.seconds),
+            device_platform=devices[0].platform if devices else "unknown",
+        )
 
     if not quiet:
         print("=== Parallel Spotify Analysis ===")

@@ -30,8 +30,15 @@ seconds (waiting for output space — the downstream is), and the max
 depth its input queue reached.  On completion the pipeline publishes
 ``<name>.<stage>_stall_s`` / ``<name>.<stage>_queue_depth_max`` gauges
 plus a structured record (:meth:`Telemetry.record_pipeline`) that lands
-in the run manifest's ``pipeline`` section, and per-item stage spans so
-the overlap shows up in ``trace_spans.json`` next to everything else.
+in the run manifest's ``pipeline`` section, and per-item spans so the
+overlap shows up in ``trace_spans.json`` next to everything else: one
+``read`` per item around the source's ``next()`` (with ``rows`` where the
+item is a list), one span per stage and item under the stage's name, and
+one ``wait`` per item on the consumer's thread (the time it stood waiting
+for that item).  Each carries its true start, ``pipeline=<name>``,
+``seq=<item index>`` (the spans of one item share it) and, as
+``parent_id``, the span that was open on the thread that called
+:meth:`PrefetchPipeline.run`.
 
 ``depth=0`` runs the same stages inline (no threads, no overlap) — the
 apples-to-apples baseline the ``overlap`` bench suite compares against.
@@ -180,6 +187,15 @@ class PrefetchPipeline:
         self._stage_stats = [StageStats(s.name) for s in self.stages]
         self._sink_stats = StageStats(sink_name)
         self._published = False
+        self._parent_id = None  # span open in run()'s caller
+
+    def _span(self, name: str, t0: float, dur: float, seq: int,
+              **attrs: Any) -> None:
+        """One per-item span: ``t0`` is its start on the monotonic clock."""
+        get_telemetry().record_span(
+            name, dur, t_mono=t0, parent_id=self._parent_id,
+            pipeline=self.name, seq=seq, **attrs,
+        )
 
     # ------------------------------------------------------- queue helpers
 
@@ -222,7 +238,7 @@ class PrefetchPipeline:
         stats = self._source_stats
         it = iter(source)
         while True:
-            t0 = time.perf_counter()
+            t0 = time.monotonic()
             try:
                 item = next(it)
             except StopIteration:
@@ -231,37 +247,53 @@ class PrefetchPipeline:
             except BaseException as exc:  # forwarded, re-raised in consumer
                 self._put(q_out, _Failure(exc), stats)
                 return
-            stats.work_s += time.perf_counter() - t0
-            stats.items += 1
+            self._account_read(t0, item)
             if not self._put(q_out, item, stats):
                 return
 
+    def _account_read(self, t0: float, item: Any) -> None:
+        stats = self._source_stats
+        dur = time.monotonic() - t0
+        stats.work_s += dur
+        # a list is a batch of rows; a tuple is one record (a chunk's
+        # bounds, a batch's arrays) and its length counts fields
+        rows = {"rows": len(item)} if isinstance(item, list) else {}
+        self._span("read", t0, dur, stats.items, **rows)
+        stats.items += 1
+
     def _timed_fn(self, stage: Stage, item: Any):
-        """Run one stage fn; returns ``(duration_s, result | _Failure)``.
+        """Run one stage fn; returns ``(start, duration_s, result |
+        _Failure)``, the start on the monotonic clock: a ``workers > 1``
+        stage accounts a result when it leaves the window, later than the
+        work ended.
 
         The watchdog scope around the call is what turns "the bench went
         silent" into ``taxonomy: stage_stall`` naming the exact stage —
         a no-op unless a watchdog is active.
         """
-        t0 = time.perf_counter()
+        t0 = time.monotonic()
         try:
             with watchdog.watch(f"{self.name}.{stage.name}", kind="stage"):
                 result = _STAGE_RETRY.call(
                     self._stage_once, stage, item, site="prefetch.stage"
                 )
         except BaseException as exc:
-            return time.perf_counter() - t0, _Failure(exc)
-        return time.perf_counter() - t0, result
+            result = _Failure(exc)
+        return t0, time.monotonic() - t0, result
 
     def _stage_once(self, stage: Stage, item: Any) -> Any:
         fault_point("prefetch.stage", stage=stage.name, pipeline=self.name)
         return stage.fn(item)
 
-    def _account(self, stage: Stage, stats: StageStats, dur: float) -> None:
+    def _account(
+        self, stage: Stage, stats: StageStats, t0: float, dur: float
+    ) -> None:
         stats.work_s += dur
-        stats.items += 1
         if stage.record_spans:
-            get_telemetry().record_span(stage.name, dur, pipeline=self.name)
+            # results leave a stage in submission order, so the count of
+            # items accounted so far is this item's index
+            self._span(stage.name, t0, dur, stats.items)
+        stats.items += 1
 
     def _stage_loop(
         self, stage: Stage, stats: StageStats,
@@ -283,10 +315,10 @@ class PrefetchPipeline:
         window: deque = deque()
         window_cap = stage.workers * 2
 
-        def emit(dur: float, result: Any) -> bool:
+        def emit(t0: float, dur: float, result: Any) -> bool:
             """Account + forward one result; False ends the loop (either
             cancellation or a failure that poisons the chain)."""
-            self._account(stage, stats, dur)
+            self._account(stage, stats, t0, dur)
             if not self._put(q_out, result, stats):
                 return False
             return not isinstance(result, _Failure)
@@ -363,6 +395,8 @@ class PrefetchPipeline:
         here; closing the generator (break / caller exception) cancels and
         joins the pipeline before control returns.
         """
+        stack = get_telemetry()._stack()
+        self._parent_id = stack[-1].span_id if stack else None
         if self.depth == 0:
             yield from self._run_inline(source)
             return
@@ -393,11 +427,13 @@ class PrefetchPipeline:
         sink = self._sink_stats
         try:
             while True:
+                t0 = time.monotonic()
                 item = self._get(self._queues[-1], sink)
                 if item is _DONE or item is _CANCELLED:
                     return
                 if isinstance(item, _Failure):
                     raise item.exc
+                self._span("wait", t0, time.monotonic() - t0, sink.items)
                 sink.items += 1
                 t0 = time.perf_counter()
                 yield item
@@ -410,18 +446,21 @@ class PrefetchPipeline:
         try:
             it = iter(source)
             while True:
-                t0 = time.perf_counter()
+                t_wait = time.monotonic()
                 try:
                     item = next(it)
                 except StopIteration:
                     return
-                self._source_stats.work_s += time.perf_counter() - t0
-                self._source_stats.items += 1
+                self._account_read(t_wait, item)
                 for stage, stats in zip(self.stages, self._stage_stats):
-                    dur, item = self._timed_fn(stage, item)
-                    self._account(stage, stats, dur)
+                    t0, dur, item = self._timed_fn(stage, item)
+                    self._account(stage, stats, t0, dur)
                     if isinstance(item, _Failure):
                         raise item.exc
+                # no overlap: the consumer waits through the read and
+                # every stage of its item
+                self._span("wait", t_wait, time.monotonic() - t_wait,
+                           self._sink_stats.items)
                 self._sink_stats.items += 1
                 t0 = time.perf_counter()
                 yield item

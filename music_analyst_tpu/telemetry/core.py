@@ -311,20 +311,43 @@ class Telemetry:
             stack.pop()
             self._record_span(sp)
 
-    def record_span(self, name: str, duration_s: float, **attrs: Any) -> None:
-        """Record an already-measured region (hot loops, worker threads)."""
+    def record_span(
+        self,
+        name: str,
+        duration_s: float,
+        t_mono: Optional[float] = None,
+        parent_id: Optional[int] = None,
+        **attrs: Any,
+    ) -> None:
+        """Record an already-measured region (hot loops, worker threads).
+
+        ``t_mono`` is the region's start on the monotonic clock; a caller
+        that records later than the work ended (a result popped from a
+        window, a span recorded for another thread's work) passes it, and
+        ``t_wall`` is derived from it.  Without it the span is stamped
+        ``now - duration_s``, which is the start only when the call is made
+        the instant the work ends.  ``parent_id`` names the span that
+        caused this one; it wins over the recording thread's own stack.
+        """
         if not self.enabled:
             return
-        stack = self._stack()
+        if parent_id is None:
+            stack = self._stack()
+            parent_id = stack[-1].span_id if stack else None
         with self._lock:
             span_id = next(self._ids)
+        if t_mono is None:
+            t_mono = time.monotonic() - duration_s
+            t_wall = time.time() - duration_s
+        else:
+            t_wall = time.time() - (time.monotonic() - t_mono)
         sp = Span(
             name,
             span_id,
-            stack[-1].span_id if stack else None,
+            parent_id,
             threading.current_thread().name,
-            time.time() - duration_s,
-            time.monotonic() - duration_s,
+            t_wall,
+            t_mono,
         )
         sp.duration_s = duration_s
         sp.attrs.update(attrs)
@@ -481,6 +504,13 @@ class Telemetry:
         exit.  Nested scopes (the joint pipeline calling the wordcount and
         sentiment engines, the sweep looping over analyses) degrade to a
         plain ``engine:<name>`` span under the owner.
+
+        The owner's exit work (collective stage table, ``run_end``, the
+        manifest with its per-device ``memory_stats()``) runs after the
+        ``engine:<name>`` span has closed, inside a span of its own,
+        ``manifest``.  That span ends after the manifest is written, so it
+        is in ``telemetry.jsonl`` (the sink closes after it) and cannot be
+        in the manifest's own ``spans`` table.
         """
         if not self.enabled:
             yield
@@ -515,29 +545,33 @@ class Telemetry:
         finally:
             if owner:
                 wall = time.monotonic() - (self._run_started_mono or 0.0)
-                # Per-stage collective table: one digestible event next to
-                # the per-call ``collective`` stream (and reset, so the
-                # next run starts clean).  Lazy import — collectives is
-                # jax-free but telemetry must not hard-require profiling.
-                try:
-                    from music_analyst_tpu.profiling.collectives import (
-                        emit_stage_table,
-                    )
+                with self.span("manifest"):
+                    # Per-stage collective table: one digestible event
+                    # next to the per-call ``collective`` stream (and
+                    # reset, so the next run starts clean).  Lazy import —
+                    # collectives is jax-free but telemetry must not
+                    # hard-require profiling.
+                    try:
+                        from music_analyst_tpu.profiling.collectives import (
+                            emit_stage_table,
+                        )
 
-                    emit_stage_table()
-                except Exception:
-                    pass
-                with self._lock:
-                    counters = dict(self.counters)
-                    gauges = dict(self.gauges)
-                self.event("run_end", engine=engine, counters=counters,
-                           gauges=gauges)
-                if directory:
-                    from music_analyst_tpu.telemetry.introspect import (
-                        write_run_manifest,
-                    )
+                        emit_stage_table()
+                    except Exception:
+                        pass
+                    with self._lock:
+                        counters = dict(self.counters)
+                        gauges = dict(self.gauges)
+                    self.event("run_end", engine=engine, counters=counters,
+                               gauges=gauges)
+                    if directory:
+                        from music_analyst_tpu.telemetry.introspect import (
+                            write_run_manifest,
+                        )
 
-                    write_run_manifest(self, directory, wall_seconds=wall)
+                        write_run_manifest(
+                            self, directory, wall_seconds=wall
+                        )
                 self.close_sink()
             with self._lock:
                 self._run_depth = max(0, self._run_depth - 1)

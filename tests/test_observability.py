@@ -139,9 +139,14 @@ def test_watchdog_stage_hang_trips_and_dumps(tmp_path, monkeypatch):
     wd = start_watchdog(0.3)
     assert wd is not None
 
+    record_path = tmp_path / "flight_record.json"
+
     def hanging_stage(item):
+        # Hang until the record is on disk: the watchdog lists the trip
+        # before it takes the thread stacks, so leaving on ``wd.trips``
+        # races the dump and the stacks can miss this frame.
         deadline = time.time() + 15.0
-        while not wd.trips and time.time() < deadline:
+        while not record_path.exists() and time.time() < deadline:
             time.sleep(0.02)
         return item
 
@@ -156,7 +161,6 @@ def test_watchdog_stage_hang_trips_and_dumps(tmp_path, monkeypatch):
     assert trip["taxonomy"] == "stage_stall"
     assert trip["task"] == "bench.tokenize"
 
-    record_path = tmp_path / "flight_record.json"
     assert record_path.exists()
     with open(record_path, encoding="utf-8") as fh:
         record = json.load(fh)

@@ -178,6 +178,85 @@ def test_pipeline_publishes_telemetry():
     assert "pipeline" in tel.summary()
 
 
+@pytest.mark.parametrize("depth", [2, 0])
+def test_pipeline_records_read_and_wait_per_item(depth):
+    """One ``read`` and one ``wait`` span per item with its index, every
+    span of the pipeline under the span open in the caller, each at its
+    true start (a ``workers > 1`` stage accounts a result when it leaves
+    the window, later than the work ended)."""
+    from music_analyst_tpu.telemetry import configure
+
+    tel = configure(enabled=True, directory=None)
+    started = {}
+
+    def slow(item):
+        started[item[0]] = time.monotonic()
+        time.sleep(0.03 if item[0] % 2 else 0.005)
+        return item
+
+    pipe = PrefetchPipeline(
+        [
+            Stage("tokenize", lambda x: x),
+            Stage("slow", slow, workers=2),
+            Stage("h2d", lambda x: x),
+        ],
+        depth=depth, name="pipeline", sink_name="compute",
+    )
+    with tel.span("caller") as caller:
+        out = list(pipe.run([i] * (i + 1) for i in range(5)))
+    assert [item[0] for item in out] == list(range(5))
+
+    by_name = {}
+    for sp in tel.spans:
+        by_name.setdefault(sp.name, []).append(sp)
+    for name in ("read", "wait", "tokenize", "slow", "h2d"):
+        spans = by_name[name]
+        assert [sp.attrs["seq"] for sp in spans] == list(range(5)), name
+        assert all(sp.parent_id == caller.span_id for sp in spans), name
+        assert all(sp.attrs["pipeline"] == "pipeline" for sp in spans)
+    assert [sp.attrs["rows"] for sp in by_name["read"]] == [1, 2, 3, 4, 5]
+    assert all("rows" not in sp.attrs for sp in by_name["wait"])
+    for read, tokenize in zip(by_name["read"], by_name["tokenize"]):
+        assert read.t_mono + read.duration_s <= tokenize.t_mono
+    # the span starts where the work started (microseconds before ``fn``
+    # ran), not where the result left the window, tens of milliseconds
+    # later; one straggler allowed for a preempted worker on a busy machine
+    late = [started[i] - sp.t_mono for i, sp in enumerate(by_name["slow"])]
+    assert all(gap >= 0.0 for gap in late), late
+    assert sum(gap < 1e-3 for gap in late) >= 4, late
+    # the consumer's waits lie on its own thread, in order, inside the
+    # caller's span
+    waits = by_name["wait"]
+    assert {sp.thread for sp in waits} == {threading.current_thread().name}
+    assert all(a.t_mono + a.duration_s <= b.t_mono
+               for a, b in zip(waits, waits[1:]))
+    assert caller.t_mono <= waits[0].t_mono
+    if depth:
+        assert {sp.thread for sp in by_name["read"]} == {"pipeline-source"}
+    else:
+        # no overlap: the consumer waits through the read and every stage
+        for i, wait in enumerate(waits):
+            assert wait.t_mono <= by_name["read"][i].t_mono
+            h2d = by_name["h2d"][i]
+            assert h2d.t_mono + h2d.duration_s <= (
+                wait.t_mono + wait.duration_s)
+
+
+def test_only_a_list_item_is_a_batch_of_rows():
+    """A tuple is one record (the streaming histogram's chunk bounds, a
+    training batch's arrays): its length counts fields, not rows."""
+    from music_analyst_tpu.telemetry import configure
+
+    tel = configure(enabled=True, directory=None)
+    list(PrefetchPipeline([Stage("first", lambda x: x[0])], depth=1).run(
+        zip(range(3), range(1, 4))))
+    reads = [sp for sp in tel.spans if sp.name == "read"]
+    assert [sp.attrs for sp in reads] == [
+        {"pipeline": "pipeline", "seq": i} for i in range(3)
+    ]
+    assert all(sp.parent_id is None for sp in reads)
+
+
 # ------------------------------------------------------------------- wire
 
 

@@ -371,8 +371,11 @@ def leg_sentiment(smoke: Smoke, corpus: str, songs: int, batch: int,
     require(second["cache_hits"] > 0,
             f"sentiment: second process had no compile-cache hits: {second}")
     # A first run that already found everything cached (the machine came
-    # with a warm $JAX_COMPILATION_CACHE_DIR) has nothing to beat.
-    if first["cache_misses"]:
+    # with a warm $JAX_COMPILATION_CACHE_DIR) has nothing to beat.  Programs
+    # that compile in under the cache's 0.2 s floor (the one-row kernel of
+    # the parameter init) are never kept and miss in every process, so
+    # "everything cached" reads as no more misses than the second run has.
+    if first["cache_misses"] > second["cache_misses"]:
         require(second["compile_seconds"] < first["compile_seconds"],
                 f"sentiment: warm compile not faster: {first} -> {second}")
     smoke.observations["sentiment"] = {"cold": first, "warm": second}
@@ -607,6 +610,7 @@ def kernels_child(out_dir: str, rehearsal: bool) -> int:
     from music_analyst_tpu.models.layers import (
         causal_mask,
         dot_product_attention,
+        padding_mask,
         segment_mask,
     )
     from music_analyst_tpu.ops.flash_attention import flash_attention
@@ -615,6 +619,7 @@ def kernels_child(out_dir: str, rehearsal: bool) -> int:
         paged_attention_reference,
     )
     from music_analyst_tpu.ops.quant import quantize_kv_page
+    from music_analyst_tpu.ops.whole_row_attention import whole_row_attention
 
     devices = jax.devices()
     on_tpu = devices[0].platform == "tpu"
@@ -721,6 +726,30 @@ def kernels_child(out_dir: str, rehearsal: bool) -> int:
 
         run_case(name, fn, args, dense)
 
+    def whole_row_case(name, rows):
+        seq, heads, head_dim = 128, 12, 64  # the encoder's attention
+        rng = np.random.default_rng(rows)
+        q = jnp.asarray(
+            3.0 * rng.normal(size=(rows, seq, heads, head_dim)), jnp.bfloat16)
+        k, v = (
+            jnp.asarray(rng.normal(size=(rows, seq, heads, head_dim)),
+                        jnp.bfloat16)
+            for _ in range(2)
+        )
+        lengths = rng.integers(1, seq + 1, size=rows)
+        lengths[0], lengths[-1] = 1, seq
+        lengths = jnp.asarray(lengths, jnp.int32)
+
+        def fn(q, k, v, lengths):
+            return whole_row_attention(
+                q, k, v, lengths, interpret=not on_tpu)
+
+        def dense():
+            f32 = (x.astype(jnp.float32) for x in (q, k, v))
+            return dot_product_attention(*f32, padding_mask(lengths, seq))
+
+        run_case(name, fn, (q, k, v, lengths), dense)
+
     # Llama-3-8B heads over a >= 1,024-token span, and the llama3-tiny
     # geometry serve really builds (65 pages: region 1024 + 16 new).
     for quantized in (False, True):
@@ -732,6 +761,9 @@ def kernels_child(out_dir: str, rehearsal: bool) -> int:
     seq = 512 if rehearsal else 4096
     flash_case(f"flash_attention S={seq} gqa causal", seq, False)
     flash_case(f"flash_attention S={seq} gqa causal segments", seq, True)
+    # The sentiment job's tail batch: 38 blocks of 8 rows and one of 2.
+    rows = 21 if rehearsal else 306
+    whole_row_case(f"whole_row_attention {rows}x12x128x64 padded", rows)
 
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "kernels.json"), "w",

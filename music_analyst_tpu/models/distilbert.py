@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
-from typing import List, Optional, Sequence, Tuple
+from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +33,7 @@ from music_analyst_tpu.models.layers import (
     segment_mask,
 )
 from music_analyst_tpu.models.tokenization import resolve_bert_tokenizer
+from music_analyst_tpu.ops.whole_row_attention import whole_row_block_rows
 
 
 # HF DistilBERT hardcodes nn.LayerNorm(eps=1e-12) (flax defaults to
@@ -85,6 +86,7 @@ class TransformerBlock(nn.Module):
     """Post-LN block: x → LN(x + attn(x)) → LN(· + mlp(·))."""
 
     config: DistilBertConfig
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, x, mask, lengths=None, segment_ids=None):
@@ -94,7 +96,7 @@ class TransformerBlock(nn.Module):
             n_heads=cfg.n_heads, dtype=dtype, attn_impl=cfg.attn_impl,
             use_bias=True,  # HF DistilBERT q/k/v/out projections have biases
             quant=cfg.quant, weight_quant=cfg.weight_quant,
-            name="attention",
+            mesh=self.mesh, name="attention",
         )(x, mask=None if cfg.attn_impl == "flash" else mask,
           lengths=lengths,
           segment_ids=segment_ids if cfg.attn_impl == "flash" else None)
@@ -110,6 +112,9 @@ class TransformerBlock(nn.Module):
 
 class DistilBertEncoder(nn.Module):
     config: DistilBertConfig
+    # The mesh of a sharded forward (None on one device); only the
+    # whole-row attention kernel reads it, to run per shard.
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, token_ids, lengths, positions=None, segment_ids=None):
@@ -151,6 +156,18 @@ class DistilBertEncoder(nn.Module):
                 None if cfg.attn_impl == "flash"
                 else segment_mask(segment_ids)
             )
+        elif cfg.attn_impl == "dense" and whole_row_block_rows(
+            token_ids.shape[1], cfg.n_heads, cfg.dim // cfg.n_heads, dtype,
+            self.mesh,
+        ):
+            # Key padding only, no cache, S_q == S_kv, and a whole key row
+            # of every head fits VMEM: `lengths` says all the mask would,
+            # and without a mask array MultiHeadAttention's dense branch
+            # runs the whole-row kernel (ops/whole_row_attention.py) — the
+            # [B, H, S, S] scores never reach HBM.  The choice is made
+            # here, from the shape, and nowhere else; longer rows build
+            # the mask below and lower to the program they always did.
+            mask = None
         else:
             mask = padding_mask(lengths, token_ids.shape[1])
         # CONTRACT: with cfg.attn_impl == "flash", attention masking is
@@ -158,7 +175,7 @@ class DistilBertEncoder(nn.Module):
         # block-diagonal); the mask array is only consumed by the dense
         # impl.
         for i in range(cfg.n_layers):
-            x = TransformerBlock(cfg, name=f"layer_{i}")(
+            x = TransformerBlock(cfg, self.mesh, name=f"layer_{i}")(
                 x, mask, lengths, segment_ids=segment_ids
             )
         return x
@@ -175,13 +192,14 @@ class DistilBertForSentiment(nn.Module):
     """
 
     config: DistilBertConfig
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, token_ids, lengths, positions=None, segment_ids=None,
                  cls_index=None):
         cfg = self.config
         dtype = jnp.dtype(cfg.dtype)
-        x = DistilBertEncoder(cfg, name="encoder")(
+        x = DistilBertEncoder(cfg, self.mesh, name="encoder")(
             token_ids, lengths, positions=positions, segment_ids=segment_ids
         )
         if cls_index is None:
@@ -461,7 +479,10 @@ class DistilBertClassifier(ClassifierBackend):
         self.tokenizer = resolve_bert_tokenizer(
             vocab_path, vocab_size=self.config.vocab_size
         )
-        self.model = DistilBertForSentiment(self.config)
+        self.model = DistilBertForSentiment(self.config, mesh)
+        # Parameters do not depend on the mesh, and the one-row dummy
+        # batch cannot be split over it: initialise without.
+        init = DistilBertForSentiment(self.config).init
         dummy = (
             jnp.zeros((1, max_len), jnp.int32),
             jnp.ones((1,), jnp.int32),
@@ -478,7 +499,7 @@ class DistilBertClassifier(ClassifierBackend):
             from music_analyst_tpu.ops.quant import WQ_DEFAULT_GROUP
 
             params_shape = jax.eval_shape(
-                self.model.init, jax.random.key(seed), *dummy
+                init, jax.random.key(seed), *dummy
             )["params"]
             cache_dir = wq_cache.resolve_cache_dir(wq_cache_dir)
             cache_key = (
@@ -499,9 +520,7 @@ class DistilBertClassifier(ClassifierBackend):
             )
             self.pretrained = True
         else:
-            self.params = self.model.init(
-                jax.random.key(seed), *dummy
-            )["params"]
+            self.params = init(jax.random.key(seed), *dummy)["params"]
             self.pretrained = False
             if checkpoint_path:
                 self.params = load_hf_torch_checkpoint(

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from music_analyst_tpu.profiling.compile import note_attention_path
+
 
 def rope_frequencies(
     head_dim: int, max_positions: int, theta: float = 10_000.0
@@ -342,6 +344,9 @@ class MultiHeadAttention(nn.Module):
     # (QuantizedParam leaves; ops/quant.py) — takes precedence over the
     # dynamic `quant` path.
     weight_quant: str = "none"
+    # The mesh a meshed forward runs under: a Pallas call is opaque to the
+    # partitioner, so the whole-row kernel needs it to run per shard.
+    mesh: Any = None
 
     @nn.compact
     def __call__(
@@ -418,7 +423,19 @@ class MultiHeadAttention(nn.Module):
                     "dense callers build the block-diagonal mask array "
                     "themselves (models/distilbert.py)"
                 )
-            out = dot_product_attention(q, k, v, mask)
+            if mask is None and lengths is not None and cache is None:
+                # Key padding described by `lengths` alone: the caller
+                # built no mask array because the shape is one the
+                # whole-row kernel takes (models/distilbert.py decides).
+                from music_analyst_tpu.ops.whole_row_attention import (
+                    whole_row_attention,
+                )
+
+                note_attention_path("whole_row")
+                out = whole_row_attention(q, k, v, lengths, mesh=self.mesh)
+            else:
+                note_attention_path("dense")
+                out = dot_product_attention(q, k, v, mask)
         out = dense_cls(
             features=features,
             axis=(-2, -1),

@@ -36,6 +36,24 @@ import jax
 _REGISTRY: List["ProfiledFunction"] = []
 _REGISTRY_LOCK = threading.Lock()
 
+# The attention paths chosen while the program a ProfiledFunction is
+# compiling gets traced, per thread: {path: layers that took it}.
+_TRACING = threading.local()
+
+
+def note_attention_path(path: str) -> None:
+    """Say, at trace time, which attention implementation a layer took
+    (``models/layers.MultiHeadAttention``): bumps the counter
+    ``attention.<path>`` and, when a :func:`profiled_jit` program is being
+    compiled on this thread, lands in its record's ``attention_paths`` —
+    so a run's manifest names the path of every compiled shape."""
+    from music_analyst_tpu.telemetry import get_telemetry
+
+    get_telemetry().count(f"attention.{path}")
+    paths = getattr(_TRACING, "attention_paths", None)
+    if paths is not None:
+        paths[path] = paths.get(path, 0) + 1
+
 
 def _leaf_sig(leaf: Any) -> str:
     shape = getattr(leaf, "shape", None)
@@ -101,7 +119,7 @@ class CompileRecord:
     __slots__ = (
         "name", "aval_key", "flops", "bytes_accessed", "temp_bytes",
         "argument_bytes", "output_bytes", "hlo_fingerprint",
-        "compile_seconds", "param_bytes",
+        "compile_seconds", "param_bytes", "attention_paths",
     )
 
     def __init__(self, name: str, aval_key: str) -> None:
@@ -117,6 +135,9 @@ class CompileRecord:
         # Weight-quantized calls only: stored vs dequant-transient bytes
         # of the argument param tree (ops.quant.param_tree_bytes).
         self.param_bytes: Optional[Dict[str, int]] = None
+        # {path: layers} noted while this program was traced
+        # (note_attention_path); empty for a program without attention.
+        self.attention_paths: Dict[str, int] = {}
 
     def as_dict(self) -> Dict[str, Any]:
         return {
@@ -130,6 +151,7 @@ class CompileRecord:
             "hlo_fingerprint": self.hlo_fingerprint,
             "compile_seconds": round(self.compile_seconds, 6),
             "param_bytes": self.param_bytes,
+            "attention_paths": self.attention_paths,
         }
 
 
@@ -184,9 +206,16 @@ class ProfiledFunction:
             from music_analyst_tpu.resilience.faults import fault_point
             from music_analyst_tpu.resilience.policy import RetryPolicy
 
+            attention_paths: Dict[str, int] = {}
+
             def _lower_and_compile():
                 fault_point("compile.first", fn=self.name)
-                low = self._jit.lower(*args, **kwargs)
+                attention_paths.clear()
+                _TRACING.attention_paths = attention_paths
+                try:
+                    low = self._jit.lower(*args, **kwargs)
+                finally:
+                    _TRACING.attention_paths = None
                 return low, low.compile()
 
             t0 = time.perf_counter()
@@ -209,6 +238,7 @@ class ProfiledFunction:
             return None
         rec = self._record(key, lowered, compiled, seconds)
         rec.param_bytes = _wq_param_bytes(args, kwargs)
+        rec.attention_paths = attention_paths
         prior = list(self.records)
         self.records[key] = rec
         tel.count("profiling.compiles")

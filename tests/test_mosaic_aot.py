@@ -9,7 +9,9 @@ running: numerics on the chip are ``chip_smoke.py``'s kernels leg.
 Geometries are the ones ``chip_smoke.py`` runs: Llama-3-8B heads
 (32 Q / 8 KV x 128) and the ``llama3-tiny`` shape ``serve`` really
 builds (8 Q / 4 KV x 16), page 16, bf16 and int8 pools; flash attention
-at GQA + causal with and without segment ids.
+at GQA + causal with and without segment ids; the whole-row kernel at the
+corpus job's two batch shapes (4,096 and the 306-row tail x 12 x 128 x 64),
+at the longest rows its VMEM arithmetic admits, and at ``distilbert-tiny``.
 """
 
 from __future__ import annotations
@@ -23,6 +25,10 @@ from music_analyst_tpu.ops.flash_attention import flash_attention
 from music_analyst_tpu.ops.paged_attention import (
     check_stream_geometry,
     paged_attention,
+)
+from music_analyst_tpu.ops.whole_row_attention import (
+    whole_row_attention,
+    whole_row_block_rows,
 )
 
 
@@ -96,6 +102,36 @@ def test_flash_attention_compiles_under_mosaic(tpu_sharding, segmented):
         )
 
     _compile_for_tpu(fn, tpu_sharding, *shapes)
+
+
+@pytest.mark.parametrize(
+    "rows,seq,heads,head_dim,dtype",
+    [
+        (4096, 128, 12, 64, jnp.bfloat16),  # the corpus job's full batch
+        (306, 128, 12, 64, jnp.bfloat16),   # its tail: 38 blocks of 8 and 2
+        (1, 128, 12, 64, jnp.bfloat16),     # the one-row init / serve batch
+        (16, 256, 12, 64, jnp.bfloat16),
+        (16, 384, 12, 64, jnp.bfloat16),    # the longest row that fits: 1 a step
+        (16, 256, 12, 64, jnp.float32),
+        (64, 128, 4, 16, jnp.bfloat16),     # distilbert-tiny
+        (64, 128, 3, 64, jnp.bfloat16),     # 12 heads split over tp=4
+    ],
+    ids=lambda x: getattr(x, "__name__", str(x)),
+)
+def test_whole_row_attention_compiles_under_mosaic(
+    tpu_sharding, rows, seq, heads, head_dim, dtype
+):
+    """Wherever the kernel's own arithmetic admits a shape, Mosaic takes
+    it inside the VMEM the call asks for."""
+    assert whole_row_block_rows(seq, heads, head_dim, dtype)
+    shape = ((rows, seq, heads, head_dim), dtype)
+
+    def fn(q, k, v, lengths):
+        return whole_row_attention(q, k, v, lengths, interpret=False)
+
+    _compile_for_tpu(
+        fn, tpu_sharding, shape, shape, shape, ((rows,), jnp.int32)
+    )
 
 
 def test_unservable_geometry_is_refused_by_name():

@@ -6,7 +6,8 @@ dispatches whole batches to an on-device classifier backend:
 
 * ``mock``   — the vectorized keyword kernel (``ops/keyword_sentiment.py``);
 * ``distilbert`` — encoder classifier (``models/distilbert.py``);
-* ``llama``  — zero-shot decoder LM (``models/llama.py``).
+* ``llama`` / ``kanana`` — zero-shot decoder LM (``models/llama.py``;
+  ``kanana-2-30b-a3b`` is latent attention + sigmoid-routed experts).
 
 Outputs are byte-for-byte the reference artifact formats:
 ``sentiment_totals.json`` (label→count, 2-space JSON) and
@@ -194,7 +195,7 @@ def get_backend(
                     else tuple(int(b) for b in length_buckets)
                 )
             return DistilBertClassifier.from_pretrained_or_random(model, **kwargs)
-        if model.startswith("llama"):
+        if model.startswith(("llama", "kanana")):
             from music_analyst_tpu.models.llama import LlamaZeroShotClassifier
 
             return LlamaZeroShotClassifier.from_pretrained_or_random(
@@ -206,7 +207,8 @@ def get_backend(
             "use --mock or --model mock for the keyword kernel"
         ) from exc
     raise ValueError(
-        f"unknown model {model!r}: expected 'mock', 'distilbert*' or 'llama*'"
+        f"unknown model {model!r}: expected 'mock', 'distilbert*', 'llama*' "
+        "or 'kanana*'"
     )
 
 
@@ -259,9 +261,7 @@ def _mesh_capable(model: str, mock: bool) -> bool:
     ``mesh=`` to :func:`get_backend`, which drops it where inapplicable;
     this predicate exists for callers deciding whether to *build* a mesh
     at all (mesh construction initializes the device backend)."""
-    return not mock and (
-        model.startswith("distilbert") or model.startswith("llama")
-    )
+    return not mock and model.startswith(("distilbert", "llama", "kanana"))
 
 
 def run_sentiment(

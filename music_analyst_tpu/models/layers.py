@@ -47,6 +47,35 @@ def apply_rope(
     return rotated.astype(x.dtype)
 
 
+def apply_rope_interleaved(
+    x: jax.Array, cos: jax.Array, sin: jax.Array, positions: jax.Array
+) -> jax.Array:
+    """:func:`apply_rope` for the interleaved layout (``rope_interleave``):
+    the rotated pairs are ``(x[2i], x[2i+1])`` where the half-split form
+    pairs ``(x[i], x[i + D/2])``.  ``x`` is ``[B, S, H, D]``; the result
+    keeps the interleaved order."""
+    cos_p = cos[positions][:, :, None, :]  # [B, S, 1, D/2]
+    sin_p = sin[positions][:, :, None, :]
+    x32 = x.astype(jnp.float32)
+    pairs = x32.reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    rotated = jnp.stack(
+        (x1 * cos_p - x2 * sin_p, x2 * cos_p + x1 * sin_p), axis=-1
+    )
+    return rotated.reshape(x.shape).astype(x.dtype)
+
+
+def fan_in_normal(fan_in: int):
+    """N(0, 1/fan_in): lecun-normal on a kernel whose leading axes are not
+    its receptive field (expert stacks, ``[rank, heads, dim]``)."""
+
+    def init(key, shape, dtype):
+        return (jax.random.normal(key, shape, jnp.float32)
+                * fan_in ** -0.5).astype(dtype)
+
+    return init
+
+
 class RMSNorm(nn.Module):
     """Root-mean-square norm (no mean subtraction), fp32 accumulation."""
 
@@ -118,6 +147,14 @@ class KVCache:
                 self.values, v_new, (0, start, 0, 0)
             )
         return KVCache(keys, values, start + k_new.shape[1])
+
+    @property
+    def max_len(self) -> int:
+        return self.keys.shape[1]
+
+    def with_length(self, length) -> "KVCache":
+        """The same buffers reporting ``length`` filled positions."""
+        return KVCache(self.keys, self.values, jnp.asarray(length, jnp.int32))
 
 
 jax.tree_util.register_dataclass(
@@ -455,6 +492,8 @@ class SwiGLU(nn.Module):
     dtype: jnp.dtype = jnp.bfloat16
     quant: str = "none"
     weight_quant: str = "none"
+    # What the float kernels are stored in (the quantized layouts own theirs).
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -469,7 +508,8 @@ class SwiGLU(nn.Module):
             )
         else:
             dense = lambda feats, name: nn.Dense(  # noqa: E731
-                feats, use_bias=False, dtype=self.dtype, name=name
+                feats, use_bias=False, dtype=self.dtype,
+                param_dtype=self.param_dtype, name=name
             )
         gate = dense(self.hidden_dim, "gate_proj")(x)
         up = dense(self.hidden_dim, "up_proj")(x)

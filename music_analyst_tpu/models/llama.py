@@ -84,8 +84,45 @@ class LlamaConfig:
     # which is what lets the 8B config fit one 16 GB chip.  Mutually
     # exclusive with the dynamic `quant` path (it subsumes the matmul).
     weight_quant: str = "none"
+    # --- layer kinds beyond Llama's own, set from a published config file
+    # (``from_hf_config``).  "mla" = multi-head latent attention with a
+    # latent cache (models/mla.py); the four widths below are its.
+    attention: str = "gqa"
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False
+    rms_norm_eps: float = 1e-5
+    # "sigmoid_noaux" = sigmoid scores, bias-corrected choice, no dropped
+    # token, shared experts (models/moe.SigmoidRoutedMoE; experts of width
+    # ``moe_hidden_dim``); "softmax_capacity" = MoESwiGLU as above.
+    moe_router: str = "softmax_capacity"
+    moe_hidden_dim: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    # Leading layers that keep the dense SwiGLU (``hidden_dim``) in a model
+    # whose other layers are routed.
+    first_k_dense_replace: int = 0
+    # What float parameters are created and held in.  "bfloat16" also
+    # makes the random init one jitted program a layer kind, on the device
+    # (a float32 tree of a 4.4 B-parameter model is 17.7 GB).
+    param_dtype: str = "float32"
+    # Offline tokenizer: "byte", or "hash_word" (word ids hashed over the
+    # whole vocabulary) for a vocabulary the bytes would barely touch.
+    tokenizer: str = "byte"
+    # The narrowest width a batch of prompts is trimmed to
+    # (``_trim_prompt_pad``).  Every width is a compiled program: a preset
+    # whose scoring step compiles for most of a minute sets it to its
+    # prompt cap and runs one shape.
+    prompt_width_floor: int = 64
 
     def __post_init__(self):
+        if self.attention not in ("gqa", "mla"):
+            raise ValueError(f"unknown attention kind {self.attention!r}")
+        if self.moe_router not in ("softmax_capacity", "sigmoid_noaux"):
+            raise ValueError(f"unknown moe_router {self.moe_router!r}")
         if self.weight_quant not in ("none", "int8", "int4"):
             raise ValueError(
                 f"weight_quant must be none/int8/int4, got "
@@ -101,6 +138,78 @@ class LlamaConfig:
                 "weight_quant does not cover the MoE expert stacks yet; "
                 "use the dynamic quant='int8' path for MoE configs"
             )
+
+    @property
+    def latent_cache(self) -> bool:
+        return self.attention == "mla"
+
+    def routed_layer(self, index: int) -> bool:
+        """Whether layer ``index`` is a routed (expert) layer."""
+        return self.n_experts > 0 and index >= self.first_k_dense_replace
+
+    @classmethod
+    def from_hf_config(cls, hf: dict, **overrides) -> "LlamaConfig":
+        """The decoder a published ``config.json`` of ``model_type:
+        deepseek_v3`` describes, key by key.  What this code cannot run is
+        refused by name, not approximated."""
+        unsupported = {
+            "model_type": hf.get("model_type") != "deepseek_v3",
+            "q_lora_rank": hf.get("q_lora_rank") is not None,
+            "n_group": hf.get("n_group", 1) != 1,
+            "topk_group": hf.get("topk_group", 1) != 1,
+            "scoring_func": hf.get("scoring_func") != "sigmoid",
+            "topk_method": hf.get("topk_method") != "noaux_tc",
+            "rope_scaling": hf.get("rope_scaling") is not None,
+            "attention_bias": bool(hf.get("attention_bias", False)),
+            "tie_word_embeddings": bool(hf.get("tie_word_embeddings", False)),
+            "hidden_act": hf.get("hidden_act", "silu") != "silu",
+            "moe_layer_freq": hf.get("moe_layer_freq", 1) != 1,
+        }
+        bad = sorted(k for k, v in unsupported.items() if v)
+        if bad:
+            raise ValueError(
+                "this decoder does not implement the configuration's "
+                + ", ".join(f"{k}={hf.get(k)!r}" for k in bad)
+            )
+        if hf["qk_head_dim"] != hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"]:
+            raise ValueError("qk_head_dim != qk_nope_head_dim + qk_rope_head_dim")
+        fields = dict(
+            vocab_size=hf["vocab_size"], dim=hf["hidden_size"],
+            n_layers=hf["num_hidden_layers"],
+            n_heads=hf["num_attention_heads"],
+            n_kv_heads=hf["num_key_value_heads"],
+            hidden_dim=hf["intermediate_size"],
+            rope_theta=float(hf["rope_theta"]),
+            max_seq_len=hf["max_position_embeddings"],
+            attention="mla", kv_lora_rank=hf["kv_lora_rank"],
+            qk_nope_head_dim=hf["qk_nope_head_dim"],
+            qk_rope_head_dim=hf["qk_rope_head_dim"],
+            v_head_dim=hf["v_head_dim"],
+            rope_interleave=bool(hf["rope_interleave"]),
+            rms_norm_eps=float(hf["rms_norm_eps"]),
+            moe_router="sigmoid_noaux", n_experts=hf["n_routed_experts"],
+            moe_top_k=hf["num_experts_per_tok"],
+            moe_hidden_dim=hf["moe_intermediate_size"],
+            n_shared_experts=hf["n_shared_experts"],
+            routed_scaling_factor=float(hf["routed_scaling_factor"]),
+            norm_topk_prob=bool(hf["norm_topk_prob"]),
+            first_k_dense_replace=hf["first_k_dense_replace"],
+        )
+        fields.update(overrides)
+        return cls(**fields)
+
+    @classmethod
+    def from_preset_file(cls, name: str) -> "LlamaConfig":
+        """``models/presets/<name>.json``: the published keys as run, and
+        under ``runtime`` what the source does not state (dtypes, the
+        offline tokenizer)."""
+        import json
+
+        path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                            "presets", name + ".json")
+        with open(path, encoding="utf-8") as fh:
+            hf = json.load(fh)
+        return cls.from_hf_config(hf, **hf.get("runtime", {}))
 
     @classmethod
     def llama3_8b(cls) -> "LlamaConfig":
@@ -120,17 +229,34 @@ PRESETS = {
     "llama3-8b": LlamaConfig.llama3_8b,
     "llama3-tiny": LlamaConfig.tiny,
     "llama-tiny": LlamaConfig.tiny,
+    # Built from files (models/presets/): latent attention + sigmoid-routed
+    # experts.  The first is the published widths with the depth one chip
+    # holds (a pipeline stage); the second the same kinds at test size.
+    "kanana-2-30b-a3b": partial(LlamaConfig.from_preset_file,
+                                "kanana-2-30b-a3b"),
+    "kanana-tiny": partial(LlamaConfig.from_preset_file, "kanana-tiny"),
 }
+
+LATENT_CACHE_REFUSAL = (
+    "this model's attention keeps a latent cache (one c_kv + k_rope vector "
+    "a token, models/mla.LatentCache); the {runtime} runtime stores pages "
+    "or slots of per-head keys and values and has no latent layout yet"
+)
 
 
 class LlamaBlock(nn.Module):
     config: LlamaConfig
+    # Only a configuration whose layers differ in kind reads it
+    # (``first_k_dense_replace``).
+    layer_index: int = 0
 
     @nn.compact
     def __call__(self, x, mask, positions, cache: Optional[KVCache],
                  lengths: Optional[jax.Array] = None,
                  segment_ids: Optional[jax.Array] = None):
         cfg = self.config
+        if cfg.attention == "mla":
+            return self._latent_block(x, mask, positions, cache, segment_ids)
         if segment_ids is not None and (
             cache is not None or cfg.attn_impl != "flash"
         ):
@@ -199,6 +325,48 @@ class LlamaBlock(nn.Module):
         return x, new_cache
 
 
+    def _latent_block(self, x, mask, positions, cache, segment_ids):
+        """Pre-norm block of the ``mla`` kind: latent attention, then the
+        dense SwiGLU in the leading layers and routed + shared experts in
+        the rest."""
+        from music_analyst_tpu.models.mla import MLAttention
+
+        cfg = self.config
+        if segment_ids is not None:
+            raise ValueError("latent attention takes no segment_ids")
+        dtype, param_dtype = jnp.dtype(cfg.dtype), jnp.dtype(cfg.param_dtype)
+        attn = MLAttention(
+            n_heads=cfg.n_heads, qk_nope_head_dim=cfg.qk_nope_head_dim,
+            qk_rope_head_dim=cfg.qk_rope_head_dim,
+            v_head_dim=cfg.v_head_dim, kv_lora_rank=cfg.kv_lora_rank,
+            rope_theta=cfg.rope_theta, rope_interleave=cfg.rope_interleave,
+            max_positions=cfg.max_seq_len, norm_eps=cfg.rms_norm_eps,
+            dtype=dtype, param_dtype=param_dtype, name="attention",
+        )
+        h = RMSNorm(epsilon=cfg.rms_norm_eps, name="attention_norm")(x)
+        with jax.named_scope("mla"):
+            if cache is not None:
+                attn_out, new_cache = attn(h, mask, positions, cache)
+            else:
+                attn_out, new_cache = attn(h, mask, positions), None
+        x = x + attn_out
+        h = RMSNorm(epsilon=cfg.rms_norm_eps, name="ffn_norm")(x)
+        if cfg.routed_layer(self.layer_index):
+            from music_analyst_tpu.models.moe import SigmoidRoutedMoE
+
+            ffn = SigmoidRoutedMoE(
+                cfg.n_experts, cfg.moe_hidden_dim, cfg.moe_top_k,
+                n_shared=cfg.n_shared_experts,
+                routed_scaling_factor=cfg.routed_scaling_factor,
+                norm_topk_prob=cfg.norm_topk_prob, dtype=dtype,
+                param_dtype=param_dtype, name="feed_forward_moe",
+            )
+        else:
+            ffn = SwiGLU(cfg.hidden_dim, dtype=dtype,
+                         param_dtype=param_dtype, name="feed_forward")
+        return x + ffn(h), new_cache
+
+
 class LlamaModel(nn.Module):
     config: LlamaConfig
 
@@ -224,18 +392,20 @@ class LlamaModel(nn.Module):
         # raises if a mask array reaches the flash branch directly.
         cfg = self.config
         dtype = jnp.dtype(cfg.dtype)
+        param_dtype = jnp.dtype(cfg.param_dtype)
         x = nn.Embed(cfg.vocab_size, cfg.dim, dtype=dtype,
+                     param_dtype=param_dtype,
                      name="tok_embeddings")(token_ids)
         new_caches: List[KVCache] = []
         for i in range(cfg.n_layers):
             cache_i = caches[i] if caches is not None else None
-            x, new_cache = LlamaBlock(cfg, name=f"layer_{i}")(
+            x, new_cache = LlamaBlock(cfg, i, name=f"layer_{i}")(
                 x, mask, positions, cache_i, lengths,
                 segment_ids=segment_ids,
             )
             if new_cache is not None:
                 new_caches.append(new_cache)
-        x = RMSNorm(name="norm")(x)
+        x = RMSNorm(epsilon=cfg.rms_norm_eps, name="norm")(x)
         if last_position is not None:
             # Gather ONE position per row BEFORE the vocab projection:
             # prefill callers only consume the last prompt logits, and a
@@ -253,14 +423,25 @@ class LlamaModel(nn.Module):
                 dtype=jnp.float32, name="lm_head",
             )(x)
         else:
-            logits = nn.Dense(cfg.vocab_size, use_bias=False,
-                              dtype=jnp.float32, name="lm_head")(x)
+            with jax.named_scope("lm_head"):
+                logits = nn.Dense(cfg.vocab_size, use_bias=False,
+                                  dtype=jnp.float32, param_dtype=param_dtype,
+                                  name="lm_head")(x)
         return logits, (new_caches if caches is not None else None)
 
 
 def init_caches(
     cfg: LlamaConfig, batch: int, max_len: int, dtype=jnp.bfloat16
 ) -> List[KVCache]:
+    """One empty cache a layer, of the layer's attention kind."""
+    if cfg.latent_cache:
+        from music_analyst_tpu.models.mla import LatentCache
+
+        return [
+            LatentCache.zeros(batch, max_len, cfg.kv_lora_rank,
+                              cfg.qk_rope_head_dim, dtype)
+            for _ in range(cfg.n_layers)
+        ]
     head_dim = cfg.dim // cfg.n_heads
     return [
         KVCache.zeros(batch, max_len, cfg.n_kv_heads, head_dim, dtype)
@@ -434,6 +615,56 @@ def _wq_group_size() -> int:
     return WQ_DEFAULT_GROUP
 
 
+def _sown_by_layer(sown, name: str) -> list:
+    """What the routed layers sowed under ``name`` (``mutable=
+    ["intermediates"]``), in layer order; empty for a model without them."""
+    layers = sorted(
+        (int(key.rsplit("_", 1)[1]), layer["feed_forward_moe"])
+        for key, layer in sown.get("intermediates", {}).items()
+        if "feed_forward_moe" in layer
+    )
+    return [moe[name][0] for _, moe in layers]
+
+
+def _expert_ids(chosen: jax.Array, n_experts: int) -> jax.Array:
+    """Expert indices in the narrowest type that holds them."""
+    return chosen.astype(jnp.uint8 if n_experts <= 256 else jnp.int32)
+
+
+def init_params_by_layer(cfg: LlamaConfig, seed: int = 0):
+    """Seeded random parameters, created on the device in
+    ``cfg.param_dtype``: one jitted program a layer kind (the routed kind's
+    is compiled once and run for every routed layer), one for the embedding
+    and one for the final norm and the head.  The float32 tree never
+    exists, and nothing is initialised op by op."""
+    dtype = jnp.dtype(cfg.dtype)
+    x = jnp.zeros((1, 8, cfg.dim), dtype)
+    positions = jnp.zeros((1, 8), jnp.int32)
+    mask = causal_mask(8, 8, 0)
+    root = jax.random.key(seed)
+
+    def program(module, *args):
+        return jax.jit(lambda key: module.init(key, *args)["params"])
+
+    inits = {}  # routed? -> jitted init of that layer kind
+    params = {}
+    embed = nn.Embed(cfg.vocab_size, cfg.dim, dtype=dtype,
+                     param_dtype=jnp.dtype(cfg.param_dtype))
+    params["tok_embeddings"] = program(embed, positions)(
+        jax.random.fold_in(root, 0))
+    for i in range(cfg.n_layers):
+        kind = cfg.routed_layer(i)
+        if kind not in inits:
+            inits[kind] = program(LlamaBlock(cfg, i), x, mask, positions, None)
+        params[f"layer_{i}"] = inits[kind](jax.random.fold_in(root, 1 + i))
+    params["norm"] = RMSNorm(epsilon=cfg.rms_norm_eps).init(root, x)["params"]
+    head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
+                    param_dtype=jnp.dtype(cfg.param_dtype))
+    params["lm_head"] = program(head, x)(
+        jax.random.fold_in(root, 1 + cfg.n_layers))
+    return params
+
+
 class LlamaZeroShotClassifier(ClassifierBackend):
     """Constrained-label zero-shot sentiment over the decoder LM."""
 
@@ -474,7 +705,8 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         self._slot_schedulers: dict = {}
         self.config = config or LlamaConfig.tiny()
         self.max_prompt_len = max_prompt_len
-        self.tokenizer = resolve_llama_tokenizer(self.config.vocab_size)
+        self.tokenizer = resolve_llama_tokenizer(
+            self.config.vocab_size, kind=self.config.tokenizer)
         # Ids above vocab_size would be silently clamped by nn.Embed's
         # gather, producing garbage labels with no diagnostic.  With real
         # weights that's fatal; on random-weight smoke runs (labels are
@@ -529,6 +761,13 @@ class LlamaZeroShotClassifier(ClassifierBackend):
                 cache_key=cache_key,
             )
             self.pretrained = True
+        elif self.config.param_dtype != "float32":
+            if checkpoint_path:
+                raise ValueError(
+                    "no checkpoint loader maps onto this configuration's "
+                    "layers yet; it runs seeded random weights"
+                )
+            self.params = init_params_by_layer(self.config, seed)
         else:
             self.params = self.model.init(
                 jax.random.key(seed), dummy_ids, dummy_pos, dummy_mask
@@ -574,17 +813,26 @@ class LlamaZeroShotClassifier(ClassifierBackend):
             # (HF tokenizers with add_bos_token=False don't).
             skip = 1 if (n > 0 and bos_id is not None
                          and row[0] == bos_id) else 0
+            if getattr(self.tokenizer, "closes_labels", False):
+                # A word-level tokenizer gives every label one token, and
+                # a one-token continuation never reads its own forward
+                # pass: score "label, then stop" (EOS) as the answer.
+                row = np.insert(row, n, self.tokenizer.eos_id)
+                n += 1
             label_rows.append(row[skip:skip + 8])  # fixed len 8
             label_lens.append(min(n - skip, 8))
         self._label_ids = np.stack(label_rows)
         self._label_lens = np.array(label_lens, dtype=np.int32)
 
-        @jax.jit
         def _score_labels(params, prompt_ids, prompt_lens, label_ids,
                           label_lens):
             """Log-likelihood of each label continuation per batch row.
 
-            prompt_ids [B, S]; label_ids [3, L].  Returns [B, 3].
+            prompt_ids [B, S]; label_ids [3, L].  Returns ``(scores [B, 3],
+            stats)``: ``stats`` holds the small device-side reductions that
+            ride back with the scores (``expert_load_max`` /
+            ``expert_load_mean`` ``[routed layers]`` of the prefill, for a
+            model with routed experts; else empty).
             """
             B, S = prompt_ids.shape
             n_labels, L = label_ids.shape
@@ -601,16 +849,24 @@ class LlamaZeroShotClassifier(ClassifierBackend):
             caches = init_caches(self.config, B, S + L)
             # last_position: only the final prompt logits are consumed, so
             # the [B,S,V] prefill logits are never materialized.
-            logits, caches = self.model.apply(
+            (logits, caches), sown = self.model.apply(
                 {"params": params}, prompt_ids, positions, mask, caches,
-                last_position=prompt_lens - 1,
+                last_position=prompt_lens - 1, mutable=["intermediates"],
             )
+            stats = {}
+            loads = _sown_by_layer(sown, "expert_load")
+            if loads:
+                load = jnp.stack(loads).astype(jnp.float32)  # [layers, E]
+                stats = {"expert_load_max": load.max(axis=-1),
+                         "expert_load_mean": load.mean(axis=-1),
+                         # [layers, B, S, k]: which experts every position
+                         # ran, for whoever compares against a reference
+                         "chosen": _expert_ids(
+                             jnp.stack(_sown_by_layer(sown, "chosen")),
+                             self.config.n_experts)}
             # Force every cache to report the true prompt length so label
             # positions line up even though the buffer was written at 0..S.
-            caches = [
-                KVCache(c.keys, c.values, jnp.asarray(S, jnp.int32))
-                for c in caches
-            ]
+            caches = [c.with_length(S) for c in caches]
             last_logits = logits[:, 0]  # [B, V]
 
             def score_one(label_row, label_len):
@@ -625,8 +881,9 @@ class LlamaZeroShotClassifier(ClassifierBackend):
                     kv_pos - S <= jnp.arange(L)[None, None, :, None]
                 )
                 mask2 = prompt_part | label_part
-                logits2, _ = self.model.apply(
-                    {"params": params}, lab, pos, mask2, caches
+                (logits2, _), sown2 = self.model.apply(
+                    {"params": params}, lab, pos, mask2, caches,
+                    mutable=["intermediates"],
                 )
                 # token 0 scored from the prompt's last logits; tokens i>0
                 # from the label forward pass
@@ -644,19 +901,29 @@ class LlamaZeroShotClassifier(ClassifierBackend):
                 # shortest label ("Neutral" is one byte shorter than the
                 # other two under the byte tokenizer).
                 total = first_lp + rest_lp.sum(axis=1)
-                return total / jnp.maximum(label_len.astype(jnp.float32), 1.0)
+                chosen2 = _sown_by_layer(sown2, "chosen")
+                return (
+                    total / jnp.maximum(label_len.astype(jnp.float32), 1.0),
+                    _expert_ids(jnp.stack(chosen2), self.config.n_experts)
+                    if chosen2 else None,
+                )
 
-            scores = jax.vmap(score_one, in_axes=(0, 0), out_axes=1)(
-                label_ids, label_lens
-            )
-            return scores  # [B, 3]
+            scores, label_chosen = jax.vmap(
+                score_one, in_axes=(0, 0), out_axes=(1, 0)
+            )(label_ids, label_lens)
+            if label_chosen is not None:
+                stats["chosen_labels"] = label_chosen  # [3, layers, B, L, k]
+            return scores, stats  # [B, 3]
 
-        self._score_labels = _score_labels
+        from music_analyst_tpu.profiling.compile import profiled_jit
+
+        self._score_labels = profiled_jit(
+            _score_labels, name="llama_score_labels")
 
         @jax.jit
         def _decode_step(params, token, position, caches):
             B = token.shape[0]
-            kv_len = caches[0].keys.shape[1]
+            kv_len = caches[0].max_len
             kv_pos = jnp.arange(kv_len)[None, None, None, :]
             mask = kv_pos <= position[:, None, None, None]
             logits, caches = self.model.apply(
@@ -697,10 +964,7 @@ class LlamaZeroShotClassifier(ClassifierBackend):
                 {"params": params}, prompt_ids, positions, mask, caches,
                 last_position=prompt_lens - 1,
             )
-            caches = [
-                KVCache(c.keys, c.values, jnp.asarray(S, jnp.int32))
-                for c in caches
-            ]
+            caches = [c.with_length(S) for c in caches]
             first = jnp.argmax(logits[:, 0], axis=-1)  # [B]
             eos = jnp.asarray(self.tokenizer.eos_id, jnp.int32)
 
@@ -762,6 +1026,13 @@ class LlamaZeroShotClassifier(ClassifierBackend):
 
         self._generate_scan = _generate_scan
 
+    @property
+    def decode_runtime_refusal(self) -> Optional[str]:
+        """Why the continuous decode runtimes (``slot_runtime`` /
+        ``paged_runtime``) cannot host this model, or ``None`` where they
+        can.  ``serve`` reads it to leave the ``generate`` op off."""
+        return LATENT_CACHE_REFUSAL if self.config.latent_cache else None
+
     @classmethod
     def from_pretrained_or_random(cls, model: str, **kwargs):
         quant = "none"
@@ -791,7 +1062,8 @@ class LlamaZeroShotClassifier(ClassifierBackend):
 
     def _trim_prompt_pad(self, ids, lens):
         """Trim tokenizer padding to the smallest power-of-two width (floor
-        64) that covers the batch's longest prompt, capped at
+        ``config.prompt_width_floor``, 64 unless a preset says otherwise)
+        that covers the batch's longest prompt, capped at
         ``max_prompt_len``.
 
         The decoder analogue of the encoder's length buckets: a
@@ -804,7 +1076,8 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         from music_analyst_tpu.utils.shapes import round_pow2
 
         longest = int(lens.max()) if len(lens) else 1
-        width = min(round_pow2(longest, 64), self.max_prompt_len)
+        width = min(round_pow2(longest, self.config.prompt_width_floor),
+                    self.max_prompt_len)
         return ids[:, :width], lens
 
     def _encode_prompts(self, texts: Sequence[str]):
@@ -815,29 +1088,58 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         ids, lens = self.tokenizer.encode_batch(prompts, self.max_prompt_len)
         return self._trim_prompt_pad(ids, lens)
 
-    def classify_batch(self, texts: Sequence[str]) -> List[str]:
+    # Staged hooks for the prefetch pipeline (engines/sentiment.py): a
+    # step's read and tokenize overlap the device's work on the step
+    # before.  ``decode_mode="generate"`` has no staged form: its hooks
+    # pass the texts through and ``launch`` classifies synchronously.
+
+    def prepare(self, texts: Sequence[str]):
+        """Host phase: the prompts' token ids at the batch's trimmed
+        width, lengths narrowed for the wire."""
         if self.decode_mode == "generate":
-            return self.classify_batch_by_generation(texts)
+            return texts
+        from music_analyst_tpu.runtime.wire import narrow_lengths
+
         prompt_ids, prompt_lens = self._encode_prompts(texts)
         # Prompt lengths cross the wire int16 (llama's 128k vocab keeps the
         # ids themselves int32); widened on device in _score_labels.
-        from music_analyst_tpu.runtime.wire import (
-            count_h2d_bytes,
-            narrow_lengths,
-        )
+        return (texts, prompt_ids,
+                narrow_lengths(prompt_lens, self.max_prompt_len))
 
-        prompt_lens = narrow_lengths(prompt_lens, self.max_prompt_len)
+    def transfer(self, prepared):
+        if self.decode_mode == "generate":
+            return prepared
+        from music_analyst_tpu.runtime.wire import count_h2d_bytes
+
+        texts, prompt_ids, prompt_lens = prepared
         count_h2d_bytes([prompt_ids, prompt_lens])
-        scores = np.asarray(
-            self._score_labels(
-                self.params,
-                jnp.asarray(prompt_ids),
-                jnp.asarray(prompt_lens),
-                jnp.asarray(self._label_ids),
-                jnp.asarray(self._label_lens),
-            )
+        lens = prompt_lens.astype(np.int64)
+        # real prompt tokens, and their causal (query, key) pairs
+        real = (int(lens.sum()), int((lens * (lens + 1) // 2).sum()))
+        return (texts, jnp.asarray(prompt_ids), jnp.asarray(prompt_lens),
+                real)
+
+    def launch(self, transferred):
+        """Dispatch the scoring program (JAX async dispatch: the handle
+        holds device arrays, nothing blocks)."""
+        if self.decode_mode == "generate":
+            return self.classify_batch_by_generation(transferred)
+        texts, prompt_ids, prompt_lens, real = transferred
+        scores, stats = self._score_labels(
+            self.params, prompt_ids, prompt_lens,
+            jnp.asarray(self._label_ids), jnp.asarray(self._label_lens),
         )
-        best = scores.argmax(axis=1)
+        return texts, scores, stats, prompt_ids.shape, real
+
+    def submit(self, texts: Sequence[str]):
+        return self.launch(self.transfer(self.prepare(texts)))
+
+    def collect(self, handle) -> List[str]:
+        if isinstance(handle, list):  # generate mode classified in launch
+            return handle
+        texts, scores, stats, (rows, width), real = handle
+        best = np.asarray(scores).argmax(axis=1)
+        self._count_step(rows, width, real, stats)
         labels = []
         for text, idx in zip(texts, best):
             if not text.strip():
@@ -845,6 +1147,47 @@ class LlamaZeroShotClassifier(ClassifierBackend):
             else:
                 labels.append(SUPPORTED_LABELS[int(idx)])
         return labels
+
+    def _count_step(self, rows: int, width: int, real, stats) -> None:
+        """What one scoring step computed, into the run's telemetry:
+        ``decoder.tokens_real`` / ``decoder.tokens_computed`` (positions
+        that went through the layers: the prompt's, and each label's
+        tokens but its last, whose forward pass nothing reads; computed
+        includes padding), the routed layers' load, the latent cache's
+        size, and the step's shape and real token counts on the span the
+        engine has open (``compute``)."""
+        from music_analyst_tpu.telemetry import get_telemetry
+
+        tel = get_telemetry()
+        tokens_real, token_pairs = real
+        n_labels, label_width = self._label_ids.shape
+        label_real = int(np.maximum(self._label_lens - 1, 0).sum())
+        tel.count("decoder.tokens_real", tokens_real + rows * label_real)
+        tel.count("decoder.tokens_computed",
+                  rows * (width + n_labels * label_width))
+        attrs = dict(rows=rows, width=width, tokens_real=tokens_real,
+                     token_pairs=token_pairs,
+                     label_positions=n_labels * label_width,
+                     label_positions_real=label_real)
+        if stats:
+            load_max = np.asarray(stats["expert_load_max"], np.float64)
+            load_mean = np.asarray(stats["expert_load_mean"], np.float64)
+            tel.count("moe.assignments",
+                      int(load_mean.sum() * self.config.n_experts))
+            tel.count("moe.expert_load_max", int(load_max.sum()))
+            tel.count("moe.expert_load_mean", int(load_mean.sum()))
+            attrs["expert_load_max_over_mean"] = [
+                round(float(m / max(a, 1e-9)), 4)
+                for m, a in zip(load_max, load_mean)]
+        if self.config.latent_cache:
+            cfg = self.config
+            tel.gauge("latent_cache_bytes", int(
+                rows * (width + label_width) * cfg.n_layers * 2
+                * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)))
+        tel.current_span().set(**attrs)
+
+    def classify_batch(self, texts: Sequence[str]) -> List[str]:
+        return self.collect(self.submit(texts))
 
     def generate(
         self, prompt: str, max_new_tokens: int = 16
@@ -862,10 +1205,7 @@ class LlamaZeroShotClassifier(ClassifierBackend):
             {"params": self.params}, jnp.asarray(ids), positions, mask, caches,
             last_position=jnp.asarray(lens, jnp.int32) - 1,
         )
-        caches = [
-            KVCache(c.keys, c.values, jnp.asarray(int(lens[0]), jnp.int32))
-            for c in caches
-        ]
+        caches = [c.with_length(int(lens[0])) for c in caches]
         token = jnp.argmax(logits[:, 0], axis=-1)
         out_tokens = []
         position = jnp.asarray([int(lens[0])], jnp.int32)
@@ -929,6 +1269,9 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         """
         from music_analyst_tpu.ops.kv_slots import SlotDecodeRuntime, SlotPlan
 
+        if self.decode_runtime_refusal:
+            raise NotImplementedError(
+                self.decode_runtime_refusal.format(runtime="slot"))
         chunk = max(1, min(int(prefill_chunk), self.max_prompt_len))
         if prompt_region is None:
             prompt_region = self.max_prompt_len
@@ -976,6 +1319,9 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         from music_analyst_tpu.ops.kv_pages import PagedDecodeRuntime, PagePlan
         from music_analyst_tpu.utils.shapes import round_pow2
 
+        if self.decode_runtime_refusal:
+            raise NotImplementedError(
+                self.decode_runtime_refusal.format(runtime="paged"))
         chunk = max(1, min(int(prefill_chunk), self.max_prompt_len))
         if prompt_region is None:
             prompt_region = self.max_prompt_len

@@ -1,0 +1,241 @@
+"""Multi-head latent attention (MLA) with a latent cache.
+
+Keys and values are not projected from the hidden state directly: one
+down-projection gives, per token, a latent ``c_kv`` (``kv_lora_rank``
+values, RMS-normed) and one RoPE key ``k_rope`` shared by all heads; an
+up-projection ``W_kvb`` expands the latent to every head's ``k_nope`` and
+``v``.  Queries are ``[q_nope | q_rope]`` per head (no query low-rank here:
+``q_lora_rank`` null), and a head's key is ``[k_nope | k_rope]``, so the
+query/key width (``nope + rope``) differs from the value width.
+
+Two forms of the same mathematics, chosen from the shapes:
+
+* **expanded** — expand the latents to per-head keys and values and run
+  ordinary attention.  Cheapest when many queries share the expansion
+  (prefill).  The scores of one block of queries at a time are live, never
+  ``[B, H, S, S]``.
+* **absorbed** — fold ``W_uk`` (the ``k_nope`` half of ``W_kvb``) into the
+  query and ``W_uv`` (the ``v`` half) behind the weighted sum, so attention
+  runs over the latents themselves:
+  ``scores = (W_uk^T q_nope) . c_kv + q_rope . k_rope``,
+  ``out = W_uv (P c_kv)``.  Cheapest for a few queries against a long cache
+  (decode, label continuations): nothing per head is ever expanded.
+
+The cache (:class:`LatentCache`) holds what both forms read: ``c_kv`` after
+its norm and ``k_rope`` after RoPE, ``kv_lora_rank + rope`` values a token
+where expanded keys and values are ``heads * (nope + rope + v)``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from music_analyst_tpu.models.layers import (
+    RMSNorm,
+    apply_rope,
+    apply_rope_interleaved,
+    fan_in_normal,
+    rope_frequencies,
+)
+from music_analyst_tpu.profiling.compile import note_traced_path
+
+
+@dataclasses.dataclass
+class LatentCache:
+    """Per-layer MLA cache: ``latents [B, max_len, kv_lora_rank]`` (after
+    ``kv_a_norm``) and ``rope_keys [B, max_len, rope_dim]`` (after RoPE).
+    ``length`` is the scalar write offset every row shares, as in the
+    static-batch :class:`~music_analyst_tpu.models.layers.KVCache`."""
+
+    latents: jax.Array
+    rope_keys: jax.Array
+    length: jax.Array
+
+    @classmethod
+    def zeros(cls, batch: int, max_len: int, kv_lora_rank: int,
+              rope_dim: int, dtype=jnp.bfloat16) -> "LatentCache":
+        return cls(
+            latents=jnp.zeros((batch, max_len, kv_lora_rank), dtype),
+            rope_keys=jnp.zeros((batch, max_len, rope_dim), dtype),
+            length=jnp.zeros((), jnp.int32),
+        )
+
+    def update(self, c_new: jax.Array, r_new: jax.Array) -> "LatentCache":
+        start = self.length
+        latents = jax.lax.dynamic_update_slice(
+            self.latents, c_new.astype(self.latents.dtype), (0, start, 0)
+        )
+        rope_keys = jax.lax.dynamic_update_slice(
+            self.rope_keys, r_new.astype(self.rope_keys.dtype), (0, start, 0)
+        )
+        return LatentCache(latents, rope_keys, start + c_new.shape[1])
+
+    @property
+    def max_len(self) -> int:
+        return self.latents.shape[1]
+
+    def with_length(self, length) -> "LatentCache":
+        """The same buffers reporting ``length`` filled positions."""
+        return LatentCache(self.latents, self.rope_keys,
+                           jnp.asarray(length, jnp.int32))
+
+
+jax.tree_util.register_dataclass(
+    LatentCache, data_fields=["latents", "rope_keys", "length"],
+    meta_fields=[],
+)
+
+
+class Kernel(nn.Module):
+    """A bare ``kernel`` leaf under a module name, for a projection that is
+    applied in more than one contraction (``kv_b_proj``: expanded whole,
+    absorbed by halves)."""
+
+    shape: tuple
+    fan_in: int
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self) -> jax.Array:
+        return self.param("kernel", fan_in_normal(self.fan_in), self.shape,
+                          self.param_dtype)
+
+
+def blocked_attention(q_nope, q_rope, k_nope, k_rope, v, mask, scale: float,
+                      block_q: int) -> jax.Array:
+    """Attention with per-head keys ``[k_nope | k_rope]`` (``k_rope`` one
+    vector a token, shared by the heads) over blocks of ``block_q``
+    queries: one block's float32 scores are live at a time.
+
+    ``q_nope [B,Sq,H,Dn]``, ``q_rope [B,Sq,H,Dr]``, ``k_nope [B,Sk,H,Dn]``,
+    ``k_rope [B,Sk,Dr]``, ``v [B,Sk,H,Dv]``; ``mask`` broadcastable to
+    ``[B,1,Sq,Sk]`` or ``None``.  Returns ``[B,Sq,H,Dv]``.
+    """
+    batch, n_q = q_nope.shape[:2]
+
+    def attend(qn, qr, m):
+        scores = jnp.einsum("bqhd,bkhd->bhqk", qn, k_nope,
+                            preferred_element_type=jnp.float32)
+        scores = scores + jnp.einsum("bqhd,bkd->bhqk", qr, k_rope,
+                                     preferred_element_type=jnp.float32)
+        scores = scores * scale
+        if m is not None:
+            scores = jnp.where(m, scores, jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+    if n_q <= block_q or n_q % block_q:
+        return attend(q_nope, q_rope, mask)
+    n_blocks = n_q // block_q
+
+    def blocks(x, axis):
+        shape = x.shape[:axis] + (n_blocks, block_q) + x.shape[axis + 1:]
+        return jnp.moveaxis(x.reshape(shape), axis, 0)
+
+    operands = [blocks(q_nope, 1), blocks(q_rope, 1)]
+    if mask is not None:
+        mask = jnp.broadcast_to(
+            mask, mask.shape[:2] + (n_q, mask.shape[-1]))
+        operands.append(blocks(mask, 2))
+    out = jax.lax.map(
+        lambda args: attend(args[0], args[1],
+                            args[2] if mask is not None else None),
+        tuple(operands),
+    )                                                   # [n, B, block, H, Dv]
+    return jnp.moveaxis(out, 0, 1).reshape(batch, n_q, *out.shape[3:])
+
+
+class MLAttention(nn.Module):
+    """Latent attention block: projections, RoPE, both attention forms and
+    the output projection.  Returns ``out`` without a cache, ``(out,
+    new_cache)`` with one, as ``MultiHeadAttention`` does."""
+
+    n_heads: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    kv_lora_rank: int
+    rope_theta: float = 10_000.0
+    rope_interleave: bool = True
+    max_positions: int = 4096
+    norm_eps: float = 1e-6
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+    # With a cache, up to this many new queries take the absorbed form and
+    # more take the expanded one.  Absorbed costs each query
+    # ``heads * (rank + rope + rank)`` multiply-adds a cached token where
+    # expanded costs ``heads * (nope + rope + v)``, and expanded pays
+    # ``rank * heads * (nope + v)`` once a cached token: they cross at
+    # ``rank * (nope + v) / (2 * rank - nope - v)`` queries (171 at the
+    # published 512 / 128 / 128).
+    absorb_max_queries: int = 128
+    block_q: int = 128
+
+    @nn.compact
+    def __call__(self, x, mask=None, positions=None,
+                 cache: Optional[LatentCache] = None):
+        dim = x.shape[-1]
+        heads, nope, rope = (self.n_heads, self.qk_nope_head_dim,
+                             self.qk_rope_head_dim)
+        rank, v_dim = self.kv_lora_rank, self.v_head_dim
+        scale = (nope + rope) ** -0.5
+
+        q = nn.DenseGeneral(
+            features=(heads, nope + rope), use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, name="q_proj",
+        )(x)
+        kv_a = nn.Dense(
+            rank + rope, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, name="kv_a_proj",
+        )(x)
+        latents = RMSNorm(epsilon=self.norm_eps, name="kv_a_norm")(
+            kv_a[..., :rank])
+        w_kvb = Kernel((rank, heads, nope + v_dim), rank, self.param_dtype,
+                       name="kv_b_proj")().astype(self.dtype)
+
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
+        cos, sin = rope_frequencies(rope, self.max_positions, self.rope_theta)
+        rotate = apply_rope_interleaved if self.rope_interleave else apply_rope
+        q_nope = q[..., :nope]
+        q_rope = rotate(q[..., nope:], cos, sin, positions)
+        k_rope = rotate(kv_a[..., None, rank:], cos, sin, positions)[:, :, 0]
+
+        new_cache = None
+        if cache is not None:
+            new_cache = cache.update(latents, k_rope)
+            latents, k_rope = new_cache.latents, new_cache.rope_keys
+
+        if cache is not None and x.shape[1] <= self.absorb_max_queries:
+            note_traced_path("mla.absorbed")
+            q_abs = jnp.einsum("bqhd,rhd->bqhr", q_nope, w_kvb[..., :nope])
+            scores = jnp.einsum("bqhr,bkr->bhqk", q_abs, latents,
+                                preferred_element_type=jnp.float32)
+            scores = scores + jnp.einsum(
+                "bqhd,bkd->bhqk", q_rope, k_rope,
+                preferred_element_type=jnp.float32)
+            scores = scores * scale
+            if mask is not None:
+                scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
+            probs = jax.nn.softmax(scores, axis=-1).astype(self.dtype)
+            ctx = jnp.einsum("bhqk,bkr->bqhr", probs, latents)
+            out = jnp.einsum("bqhr,rhd->bqhd", ctx, w_kvb[..., nope:])
+        else:
+            note_traced_path("mla.expanded")
+            kv = jnp.einsum("bkr,rhd->bkhd", latents, w_kvb)
+            out = blocked_attention(
+                q_nope, q_rope, kv[..., :nope], k_rope, kv[..., nope:],
+                mask, scale, self.block_q,
+            )
+        out = nn.DenseGeneral(
+            features=dim, axis=(-2, -1), use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, name="o_proj",
+        )(out)
+        if cache is not None:
+            return out, new_cache
+        return out

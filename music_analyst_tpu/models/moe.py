@@ -193,3 +193,144 @@ class MoESwiGLU(nn.Module):
         mean_prob = probs.mean(axis=(0, 1))
         dispatch = jax.nn.one_hot(top_idx[..., 0], n_experts).mean(axis=(0, 1))
         return n_experts * jnp.sum(mean_prob * dispatch)
+
+
+def route_sigmoid_noaux(
+    logits: jax.Array, bias: jax.Array, top_k: int,
+    routed_scaling_factor: float, norm_topk_prob: bool = True,
+):
+    """``sigmoid`` scores with ``noaux_tc`` selection and no group stage
+    (``n_group = topk_group = 1``).
+
+    Choice: the ``top_k`` largest of ``s + bias`` where ``s =
+    sigmoid(logits)`` and ``bias`` is the per-expert
+    ``e_score_correction_bias``.  Weights: ``s`` itself (without the bias)
+    at the chosen experts, divided by their sum, times
+    ``routed_scaling_factor``.  ``logits [T, E]`` float32; returns
+    ``(indices [T, k] int32, weights [T, k] float32)``.
+    """
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    if norm_topk_prob:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), weights * routed_scaling_factor
+
+
+@jax.custom_batching.custom_vmap
+def grouped_experts(xt, chosen, weights, gate_w, up_w, down_w):
+    """``sum_k weights[t, k] * expert_{chosen[t, k]}(xt[t])`` for every
+    token, no capacity: assignments sorted by expert, one
+    ``jax.lax.ragged_dot`` a projection over the ragged groups, results
+    returned to token order and combined in float32.  ``xt [T, D]``,
+    ``chosen``/``weights [T, k]``, expert stacks ``[E, D, H]`` /
+    ``[E, H, D]``.  Returns ``[T, D]`` float32."""
+    T, D = xt.shape
+    k = chosen.shape[-1]
+    flat_expert = chosen.reshape(T * k)
+    order = jnp.argsort(flat_expert, stable=True)
+    group_sizes = jnp.zeros((gate_w.shape[0],), jnp.int32).at[
+        flat_expert].add(1)
+    xs = xt[order // k]                                           # [A, D]
+    gate = jax.lax.ragged_dot(xs, gate_w, group_sizes)
+    up = jax.lax.ragged_dot(xs, up_w, group_sizes)
+    ys = jax.lax.ragged_dot(nn.silu(gate) * up, down_w, group_sizes)
+    # back to assignment order (token-major), weighted sum in float32
+    y = ys[jnp.argsort(order)].reshape(T, k, D).astype(jnp.float32)
+    return jnp.einsum("tkd,tk->td", y, weights.astype(jnp.float32))
+
+
+@grouped_experts.def_vmap
+def _grouped_experts_vmap(axis_size, in_batched, xt, chosen, weights,
+                          gate_w, up_w, down_w):
+    """Tokens are independent, so a batch of token sets is one larger set
+    (``ragged_dot`` has no batching rule over its token axis): the label
+    continuations of ``_score_labels`` run as one grouped matmul."""
+    if any(in_batched[3:]):
+        raise NotImplementedError("grouped_experts: batched expert weights")
+    def merge(x, batched):
+        if not batched:
+            x = jnp.broadcast_to(x[None], (axis_size,) + x.shape)
+        return x.reshape((axis_size * x.shape[1],) + x.shape[2:])
+    out = grouped_experts(
+        merge(xt, in_batched[0]), merge(chosen, in_batched[1]),
+        merge(weights, in_batched[2]), gate_w, up_w, down_w)
+    return out.reshape((axis_size, -1) + out.shape[1:]), True
+
+
+class SigmoidRoutedMoE(nn.Module):
+    """Sigmoid-routed experts with **no capacity and no dropped token**,
+    plus shared experts every token passes through.
+
+    The assignments (token, expert) are sorted by expert and each of the
+    three projections is one grouped matrix multiplication over the ragged
+    groups (``jax.lax.ragged_dot``: on the TPU XLA lowers it to its grouped
+    matmul kernel, the work is ``top_k`` experts a token however uneven the
+    routing).  The results go back to token order and are combined with the
+    router's weights in float32.  ``n_shared`` shared experts are one
+    SwiGLU of width ``n_shared * hidden_dim``.
+
+    Sows ``expert_load`` (assignments each expert received, ``[E]`` int32)
+    and ``chosen`` (``[B, S, k]``) into the ``intermediates`` collection for
+    callers that ask for it.
+    """
+
+    n_experts: int
+    hidden_dim: int
+    top_k: int
+    n_shared: int = 0
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        from music_analyst_tpu.models.layers import SwiGLU, fan_in_normal
+        from music_analyst_tpu.profiling.compile import note_traced_path
+
+        B, S, D = x.shape
+        E, H, k = self.n_experts, self.hidden_dim, self.top_k
+        T, A = B * S, B * S * k
+        gate_w = self.param("gate_experts", fan_in_normal(D), (E, D, H),
+                            self.param_dtype)
+        up_w = self.param("up_experts", fan_in_normal(D), (E, D, H),
+                          self.param_dtype)
+        down_w = self.param("down_experts", fan_in_normal(H), (E, H, D),
+                            self.param_dtype)
+        # The router stays float32 at the highest matmul precision: its
+        # cost is a sliver, and a rounding there changes *which* experts
+        # run, not how precisely.
+        router_w = self.param("router", fan_in_normal(D), (D, E),
+                              jnp.float32)
+        bias = self.param(
+            "e_score_correction_bias",
+            lambda key, shape, dtype: 0.01 * jax.random.normal(
+                key, shape, dtype),
+            (E,), jnp.float32,
+        )
+        xt = x.reshape(T, D).astype(self.dtype)
+
+        with jax.named_scope("moe.route"):
+            logits = jnp.dot(xt.astype(jnp.float32), router_w,
+                             precision=jax.lax.Precision.HIGHEST)
+            chosen, weights = route_sigmoid_noaux(
+                logits, bias, k, self.routed_scaling_factor,
+                self.norm_topk_prob)
+
+        with jax.named_scope("moe.experts"):
+            note_traced_path("moe.grouped")
+            self.sow("intermediates", "expert_load",
+                     jnp.zeros((E,), jnp.int32).at[chosen.reshape(A)].add(1))
+            self.sow("intermediates", "chosen", chosen.reshape(B, S, k))
+            out = grouped_experts(
+                xt, chosen, weights, gate_w.astype(self.dtype),
+                up_w.astype(self.dtype), down_w.astype(self.dtype))
+
+        if self.n_shared:
+            with jax.named_scope("moe.shared"):
+                out = out + SwiGLU(
+                    self.n_shared * H, dtype=self.dtype,
+                    param_dtype=self.param_dtype, name="shared_experts",
+                )(xt).astype(jnp.float32)
+        return out.reshape(B, S, D).astype(x.dtype)

@@ -363,18 +363,56 @@ class HFTokenizerAdapter:
         )
 
 
+class HashWordLMTokenizer(HashWordTokenizer):
+    """Offline decoder tokenizer over a large vocabulary: BOS, then
+    :class:`HashWordTokenizer`'s word rule (about one token a word or
+    mark), each word hashed over the whole id range.  The byte tokenizer
+    would touch 259 rows of a 128k-row embedding and make every prompt
+    four times a BPE's length.  Ids do not invert: ``decode`` names them.
+    """
+
+    # One token a word: the classifier closes a label continuation with
+    # EOS so that it has a second token to score (models/llama.py).
+    closes_labels = True
+
+    def __init__(self, vocab_size: int) -> None:
+        super().__init__(vocab_size, pad_id=0, reserved=16)
+        self.bos_id, self.eos_id = 1, 2
+        self._ids: dict = {}  # word bytes -> id (lyrics repeat their words)
+
+    def _hash_id(self, data: bytes) -> int:
+        found = self._ids.get(data)
+        if found is None:
+            found = self._ids[data] = super()._hash_id(data)
+        return found
+
+    def encode(self, text: str, max_len: int) -> Tuple[np.ndarray, int]:
+        ids = [self.bos_id] + self._token_ids(text, max_len - 1)
+        out = np.full(max_len, self.pad_id, dtype=np.int32)
+        out[: len(ids)] = ids
+        return out, len(ids)
+
+    def decode(self, ids: Sequence[int]) -> str:
+        return " ".join(f"<{int(i)}>" for i in ids
+                        if int(i) not in (self.pad_id, self.bos_id,
+                                          self.eos_id))
+
+
 def resolve_llama_tokenizer(
-    vocab_size: int, path: Optional[str] = None
+    vocab_size: int, path: Optional[str] = None, kind: str = "byte"
 ):
     """Best-available decoder tokenizer.
 
     A local HF tokenizer directory (``$MUSICAAL_LLAMA_TOKENIZER``) gives
-    exact Llama-3 BPE for real checkpoints; otherwise the byte tokenizer
-    keeps everything runnable offline.
+    exact BPE for real checkpoints; otherwise the offline tokenizer the
+    configuration names (``kind``: ``"byte"`` or ``"hash_word"``) keeps
+    everything runnable.
     """
     path = path or os.environ.get("MUSICAAL_LLAMA_TOKENIZER")
     if path and os.path.exists(path):
         return HFTokenizerAdapter(path)
+    if kind == "hash_word":
+        return HashWordLMTokenizer(vocab_size)
     return ByteTokenizer(vocab_size)
 
 

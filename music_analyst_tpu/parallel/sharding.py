@@ -28,13 +28,19 @@ TP_RULES: List[Tuple[str, P]] = [
     (r".*(q_proj|k_proj|v_proj)/kernel$", P(None, "tp", None)),
     # attention bias [heads, head_dim] (BERT family) — shard heads to match
     (r".*(q_proj|k_proj|v_proj)/bias$", P("tp", None)),
+    # latent attention: the up-projection [rank, heads, nope + v] shards
+    # its heads like q_proj; the down-projection (kv_a_proj, one latent and
+    # one RoPE key a token, shared by every head) matches no rule and
+    # replicates, as does the latent cache it fills
+    (r".*kv_b_proj/kernel$", P(None, "tp", None)),
     # output proj: kernel [heads, head_dim, dim] — shard input heads
     (r".*o_proj/kernel$", P("tp", None, None)),
     # gated MLP: [dim, hidden] / [hidden, dim]
     (r".*(gate_proj|up_proj)/kernel$", P(None, "tp")),
     (r".*down_proj/kernel$", P("tp", None)),
     # MoE expert stacks: [E, dim, hidden] / [E, hidden, dim] — expert axis
-    # over ep, hidden over tp; router replicated (matches no rule)
+    # over ep, hidden over tp; router and e_score_correction_bias
+    # replicated (match no rule); shared experts are a SwiGLU (rules above)
     (r".*(gate_experts|up_experts)$", P("ep", None, "tp")),
     (r".*down_experts$", P("ep", "tp", None)),
     # BERT-style MLP

@@ -55,6 +55,19 @@ def note_attention_path(path: str) -> None:
         paths[path] = paths.get(path, 0) + 1
 
 
+def note_traced_path(path: str) -> None:
+    """Say, at trace time, which form of a layer a program took where the
+    shapes decide it (``mla.expanded`` / ``mla.absorbed``,
+    ``moe.grouped``): bumps the counter ``traced.<path>`` and lands in the
+    compile record's ``traced_paths`` ({path: layers that took it})."""
+    from music_analyst_tpu.telemetry import get_telemetry
+
+    get_telemetry().count(f"traced.{path}")
+    paths = getattr(_TRACING, "traced_paths", None)
+    if paths is not None:
+        paths[path] = paths.get(path, 0) + 1
+
+
 def _leaf_sig(leaf: Any) -> str:
     shape = getattr(leaf, "shape", None)
     dtype = getattr(leaf, "dtype", None)
@@ -119,7 +132,7 @@ class CompileRecord:
     __slots__ = (
         "name", "aval_key", "flops", "bytes_accessed", "temp_bytes",
         "argument_bytes", "output_bytes", "hlo_fingerprint",
-        "compile_seconds", "param_bytes", "attention_paths",
+        "compile_seconds", "param_bytes", "attention_paths", "traced_paths",
     )
 
     def __init__(self, name: str, aval_key: str) -> None:
@@ -138,6 +151,8 @@ class CompileRecord:
         # {path: layers} noted while this program was traced
         # (note_attention_path); empty for a program without attention.
         self.attention_paths: Dict[str, int] = {}
+        # {path: layers} noted by note_traced_path; empty for most programs.
+        self.traced_paths: Dict[str, int] = {}
 
     def as_dict(self) -> Dict[str, Any]:
         return {
@@ -152,6 +167,7 @@ class CompileRecord:
             "compile_seconds": round(self.compile_seconds, 6),
             "param_bytes": self.param_bytes,
             "attention_paths": self.attention_paths,
+            "traced_paths": self.traced_paths,
         }
 
 
@@ -207,15 +223,19 @@ class ProfiledFunction:
             from music_analyst_tpu.resilience.policy import RetryPolicy
 
             attention_paths: Dict[str, int] = {}
+            traced_paths: Dict[str, int] = {}
 
             def _lower_and_compile():
                 fault_point("compile.first", fn=self.name)
                 attention_paths.clear()
+                traced_paths.clear()
                 _TRACING.attention_paths = attention_paths
+                _TRACING.traced_paths = traced_paths
                 try:
                     low = self._jit.lower(*args, **kwargs)
                 finally:
                     _TRACING.attention_paths = None
+                    _TRACING.traced_paths = None
                 return low, low.compile()
 
             t0 = time.perf_counter()
@@ -239,6 +259,7 @@ class ProfiledFunction:
         rec = self._record(key, lowered, compiled, seconds)
         rec.param_bytes = _wq_param_bytes(args, kwargs)
         rec.attention_paths = attention_paths
+        rec.traced_paths = traced_paths
         prior = list(self.records)
         self.records[key] = rec
         tel.count("profiling.compiles")

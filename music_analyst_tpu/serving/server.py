@@ -789,7 +789,15 @@ def run_server(
         # the backend exposes a slot runtime (capability probe) and slots
         # weren't explicitly disabled with --slots=0.
         decode = None
-        if hasattr(clf, "slot_runtime") and (slots is None or slots > 0):
+        refusal = getattr(clf, "decode_runtime_refusal", None)
+        if refusal and not quiet:
+            # The batched ops (``sentiment``) serve; ``generate`` needs a
+            # decode runtime this model cannot have yet.
+            print("serve: generate op off: "
+                  + refusal.format(runtime="continuous decode"),
+                  file=sys.stderr)
+        if (hasattr(clf, "slot_runtime") and not refusal
+                and (slots is None or slots > 0)):
             from music_analyst_tpu.serving.decode_loop import (
                 ContinuousScheduler,
             )

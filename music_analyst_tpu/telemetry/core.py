@@ -311,6 +311,14 @@ class Telemetry:
             stack.pop()
             self._record_span(sp)
 
+    def current_span(self) -> Any:
+        """The innermost span open on this thread (a do-nothing handle
+        where none is, or telemetry is off): lets the code a span wraps
+        attach what it learns (``collect`` puts a batch's width and token
+        counts on the engine's ``compute`` span)."""
+        stack = self._stack() if self.enabled else None
+        return stack[-1] if stack else _NULL_SPAN
+
     def record_span(
         self,
         name: str,

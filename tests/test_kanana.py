@@ -1,0 +1,590 @@
+"""The ``kanana`` decoder kinds (latent attention with a latent cache,
+sigmoid-routed experts with no dropped token, shared experts) against the
+plain float32 reference ``perfbench/reference/deepseek_v3_f32.py``, at the
+``kanana-tiny`` size with seeded weights.
+
+Layer tests run the program's modules in float32 on the reference's own
+inputs, so they hold the equations (tolerance: float32 rounding).  The
+end-to-end tests run the system as it is served, bfloat16, and hold it to
+``TEST_TOLERANCE``, which is set from the measured bfloat16 differences and
+is tight enough that int8 experts or a dropped shared expert fail it.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if os.path.join(REPO, "perfbench") not in sys.path:
+    sys.path.insert(0, os.path.join(REPO, "perfbench"))
+
+from reference import deepseek_v3_f32 as ref  # noqa: E402
+
+from music_analyst_tpu.models.layers import causal_mask  # noqa: E402
+from music_analyst_tpu.models.llama import (  # noqa: E402
+    PRESETS,
+    LlamaConfig,
+    LlamaZeroShotClassifier,
+    init_caches,
+)
+from music_analyst_tpu.models.mla import LatentCache, MLAttention  # noqa: E402
+from music_analyst_tpu.models.moe import (  # noqa: E402
+    SigmoidRoutedMoE,
+    route_sigmoid_noaux,
+)
+
+F32_TOL = 2e-4  # float32 program against float32 reference
+
+
+def _preset(name):
+    path = os.path.join(REPO, "music_analyst_tpu", "models", "presets",
+                        name + ".json")
+    with open(path, encoding="utf-8") as fh:
+        return json.load(fh)
+
+
+HF = _preset("kanana-tiny")
+
+_WORDS = ("love rain night baby tears dance road fire cold heart sun blue "
+          "you me the and never always gone stay").split()
+
+
+def _lyrics(seed: int, rows: int):
+    rng = np.random.default_rng(seed)
+    return [" ".join(rng.choice(_WORDS, size=int(n)))
+            for n in rng.integers(5, 400, size=rows)]
+
+
+# 12 seeded lyrics of 5 to 400 words (several past 128 tokens, so the
+# prefill is the expanded form in blocks), and the empty lyric.
+LYRICS = _lyrics(0, 12) + [""]
+
+
+@pytest.fixture(scope="module")
+def clf():
+    from music_analyst_tpu.engines.sentiment import get_backend
+
+    return get_backend("kanana-tiny")
+
+
+def _f32(tree):
+    return jax.tree_util.tree_map(lambda a: jnp.asarray(a, jnp.float32), tree)
+
+
+def _mla(cfg: LlamaConfig, **kw):
+    return MLAttention(
+        n_heads=cfg.n_heads, qk_nope_head_dim=cfg.qk_nope_head_dim,
+        qk_rope_head_dim=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
+        kv_lora_rank=cfg.kv_lora_rank, rope_theta=cfg.rope_theta,
+        rope_interleave=cfg.rope_interleave, max_positions=cfg.max_seq_len,
+        norm_eps=cfg.rms_norm_eps, dtype=jnp.float32, **kw)
+
+
+def _moe(cfg: LlamaConfig, dtype=jnp.float32):
+    return SigmoidRoutedMoE(
+        cfg.n_experts, cfg.moe_hidden_dim, cfg.moe_top_k,
+        n_shared=cfg.n_shared_experts,
+        routed_scaling_factor=cfg.routed_scaling_factor,
+        norm_topk_prob=cfg.norm_topk_prob, dtype=dtype)
+
+
+def _hidden(rows, n_tok, dim, seed=0):
+    return jax.random.normal(jax.random.key(seed), (rows, n_tok, dim),
+                             jnp.float32)
+
+
+# ----------------------------------------------------------- configuration
+
+def test_presets_are_built_from_their_files(clf):
+    cfg = clf.config
+    assert (cfg.attention, cfg.moe_router) == ("mla", "sigmoid_noaux")
+    assert (cfg.n_layers, cfg.first_k_dense_replace) == (3, 1)
+    assert [cfg.routed_layer(i) for i in range(3)] == [False, True, True]
+    assert (cfg.n_experts, cfg.moe_top_k, cfg.n_shared_experts) == (8, 2, 1)
+    assert (cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+            cfg.v_head_dim) == (16, 16, 8, 16)
+    big = PRESETS["kanana-2-30b-a3b"]()
+    published = _preset("kanana-2-30b-a3b")
+    assert (big.dim, big.n_heads, big.hidden_dim) == (2048, 32, 6144)
+    assert (big.n_experts, big.moe_hidden_dim, big.moe_top_k,
+            big.n_shared_experts) == (128, 768, 6, 2)
+    assert (big.kv_lora_rank, big.qk_nope_head_dim, big.qk_rope_head_dim,
+            big.v_head_dim, big.vocab_size) == (512, 128, 64, 128, 128256)
+    assert big.n_layers == published["num_hidden_layers"] == 7
+    assert big.param_dtype == big.dtype == "bfloat16"
+    # one compiled width at the published size; the test size trims
+    assert (big.prompt_width_floor, cfg.prompt_width_floor) == (1024, 64)
+    short, _ = clf._encode_prompts(["la la"])
+    assert short.shape[1] == 64
+
+
+@pytest.mark.parametrize("key,value", [
+    ("q_lora_rank", 1536), ("n_group", 8), ("scoring_func", "softmax"),
+    ("topk_method", "greedy"), ("rope_scaling", {"type": "yarn"}),
+    ("model_type", "llama"),
+])
+def test_unsupported_configuration_is_refused_by_name(key, value):
+    with pytest.raises(ValueError, match=key):
+        LlamaConfig.from_hf_config({**HF, key: value})
+
+
+def test_init_is_bfloat16_on_device_with_a_live_correction_bias(clf):
+    leaves = jax.tree_util.tree_leaves_with_path(clf.params)
+    for path, leaf in leaves:
+        name = jax.tree_util.keystr(path)
+        if any(k in name for k in ("scale", "router", "correction_bias")):
+            assert leaf.dtype == jnp.float32, name
+        else:
+            assert leaf.dtype == jnp.bfloat16, name
+    moe = clf.params["layer_1"]["feed_forward_moe"]
+    bias = np.asarray(moe["e_score_correction_bias"])
+    assert 0 < np.abs(bias).max() < 0.1
+    # the two routed layers are the same program run on different keys
+    other = clf.params["layer_2"]["feed_forward_moe"]
+    assert not np.array_equal(np.asarray(moe["gate_experts"], np.float32),
+                              np.asarray(other["gate_experts"], np.float32))
+    std = float(np.asarray(moe["gate_experts"], np.float32).std())
+    assert abs(std - clf.config.dim ** -0.5) < 0.02
+
+
+def test_hash_word_tokenizer_covers_the_vocabulary():
+    from music_analyst_tpu.models.tokenization import HashWordLMTokenizer
+
+    tok = HashWordLMTokenizer(128_256)
+    text = "Hold me tight, don't let go! " * 40
+    ids, n = tok.encode(text, 1024)
+    words = len(text.split())
+    assert ids[0] == tok.bos_id and n - 1 <= 2 * words  # ~1 token a word/mark
+    assert ids[:n].max() < 128_256 and ids[1:n].min() >= 16
+    assert len(set(ids[1:n].tolist())) >= 7
+    again, _ = tok.encode(text, 1024)
+    assert np.array_equal(ids, again)
+    short, m = tok.encode(text, 32)
+    assert m == 32 and np.array_equal(short, ids[:32])
+    assert tok.decode([5, 17, tok.eos_id]) == "<5> <17>"
+
+
+# ------------------------------------------------------------ layer kinds
+
+def test_mla_expanded_matches_reference(clf):
+    cfg = clf.config
+    p = _f32(clf.params["layer_1"]["attention"])
+    h = _hidden(2, 256, cfg.dim)
+    positions = jnp.broadcast_to(jnp.arange(256), (2, 256))
+    want = ref.mla_attention(p, h, positions, HF)
+    # 256 queries in blocks of 128: the blocked form
+    got = _mla(cfg).apply({"params": p}, h, causal_mask(256, 256, 0),
+                          positions)
+    assert float(jnp.abs(got - want).max()) < F32_TOL
+    whole = _mla(cfg, block_q=512).apply(
+        {"params": p}, h, causal_mask(256, 256, 0), positions)
+    assert float(jnp.abs(got - whole).max()) < F32_TOL
+
+
+def test_mla_absorbed_through_the_cache_equals_expanded(clf):
+    cfg = clf.config
+    p = _f32(clf.params["layer_2"]["attention"])
+    rows, n_prompt, n_new = 2, 24, 5
+    total = n_prompt + n_new
+    h = _hidden(rows, total, cfg.dim, seed=3)
+    positions = jnp.broadcast_to(jnp.arange(total), (rows, total))
+    want = ref.mla_attention(p, h, positions, HF)
+
+    def run(absorb_max_queries):
+        mla = _mla(cfg, absorb_max_queries=absorb_max_queries)
+        cache = LatentCache.zeros(rows, total, cfg.kv_lora_rank,
+                                  cfg.qk_rope_head_dim, jnp.float32)
+        out, cache = mla.apply(
+            {"params": p}, h[:, :n_prompt],
+            causal_mask(n_prompt, total, 0), positions[:, :n_prompt], cache)
+        outs = [out]
+        for t in range(n_prompt, total):  # one token at a time
+            mask = (jnp.arange(total) <= t)[None, None, None, :]
+            out, cache = mla.apply({"params": p}, h[:, t:t + 1], mask,
+                                   positions[:, t:t + 1], cache)
+            outs.append(out)
+        assert int(cache.length) == total
+        return jnp.concatenate(outs, axis=1)
+
+    absorbed, expanded = run(128), run(0)
+    assert float(jnp.abs(absorbed - want).max()) < F32_TOL
+    assert float(jnp.abs(expanded - want).max()) < F32_TOL
+    # the cache holds kv_lora_rank + rope values a token
+    cache = init_caches(cfg, 2, 10)[0]
+    assert isinstance(cache, LatentCache)
+    assert cache.latents.nbytes + cache.rope_keys.nbytes == 2 * 10 * 24 * 2
+
+
+def test_router_choice_and_weights_match_reference(clf):
+    cfg = clf.config
+    p = clf.params["layer_1"]["feed_forward_moe"]
+    h = _hidden(4, 64, cfg.dim, seed=5)
+    logits = h.reshape(-1, cfg.dim) @ p["router"]
+    chosen, weights = route_sigmoid_noaux(
+        logits, p["e_score_correction_bias"], cfg.moe_top_k,
+        cfg.routed_scaling_factor)
+    _, want_chosen, combine, _ = ref.route(p, h.reshape(-1, cfg.dim), HF)
+    assert np.array_equal(np.sort(chosen, -1), np.sort(want_chosen, -1))
+    got = np.zeros(combine.shape, np.float32)
+    np.put_along_axis(got, np.asarray(chosen), np.asarray(weights), -1)
+    assert np.abs(got - np.asarray(combine)).max() < 1e-5
+    assert np.allclose(np.asarray(weights).sum(-1),
+                       cfg.routed_scaling_factor, atol=1e-4)
+
+
+def test_correction_bias_moves_the_choice_and_not_the_weight():
+    # sigmoid scores: experts 0, 1 lead; the bias lifts expert 3 over 1
+    logits = jnp.asarray([[2.0, 1.0, -1.0, 0.9]])
+    none = jnp.zeros(4)
+    bias = jnp.asarray([0.0, 0.0, 0.0, 0.05])
+    plain, w_plain = route_sigmoid_noaux(logits, none, 2, 1.0)
+    moved, w_moved = route_sigmoid_noaux(logits, bias, 2, 1.0)
+    assert sorted(np.asarray(plain)[0].tolist()) == [0, 1]
+    assert sorted(np.asarray(moved)[0].tolist()) == [0, 3]
+    s = np.asarray(jax.nn.sigmoid(logits))[0]
+    order = np.asarray(moved)[0].tolist()
+    want = np.asarray([s[e] for e in order]) / (s[0] + s[3])
+    assert np.allclose(np.asarray(w_moved)[0], want, atol=1e-6)  # no bias in
+    assert not np.allclose(np.asarray(w_moved)[0].sum() * (s[0] + s[3]),
+                           s[0] + s[3] + 0.05)
+
+
+def test_experts_drop_nothing_under_the_most_uneven_routing(clf):
+    cfg = clf.config
+    p = dict(_f32(clf.params["layer_1"]["feed_forward_moe"]))
+    # a bias this large sends every token to experts 6 and 2
+    p["e_score_correction_bias"] = jnp.zeros(cfg.n_experts).at[
+        jnp.asarray([6, 2])].set(10.0)
+    h = _hidden(3, 40, cfg.dim, seed=7)
+    want, chosen, _ = ref.moe_ffn(p, h, HF)
+    assert set(np.asarray(chosen).reshape(-1).tolist()) == {2, 6}
+    got, sown = _moe(cfg).apply({"params": p}, h, mutable=["intermediates"])
+    assert float(jnp.abs(got - want).max()) < F32_TOL
+    load = np.asarray(sown["intermediates"]["expert_load"][0])
+    assert load.tolist() == [0, 0, 120, 0, 0, 0, 120, 0]  # all 120 tokens
+    # and under the seeded routing
+    q = _f32(clf.params["layer_2"]["feed_forward_moe"])
+    want, _, _ = ref.moe_ffn(q, h, HF)
+    got, sown = _moe(cfg).apply({"params": q}, h, mutable=["intermediates"])
+    assert float(jnp.abs(got - want).max()) < F32_TOL
+    assert int(sown["intermediates"]["expert_load"][0].sum()) == 120 * 2
+
+
+def test_shared_experts_reach_every_token(clf):
+    cfg = clf.config
+    p = dict(_f32(clf.params["layer_1"]["feed_forward_moe"]))
+    p["down_experts"] = jnp.zeros_like(p["down_experts"])  # routed part off
+    h = _hidden(2, 16, cfg.dim, seed=9)
+    got = _moe(cfg).apply({"params": p}, h)
+    want = ref.swiglu(p["shared_experts"], h)
+    assert float(jnp.abs(want).max()) > 0.1
+    assert float(jnp.abs(got - want).max()) < F32_TOL
+
+
+def test_router_ties_are_counted_not_hidden(clf):
+    """bfloat16 activations against float32: a token whose k-th and
+    (k+1)-th corrected scores are further apart than ``margin`` chooses the
+    same experts; the others are counted, and are few."""
+    cfg = clf.config
+    p = clf.params["layer_1"]["feed_forward_moe"]
+    h = _hidden(8, 128, cfg.dim, seed=11).reshape(-1, cfg.dim)
+    scores, want, _, _ = ref.route(p, h, HF)
+    corrected = np.sort(np.asarray(scores) + np.asarray(
+        p["e_score_correction_bias"]), -1)
+    gap = corrected[:, -cfg.moe_top_k] - corrected[:, -cfg.moe_top_k - 1]
+    logits = jnp.dot(h.astype(jnp.bfloat16).astype(jnp.float32), p["router"],
+                     precision=jax.lax.Precision.HIGHEST)
+    got, _ = route_sigmoid_noaux(logits, p["e_score_correction_bias"],
+                                 cfg.moe_top_k, cfg.routed_scaling_factor)
+    same = (np.sort(np.asarray(got), -1)
+            == np.sort(np.asarray(want), -1)).all(-1)
+    margin = 0.01
+    assert same[gap > margin].all()
+    close = int((gap <= margin).sum())
+    assert 0 < close < 0.15 * len(gap)
+    assert int((~same).sum()) <= close
+
+
+# ------------------------------------------------------------- end to end
+
+def _prompts(clf, texts):
+    ids, lens = clf._encode_prompts(texts)
+    return np.asarray(ids), np.asarray(lens)
+
+
+def _program(clf, params, ids, lens):
+    """Label scores and the experts every position ran, from the scoring
+    program as it is served."""
+    scores, stats = clf._score_labels(
+        params, jnp.asarray(ids), jnp.asarray(lens),
+        jnp.asarray(clf._label_ids), jnp.asarray(clf._label_lens))
+    prefer = ref.prefer_from_system(stats["chosen"], stats["chosen_labels"],
+                                    lens)
+    return np.asarray(scores, np.float64), prefer
+
+
+def _program_scores(clf, params, ids, lens):
+    return _program(clf, params, ids, lens)[0]
+
+
+def _reference(clf, ids, lens, prefer=None, **kw):
+    return ref.label_scores(
+        clf.params, HF, ids, lens, clf._label_ids, clf._label_lens,
+        prefer=prefer, margin=ref.TEST_TOLERANCE["route_margin"], **kw)
+
+
+@pytest.fixture(scope="module")
+def scored(clf):
+    ids, lens = _prompts(clf, LYRICS)
+    assert ids.shape[1] == 512 and lens.max() > 128  # expanded, blocked
+    got, prefer = _program(clf, clf.params, ids, lens)
+    return ids, lens, got, _reference(clf, ids, lens, prefer)
+
+
+def test_label_scores_through_the_latent_cache_match_reference(clf, scored):
+    """Router ties are the system's to break (the reference takes its
+    experts where they lie within the margin and counts the rest); given
+    equal choices the scores agree to bfloat16's rounding."""
+    ids, lens, got, want = scored
+    tol = ref.TEST_TOLERANCE
+    routing = want["routing"]
+    assert routing["compared"] == 2 * 3 * int((lens + 8).sum())
+    assert 0 < routing["differ"] < 0.05 * routing["compared"]
+    assert routing["wrong"] == tol["wrong_choices"]
+    assert routing["deepest_tie"] < tol["route_margin"]
+    diff = np.abs(got - want["scores"])
+    assert np.median(diff) < tol["label_score_median"], diff
+    assert diff.max() < tol["label_score_max"], diff
+    # left to its own choices the reference drifts off on the flipped rows
+    free = _reference(clf, ids, lens)
+    assert np.abs(got - free["scores"]).max() > diff.max()
+    record = list(clf._score_labels.records.values())[-1]
+    assert record.traced_paths["mla.expanded"] == 3      # the prefill
+    assert record.traced_paths["mla.absorbed"] == 3      # label passes
+    assert record.traced_paths["moe.grouped"] == 4       # 2 layers x 2
+
+
+def test_prefill_last_position_logits_match_reference(clf, scored):
+    ids, lens, _, want = scored
+    rows, width = ids.shape
+    caches = init_caches(clf.config, rows, width + 8)
+    positions = jnp.broadcast_to(jnp.arange(width), (rows, width))
+    mask = causal_mask(width, width + 8, 0)
+    logits, _ = clf.model.apply(
+        {"params": clf.params}, jnp.asarray(ids), positions, mask, caches,
+        last_position=jnp.asarray(lens) - 1)
+    diff = np.abs(np.asarray(logits[:, 0], np.float64) - want["last_logits"])
+    assert np.median(diff) < ref.TEST_TOLERANCE["last_logit_median"]
+
+
+def _int8_rounded(w):
+    w = jnp.asarray(w, jnp.float32)
+    scale = jnp.max(jnp.abs(w), axis=1, keepdims=True) / 127.0
+    return (jnp.round(w / scale) * scale).astype(jnp.bfloat16)
+
+
+def test_a_dropped_shared_expert_and_int8_fail_the_tolerance(clf, scored):
+    """End to end: a system that leaves the shared experts out fails the
+    score limits, and the reference computed in int8 (the precision below
+    the configuration's) fails by its choices and by the median."""
+    ids, lens, _, want = scored
+    tol = ref.TEST_TOLERANCE
+    params = jax.tree_util.tree_map(lambda a: a, clf.params)
+    for i in (1, 2):
+        moe = dict(params[f"layer_{i}"]["feed_forward_moe"])
+        shared = dict(moe["shared_experts"])
+        shared["down_proj"] = {"kernel": jnp.zeros_like(
+            shared["down_proj"]["kernel"])}
+        moe["shared_experts"] = shared
+        params[f"layer_{i}"] = {**params[f"layer_{i}"],
+                                "feed_forward_moe": moe}
+    got, prefer = _program(clf, params, ids, lens)
+    diff = np.abs(got - _reference(clf, ids, lens, prefer)["scores"])
+    assert np.median(diff) > 3 * tol["label_score_median"]
+    assert diff.max() > tol["label_score_max"]
+    low = _reference(clf, ids, lens, variant="int8")
+    judged = _reference(clf, ids, lens, low["chosen"])
+    assert judged["routing"]["wrong"] > tol["wrong_choices"]
+    assert np.median(np.abs(low["scores"] - judged["scores"])) > tol[
+        "label_score_median"]
+
+
+def test_expert_layer_in_bfloat16_meets_a_limit_int8_experts_fail(clf):
+    """The expert layer as served (bfloat16) against the reference, by the
+    median over tokens of a token's largest error over the output's RMS;
+    the same layer with its expert weights rounded to int8 fails."""
+    cfg = clf.config
+    tol = ref.TEST_TOLERANCE["expert_layer_median"]
+    p = clf.params["layer_1"]["feed_forward_moe"]
+    h = _hidden(8, 64, cfg.dim, seed=1).astype(jnp.bfloat16)
+    want, _, _ = ref.moe_ffn(p, h.astype(jnp.float32), HF)
+    rms = float(jnp.sqrt(jnp.mean(want ** 2)))
+
+    def reading(params):
+        got = _moe(cfg, dtype=jnp.bfloat16).apply({"params": params}, h)
+        err = np.abs(np.asarray(got, np.float32) - np.asarray(want)) / rms
+        return float(np.median(err.max(-1)))
+
+    int8 = dict(p)
+    for name in ("gate_experts", "up_experts", "down_experts"):
+        int8[name] = _int8_rounded(p[name])
+    assert reading(p) < tol < reading(int8)
+
+
+def test_generate_batch_logits_step_by_step_match_full_forward(clf):
+    """``generate_batch`` (prefill, then one token a step through the
+    latent cache, one program) against the reference's full forward: the
+    same steps taken one at a time, fed the tokens ``generate_batch`` chose,
+    give logits that match the full forward at every step, and every chosen
+    token is the largest logit up to rounding."""
+    prompts = ["hold me close tonight", "rain on the window, " * 6]
+    steps = 4
+    texts = clf.generate_batch(prompts, max_new_tokens=steps,
+                               early_exit=False)
+    tokens = np.asarray([[int(t.strip("<>")) for t in text.split()]
+                         for text in texts])
+    assert tokens.shape == (2, steps)
+    ids, lens = clf.tokenizer.encode_batch(prompts, clf.max_prompt_len)
+    ids, lens = clf._trim_prompt_pad(ids, lens)
+    rows, width = ids.shape
+    total = width + steps
+    caches = init_caches(clf.config, rows, total)
+    positions = jnp.broadcast_to(jnp.arange(width), (rows, width))
+    lens_j = jnp.asarray(lens)
+    kv = jnp.arange(total)[None, None, None, :]
+    mask = causal_mask(width, total, 0) & (kv < lens_j[:, None, None, None])
+    logits, caches = clf.model.apply(
+        {"params": clf.params}, jnp.asarray(ids), positions, mask, caches,
+        last_position=lens_j - 1)
+    caches = [c.with_length(width) for c in caches]
+    step_logits = [np.asarray(logits[:, 0], np.float64)]
+    for t in range(steps - 1):
+        step_mask = (kv < lens_j[:, None, None, None]) | (
+            (kv >= width) & (kv - width <= t))
+        logits, caches = clf.model.apply(
+            {"params": clf.params}, jnp.asarray(tokens[:, t])[:, None],
+            (lens_j + t)[:, None], step_mask, caches)
+        step_logits.append(np.asarray(logits[:, -1], np.float64))
+    full = np.zeros((rows, total), np.int32)
+    for r in range(rows):
+        full[r, :lens[r]] = ids[r, :lens[r]]
+        full[r, lens[r]:lens[r] + steps] = tokens[r]
+    read_at = (np.asarray(lens)[:, None] - 1) + np.arange(steps)[None, :]
+    want = ref.forward(clf.params, HF, full, read_at)["logits"]
+    tol = ref.TEST_TOLERANCE["last_logit_median"]
+    for t in range(steps):
+        assert np.median(np.abs(step_logits[t] - want[:, t])) < tol, t
+        chosen = want[np.arange(rows), t, tokens[:, t]]
+        assert (want[:, t].max(-1) - chosen < 4 * tol).all(), t
+
+
+# ------------------------------------------------------ runtimes, entry points
+
+@pytest.mark.parametrize("runtime", ["paged_runtime", "slot_runtime"])
+def test_decode_runtimes_refuse_the_latent_cache(clf, runtime):
+    with pytest.raises(NotImplementedError, match="latent cache"):
+        getattr(clf, runtime)()
+    assert "latent cache" in clf.decode_runtime_refusal
+    assert LlamaZeroShotClassifier(
+        config=LlamaConfig.tiny()).decode_runtime_refusal is None
+
+
+def test_staged_hooks_equal_classify_batch_and_count_the_step(clf):
+    from music_analyst_tpu.telemetry import get_telemetry
+
+    tel = get_telemetry()
+    before = dict(tel.counters)
+    with tel.span("compute") as span:
+        labels = clf.collect(clf.launch(clf.transfer(clf.prepare(LYRICS))))
+    assert labels == clf.classify_batch(LYRICS)
+    assert labels[-1] == "Neutral"  # the empty lyric
+    ids, lens = _prompts(clf, LYRICS)
+    rows, width = ids.shape
+    # a label is its word then EOS; the EOS's own forward is read by nothing
+    assert clf._label_lens.tolist() == [2, 2, 2]
+    assert (clf._label_ids[:, 1] == clf.tokenizer.eos_id).all()
+    real = int(lens.sum()) + rows * 3
+    assert tel.counters["decoder.tokens_real"] - before.get(
+        "decoder.tokens_real", 0) == 2 * real
+    assert tel.counters["decoder.tokens_computed"] - before.get(
+        "decoder.tokens_computed", 0) == 2 * rows * (width + 3 * 8)
+    assert span.attrs["rows"] == rows and span.attrs["width"] == width
+    assert span.attrs["tokens_real"] == int(lens.sum())
+    assert span.attrs["token_pairs"] == int((lens * (lens + 1) // 2).sum())
+    assert (span.attrs["label_positions"],
+            span.attrs["label_positions_real"]) == (24, 3)
+    ratios = span.attrs["expert_load_max_over_mean"]
+    assert len(ratios) == 2 and all(1.0 <= r <= 8.0 for r in ratios)
+    assert tel.gauges["latent_cache_bytes"] == rows * (width + 8) * 3 * 2 * 24
+    # every assignment of the prefill is counted: rows * width * top_k a layer
+    assert (tel.counters["moe.assignments"] - before.get("moe.assignments", 0)
+            == 2 * 2 * rows * width * 2)
+
+
+def test_sentiment_cli_end_to_end(tmp_path, fixture_csv):
+    from music_analyst_tpu.cli.main import main
+
+    out = tmp_path / "out"
+    assert main(["sentiment", str(fixture_csv), "--model", "kanana-tiny",
+                 "--output-dir", str(out), "--batch-size", "4"]) == 0
+    totals = json.loads((out / "sentiment_totals.json").read_text())
+    assert sum(totals.values()) == 8
+    manifest = json.loads((out / "run_manifest.json").read_text())
+    counters = manifest["counters"]
+    assert counters["decoder.tokens_computed"] > counters[
+        "decoder.tokens_real"] > 0
+    assert counters["traced.mla.absorbed"] and counters["moe.assignments"]
+    compute = [json.loads(line) for line in
+               (out / "telemetry.jsonl").read_text().splitlines()]
+    compute = [e for e in compute
+               if e.get("type") == "span" and e["name"] == "compute"]
+    assert len(compute) == 2 and all(
+        {"rows", "width", "tokens_real"} <= set(e["attrs"]) for e in compute)
+
+
+def test_serve_sentiment_op_runs_and_generate_is_refused(clf):
+    from music_analyst_tpu.serving.residency import ModelResidency
+    from music_analyst_tpu.serving.server import build_resident_ops
+
+    ops = build_resident_ops(ModelResidency(model="kanana-tiny", backend=clf))
+    replies = ops["sentiment"](["la la love", ""])
+    assert [r["label"] for r in replies][1] == "Neutral"
+    from music_analyst_tpu.serving.decode_loop import ContinuousScheduler
+
+    with pytest.raises(NotImplementedError, match="latent cache"):
+        ContinuousScheduler(clf, n_slots=2)
+
+
+def test_sharded_equals_unsharded(clf):
+    from music_analyst_tpu.parallel.mesh import MeshSpec, build_mesh
+    from music_analyst_tpu.parallel.sharding import partition_specs
+
+    mesh = build_mesh(MeshSpec((("dp", 1), ("ep", 2), ("tp", 2))),
+                      devices=jax.devices()[:4])
+    sharded = LlamaZeroShotClassifier(
+        config=clf.config, mesh=mesh, max_prompt_len=clf.max_prompt_len)
+    specs = partition_specs(sharded.params)
+    moe = specs["layer_1"]["feed_forward_moe"]
+    assert tuple(moe["gate_experts"]) == ("ep", None, "tp")
+    assert tuple(moe["down_experts"]) == ("ep", "tp", None)
+    assert tuple(moe["router"]) == ()
+    attn = specs["layer_1"]["attention"]
+    assert tuple(attn["kv_b_proj"]["kernel"]) == (None, "tp", None)
+    assert tuple(attn["q_proj"]["kernel"]) == (None, "tp", None)
+    assert tuple(attn["kv_a_proj"]["kernel"]) == ()
+    gate = sharded.params["layer_1"]["feed_forward_moe"]["gate_experts"]
+    assert gate.sharding.shard_shape(gate.shape) == (4, 64, 16)
+    ids, lens = _prompts(clf, LYRICS[:4])
+    want = _program_scores(clf, clf.params, ids, lens)
+    got = _program_scores(sharded, sharded.params, ids, lens)
+    # same seeded weights, same mathematics; the partitioned sums differ in
+    # bfloat16 rounding only
+    assert np.median(np.abs(got - want)) < ref.TEST_TOLERANCE[
+        "label_score_median"]

@@ -25,6 +25,7 @@ _SUITES: Dict[str, Callable[[], dict]] = {}
 _SUITE_MODULES = (
     "benchmarks.roofline",
     "benchmarks.flash_sweep",
+    "benchmarks.mla_prefill",
     "benchmarks.generation",
     "benchmarks.coldstart",
     "benchmarks.ingest",

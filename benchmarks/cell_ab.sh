@@ -1,0 +1,38 @@
+#!/bin/bash
+# Parent against change in one cell of BENCHMARK.json, in ONE chip call, so
+# both sides are measured on the same chip (PERF.md section 6 quotes its
+# pairs).  Run from the repo root after
+#
+#   mkdir -p .checkout .scratch/final
+#   git archive HEAD | tar -x -C .checkout                    # the parent
+#   git add -A && git archive $(git write-tree) | tar -x -C .scratch/final
+#   chiprun --timeout 3000 -- bash benchmarks/cell_ab.sh \
+#       llm_sentiment_releases "P0 C0 C0 P0" 2463534242
+#
+# usage: cell_ab.sh <workload> <legs> <seed base> [pair|solo]
+#   legs  P = parent (.checkout), C = change (.scratch/final); the digit is
+#         --trace.  "pair": consecutive legs share a seed (the two sides of
+#         one comparison); "solo": every leg has its own.
+# Each leg's result line is printed and kept, with a traced leg's reduced
+# trace and warm-up manifest, under chiprun_out/ab/.
+wl=$1; order=$2; seed=$3; mode=${4:-pair}
+root=$PWD
+mkdir -p chiprun_out/ab
+i=0
+for leg in $order; do
+  side=${leg:0:1}; tr=${leg:1:1}
+  if [ "$mode" = "pair" ]; then k=$((i/2)); else k=$i; fi
+  s=$((seed + 7919 * k + 1000003 * tr))
+  if [ "$side" = "P" ]; then dir=.checkout; else dir=.scratch/final; fi
+  out=$root/chiprun_out/ab/${wl}_${leg}_seed${s}
+  ( cd $dir && python3 perfbench/run.py --workload $wl --seed $s \
+        --seconds 50 --trace $tr > $out.out 2> $out.err
+    echo "rc=$?" >> $out.out
+    if [ "$tr" = "1" ]; then
+      cp perfbench/out/$wl/trace_reduced.json $out.trace_reduced.json
+      cp perfbench/out/$wl/run/warmup/sentiment/run_manifest.json \
+         $out.manifest.json
+    fi )
+  echo "== $leg seed $s"; tail -n 2 $out.out | cut -c1-2500
+  i=$((i+1))
+done

@@ -253,10 +253,12 @@ class LlamaBlock(nn.Module):
     @nn.compact
     def __call__(self, x, mask, positions, cache: Optional[KVCache],
                  lengths: Optional[jax.Array] = None,
-                 segment_ids: Optional[jax.Array] = None):
+                 segment_ids: Optional[jax.Array] = None,
+                 prefill_lengths: Optional[jax.Array] = None):
         cfg = self.config
         if cfg.attention == "mla":
-            return self._latent_block(x, mask, positions, cache, segment_ids)
+            return self._latent_block(x, mask, positions, cache,
+                                      prefill_lengths, segment_ids)
         if segment_ids is not None and (
             cache is not None or cfg.attn_impl != "flash"
         ):
@@ -325,7 +327,8 @@ class LlamaBlock(nn.Module):
         return x, new_cache
 
 
-    def _latent_block(self, x, mask, positions, cache, segment_ids):
+    def _latent_block(self, x, mask, positions, cache, prefill_lengths,
+                      segment_ids):
         """Pre-norm block of the ``mla`` kind: latent attention, then the
         dense SwiGLU in the leading layers and routed + shared experts in
         the rest."""
@@ -346,9 +349,12 @@ class LlamaBlock(nn.Module):
         h = RMSNorm(epsilon=cfg.rms_norm_eps, name="attention_norm")(x)
         with jax.named_scope("mla"):
             if cache is not None:
-                attn_out, new_cache = attn(h, mask, positions, cache)
+                attn_out, new_cache = attn(h, mask, positions, cache,
+                                           prefill_lengths)
             else:
-                attn_out, new_cache = attn(h, mask, positions), None
+                attn_out = attn(h, mask, positions,
+                                prefill_lengths=prefill_lengths)
+                new_cache = None
         x = x + attn_out
         h = RMSNorm(epsilon=cfg.rms_norm_eps, name="ffn_norm")(x)
         if cfg.routed_layer(self.layer_index):
@@ -380,7 +386,13 @@ class LlamaModel(nn.Module):
         lengths: Optional[jax.Array] = None,       # [B] — flash path masks
         last_position: Optional[jax.Array] = None,  # [B] — see below
         segment_ids: Optional[jax.Array] = None,   # [B, S] — packed docs
+        prefill_lengths: Optional[jax.Array] = None,  # [B] — see below
     ):
+        # ``prefill_lengths`` is read by latent-attention layers alone and
+        # is a promise about THIS call (models/mla.MLAttention): a causal
+        # prefill from position 0 on empty caches, ``mask`` = causal and
+        # key padding by these lengths, one device.  ``lengths`` keeps its
+        # one meaning, the flash path's key padding:
         # CONTRACT: with cfg.attn_impl == "flash" (and no caches), the
         # `mask` argument is NOT applied — attention is causal + key-
         # padding-by-`lengths` + optional same-segment (packed documents,
@@ -401,7 +413,7 @@ class LlamaModel(nn.Module):
             cache_i = caches[i] if caches is not None else None
             x, new_cache = LlamaBlock(cfg, i, name=f"layer_{i}")(
                 x, mask, positions, cache_i, lengths,
-                segment_ids=segment_ids,
+                segment_ids=segment_ids, prefill_lengths=prefill_lengths,
             )
             if new_cache is not None:
                 new_caches.append(new_cache)
@@ -851,7 +863,9 @@ class LlamaZeroShotClassifier(ClassifierBackend):
             # the [B,S,V] prefill logits are never materialized.
             (logits, caches), sown = self.model.apply(
                 {"params": params}, prompt_ids, positions, mask, caches,
-                last_position=prompt_lens - 1, mutable=["intermediates"],
+                last_position=prompt_lens - 1,
+                prefill_lengths=self._prefill_lengths(prompt_lens),
+                mutable=["intermediates"],
             )
             stats = {}
             loads = _sown_by_layer(sown, "expert_load")
@@ -963,6 +977,7 @@ class LlamaZeroShotClassifier(ClassifierBackend):
             logits, caches = self.model.apply(
                 {"params": params}, prompt_ids, positions, mask, caches,
                 last_position=prompt_lens - 1,
+                prefill_lengths=self._prefill_lengths(prompt_lens),
             )
             caches = [c.with_length(S) for c in caches]
             first = jnp.argmax(logits[:, 0], axis=-1)  # [B]
@@ -1079,6 +1094,17 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         width = min(round_pow2(longest, self.config.prompt_width_floor),
                     self.max_prompt_len)
         return ids[:, :width], lens
+
+    def _prefill_lengths(self, prompt_lens):
+        """What a prefill from position 0 on empty caches hands the
+        latent-attention layers beside the mask (``models/mla.py``: the
+        kernel that reads lengths in its place): the prompts' lengths on
+        one device, nothing under a mesh.  The kernel's call is opaque to
+        the partitioner, which would gather its operands and give every
+        chip all the work, where the XLA form is partitioned."""
+        if self.mesh is not None and self.mesh.size > 1:
+            return None
+        return prompt_lens
 
     def _encode_prompts(self, texts: Sequence[str]):
         prompts = [

@@ -12,7 +12,12 @@ Two forms of the same mathematics, chosen from the shapes:
 
 * **expanded** — expand the latents to per-head keys and values and run
   ordinary attention.  Cheapest when many queries share the expansion
-  (prefill).  The scores of one block of queries at a time are live, never
+  (prefill).  A causal prefill from position 0 on one device, which its
+  caller declares by giving the rows' ``prefill_lengths``, runs as one
+  Pallas kernel (``ops/mla_prefill_attention.py``) that skips what lies
+  above the diagonal or past a row's length and keeps no score outside
+  VMEM; every other call takes :func:`blocked_attention`, the XLA form,
+  where the scores of one block of queries at a time are live, never
   ``[B, H, S, S]``.
 * **absorbed** — fold ``W_uk`` (the ``k_nope`` half of ``W_kvb``) into the
   query and ``W_uv`` (the ``v`` half) behind the weighted sum, so attention
@@ -29,7 +34,8 @@ where expanded keys and values are ``heads * (nope + rope + v)``.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+import math
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -42,7 +48,14 @@ from music_analyst_tpu.models.layers import (
     fan_in_normal,
     rope_frequencies,
 )
-from music_analyst_tpu.profiling.compile import note_traced_path
+from music_analyst_tpu.ops.mla_prefill_attention import (
+    mla_prefill_attention,
+    prefill_block,
+)
+from music_analyst_tpu.profiling.compile import (
+    note_attention_path,
+    note_traced_path,
+)
 
 
 @dataclasses.dataclass
@@ -91,26 +104,48 @@ jax.tree_util.register_dataclass(
 )
 
 
+def dense_general_init(key, shape, dtype):
+    """What ``nn.DenseGeneral`` draws for a kernel of ``shape`` contracted
+    over its first axis: lecun-normal on the kernel flattened to 2-D.
+    ``q_proj`` was such a module, and a seed keeps the weights it gave."""
+    flat = nn.initializers.lecun_normal()(
+        key, (shape[0], math.prod(shape[1:])), dtype)
+    return flat.reshape(shape)
+
+
 class Kernel(nn.Module):
     """A bare ``kernel`` leaf under a module name, for a projection that is
     applied in more than one contraction (``kv_b_proj``: expanded whole,
-    absorbed by halves)."""
+    absorbed by halves; ``q_proj``: 4-D, or 2-D by halves for the prefill
+    kernel)."""
 
     shape: tuple
     fan_in: int
     param_dtype: jnp.dtype = jnp.float32
+    init: Optional[Callable] = None      # default: N(0, 1 / fan_in)
 
     @nn.compact
     def __call__(self) -> jax.Array:
-        return self.param("kernel", fan_in_normal(self.fan_in), self.shape,
-                          self.param_dtype)
+        return self.param("kernel", self.init or fan_in_normal(self.fan_in),
+                          self.shape, self.param_dtype)
 
 
 def blocked_attention(q_nope, q_rope, k_nope, k_rope, v, mask, scale: float,
                       block_q: int) -> jax.Array:
     """Attention with per-head keys ``[k_nope | k_rope]`` (``k_rope`` one
     vector a token, shared by the heads) over blocks of ``block_q``
-    queries: one block's float32 scores are live at a time.
+    queries: one block's float32 scores are live at a time, the whole row
+    of keys is computed and ``mask`` decides afterwards.
+
+    The expanded form of every call the prefill kernel does not take:
+    a caller that gives no ``prefill_lengths`` (``MLAttention`` says what
+    they promise: ``init``, training, the layer tests, a continuation of
+    more than ``absorb_max_queries`` tokens on a filled cache, any forward
+    under a mesh, where XLA partitions this form and could not the
+    kernel's call), and with them a number of queries outside
+    ``ops/mla_prefill_attention.prefill_block`` (not whole kernel blocks,
+    or fewer than two: prompt widths of 64 to 256).  The kernel's tests
+    compare with it.
 
     ``q_nope [B,Sq,H,Dn]``, ``q_rope [B,Sq,H,Dr]``, ``k_nope [B,Sk,H,Dn]``,
     ``k_rope [B,Sk,Dr]``, ``v [B,Sk,H,Dv]``; ``mask`` broadcastable to
@@ -178,17 +213,32 @@ class MLAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, mask=None, positions=None,
-                 cache: Optional[LatentCache] = None):
+                 cache: Optional[LatentCache] = None,
+                 prefill_lengths: Optional[jax.Array] = None):
+        """``prefill_lengths [B]`` is a promise only the caller can make
+        (``cache.length`` is traced, nothing here can check it): this call
+        is a causal prefill from position 0 (query ``i`` is key ``i``, on
+        an empty cache if any), ``mask`` is the causal rule and key padding
+        by these lengths, nothing reads the output at a padding position,
+        and the call is not partitioned over a mesh.  The expanded form may
+        then take the kernel that reads the lengths IN PLACE OF ``mask``
+        (:func:`prefill_block` decides from the number of queries).  A
+        continuation on a filled cache, a chunked prefill, any other mask,
+        training (the kernel has no gradient) and a meshed forward withhold
+        it, and ``mask`` is applied as given."""
         dim = x.shape[-1]
+        batch, n_q = x.shape[:2]
         heads, nope, rope = (self.n_heads, self.qk_nope_head_dim,
                              self.qk_rope_head_dim)
         rank, v_dim = self.kv_lora_rank, self.v_head_dim
         scale = (nope + rope) ** -0.5
+        absorbed = cache is not None and n_q <= self.absorb_max_queries
+        flash = (not absorbed and prefill_lengths is not None
+                 and bool(prefill_block(n_q)))
 
-        q = nn.DenseGeneral(
-            features=(heads, nope + rope), use_bias=False, dtype=self.dtype,
-            param_dtype=self.param_dtype, name="q_proj",
-        )(x)
+        x = x.astype(self.dtype)
+        w_q = Kernel((dim, heads, nope + rope), dim, self.param_dtype,
+                     dense_general_init, name="q_proj")().astype(self.dtype)
         kv_a = nn.Dense(
             rank + rope, use_bias=False, dtype=self.dtype,
             param_dtype=self.param_dtype, name="kv_a_proj",
@@ -199,11 +249,21 @@ class MLAttention(nn.Module):
                        name="kv_b_proj")().astype(self.dtype)
 
         if positions is None:
-            positions = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
+            positions = jnp.broadcast_to(jnp.arange(n_q), (batch, n_q))
         cos, sin = rope_frequencies(rope, self.max_positions, self.rope_theta)
         rotate = apply_rope_interleaved if self.rope_interleave else apply_rope
-        q_nope = q[..., :nope]
-        q_rope = rotate(q[..., nope:], cos, sin, positions)
+        if flash:
+            # The kernel reads ``[B, S, H*D]``, which is how XLA lays out a
+            # contraction with a 2-D weight; fed from ``[B, S, H, D]`` the
+            # call gets a slice and three transposing copies a layer
+            # (tests/test_mosaic_aot.py pins this).
+            q_nope = x @ w_q[..., :nope].reshape(dim, heads * nope)
+            q_rope = (x @ w_q[..., nope:].reshape(dim, heads * rope)
+                      ).reshape(batch, n_q, heads, rope)
+        else:
+            q = jnp.einsum("bsd,dhe->bshe", x, w_q)
+            q_nope, q_rope = q[..., :nope], q[..., nope:]
+        q_rope = rotate(q_rope, cos, sin, positions)
         k_rope = rotate(kv_a[..., None, rank:], cos, sin, positions)[:, :, 0]
 
         new_cache = None
@@ -211,7 +271,7 @@ class MLAttention(nn.Module):
             new_cache = cache.update(latents, k_rope)
             latents, k_rope = new_cache.latents, new_cache.rope_keys
 
-        if cache is not None and x.shape[1] <= self.absorb_max_queries:
+        if absorbed:
             note_traced_path("mla.absorbed")
             q_abs = jnp.einsum("bqhd,rhd->bqhr", q_nope, w_kvb[..., :nope])
             scores = jnp.einsum("bqhr,bkr->bhqk", q_abs, latents,
@@ -225,8 +285,17 @@ class MLAttention(nn.Module):
             probs = jax.nn.softmax(scores, axis=-1).astype(self.dtype)
             ctx = jnp.einsum("bhqk,bkr->bqhr", probs, latents)
             out = jnp.einsum("bqhr,rhd->bqhd", ctx, w_kvb[..., nope:])
+        elif flash:
+            note_traced_path("mla.expanded")
+            note_attention_path("mla_flash")
+            kv = latents @ w_kvb.reshape(rank, heads * (nope + v_dim))
+            out = mla_prefill_attention(
+                q_nope, q_rope.reshape(batch, n_q, heads * rope), kv, k_rope,
+                prefill_lengths, heads, scale,
+            ).reshape(batch, n_q, heads, v_dim)
         else:
             note_traced_path("mla.expanded")
+            note_attention_path("mla_blocked")
             kv = jnp.einsum("bkr,rhd->bkhd", latents, w_kvb)
             out = blocked_attention(
                 q_nope, q_rope, kv[..., :nope], k_rope, kv[..., nope:],

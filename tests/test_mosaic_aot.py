@@ -11,10 +11,15 @@ Geometries are the ones ``chip_smoke.py`` runs: Llama-3-8B heads
 builds (8 Q / 4 KV x 16), page 16, bf16 and int8 pools; flash attention
 at GQA + causal with and without segment ids; the whole-row kernel at the
 corpus job's two batch shapes (4,096 and the 306-row tail x 12 x 128 x 64),
-at the longest rows its VMEM arithmetic admits, and at ``distilbert-tiny``.
+at the longest rows its VMEM arithmetic admits, and at ``distilbert-tiny``;
+the latent-attention prefill kernel at the decoder cell's step (32 x 1,024,
+32 heads of 192 | 128, the cache's 1,032-key buffer), at its smallest
+admitted width and at ``kanana-tiny``'s widths.
 """
 
 from __future__ import annotations
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +27,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from music_analyst_tpu.ops.flash_attention import flash_attention
+from music_analyst_tpu.ops.mla_prefill_attention import (
+    mla_prefill_attention,
+    prefill_block,
+)
 from music_analyst_tpu.ops.paged_attention import (
     check_stream_geometry,
     paged_attention,
@@ -132,6 +141,99 @@ def test_whole_row_attention_compiles_under_mosaic(
     _compile_for_tpu(
         fn, tpu_sharding, shape, shape, shape, ((rows,), jnp.int32)
     )
+
+
+@pytest.mark.parametrize(
+    "rows,seq,heads,nope,rope,v_dim",
+    [
+        (32, 1024, 32, 128, 64, 128),   # kanana-2-30b-a3b, the cell's step
+        (4, 512, 32, 128, 64, 128),     # two blocks, the smallest admitted
+        (8, 512, 4, 16, 8, 16),         # kanana-tiny: heads inside a lane tile
+    ],
+)
+def test_mla_prefill_attention_compiles_under_mosaic(
+    tpu_sharding, rows, seq, heads, nope, rope, v_dim
+):
+    """The loop over heads with lane slices at traced offsets, and the
+    unrolled form where a head is narrower than a lane tile, inside the
+    VMEM the call asks for; keys are the cache's buffer, 8 past the
+    queries."""
+    assert prefill_block(seq)
+
+    def fn(q_nope, q_rope, kv, k_rope, lengths):
+        return mla_prefill_attention(
+            q_nope, q_rope, kv, k_rope, lengths, heads,
+            (nope + rope) ** -0.5, interpret=False)
+
+    _compile_for_tpu(
+        fn, tpu_sharding,
+        ((rows, seq, heads * nope), jnp.bfloat16),
+        ((rows, seq, heads * rope), jnp.bfloat16),
+        ((rows, seq + 8, heads * (nope + v_dim)), jnp.bfloat16),
+        ((rows, seq + 8, rope), jnp.bfloat16),
+        ((rows,), jnp.int32),
+    )
+
+
+def _opcode(program: str, name: str) -> str:
+    """Opcode of the instruction ``%name`` in a compiled program's text."""
+    (line,) = re.findall(rf"^\s*(?:ROOT )?%{re.escape(name)} = .*$", program,
+                         re.MULTILINE)
+    return re.search(r"[\])}] ([a-z-]+)\(", line).group(1)
+
+
+def test_projections_feed_the_prefill_kernel_without_a_copy(
+    tpu_sharding, monkeypatch
+):
+    """One latent-attention layer at the published widths, compiled for a
+    v5e: the kernel's large operands, ``q_nope`` and the expanded ``[k_nope
+    | v]`` (nine tenths of what it reads), are the projections' own fusions
+    and its result reaches ``o_proj`` as a bitcast.  ``MLAttention`` writes
+    those projections as contractions with 2-D weights for this; from
+    ``[B, S, H, D]`` einsums each got a transposing copy.  The rotated
+    ``q_rope`` still comes through one (RoPE works on ``[B, S, H, D]``)."""
+    from music_analyst_tpu.models.layers import causal_mask
+    from music_analyst_tpu.models.mla import LatentCache, MLAttention
+    from music_analyst_tpu.ops import flash_attention
+
+    monkeypatch.setattr(flash_attention, "interpret_default", lambda: False)
+    rows, seq, labels, dim, rank, rope = 4, 512, 8, 2048, 512, 64
+    attention = MLAttention(
+        n_heads=32, qk_nope_head_dim=128, qk_rope_head_dim=rope,
+        v_head_dim=128, kv_lora_rank=rank, param_dtype=jnp.bfloat16)
+
+    def forward(params, x, lens):
+        cache = LatentCache.zeros(rows, seq + labels, rank, rope,
+                                  jnp.bfloat16)
+        return attention.apply(
+            params, x, causal_mask(seq, seq + labels, 0), None, cache,
+            prefill_lengths=lens)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=tpu_sharding), tree)
+
+    params = jax.eval_shape(
+        lambda: attention.init(jax.random.key(0),
+                               jnp.zeros((1, 8, dim), jnp.bfloat16)))
+    program = jax.jit(forward).trace(
+        placed(params), placed(jnp.zeros((rows, seq, dim), jnp.bfloat16)),
+        placed(jnp.zeros((rows,), jnp.int32)),
+    ).lower(lowering_platforms=("tpu",)).compile().as_text()
+
+    (call,) = re.findall(
+        r"^\s*%(_prefill_call[.\d]*) = \S+ custom-call\(([^)]*)\)", program,
+        re.MULTILINE)
+    name, operands = call[0], re.findall(r"%([\w.-]+)", call[1])
+    lengths, q_nope, q_rope, kv, k_rope = operands
+    assert _opcode(program, q_nope) == "fusion"
+    assert _opcode(program, kv) == "fusion"
+    assert _opcode(program, q_rope) in ("copy", "fusion")
+    readers = re.findall(
+        rf"^\s*(?:ROOT )?%[\w.-]+ = \S+ ([a-z-]+)\([^)]*%{re.escape(name)}[,)]",
+        program, re.MULTILINE)
+    assert readers and set(readers) <= {"bitcast", "fusion"}, readers
 
 
 def test_unservable_geometry_is_refused_by_name():

@@ -13,8 +13,9 @@
 #   legs  P = parent (.checkout), C = change (.scratch/final); the digit is
 #         --trace.  "pair": consecutive legs share a seed (the two sides of
 #         one comparison); "solo": every leg has its own.
-# Each leg's result line is printed and kept, with a traced leg's reduced
-# trace and warm-up manifest, under chiprun_out/ab/.
+# Each leg's result line is printed and kept, with its warm-up job's manifest
+# (`profiling.compiles`: the `hlo_fingerprint` of every compiled program) and
+# a traced leg's reduced trace, under chiprun_out/ab/.
 wl=$1; order=$2; seed=$3; mode=${4:-pair}
 root=$PWD
 mkdir -p chiprun_out/ab
@@ -28,10 +29,10 @@ for leg in $order; do
   ( cd $dir && python3 perfbench/run.py --workload $wl --seed $s \
         --seconds 50 --trace $tr > $out.out 2> $out.err
     echo "rc=$?" >> $out.out
+    cp perfbench/out/$wl/run/warmup/sentiment/run_manifest.json \
+       $out.manifest.json
     if [ "$tr" = "1" ]; then
       cp perfbench/out/$wl/trace_reduced.json $out.trace_reduced.json
-      cp perfbench/out/$wl/run/warmup/sentiment/run_manifest.json \
-         $out.manifest.json
     fi )
   echo "== $leg seed $s"; tail -n 2 $out.out | cut -c1-2500
   i=$((i+1))

@@ -1035,7 +1035,7 @@ def _response_cache_scenario(n_requests: int) -> dict:
     cache can make an answer cheaper, never different."""
     from music_analyst_tpu.resilience import configure_faults, fault_stats
     from music_analyst_tpu.serving.batcher import DynamicBatcher
-    from music_analyst_tpu.serving.residency import ModelResidency
+    from music_analyst_tpu.models.backend import ModelResidency
     from music_analyst_tpu.serving.response_cache import ResponseCache
     from music_analyst_tpu.serving.server import build_ops
 

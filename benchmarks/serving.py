@@ -184,7 +184,7 @@ def _zipf_cache_scenario(ops, max_batch: int) -> dict:
 
 @suite("serving")
 def run() -> dict:
-    from music_analyst_tpu.serving.residency import ModelResidency
+    from music_analyst_tpu.models.backend import ModelResidency
     from music_analyst_tpu.serving.server import build_ops
 
     if smoke():

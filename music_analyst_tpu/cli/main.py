@@ -686,33 +686,29 @@ def _dispatch(parser: argparse.ArgumentParser,
 
     if args.command == "sentiment":
         from music_analyst_tpu.engines.sentiment import run_sentiment
+        from music_analyst_tpu.models.backend import family_takes
         from music_analyst_tpu.profiling.trace import maybe_trace
 
         # Fail as a usage error, not a mid-run traceback: buckets only
-        # apply to the encoder classifier family (engines/sentiment.py
-        # raises the same constraint later for programmatic callers).
-        if args.length_buckets and (
-            args.mock or not args.model.startswith("distilbert")
-        ):
+        # apply to the encoder classifier family (models/backend.py's
+        # table; get_backend raises the same for programmatic callers).
+        if args.length_buckets and not family_takes(
+                args.model, args.mock, "length_buckets"):
             parser.error(
                 "--length-buckets requires --model distilbert[-*] "
                 "(not --mock or decoder models)"
             )
-        if args.weight_quant != "none" and (
-            args.mock or not (args.model.startswith("distilbert")
-                              or args.model.startswith("llama"))
-        ):
+        if args.weight_quant != "none" and not family_takes(
+                args.model, args.mock, "weight_quant"):
             parser.error(
                 "--weight-quant requires an on-device model family "
                 "(distilbert[-*] or llama[3*])"
             )
         mesh = None
         if args.devices:
-            from music_analyst_tpu.engines.sentiment import _mesh_capable
-
             # Don't initialize the device backend just to build a mesh
             # the backend family can't take.
-            if _mesh_capable(args.model, args.mock):
+            if family_takes(args.model, args.mock, "mesh"):
                 from music_analyst_tpu.parallel.mesh import data_parallel_mesh
 
                 mesh = data_parallel_mesh(args.devices)
@@ -733,14 +729,13 @@ def _dispatch(parser: argparse.ArgumentParser,
         return 0
 
     if args.command == "serve":
+        from music_analyst_tpu.models.backend import family_takes
         from music_analyst_tpu.serving.server import run_server
 
         if not args.stdio and not args.socket:
             parser.error("serve requires --socket PATH or --stdio")
-        if args.weight_quant != "none" and (
-            args.mock or not (args.model.startswith("distilbert")
-                              or args.model.startswith("llama"))
-        ):
+        if args.weight_quant != "none" and not family_takes(
+                args.model, args.mock, "weight_quant"):
             parser.error(
                 "--weight-quant requires an on-device model family "
                 "(distilbert[-*] or llama[3*])"

@@ -31,6 +31,7 @@ import numpy as np
 
 from music_analyst_tpu.engines.train import TrainState
 from music_analyst_tpu.resilience.faults import fault_point
+from music_analyst_tpu.telemetry import register_manifest_section
 
 
 def _checkpointer():
@@ -96,6 +97,21 @@ def last_load_stats() -> Dict[str, Any]:
     """Snapshot of the most recent quantized load (empty before any)."""
     with _LOAD_LOCK:
         return dict(_LAST_LOAD_STATS)
+
+
+def _manifest_section() -> Dict[str, Any]:
+    """Quantized-checkpoint cache hit/miss/stores/bytes-saved plus the
+    most recent streaming load's peak-host-staging digest — only once the
+    cache was consulted or a load ran."""
+    from music_analyst_tpu.engines import wq_cache
+
+    stats, load = wq_cache.cache_stats(), last_load_stats()
+    if not (any(stats.values()) or load):
+        return {}
+    return {**stats, **({"last_load": load} if load else {})}
+
+
+register_manifest_section("wq_cache", _manifest_section)
 
 
 def _leaf_bytes(leaf) -> int:

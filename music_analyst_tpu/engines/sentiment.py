@@ -9,6 +9,9 @@ dispatches whole batches to an on-device classifier backend:
 * ``llama`` / ``kanana`` — zero-shot decoder LM (``models/llama.py``;
   ``kanana-2-30b-a3b`` is latent attention + sigmoid-routed experts).
 
+Which families exist and what each takes is ``models/backend.py``'s table;
+this engine builds its backend through that module's ``ModelResidency``.
+
 Outputs are byte-for-byte the reference artifact formats:
 ``sentiment_totals.json`` (label→count, 2-space JSON) and
 ``sentiment_details.csv`` (``artist,song,label,latency_seconds`` with
@@ -26,6 +29,14 @@ import time
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from music_analyst_tpu.data.csv_io import iter_songs
+from music_analyst_tpu.models.backend import (
+    ClassifierBackend,
+    ModelResidency,
+    has_buckets,
+)
+# Part of this module's surface: callers that build a backend themselves
+# (the benchmark's drivers) import ``get_backend`` from here.
+from music_analyst_tpu.models.backend import get_backend  # noqa: F401
 from music_analyst_tpu.observability import watchdog
 from music_analyst_tpu.runtime import (
     PrefetchPipeline,
@@ -53,163 +64,6 @@ class SentimentResult:
     rows: List[SentimentRow]
     output_paths: Dict[str, str]
     songs_per_second: float
-
-
-class ClassifierBackend:
-    """Interface all sentiment backends implement."""
-
-    name = "base"
-    # Whether per-song latency is meaningful for this backend.  The
-    # reference's mock path always records 0.0 (scripts/
-    # sentiment_classifier.py:83) — mock sets this False to keep
-    # sentiment_details.csv byte-identical; device model backends report
-    # amortized batch latency instead of the reference's per-song HTTP time.
-    reports_latency = True
-
-    def classify_batch(self, texts: Sequence[str]) -> List[str]:
-        """Labels for a batch of raw lyric strings."""
-        raise NotImplementedError
-
-    # Staged hooks for the host↔device prefetch pipeline
-    # (music_analyst_tpu/runtime/prefetch.py).  The engine runs
-    # ``prepare`` (host tokenize + batch planning), ``transfer``
-    # (``jax.device_put`` of the wire payload), and ``launch`` (dispatch
-    # the jitted forwards without blocking) in separate pipeline stages,
-    # then blocks on ``collect`` in the consumer — so batch i+2 tokenizes
-    # and batch i+1 transfers while batch i runs on the chips.  The
-    # defaults collapse the three stages into ``submit``, so a backend
-    # that only implements submit/collect (or just classify_batch) works
-    # unchanged — the pipeline simply gets no tokenize/transfer overlap
-    # from it.
-    def prepare(self, texts: Sequence[str]):
-        """Host-only work: tokenize + plan the batch.  Must not touch the
-        device."""
-        return texts
-
-    def transfer(self, prepared):
-        """Ship the prepared payload host→device (``jax.device_put``)."""
-        return prepared
-
-    def launch(self, transferred):
-        """Dispatch device work for a transferred payload; returns the
-        handle ``collect`` blocks on."""
-        return self.submit(transferred)
-
-    # Async pair kept as the single-call surface: ``submit`` does the host
-    # work and dispatches device work without blocking; ``collect`` blocks
-    # on the result.  Backends that implement the staged hooks above
-    # compose them here so direct submit/collect callers see one behavior.
-    def submit(self, texts: Sequence[str]):
-        return self.classify_batch(texts)
-
-    def collect(self, handle) -> List[str]:
-        return handle
-
-
-def _has_buckets(length_buckets) -> bool:
-    """Whether a ``length_buckets`` value actually requests bucketing.
-
-    ``None`` and an empty sequence both mean "unset"; `len(...)` (not
-    truthiness) so numpy arrays work as sequences; strings ("auto" or a
-    mistaken "32,64") count as set and defer to the classifier's own
-    validation for a clear message.  Shared by ``get_backend`` and
-    ``run_sentiment``'s injected-backend guard so the two entry points
-    agree on what "unset" means (r4 advisor finding).
-    """
-    if length_buckets is None:
-        return False
-    if isinstance(length_buckets, str):
-        return True
-    try:
-        return len(length_buckets) > 0
-    except TypeError:
-        # A scalar (length_buckets=32) is a plausible slip for a
-        # one-bucket list; name the misuse instead of letting a bare
-        # `len(int)` TypeError surface from deep inside either caller.
-        raise TypeError(
-            "length_buckets must be a string ('auto') or a sequence of "
-            f"ints, got {type(length_buckets).__name__}"
-        ) from None
-
-
-def get_backend(
-    model: str,
-    mock: bool = False,
-    mesh=None,
-    length_buckets: Optional[Sequence[int]] = None,
-    weight_quant: Optional[str] = None,
-    **kwargs,
-) -> ClassifierBackend:
-    """Resolve the ``--model``/``--mock`` flag surface to a backend.
-
-    Mirrors the reference's dispatch (``--mock`` wins over ``--model``,
-    ``scripts/sentiment_classifier.py:140``); model names map to on-device
-    families instead of Ollama model tags.
-
-    The dispatch also owns per-family capabilities, so callers pass
-    ``mesh``/``length_buckets`` unconditionally: ``mesh`` shards model
-    batches over dp and places params per the TP rules but is dropped for
-    the mesh-incapable families (the keyword kernel, the Ollama HTTP
-    passthrough); ``length_buckets`` is encoder-only and *raises* elsewhere
-    (silently running every row at full length would defeat the flag).
-    """
-    has_buckets = _has_buckets(length_buckets)
-    if has_buckets and (mock or not model.startswith("distilbert")):
-        raise ValueError(
-            "length_buckets is an encoder-classifier option; "
-            f"model {model!r} does not support it"
-        )
-    has_wq = weight_quant not in (None, "none")
-    if has_wq and (
-        mock or not (model.startswith("distilbert")
-                     or model.startswith("llama"))
-    ):
-        # Same posture as length_buckets: silently running float would
-        # defeat the flag.
-        raise ValueError(
-            "weight_quant is an on-device model option; "
-            f"model {model!r} does not support it"
-        )
-    if mock or model == "mock":
-        from music_analyst_tpu.models.mock import MockKeywordClassifier
-
-        return MockKeywordClassifier(**kwargs)
-    if model.startswith("ollama:") or model == "ollama":
-        from music_analyst_tpu.models.ollama import OllamaClassifier
-
-        tag = model.split(":", 1)[1] if ":" in model else "llama3"
-        return OllamaClassifier(model=tag, **kwargs)
-    if mesh is not None:
-        kwargs["mesh"] = mesh
-    if has_wq:
-        kwargs["weight_quant"] = weight_quant
-    try:
-        if model.startswith("distilbert"):
-            from music_analyst_tpu.models.distilbert import DistilBertClassifier
-
-            if has_buckets:
-                # Strings pass through (the classifier validates "auto" vs
-                # mistakes); a sequence is normalized to a tuple.
-                kwargs["length_buckets"] = (
-                    length_buckets if isinstance(length_buckets, str)
-                    else tuple(int(b) for b in length_buckets)
-                )
-            return DistilBertClassifier.from_pretrained_or_random(model, **kwargs)
-        if model.startswith(("llama", "kanana")):
-            from music_analyst_tpu.models.llama import LlamaZeroShotClassifier
-
-            return LlamaZeroShotClassifier.from_pretrained_or_random(
-                model, **kwargs
-            )
-    except ImportError as exc:
-        raise RuntimeError(
-            f"model backend {model!r} is unavailable ({exc}); "
-            "use --mock or --model mock for the keyword kernel"
-        ) from exc
-    raise ValueError(
-        f"unknown model {model!r}: expected 'mock', 'distilbert*', 'llama*' "
-        "or 'kanana*'"
-    )
 
 
 def _read_completed_details(details_path: str) -> Tuple[int, Dict[str, int]]:
@@ -252,16 +106,6 @@ def _read_completed_details(details_path: str) -> Tuple[int, Dict[str, int]]:
                 counts[label] += 1
             done += 1
     return done, counts
-
-
-def _mesh_capable(model: str, mock: bool) -> bool:
-    """Whether the resolved backend family takes a device mesh (the
-    on-device model families do; the keyword kernel and the Ollama HTTP
-    passthrough do not).  Callers that just want a backend should pass
-    ``mesh=`` to :func:`get_backend`, which drops it where inapplicable;
-    this predicate exists for callers deciding whether to *build* a mesh
-    at all (mesh construction initializes the device backend)."""
-    return not mock and model.startswith(("distilbert", "llama", "kanana"))
 
 
 def run_sentiment(
@@ -322,7 +166,7 @@ def _run_sentiment_impl(
     os.makedirs(output_dir, exist_ok=True)
     depth = resolve_prefetch_depth(prefetch_depth)
     if backend is not None and (
-            mesh is not None or _has_buckets(length_buckets)
+            mesh is not None or has_buckets(length_buckets)
             or weight_quant not in (None, "none")):
         # An injected backend was constructed by the caller; silently
         # dropping construction-time options here would be a lie.
@@ -333,9 +177,7 @@ def _run_sentiment_impl(
         )
     # One owner for the backend lifetime, batch runs included: the
     # device-loss recovery below reloads through the same object the
-    # server's failover hook uses (serving/residency.py).
-    from music_analyst_tpu.serving.residency import ModelResidency
-
+    # server's failover hook uses (models/backend.py).
     residency = ModelResidency(
         model=model, mock=mock, weight_quant=weight_quant, mesh=mesh,
         backend=backend, length_buckets=length_buckets,

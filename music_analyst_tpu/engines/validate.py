@@ -33,21 +33,6 @@ import numpy as np
 
 from music_analyst_tpu.utils.labels import SUPPORTED_LABELS
 
-_ENV_BY_FAMILY = {
-    "distilbert": "MUSICAAL_DISTILBERT_CKPT",
-    "llama": "MUSICAAL_LLAMA_CKPT",
-}
-
-
-def _family(model: str) -> str:
-    for family in _ENV_BY_FAMILY:
-        if model.startswith(family):  # "llama" also covers "llama3*"
-            return family
-    raise ValueError(
-        f"validate supports distilbert[-*] and llama[3*] models, got "
-        f"{model!r} (mock/ollama have no checkpoint to validate)"
-    )
-
 
 def _oracle_distilbert_labels(
     checkpoint_path: str, clf, texts: Sequence[str]
@@ -223,15 +208,23 @@ def run_validation(
     from the same ``MUSICAAL_*_CKPT`` env var a production run uses.
     """
     from music_analyst_tpu.data.csv_io import iter_songs
-    from music_analyst_tpu.serving.residency import ModelResidency
+    from music_analyst_tpu.models.backend import ModelResidency, family_of
 
-    family = _family(model)
+    try:
+        family = family_of(model)
+    except ValueError:
+        family = None
+    if family is None or not family.checkpoint_env:
+        raise ValueError(
+            f"validate supports distilbert[-*] and llama[3*] models, got "
+            f"{model!r} (mock/ollama have no checkpoint to validate)"
+        )
     checkpoint_path = checkpoint_path or os.environ.get(
-        _ENV_BY_FAMILY[family]
+        family.checkpoint_env
     )
     if not checkpoint_path:
         raise RuntimeError(
-            f"no checkpoint to validate: set {_ENV_BY_FAMILY[family]} (or "
+            f"no checkpoint to validate: set {family.checkpoint_env} (or "
             "pass checkpoint_path=)"
         )
     clf = ModelResidency(
@@ -263,7 +256,7 @@ def run_validation(
         with tel.span("oracle", rows=len(texts)):
             oracle = (
                 _oracle_distilbert_labels(checkpoint_path, clf, texts)
-                if family == "distilbert"
+                if family.name == "distilbert"
                 else _oracle_llama_labels(checkpoint_path, clf, texts)
             )
 

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
-from music_analyst_tpu.engines.sentiment import ClassifierBackend
+from music_analyst_tpu.models.backend import ClassifierBackend
 from music_analyst_tpu.models.layers import (
     GeluMLP,
     MultiHeadAttention,

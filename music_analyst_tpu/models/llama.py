@@ -28,9 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
-from music_analyst_tpu.engines.sentiment import ClassifierBackend
+from music_analyst_tpu.models.backend import ClassifierBackend, preset_files
 from music_analyst_tpu.models.layers import (
-    KVCache,
     MultiHeadAttention,
     RMSNorm,
     SwiGLU,
@@ -41,6 +40,8 @@ from music_analyst_tpu.models.tokenization import (
     ByteTokenizer,
     resolve_llama_tokenizer,
 )
+from music_analyst_tpu.ops.kv_cache import KVCache
+from music_analyst_tpu.profiling.compile import profiled_jit
 from music_analyst_tpu.utils.labels import SUPPORTED_LABELS, normalise_label
 
 # Reference prompt, scripts/sentiment_classifier.py:32-36 (behavioral
@@ -199,14 +200,12 @@ class LlamaConfig:
         return cls(**fields)
 
     @classmethod
-    def from_preset_file(cls, name: str) -> "LlamaConfig":
-        """``models/presets/<name>.json``: the published keys as run, and
+    def from_preset_file(cls, path: str) -> "LlamaConfig":
+        """A file of ``models/presets/``: the published keys as run, and
         under ``runtime`` what the source does not state (dtypes, the
         offline tokenizer)."""
         import json
 
-        path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                            "presets", name + ".json")
         with open(path, encoding="utf-8") as fh:
             hf = json.load(fh)
         return cls.from_hf_config(hf, **hf.get("runtime", {}))
@@ -224,18 +223,23 @@ class LlamaConfig:
         )
 
 
-PRESETS = {
-    "llama3": LlamaConfig.llama3_8b,
-    "llama3-8b": LlamaConfig.llama3_8b,
-    "llama3-tiny": LlamaConfig.tiny,
-    "llama-tiny": LlamaConfig.tiny,
-    # Built from files (models/presets/): latent attention + sigmoid-routed
-    # experts.  The first is the published widths with the depth one chip
-    # holds (a pipeline stage); the second the same kinds at test size.
-    "kanana-2-30b-a3b": partial(LlamaConfig.from_preset_file,
-                                "kanana-2-30b-a3b"),
-    "kanana-tiny": partial(LlamaConfig.from_preset_file, "kanana-tiny"),
-}
+def presets() -> dict:
+    """``{--model name: () -> LlamaConfig}``: the configurations written
+    here and one a file of ``models/presets/`` (latent attention +
+    sigmoid-routed experts: ``kanana-2-30b-a3b``, the published widths with
+    the depth one chip holds, a pipeline stage; ``kanana-tiny``, the same
+    kinds at test size).  A new file is a new preset."""
+    return {
+        "llama3": LlamaConfig.llama3_8b,
+        "llama3-8b": LlamaConfig.llama3_8b,
+        "llama3-tiny": LlamaConfig.tiny,
+        "llama-tiny": LlamaConfig.tiny,
+        **{name: partial(LlamaConfig.from_preset_file, path)
+           for name, path in preset_files().items()},
+    }
+
+
+PRESETS = presets()
 
 LATENT_CACHE_REFUSAL = (
     "this model's attention keeps a latent cache (one c_kv + k_rope vector "
@@ -677,8 +681,344 @@ def init_params_by_layer(cfg: LlamaConfig, seed: int = 0):
     return params
 
 
+def _prefill_lengths(mesh, prompt_lens):
+    """What a prefill from position 0 on empty caches hands the
+    latent-attention layers beside the mask (``models/mla.py``: the
+    kernel that reads lengths in its place): the prompts' lengths on
+    one device, nothing under a mesh.  The kernel's call is opaque to
+    the partitioner, which would gather its operands and give every
+    chip all the work, where the XLA form is partitioned."""
+    if mesh is not None and mesh.size > 1:
+        return None
+    return prompt_lens
+
+
+# The decoder's three programs, built from ``(model, config, …)``: whoever
+# holds a model and its parameters can run them (the zero-shot classifier
+# below does; a decode runtime need not go through it).  The traced
+# functions' names are part of what a run records (a device trace finds
+# the scoring step by ``score_labels``).
+
+def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
+    """The jitted scoring step: one prompt prefill a row, then the
+    teacher-forced label continuations on its cache (``profiled_jit``
+    name ``llama_score_labels``)."""
+
+    def _score_labels(params, prompt_ids, prompt_lens, label_ids,
+                      label_lens):
+        """Log-likelihood of each label continuation per batch row.
+
+        prompt_ids [B, S]; label_ids [3, L].  Returns ``(scores [B, 3],
+        stats)``: ``stats`` holds the small device-side reductions that
+        ride back with the scores (``expert_load_max`` /
+        ``expert_load_mean`` ``[routed layers]`` of the prefill, for a
+        model with routed experts; else empty).
+        """
+        B, S = prompt_ids.shape
+        n_labels, L = label_ids.shape
+        # prompt_lens may arrive int16 (wire narrowing) — widen once
+        # on device before the arithmetic/broadcast uses below.
+        prompt_lens = prompt_lens.astype(jnp.int32)
+        positions = jnp.arange(S)[None, :].repeat(B, 0)
+        # kv length is S+L (the cache buffer); the label slots are
+        # causally unreachable during prefill and masked out anyway.
+        mask = causal_mask(S, S + L, 0) & jnp.pad(
+            padding_mask(prompt_lens, S),
+            ((0, 0), (0, 0), (0, 0), (0, L)),
+        )
+        caches = init_caches(config, B, S + L)
+        # last_position: only the final prompt logits are consumed, so
+        # the [B,S,V] prefill logits are never materialized.
+        (logits, caches), sown = model.apply(
+            {"params": params}, prompt_ids, positions, mask, caches,
+            last_position=prompt_lens - 1,
+            prefill_lengths=_prefill_lengths(mesh, prompt_lens),
+            mutable=["intermediates"],
+        )
+        stats = {}
+        loads = _sown_by_layer(sown, "expert_load")
+        if loads:
+            load = jnp.stack(loads).astype(jnp.float32)  # [layers, E]
+            stats = {"expert_load_max": load.max(axis=-1),
+                     "expert_load_mean": load.mean(axis=-1),
+                     # [layers, B, S, k]: which experts every position
+                     # ran, for whoever compares against a reference
+                     "chosen": _expert_ids(
+                         jnp.stack(_sown_by_layer(sown, "chosen")),
+                         config.n_experts)}
+        # Force every cache to report the true prompt length so label
+        # positions line up even though the buffer was written at 0..S.
+        caches = [c.with_length(S) for c in caches]
+        last_logits = logits[:, 0]  # [B, V]
+
+        def score_one(label_row, label_len):
+            lab = jnp.broadcast_to(label_row[None, :], (B, L))
+            pos = prompt_lens[:, None] + jnp.arange(L)[None, :]
+            # decode attends to the full prompt (masked by its length)
+            # plus the causal prefix of the label tokens
+            kv_len = S + L
+            kv_pos = jnp.arange(kv_len)[None, None, None, :]
+            prompt_part = kv_pos < prompt_lens[:, None, None, None]
+            label_part = (kv_pos >= S) & (
+                kv_pos - S <= jnp.arange(L)[None, None, :, None]
+            )
+            mask2 = prompt_part | label_part
+            (logits2, _), sown2 = model.apply(
+                {"params": params}, lab, pos, mask2, caches,
+                mutable=["intermediates"],
+            )
+            # token 0 scored from the prompt's last logits; tokens i>0
+            # from the label forward pass
+            logp_all = jax.nn.log_softmax(logits2, axis=-1)
+            first_lp = jnp.take_along_axis(
+                jax.nn.log_softmax(last_logits, axis=-1),
+                lab[:, :1], axis=1,
+            )[:, 0]
+            rest_lp = jnp.take_along_axis(
+                logp_all[:, :-1], lab[:, 1:, None], axis=2
+            )[:, :, 0]
+            idx = jnp.arange(L - 1)[None, :]
+            rest_lp = jnp.where(idx < label_len - 1, rest_lp, 0.0)
+            # Length-normalize: summed log-probs otherwise favor the
+            # shortest label ("Neutral" is one byte shorter than the
+            # other two under the byte tokenizer).
+            total = first_lp + rest_lp.sum(axis=1)
+            chosen2 = _sown_by_layer(sown2, "chosen")
+            return (
+                total / jnp.maximum(label_len.astype(jnp.float32), 1.0),
+                _expert_ids(jnp.stack(chosen2), config.n_experts)
+                if chosen2 else None,
+            )
+
+        scores, label_chosen = jax.vmap(
+            score_one, in_axes=(0, 0), out_axes=(1, 0)
+        )(label_ids, label_lens)
+        if label_chosen is not None:
+            stats["chosen_labels"] = label_chosen  # [3, layers, B, L, k]
+        return scores, stats  # [B, 3]
+
+    return profiled_jit(_score_labels, name="llama_score_labels")
+
+
+def decode_step_program(model: LlamaModel):
+    """One greedy token a row against the caches (the explicit step loop
+    of :meth:`LlamaZeroShotClassifier.generate`)."""
+
+    @jax.jit
+    def _decode_step(params, token, position, caches):
+        B = token.shape[0]
+        kv_len = caches[0].max_len
+        kv_pos = jnp.arange(kv_len)[None, None, None, :]
+        mask = kv_pos <= position[:, None, None, None]
+        logits, caches = model.apply(
+            {"params": params}, token, position[:, None], mask, caches
+        )
+        return jnp.argmax(logits[:, -1], axis=-1), caches
+
+    return _decode_step
+
+
+def generate_scan_program(model: LlamaModel, config: LlamaConfig,
+                          eos_id: int, mesh=None):
+    """Prefill and every decode step of a batch as one jitted program."""
+
+    @partial(jax.jit, static_argnames=("max_new_tokens", "early_exit"))
+    def _generate_scan(params, prompt_ids, prompt_lens, max_new_tokens,
+                       early_exit=True):
+        """Batched greedy decode as ONE compiled program.
+
+        The reference's generation is a remote server call per song
+        (``scripts/sentiment_classifier.py:94``); a naive on-device port
+        would still pay one host→device round-trip per token.  Here
+        prefill + every decode step run inside a single jit: the token
+        loop is a ``lax.scan`` over the KV cache (static trip count,
+        EOS handled by masking — XLA-shaped control flow, SURVEY.md
+        §2.4 design notes).  With ``early_exit`` the scan is cut into
+        fixed-size segments under a ``lax.while_loop`` whose predicate
+        stops once every row has emitted EOS: the all-done tail of a
+        short batch is skipped instead of decoded, and because the
+        token buffer is pre-filled with EOS (exactly what the skipped
+        steps would have emitted) the outputs are identical to the
+        full scan.
+        """
+        B, S = prompt_ids.shape
+        positions = jnp.arange(S)[None, :].repeat(B, 0)
+        total = S + max_new_tokens
+        mask = causal_mask(S, total, 0) & jnp.pad(
+            padding_mask(prompt_lens, S),
+            ((0, 0), (0, 0), (0, 0), (0, max_new_tokens)),
+        )
+        caches = init_caches(config, B, total)
+        logits, caches = model.apply(
+            {"params": params}, prompt_ids, positions, mask, caches,
+            last_position=prompt_lens - 1,
+            prefill_lengths=_prefill_lengths(mesh, prompt_lens),
+        )
+        caches = [c.with_length(S) for c in caches]
+        first = jnp.argmax(logits[:, 0], axis=-1)  # [B]
+        eos = jnp.asarray(eos_id, jnp.int32)
+
+        def step(carry, t):
+            # Ragged prompts: row b's decode token t sits at *slot*
+            # S + t (uniform, so one dynamic_update_slice serves the
+            # whole batch) while its *position* is prompt_lens[b] + t
+            # (per-row, for RoPE and the mask) — the same slot/position
+            # split _score_labels uses.
+            token, done, caches = carry
+            pos = prompt_lens + t                              # [B]
+            kv_pos = jnp.arange(total)[None, None, None, :]
+            prompt_part = kv_pos < prompt_lens[:, None, None, None]
+            decode_part = (kv_pos >= S) & (kv_pos - S <= t)
+            step_mask = prompt_part | decode_part
+            lg, caches = model.apply(
+                {"params": params}, token[:, None], pos[:, None],
+                step_mask, caches,
+            )
+            nxt = jnp.argmax(lg[:, -1], axis=-1).astype(jnp.int32)
+            done = done | (token == eos)
+            nxt = jnp.where(done, eos, nxt)
+            return (nxt, done, caches), token
+
+        init = (first.astype(jnp.int32), first == eos, caches)
+        if not early_exit:
+            (_, _, caches), tokens = jax.lax.scan(
+                step, init, jnp.arange(max_new_tokens)
+            )
+            return tokens.T  # [B, max_new_tokens]
+
+        # Early exit: fixed-size scan segments inside a while_loop with
+        # an all-done predicate between segments.  Segment boundaries
+        # keep the compiled-shape set O(1); the EOS-pre-filled buffer
+        # makes a skipped tail byte-identical to a decoded one (post-
+        # done steps emit exactly EOS).
+        seg = min(8, max_new_tokens)
+        n_seg = -(-max_new_tokens // seg)
+        buf = jnp.full((n_seg * seg, B), eos, jnp.int32)
+
+        def seg_cond(state):
+            k, _, done, _, _ = state
+            return (k < n_seg) & ~jnp.all(done)
+
+        def seg_body(state):
+            k, token, done, caches, buf = state
+            (token, done, caches), seg_tokens = jax.lax.scan(
+                step, (token, done, caches),
+                k * seg + jnp.arange(seg),
+            )
+            buf = jax.lax.dynamic_update_slice(
+                buf, seg_tokens, (k * seg, jnp.asarray(0, jnp.int32))
+            )
+            return (k + 1, token, done, caches, buf)
+
+        state = (jnp.asarray(0, jnp.int32),) + init + (buf,)
+        _, _, _, _, buf = jax.lax.while_loop(seg_cond, seg_body, state)
+        return buf[:max_new_tokens].T  # [B, max_new_tokens]
+
+    return _generate_scan
+
+
+def build_params(model: LlamaModel, config: LlamaConfig,
+                 checkpoint_path: Optional[str] = None, seed: int = 0,
+                 mesh=None, wq_cache_dir: Optional[str] = None):
+    """``(params, pretrained)``: the checkpoint's weights where a path is
+    given (streamed through quantize-on-load under ``weight_quant``), else
+    seeded random ones in the configuration's ``param_dtype``; not yet
+    placed on ``mesh`` (``parallel/sharding.shard_params`` does that)."""
+    dummy_ids = jnp.zeros((1, 8), jnp.int32)
+    dummy_pos = jnp.zeros((1, 8), jnp.int32)
+    dummy_mask = causal_mask(8, 8, 0)
+    wq = config.weight_quant
+    if checkpoint_path and wq != "none":
+        # Streaming quantize-on-load: the float tree is never
+        # materialized — shapes come from eval_shape, checkpoint
+        # tensors stream through quantize→H2D one layer at a time,
+        # and a warm wq-cache hit skips torch entirely.
+        from music_analyst_tpu.engines import wq_cache
+        from music_analyst_tpu.engines.checkpoint import (
+            load_quantized_params,
+        )
+
+        params_shape = jax.eval_shape(
+            model.init, jax.random.key(seed), dummy_ids,
+            dummy_pos, dummy_mask,
+        )["params"]
+        cache_dir = wq_cache.resolve_cache_dir(wq_cache_dir)
+        cache_key = (
+            wq_cache.wq_key(checkpoint_path, "llama", wq,
+                            _wq_group_size())
+            if cache_dir else None
+        )
+        params = load_quantized_params(
+            params_shape,
+            lambda: iter_hf_param_units(
+                params_shape, checkpoint_path, mmap=True
+            ),
+            wq,
+            group_size=_wq_group_size(),
+            mesh=mesh,
+            cache_dir=cache_dir,
+            cache_key=cache_key,
+        )
+        return params, True
+    if config.param_dtype != "float32":
+        if checkpoint_path:
+            raise ValueError(
+                "no checkpoint loader maps onto this configuration's "
+                "layers yet; it runs seeded random weights"
+            )
+        return init_params_by_layer(config, seed), False
+    params = model.init(
+        jax.random.key(seed), dummy_ids, dummy_pos, dummy_mask
+    )["params"]
+    if checkpoint_path:
+        params = load_hf_torch_checkpoint(params, checkpoint_path)
+    if wq != "none":
+        # Random-init WQ model (smoke/A-B runs): quantize the
+        # just-initialized tree in place so the forward exercises
+        # the exact stored-weight path a checkpoint load produces.
+        from music_analyst_tpu.ops.quant import quantize_tree
+
+        params = quantize_tree(params, wq, _wq_group_size())
+    return params, bool(checkpoint_path)
+
+
+def _label_table(tokenizer):
+    """``(ids [3, 8], lens [3])``: the label continuations scored
+    teacher-forced after a shared prompt prefill, padded to one fixed
+    length so a single jitted function scores them as a batch dimension."""
+    bos_id = getattr(tokenizer, "bos_id", None)
+    label_rows, label_lens = [], []
+    for label in SUPPORTED_LABELS:
+        row, n = tokenizer.encode(label, 16)
+        # Drop the leading BOS only if this tokenizer actually adds one
+        # (HF tokenizers with add_bos_token=False don't).
+        skip = 1 if (n > 0 and bos_id is not None
+                     and row[0] == bos_id) else 0
+        if getattr(tokenizer, "closes_labels", False):
+            # A word-level tokenizer gives every label one token, and
+            # a one-token continuation never reads its own forward
+            # pass: score "label, then stop" (EOS) as the answer.
+            row = np.insert(row, n, tokenizer.eos_id)
+            n += 1
+        label_rows.append(row[skip:skip + 8])  # fixed len 8
+        label_lens.append(min(n - skip, 8))
+    return np.stack(label_rows), np.array(label_lens, dtype=np.int32)
+
+
+def zero_shot_prompt(lyrics: str) -> str:
+    """The reference's prompt around one song's lyrics."""
+    return PROMPT_TEMPLATE.format(lyrics=lyrics.strip()[:LYRICS_TRUNCATION])
+
+
 class LlamaZeroShotClassifier(ClassifierBackend):
-    """Constrained-label zero-shot sentiment over the decoder LM."""
+    """Constrained-label zero-shot sentiment over the decoder LM.
+
+    Holds what a decoder is made of — ``model``, ``params``, ``config``,
+    ``tokenizer``, ``mesh``, ``max_prompt_len`` — and the prompt and label
+    handling of the sentiment task; the programs it runs are built above,
+    and the continuous decode runtimes are built from the same six
+    attributes by ``serving/decode_runtime.py``.
+    """
 
     name = "llama"
 
@@ -689,32 +1029,8 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         max_prompt_len: int = 1024,
         mesh=None,
         seed: int = 0,
-        decode_mode: str = "score",
         wq_cache_dir: Optional[str] = None,
-        continuous_slots: Optional[int] = None,
     ) -> None:
-        if decode_mode not in ("score", "generate"):
-            raise ValueError(
-                f"decode_mode must be 'score' or 'generate', got "
-                f"{decode_mode!r}"
-            )
-        self.decode_mode = decode_mode
-        # > 0 routes classify_batch_by_generation / generate_batch through
-        # the continuous slot runtime (ops/kv_slots.py) at that slot count;
-        # None/0 keeps the static scan path.  Env fallback so CLI runs can
-        # opt in without new plumbing at every call site.
-        if continuous_slots is None:
-            env = os.environ.get("MUSICAAL_CONTINUOUS_SLOTS", "").strip()
-            if env:
-                try:
-                    continuous_slots = int(env)
-                except ValueError:
-                    raise ValueError(
-                        f"MUSICAAL_CONTINUOUS_SLOTS must be an integer, "
-                        f"got {env!r}"
-                    ) from None
-        self.continuous_slots = int(continuous_slots or 0)
-        self._slot_schedulers: dict = {}
         self.config = config or LlamaConfig.tiny()
         self.max_prompt_len = max_prompt_len
         self.tokenizer = resolve_llama_tokenizer(
@@ -736,68 +1052,9 @@ class LlamaZeroShotClassifier(ClassifierBackend):
             warnings.warn(message + "; out-of-range ids will clamp",
                           stacklevel=2)
         self.model = LlamaModel(self.config)
-        dummy_ids = jnp.zeros((1, 8), jnp.int32)
-        dummy_pos = jnp.zeros((1, 8), jnp.int32)
-        dummy_mask = causal_mask(8, 8, 0)
-        wq = self.config.weight_quant
-        self.pretrained = False
-        if checkpoint_path and wq != "none":
-            # Streaming quantize-on-load: the float tree is never
-            # materialized — shapes come from eval_shape, checkpoint
-            # tensors stream through quantize→H2D one layer at a time,
-            # and a warm wq-cache hit skips torch entirely.
-            from music_analyst_tpu.engines import wq_cache
-            from music_analyst_tpu.engines.checkpoint import (
-                load_quantized_params,
-            )
-
-            params_shape = jax.eval_shape(
-                self.model.init, jax.random.key(seed), dummy_ids,
-                dummy_pos, dummy_mask,
-            )["params"]
-            cache_dir = wq_cache.resolve_cache_dir(wq_cache_dir)
-            cache_key = (
-                wq_cache.wq_key(checkpoint_path, "llama", wq,
-                                _wq_group_size())
-                if cache_dir else None
-            )
-            self.params = load_quantized_params(
-                params_shape,
-                lambda: iter_hf_param_units(
-                    params_shape, checkpoint_path, mmap=True
-                ),
-                wq,
-                group_size=_wq_group_size(),
-                mesh=mesh,
-                cache_dir=cache_dir,
-                cache_key=cache_key,
-            )
-            self.pretrained = True
-        elif self.config.param_dtype != "float32":
-            if checkpoint_path:
-                raise ValueError(
-                    "no checkpoint loader maps onto this configuration's "
-                    "layers yet; it runs seeded random weights"
-                )
-            self.params = init_params_by_layer(self.config, seed)
-        else:
-            self.params = self.model.init(
-                jax.random.key(seed), dummy_ids, dummy_pos, dummy_mask
-            )["params"]
-            if checkpoint_path:
-                self.params = load_hf_torch_checkpoint(
-                    self.params, checkpoint_path
-                )
-                self.pretrained = True
-            if wq != "none":
-                # Random-init WQ model (smoke/A-B runs): quantize the
-                # just-initialized tree in place so the forward exercises
-                # the exact stored-weight path a checkpoint load produces.
-                from music_analyst_tpu.ops.quant import quantize_tree
-
-                self.params = quantize_tree(
-                    self.params, wq, _wq_group_size()
-                )
+        self.params, self.pretrained = build_params(
+            self.model, self.config, checkpoint_path, seed, mesh,
+            wq_cache_dir)
         if self.pretrained and isinstance(self.tokenizer, ByteTokenizer):
             import warnings
 
@@ -813,239 +1070,18 @@ class LlamaZeroShotClassifier(ClassifierBackend):
             from music_analyst_tpu.parallel.sharding import shard_params
 
             self.params = shard_params(self.params, mesh)
-
-        # Label continuations are scored teacher-forced after a shared
-        # prompt prefill.  All three labels are padded to one fixed length
-        # so a single jitted function scores them as a batch dimension.
-        bos_id = getattr(self.tokenizer, "bos_id", None)
-        label_rows, label_lens = [], []
-        for label in SUPPORTED_LABELS:
-            row, n = self.tokenizer.encode(label, 16)
-            # Drop the leading BOS only if this tokenizer actually adds one
-            # (HF tokenizers with add_bos_token=False don't).
-            skip = 1 if (n > 0 and bos_id is not None
-                         and row[0] == bos_id) else 0
-            if getattr(self.tokenizer, "closes_labels", False):
-                # A word-level tokenizer gives every label one token, and
-                # a one-token continuation never reads its own forward
-                # pass: score "label, then stop" (EOS) as the answer.
-                row = np.insert(row, n, self.tokenizer.eos_id)
-                n += 1
-            label_rows.append(row[skip:skip + 8])  # fixed len 8
-            label_lens.append(min(n - skip, 8))
-        self._label_ids = np.stack(label_rows)
-        self._label_lens = np.array(label_lens, dtype=np.int32)
-
-        def _score_labels(params, prompt_ids, prompt_lens, label_ids,
-                          label_lens):
-            """Log-likelihood of each label continuation per batch row.
-
-            prompt_ids [B, S]; label_ids [3, L].  Returns ``(scores [B, 3],
-            stats)``: ``stats`` holds the small device-side reductions that
-            ride back with the scores (``expert_load_max`` /
-            ``expert_load_mean`` ``[routed layers]`` of the prefill, for a
-            model with routed experts; else empty).
-            """
-            B, S = prompt_ids.shape
-            n_labels, L = label_ids.shape
-            # prompt_lens may arrive int16 (wire narrowing) — widen once
-            # on device before the arithmetic/broadcast uses below.
-            prompt_lens = prompt_lens.astype(jnp.int32)
-            positions = jnp.arange(S)[None, :].repeat(B, 0)
-            # kv length is S+L (the cache buffer); the label slots are
-            # causally unreachable during prefill and masked out anyway.
-            mask = causal_mask(S, S + L, 0) & jnp.pad(
-                padding_mask(prompt_lens, S),
-                ((0, 0), (0, 0), (0, 0), (0, L)),
-            )
-            caches = init_caches(self.config, B, S + L)
-            # last_position: only the final prompt logits are consumed, so
-            # the [B,S,V] prefill logits are never materialized.
-            (logits, caches), sown = self.model.apply(
-                {"params": params}, prompt_ids, positions, mask, caches,
-                last_position=prompt_lens - 1,
-                prefill_lengths=self._prefill_lengths(prompt_lens),
-                mutable=["intermediates"],
-            )
-            stats = {}
-            loads = _sown_by_layer(sown, "expert_load")
-            if loads:
-                load = jnp.stack(loads).astype(jnp.float32)  # [layers, E]
-                stats = {"expert_load_max": load.max(axis=-1),
-                         "expert_load_mean": load.mean(axis=-1),
-                         # [layers, B, S, k]: which experts every position
-                         # ran, for whoever compares against a reference
-                         "chosen": _expert_ids(
-                             jnp.stack(_sown_by_layer(sown, "chosen")),
-                             self.config.n_experts)}
-            # Force every cache to report the true prompt length so label
-            # positions line up even though the buffer was written at 0..S.
-            caches = [c.with_length(S) for c in caches]
-            last_logits = logits[:, 0]  # [B, V]
-
-            def score_one(label_row, label_len):
-                lab = jnp.broadcast_to(label_row[None, :], (B, L))
-                pos = prompt_lens[:, None] + jnp.arange(L)[None, :]
-                # decode attends to the full prompt (masked by its length)
-                # plus the causal prefix of the label tokens
-                kv_len = S + L
-                kv_pos = jnp.arange(kv_len)[None, None, None, :]
-                prompt_part = kv_pos < prompt_lens[:, None, None, None]
-                label_part = (kv_pos >= S) & (
-                    kv_pos - S <= jnp.arange(L)[None, None, :, None]
-                )
-                mask2 = prompt_part | label_part
-                (logits2, _), sown2 = self.model.apply(
-                    {"params": params}, lab, pos, mask2, caches,
-                    mutable=["intermediates"],
-                )
-                # token 0 scored from the prompt's last logits; tokens i>0
-                # from the label forward pass
-                logp_all = jax.nn.log_softmax(logits2, axis=-1)
-                first_lp = jnp.take_along_axis(
-                    jax.nn.log_softmax(last_logits, axis=-1),
-                    lab[:, :1], axis=1,
-                )[:, 0]
-                rest_lp = jnp.take_along_axis(
-                    logp_all[:, :-1], lab[:, 1:, None], axis=2
-                )[:, :, 0]
-                idx = jnp.arange(L - 1)[None, :]
-                rest_lp = jnp.where(idx < label_len - 1, rest_lp, 0.0)
-                # Length-normalize: summed log-probs otherwise favor the
-                # shortest label ("Neutral" is one byte shorter than the
-                # other two under the byte tokenizer).
-                total = first_lp + rest_lp.sum(axis=1)
-                chosen2 = _sown_by_layer(sown2, "chosen")
-                return (
-                    total / jnp.maximum(label_len.astype(jnp.float32), 1.0),
-                    _expert_ids(jnp.stack(chosen2), self.config.n_experts)
-                    if chosen2 else None,
-                )
-
-            scores, label_chosen = jax.vmap(
-                score_one, in_axes=(0, 0), out_axes=(1, 0)
-            )(label_ids, label_lens)
-            if label_chosen is not None:
-                stats["chosen_labels"] = label_chosen  # [3, layers, B, L, k]
-            return scores, stats  # [B, 3]
-
-        from music_analyst_tpu.profiling.compile import profiled_jit
-
-        self._score_labels = profiled_jit(
-            _score_labels, name="llama_score_labels")
-
-        @jax.jit
-        def _decode_step(params, token, position, caches):
-            B = token.shape[0]
-            kv_len = caches[0].max_len
-            kv_pos = jnp.arange(kv_len)[None, None, None, :]
-            mask = kv_pos <= position[:, None, None, None]
-            logits, caches = self.model.apply(
-                {"params": params}, token, position[:, None], mask, caches
-            )
-            return jnp.argmax(logits[:, -1], axis=-1), caches
-
-        self._decode_step = _decode_step
-
-        @partial(jax.jit, static_argnames=("max_new_tokens", "early_exit"))
-        def _generate_scan(params, prompt_ids, prompt_lens, max_new_tokens,
-                           early_exit=True):
-            """Batched greedy decode as ONE compiled program.
-
-            The reference's generation is a remote server call per song
-            (``scripts/sentiment_classifier.py:94``); a naive on-device port
-            would still pay one host→device round-trip per token.  Here
-            prefill + every decode step run inside a single jit: the token
-            loop is a ``lax.scan`` over the KV cache (static trip count,
-            EOS handled by masking — XLA-shaped control flow, SURVEY.md
-            §2.4 design notes).  With ``early_exit`` the scan is cut into
-            fixed-size segments under a ``lax.while_loop`` whose predicate
-            stops once every row has emitted EOS: the all-done tail of a
-            short batch is skipped instead of decoded, and because the
-            token buffer is pre-filled with EOS (exactly what the skipped
-            steps would have emitted) the outputs are identical to the
-            full scan.
-            """
-            B, S = prompt_ids.shape
-            positions = jnp.arange(S)[None, :].repeat(B, 0)
-            total = S + max_new_tokens
-            mask = causal_mask(S, total, 0) & jnp.pad(
-                padding_mask(prompt_lens, S),
-                ((0, 0), (0, 0), (0, 0), (0, max_new_tokens)),
-            )
-            caches = init_caches(self.config, B, total)
-            logits, caches = self.model.apply(
-                {"params": params}, prompt_ids, positions, mask, caches,
-                last_position=prompt_lens - 1,
-                prefill_lengths=self._prefill_lengths(prompt_lens),
-            )
-            caches = [c.with_length(S) for c in caches]
-            first = jnp.argmax(logits[:, 0], axis=-1)  # [B]
-            eos = jnp.asarray(self.tokenizer.eos_id, jnp.int32)
-
-            def step(carry, t):
-                # Ragged prompts: row b's decode token t sits at *slot*
-                # S + t (uniform, so one dynamic_update_slice serves the
-                # whole batch) while its *position* is prompt_lens[b] + t
-                # (per-row, for RoPE and the mask) — the same slot/position
-                # split _score_labels uses.
-                token, done, caches = carry
-                pos = prompt_lens + t                              # [B]
-                kv_pos = jnp.arange(total)[None, None, None, :]
-                prompt_part = kv_pos < prompt_lens[:, None, None, None]
-                decode_part = (kv_pos >= S) & (kv_pos - S <= t)
-                step_mask = prompt_part | decode_part
-                lg, caches = self.model.apply(
-                    {"params": params}, token[:, None], pos[:, None],
-                    step_mask, caches,
-                )
-                nxt = jnp.argmax(lg[:, -1], axis=-1).astype(jnp.int32)
-                done = done | (token == eos)
-                nxt = jnp.where(done, eos, nxt)
-                return (nxt, done, caches), token
-
-            init = (first.astype(jnp.int32), first == eos, caches)
-            if not early_exit:
-                (_, _, caches), tokens = jax.lax.scan(
-                    step, init, jnp.arange(max_new_tokens)
-                )
-                return tokens.T  # [B, max_new_tokens]
-
-            # Early exit: fixed-size scan segments inside a while_loop with
-            # an all-done predicate between segments.  Segment boundaries
-            # keep the compiled-shape set O(1); the EOS-pre-filled buffer
-            # makes a skipped tail byte-identical to a decoded one (post-
-            # done steps emit exactly EOS).
-            seg = min(8, max_new_tokens)
-            n_seg = -(-max_new_tokens // seg)
-            buf = jnp.full((n_seg * seg, B), eos, jnp.int32)
-
-            def seg_cond(state):
-                k, _, done, _, _ = state
-                return (k < n_seg) & ~jnp.all(done)
-
-            def seg_body(state):
-                k, token, done, caches, buf = state
-                (token, done, caches), seg_tokens = jax.lax.scan(
-                    step, (token, done, caches),
-                    k * seg + jnp.arange(seg),
-                )
-                buf = jax.lax.dynamic_update_slice(
-                    buf, seg_tokens, (k * seg, jnp.asarray(0, jnp.int32))
-                )
-                return (k + 1, token, done, caches, buf)
-
-            state = (jnp.asarray(0, jnp.int32),) + init + (buf,)
-            _, _, _, _, buf = jax.lax.while_loop(seg_cond, seg_body, state)
-            return buf[:max_new_tokens].T  # [B, max_new_tokens]
-
-        self._generate_scan = _generate_scan
+        self._label_ids, self._label_lens = _label_table(self.tokenizer)
+        self._score_labels = score_labels_program(
+            self.model, self.config, mesh)
+        self._decode_step = decode_step_program(self.model)
+        self._generate_scan = generate_scan_program(
+            self.model, self.config, self.tokenizer.eos_id, mesh)
 
     @property
     def decode_runtime_refusal(self) -> Optional[str]:
-        """Why the continuous decode runtimes (``slot_runtime`` /
-        ``paged_runtime``) cannot host this model, or ``None`` where they
-        can.  ``serve`` reads it to leave the ``generate`` op off."""
+        """Why the continuous decode runtimes (``serving/
+        decode_runtime.py``) cannot host this model, or ``None`` where
+        they can.  ``serve`` reads it to leave the ``generate`` op off."""
         return LATENT_CACHE_REFUSAL if self.config.latent_cache else None
 
     @classmethod
@@ -1095,35 +1131,18 @@ class LlamaZeroShotClassifier(ClassifierBackend):
                     self.max_prompt_len)
         return ids[:, :width], lens
 
-    def _prefill_lengths(self, prompt_lens):
-        """What a prefill from position 0 on empty caches hands the
-        latent-attention layers beside the mask (``models/mla.py``: the
-        kernel that reads lengths in its place): the prompts' lengths on
-        one device, nothing under a mesh.  The kernel's call is opaque to
-        the partitioner, which would gather its operands and give every
-        chip all the work, where the XLA form is partitioned."""
-        if self.mesh is not None and self.mesh.size > 1:
-            return None
-        return prompt_lens
-
     def _encode_prompts(self, texts: Sequence[str]):
-        prompts = [
-            PROMPT_TEMPLATE.format(lyrics=t.strip()[:LYRICS_TRUNCATION])
-            for t in texts
-        ]
-        ids, lens = self.tokenizer.encode_batch(prompts, self.max_prompt_len)
+        ids, lens = self.tokenizer.encode_batch(
+            [zero_shot_prompt(t) for t in texts], self.max_prompt_len)
         return self._trim_prompt_pad(ids, lens)
 
     # Staged hooks for the prefetch pipeline (engines/sentiment.py): a
     # step's read and tokenize overlap the device's work on the step
-    # before.  ``decode_mode="generate"`` has no staged form: its hooks
-    # pass the texts through and ``launch`` classifies synchronously.
+    # before.
 
     def prepare(self, texts: Sequence[str]):
         """Host phase: the prompts' token ids at the batch's trimmed
         width, lengths narrowed for the wire."""
-        if self.decode_mode == "generate":
-            return texts
         from music_analyst_tpu.runtime.wire import narrow_lengths
 
         prompt_ids, prompt_lens = self._encode_prompts(texts)
@@ -1133,8 +1152,6 @@ class LlamaZeroShotClassifier(ClassifierBackend):
                 narrow_lengths(prompt_lens, self.max_prompt_len))
 
     def transfer(self, prepared):
-        if self.decode_mode == "generate":
-            return prepared
         from music_analyst_tpu.runtime.wire import count_h2d_bytes
 
         texts, prompt_ids, prompt_lens = prepared
@@ -1148,8 +1165,6 @@ class LlamaZeroShotClassifier(ClassifierBackend):
     def launch(self, transferred):
         """Dispatch the scoring program (JAX async dispatch: the handle
         holds device arrays, nothing blocks)."""
-        if self.decode_mode == "generate":
-            return self.classify_batch_by_generation(transferred)
         texts, prompt_ids, prompt_lens, real = transferred
         scores, stats = self._score_labels(
             self.params, prompt_ids, prompt_lens,
@@ -1161,8 +1176,6 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         return self.launch(self.transfer(self.prepare(texts)))
 
     def collect(self, handle) -> List[str]:
-        if isinstance(handle, list):  # generate mode classified in launch
-            return handle
         texts, scores, stats, (rows, width), real = handle
         best = np.asarray(scores).argmax(axis=1)
         self._count_step(rows, width, real, stats)
@@ -1258,7 +1271,9 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         API parity and as the differential oracle).  ``early_exit`` stops
         decoding once every row has emitted EOS (identical outputs either
         way; ``False`` keeps the always-``max_new_tokens`` scan as the
-        equivalence oracle).
+        equivalence oracle).  The continuous-batching sibling (slots that
+        free mid-flight) is ``serving/decode_runtime.
+        generate_batch_continuous``.
         """
         ids, lens = self.tokenizer.encode_batch(prompts, self.max_prompt_len)
         ids, lens = self._trim_prompt_pad(ids, lens)
@@ -1279,194 +1294,9 @@ class LlamaZeroShotClassifier(ClassifierBackend):
             outs.append(self.tokenizer.decode(ids_out))
         return outs
 
-    def slot_runtime(
-        self,
-        n_slots: int = 8,
-        prefill_chunk: int = 64,
-        max_new_tokens: int = 16,
-        prompt_region: Optional[int] = None,
-        decode_span: int = 4,
-    ):
-        """Build the continuous-batching device runtime for this model.
-
-        The presence of this method is the capability probe the serving
-        layer uses (``hasattr(backend, "slot_runtime")``) to decide whether
-        a server can host the ``generate`` task.
-        """
-        from music_analyst_tpu.ops.kv_slots import SlotDecodeRuntime, SlotPlan
-
-        if self.decode_runtime_refusal:
-            raise NotImplementedError(
-                self.decode_runtime_refusal.format(runtime="slot"))
-        chunk = max(1, min(int(prefill_chunk), self.max_prompt_len))
-        if prompt_region is None:
-            prompt_region = self.max_prompt_len
-        region = min(int(prompt_region), self.max_prompt_len)
-        region = max(chunk, chunk * ((region + chunk - 1) // chunk))
-        plan = SlotPlan(
-            n_slots=int(n_slots),
-            prefill_chunk=chunk,
-            prompt_region=region,
-            max_new=int(max_new_tokens),
-            decode_span=int(decode_span),
-        )
-        eos_id = getattr(self.tokenizer, "eos_id", ByteTokenizer.EOS)
-        return SlotDecodeRuntime(self.model, self.config, plan, eos_id,
-                                 mesh=self.mesh)
-
-    def paged_runtime(
-        self,
-        n_slots: int = 8,
-        prefill_chunk: int = 64,
-        max_new_tokens: int = 16,
-        prompt_region: Optional[int] = None,
-        decode_span: int = 4,
-        page_size: int = 16,
-        kv_pages: int = 0,
-        kv_quant: str = "none",
-    ):
-        """Build the prefix-shared paged decode runtime for this model.
-
-        The paged sibling of :meth:`slot_runtime` (and the capability
-        probe the serving layer uses for the default KV backend): the
-        per-slot KV buffer becomes a view through an int32 page table
-        over a shared page pool, so sequences with a common token prefix
-        — every zero-shot prompt shares ``PROMPT_TEMPLATE``'s head —
-        can map the same physical pages.  Prefix identity is keyed on
-        *token ids* (whatever tokenizer is resolved), not on text, so
-        byte/llama tokenizers share exactly what their encodings share.
-        ``kv_pages=0`` auto-sizes the pool to one full sequence per slot.
-        ``kv_quant="int8"`` stores the page pool as int8 codes with
-        per-(page, row) scales, dequantized inside the fused
-        paged-attention kernel (ops/paged_attention.py).
-        """
-        import math
-
-        from music_analyst_tpu.ops.kv_pages import PagedDecodeRuntime, PagePlan
-        from music_analyst_tpu.utils.shapes import round_pow2
-
-        if self.decode_runtime_refusal:
-            raise NotImplementedError(
-                self.decode_runtime_refusal.format(runtime="paged"))
-        chunk = max(1, min(int(prefill_chunk), self.max_prompt_len))
-        if prompt_region is None:
-            prompt_region = self.max_prompt_len
-        region = min(int(prompt_region), self.max_prompt_len)
-        region = max(chunk, chunk * ((region + chunk - 1) // chunk))
-        page = min(round_pow2(max(1, int(page_size)), 1), region)
-        # The region must be a multiple of both the chunk and the page.
-        unit = math.lcm(chunk, page)
-        region = unit * ((region + unit - 1) // unit)
-        pages_per_slot = region // page + -(-int(max_new_tokens) // page)
-        n_pages = int(kv_pages) or int(n_slots) * pages_per_slot
-        n_pages = max(n_pages, int(n_slots), pages_per_slot)
-        plan = PagePlan(
-            n_slots=int(n_slots),
-            prefill_chunk=chunk,
-            prompt_region=region,
-            max_new=int(max_new_tokens),
-            decode_span=int(decode_span),
-            page_size=page,
-            n_pages=n_pages,
-        )
-        eos_id = getattr(self.tokenizer, "eos_id", ByteTokenizer.EOS)
-        return PagedDecodeRuntime(self.model, self.config, plan, eos_id,
-                                  mesh=self.mesh, kv_quant=kv_quant)
-
-    def generate_batch_continuous(
-        self,
-        prompts: Sequence[str],
-        max_new_tokens: int = 16,
-        n_slots: Optional[int] = None,
-        prefill_chunk: int = 64,
-        decode_span: int = 4,
-        budgets: Optional[Sequence[int]] = None,
-        page_size: Optional[int] = None,
-        kv_pages: Optional[int] = None,
-        kv_quant: Optional[str] = None,
-        prefix_cache: bool = True,
-        speculate_k: Optional[int] = None,
-    ) -> List[str]:
-        """Greedy generation via the continuous slot runtime, synchronously.
-
-        Same outputs as :meth:`generate_batch` (byte-identical tokens per
-        prompt — the slot cache mirrors the static layout, see
-        ``ops/kv_slots.py``), but requests flow through admit→prefill→
-        decode slots instead of one padded static batch, so rows with
-        small ``budgets`` release their compute to waiting prompts
-        mid-flight.  The scheduler is cached per geometry, so repeat calls
-        reuse the compiled programs.
-
-        The KV cache is paged with prefix sharing by default (see
-        :meth:`paged_runtime`): prompts sharing a token-id prefix — the
-        zero-shot template head, repeat songs — skip the shared prefill
-        chunks and share physical pages.  ``page_size=0`` pins the
-        monolithic slot cache; ``prefix_cache=False`` pages without
-        sharing.  ``speculate_k > 0`` turns on draft-and-verify
-        speculative decoding (see ``serving/decode_loop.py``) — fewer
-        dispatches on self-similar completions.  All routes emit
-        byte-identical tokens.
-        """
-        from music_analyst_tpu.serving.decode_loop import ContinuousScheduler
-        from music_analyst_tpu.utils.shapes import round_pow2
-
-        if not prompts:
-            return []
-        n_slots = int(n_slots or self.continuous_slots or 8)
-        budgets = (
-            [int(b) for b in budgets]
-            if budgets is not None
-            else [int(max_new_tokens)] * len(prompts)
-        )
-        if len(budgets) != len(prompts):
-            raise ValueError("budgets must match prompts 1:1")
-        # Match the static path's padded prompt width exactly so the slot
-        # cache's KV geometry (and therefore every greedy token) lines up
-        # with generate_batch on the same prompts.
-        _, lens = self.tokenizer.encode_batch(prompts, self.max_prompt_len)
-        longest = int(lens.max()) if len(lens) else 1
-        region = min(round_pow2(longest, 64), self.max_prompt_len)
-        chunk = min(int(prefill_chunk), region)
-        cap = max(1, max(budgets))
-        key = (n_slots, chunk, region, cap, int(decode_span),
-               page_size, kv_pages, kv_quant, bool(prefix_cache), speculate_k)
-        sched = self._slot_schedulers.get(key)
-        if sched is None:
-            sched = ContinuousScheduler(
-                self,
-                n_slots=n_slots,
-                prefill_chunk=chunk,
-                prompt_region=region,
-                max_new_tokens=cap,
-                decode_span=int(decode_span),
-                max_queue=max(len(prompts), 64),
-                page_size=page_size,
-                kv_pages=kv_pages,
-                kv_quant=kv_quant,
-                prefix_cache=prefix_cache,
-                speculate_k=speculate_k,
-            )
-            self._slot_schedulers[key] = sched
-        reqs = [
-            sched.submit(i, prompt, max_new_tokens=budget)
-            for i, (prompt, budget) in enumerate(zip(prompts, budgets))
-        ]
-        sched.run_until_idle()
-        outs = []
-        for req in reqs:
-            resp = req.response or {}
-            if not resp.get("ok"):
-                raise RuntimeError(
-                    f"continuous generation failed for prompt {req.id}: "
-                    f"{resp.get('error', 'unknown error')}"
-                )
-            outs.append(resp["text"])
-        return outs
-
     def classify_by_generation(self, text: str) -> str:
         """Reference-semantics path: generate text, normalise first token."""
-        prompt = PROMPT_TEMPLATE.format(lyrics=text.strip()[:LYRICS_TRUNCATION])
-        return normalise_label(self.generate(prompt))
+        return normalise_label(self.generate(zero_shot_prompt(text)))
 
     def classify_batch_by_generation(
         self, texts: Sequence[str]
@@ -1475,20 +1305,10 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         (one scan-jitted program for the whole batch) then the shared label
         normalizer (``scripts/sentiment_classifier.py:102-108``, empty-
         output crash fixed)."""
-        prompts = [
-            PROMPT_TEMPLATE.format(lyrics=t.strip()[:LYRICS_TRUNCATION])
-            for t in texts
-        ]
         # Same token budget as generate()'s default so the batch path and
-        # the single-song reference path yield identical labels.  With
-        # continuous_slots set, batch generation rides the continuous slot
-        # runtime (identical tokens; see generate_batch_continuous).
-        if self.continuous_slots:
-            generations = self.generate_batch_continuous(
-                prompts, max_new_tokens=16, n_slots=self.continuous_slots
-            )
-        else:
-            generations = self.generate_batch(prompts, max_new_tokens=16)
+        # the single-song reference path yield identical labels.
+        generations = self.generate_batch(
+            [zero_shot_prompt(t) for t in texts], max_new_tokens=16)
         return [
             "Neutral" if not text.strip() else normalise_label(gen)
             for text, gen in zip(texts, generations)

@@ -63,7 +63,7 @@ class LatentCache:
     """Per-layer MLA cache: ``latents [B, max_len, kv_lora_rank]`` (after
     ``kv_a_norm``) and ``rope_keys [B, max_len, rope_dim]`` (after RoPE).
     ``length`` is the scalar write offset every row shares, as in the
-    static-batch :class:`~music_analyst_tpu.models.layers.KVCache`."""
+    static-batch :class:`~music_analyst_tpu.ops.kv_cache.KVCache`."""
 
     latents: jax.Array
     rope_keys: jax.Array

@@ -12,7 +12,7 @@ from __future__ import annotations
 from typing import List, Sequence
 
 
-from music_analyst_tpu.engines.sentiment import ClassifierBackend
+from music_analyst_tpu.models.backend import ClassifierBackend
 from music_analyst_tpu.ops.keyword_sentiment import score_texts
 from music_analyst_tpu.utils.labels import score_to_label
 

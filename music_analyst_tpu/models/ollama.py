@@ -16,7 +16,7 @@ import os
 import time
 from typing import List, Sequence
 
-from music_analyst_tpu.engines.sentiment import ClassifierBackend
+from music_analyst_tpu.models.backend import ClassifierBackend
 from music_analyst_tpu.models.llama import LYRICS_TRUNCATION, PROMPT_TEMPLATE
 from music_analyst_tpu.resilience.faults import fault_point
 from music_analyst_tpu.resilience.policy import (

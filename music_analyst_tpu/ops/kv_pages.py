@@ -62,7 +62,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from music_analyst_tpu.models.layers import KVCache
+from music_analyst_tpu.ops.kv_cache import KVCache
 from music_analyst_tpu.ops.flash_attention import interpret_default
 from music_analyst_tpu.ops.paged_attention import (
     PagedAttnView,

@@ -65,7 +65,7 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from music_analyst_tpu.models.layers import KVCache
+from music_analyst_tpu.ops.kv_cache import KVCache
 from music_analyst_tpu.profiling.compile import profiled_jit
 
 

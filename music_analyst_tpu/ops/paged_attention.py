@@ -50,7 +50,7 @@ int8 and the streaming body carry a bounded-error contract instead
 
 :class:`PagedAttnView` is the cache-shaped adapter: a registered
 dataclass carrying (pool, scales, table, write offsets) that duck-types
-``models/layers.KVCache`` — ``update`` writes the new token's KV row
+``ops/kv_cache.KVCache`` — ``update`` writes the new token's KV row
 directly into its physical page (quantizing per-row for int8) and
 ``attend`` invokes the kernel — so the paged decode runtime passes it
 through the unmodified model stack and the whole decode span runs with
@@ -379,7 +379,7 @@ class PagedAttnView:
     """KVCache-shaped adapter binding one decode step to the page pool.
 
     Carries the physical pool (codes + scales for int8), the page table,
-    and per-slot write offsets; duck-types ``models/layers.KVCache`` so
+    and per-slot write offsets; duck-types ``ops/kv_cache.KVCache`` so
     the unmodified model stack drives the fused kernel: ``update`` lands
     the step's new KV row directly in its physical page (``off // P``
     within the slot's row, quantized per-row for int8) and ``attend``

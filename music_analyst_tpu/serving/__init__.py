@@ -7,7 +7,8 @@ production-inference shape the ROADMAP north star asks for:
 * :mod:`music_analyst_tpu.serving.batcher` — deadline-aware dynamic
   batcher (flush on ``max_batch`` or ``max_wait_ms``) with bounded
   admission queues that shed via structured ``queue_full`` errors;
-* :mod:`music_analyst_tpu.serving.residency` — load-once / warm-once
+* :class:`music_analyst_tpu.models.backend.ModelResidency` (below this
+  package: the batch engines use it too) — load-once / warm-once
   backend holder (weight-quant + persistent caches included);
 * :mod:`music_analyst_tpu.serving.server` — NDJSON protocol over a unix
   socket or stdio, graceful SIGTERM drain, watchdog + flight-recorder
@@ -17,58 +18,11 @@ production-inference shape the ROADMAP north star asks for:
   in ``ops/kv_slots.py``) hosting the ``generate`` op;
 * :mod:`music_analyst_tpu.serving.journal` — durable request journal
   (CRC-framed WAL): replay admitted-but-unanswered requests after a
-  crash, dedup already-sent replies — exactly-once at the wire.
+  crash, dedup already-sent replies — exactly-once at the wire;
+* :mod:`music_analyst_tpu.serving.decode_runtime` — the two decode
+  runtimes built from a decoder's parts, and the one question asked of a
+  backend before the ``generate`` op exists.
+
+Import the module you need: the package itself imports none of them (a
+router parent stays off the scheduler, a batch job off all of it).
 """
-
-from music_analyst_tpu.serving.batcher import (
-    DEFAULT_MAX_BATCH,
-    DEFAULT_MAX_QUEUE,
-    DEFAULT_MAX_WAIT_MS,
-    DEFAULT_PREFILL_CHUNK,
-    DEFAULT_SLOTS,
-    DynamicBatcher,
-    ServeRequest,
-    resolve_max_batch,
-    resolve_max_queue,
-    resolve_max_wait_ms,
-    resolve_prefill_chunk,
-    resolve_slots,
-)
-from music_analyst_tpu.serving.decode_loop import ContinuousScheduler
-from music_analyst_tpu.serving.journal import (
-    RequestJournal,
-    resolve_journal_dir,
-)
-from music_analyst_tpu.serving.residency import ModelResidency, warmup_sizes
-from music_analyst_tpu.serving.server import (
-    PROTOCOL,
-    SentimentServer,
-    build_ops,
-    run_server,
-    serving_stats,
-)
-
-__all__ = [
-    "ContinuousScheduler",
-    "DEFAULT_MAX_BATCH",
-    "DEFAULT_MAX_QUEUE",
-    "DEFAULT_MAX_WAIT_MS",
-    "DEFAULT_PREFILL_CHUNK",
-    "DEFAULT_SLOTS",
-    "DynamicBatcher",
-    "ModelResidency",
-    "PROTOCOL",
-    "RequestJournal",
-    "SentimentServer",
-    "ServeRequest",
-    "build_ops",
-    "resolve_journal_dir",
-    "resolve_max_batch",
-    "resolve_max_queue",
-    "resolve_max_wait_ms",
-    "resolve_prefill_chunk",
-    "resolve_slots",
-    "run_server",
-    "serving_stats",
-    "warmup_sizes",
-]

@@ -98,7 +98,7 @@ import json
 import threading
 import time
 from collections import OrderedDict
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -126,12 +126,14 @@ from music_analyst_tpu.serving.batcher import (
     resolve_tpot_slo_ms,
     resolve_ttft_slo_ms,
 )
+from music_analyst_tpu.serving.decode_runtime import paged_runtime, slot_runtime
 from music_analyst_tpu.serving.response_cache import normalize_text, try_answer
 from music_analyst_tpu.serving.slo import FairQueue, RateMeter, TokenBucket
 from music_analyst_tpu.telemetry import get_telemetry
 from music_analyst_tpu.telemetry.reqtrace import get_reqtrace
 from music_analyst_tpu.telemetry.core import Histogram
 from music_analyst_tpu.utils.labels import normalise_label
+from music_analyst_tpu.utils.shapes import round_pow2
 
 # Per-token latency buckets: decode steps are ms-scale on-device, up to
 # second-scale on the CPU-emulated mesh.
@@ -266,11 +268,13 @@ class _Checkpoint:
 
 
 class ContinuousScheduler:
-    """Admit→prefill→decode loop over a backend's slot runtime.
+    """Admit→prefill→decode loop over a decoder's slot or paged runtime.
 
-    ``backend`` must expose ``slot_runtime(...)`` (capability probe),
-    ``params``, and ``tokenizer`` — ``models/llama.py``'s zero-shot
-    classifier is the canonical one.  Usable two ways: synchronously
+    ``backend`` is a decoder (``model``, ``params``, ``config``,
+    ``tokenizer``, ``mesh``, ``max_prompt_len`` — ``models/llama.py``'s
+    zero-shot classifier is the canonical one) that a runtime of
+    ``serving/decode_runtime.py`` can host; one that cannot be hosted is
+    refused there, by its own reason.  Usable two ways: synchronously
     (``submit(...)`` then :meth:`run_until_idle`, the batch-generation
     path) or threaded (:meth:`start` / :meth:`drain`, the server path).
     """
@@ -320,7 +324,7 @@ class ContinuousScheduler:
             integer=True, minimum=0,
         ))
         page = resolve_page_size(page_size)
-        self.paged = bool(page) and hasattr(backend, "paged_runtime")
+        self.paged = bool(page)
         self.kv_quant = resolve_kv_quant(kv_quant)
         self._kv_quant_degraded = False
         if self.kv_quant != "none" and not self.paged:
@@ -339,7 +343,8 @@ class ContinuousScheduler:
                 self.kv_quant = "none"
                 self._kv_quant_degraded = True
         if self.paged:
-            self.runtime = backend.paged_runtime(
+            self.runtime = paged_runtime(
+                backend,
                 n_slots=self.n_slots,
                 prefill_chunk=self.prefill_chunk,
                 max_new_tokens=max_new_tokens,
@@ -350,7 +355,8 @@ class ContinuousScheduler:
                 kv_quant=self.kv_quant,
             )
         else:
-            self.runtime = backend.slot_runtime(
+            self.runtime = slot_runtime(
+                backend,
                 n_slots=self.n_slots,
                 prefill_chunk=self.prefill_chunk,
                 max_new_tokens=max_new_tokens,
@@ -2170,3 +2176,91 @@ class ContinuousScheduler:
             "sheds": sheds,
             "tenants": tenants,
         }
+
+
+def generate_batch_continuous(
+    decoder,
+    prompts: Sequence[str],
+    max_new_tokens: int = 16,
+    n_slots: int = 8,
+    prefill_chunk: int = 64,
+    decode_span: int = 4,
+    budgets: Optional[Sequence[int]] = None,
+    page_size: Optional[int] = None,
+    kv_pages: Optional[int] = None,
+    kv_quant: Optional[str] = None,
+    prefix_cache: bool = True,
+    speculate_k: Optional[int] = None,
+) -> List[str]:
+    """Greedy generation via the continuous runtime, synchronously.
+
+    Same outputs as the decoder's static ``generate_batch``
+    (byte-identical tokens per prompt — the slot cache mirrors the static
+    layout, see ``ops/kv_slots.py``), but requests flow through
+    admit→prefill→decode slots instead of one padded static batch, so rows
+    with small ``budgets`` release their compute to waiting prompts
+    mid-flight.  The scheduler is cached per geometry on the decoder (it
+    holds the decoder, so it goes when the decoder goes), and repeat calls
+    reuse the compiled programs.
+
+    The KV cache is paged with prefix sharing by default (see
+    ``decode_runtime.paged_runtime``): prompts sharing a token-id prefix —
+    the zero-shot template head, repeat songs — skip the shared prefill
+    chunks and share physical pages.  ``page_size=0`` pins the monolithic
+    slot cache; ``prefix_cache=False`` pages without sharing.
+    ``speculate_k > 0`` turns on draft-and-verify speculative decoding —
+    fewer dispatches on self-similar completions.  All routes emit
+    byte-identical tokens.
+    """
+    if not prompts:
+        return []
+    n_slots = int(n_slots)
+    budgets = (
+        [int(b) for b in budgets]
+        if budgets is not None
+        else [int(max_new_tokens)] * len(prompts)
+    )
+    if len(budgets) != len(prompts):
+        raise ValueError("budgets must match prompts 1:1")
+    # Match the static path's padded prompt width exactly so the slot
+    # cache's KV geometry (and therefore every greedy token) lines up
+    # with generate_batch on the same prompts.
+    _, lens = decoder.tokenizer.encode_batch(prompts, decoder.max_prompt_len)
+    longest = int(lens.max()) if len(lens) else 1
+    region = min(round_pow2(longest, 64), decoder.max_prompt_len)
+    chunk = min(int(prefill_chunk), region)
+    cap = max(1, max(budgets))
+    key = (n_slots, chunk, region, cap, int(decode_span),
+           page_size, kv_pages, kv_quant, bool(prefix_cache), speculate_k)
+    schedulers = vars(decoder).setdefault("_batch_schedulers", {})
+    sched = schedulers.get(key)
+    if sched is None:
+        sched = schedulers[key] = ContinuousScheduler(
+            decoder,
+            n_slots=n_slots,
+            prefill_chunk=chunk,
+            prompt_region=region,
+            max_new_tokens=cap,
+            decode_span=int(decode_span),
+            max_queue=max(len(prompts), 64),
+            page_size=page_size,
+            kv_pages=kv_pages,
+            kv_quant=kv_quant,
+            prefix_cache=prefix_cache,
+            speculate_k=speculate_k,
+        )
+    reqs = [
+        sched.submit(i, prompt, max_new_tokens=budget)
+        for i, (prompt, budget) in enumerate(zip(prompts, budgets))
+    ]
+    sched.run_until_idle()
+    outs = []
+    for req in reqs:
+        resp = req.response or {}
+        if not resp.get("ok"):
+            raise RuntimeError(
+                f"continuous generation failed for prompt {req.id}: "
+                f"{resp.get('error', 'unknown error')}"
+            )
+        outs.append(resp["text"])
+    return outs

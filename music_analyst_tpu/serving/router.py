@@ -1235,9 +1235,10 @@ def spawn_replicas(
     respawn relaunches the same cmd, pointing the new process at the
     dead one's journal to replay its unanswered requests.
     """
-    from music_analyst_tpu.engines.sentiment import _mesh_capable
+    from music_analyst_tpu.models.backend import family_takes
 
-    envs = replica_environments(n, tp, on_device=_mesh_capable(model, mock))
+    envs = replica_environments(
+        n, tp, on_device=family_takes(model, mock, "mesh"))
     log_dir = log_dir or base_dir
     os.makedirs(log_dir, exist_ok=True)
     handles: List[ReplicaHandle] = []

@@ -2,7 +2,7 @@
 
 The reference's sentiment path is one process per invocation; this is
 the shape of a production stack instead — a process that loads the model
-once (``serving/residency.py``), keeps it warm, and answers requests as
+once (``models/backend.ModelResidency``), keeps it warm, and answers requests as
 they arrive through the dynamic batcher (``serving/batcher.py``).
 
 **Protocol** (``ndjson/v1``, loopback-only by construction — a unix
@@ -64,14 +64,14 @@ from music_analyst_tpu.serving.journal import (
     RequestJournal,
     resolve_journal_dir,
 )
-from music_analyst_tpu.serving.residency import ModelResidency
+from music_analyst_tpu.models.backend import ModelResidency
 from music_analyst_tpu.serving.response_cache import (
     ResponseCache,
     backend_fingerprint,
     checkpoint_stamp,
     resolve_response_cache_dir,
 )
-from music_analyst_tpu.telemetry import get_telemetry
+from music_analyst_tpu.telemetry import get_telemetry, register_manifest_section
 from music_analyst_tpu.telemetry.introspect import device_summary
 from music_analyst_tpu.observability.metrics_plane import (
     configure_metrics,
@@ -96,6 +96,13 @@ def serving_stats() -> Dict[str, Any]:
     """Stats of the most recent server in this process ({} if none)."""
     server = _LAST_SERVER
     return server.stats_snapshot() if server is not None else {}
+
+
+# Serving-layer snapshot (protocol, admission counters, batch occupancy,
+# latency quantiles, residency/warmup state) in the run manifest — present
+# only when a server ran in this process, so batch runs keep the original
+# key set (and never import this package).
+register_manifest_section("serving", serving_stats)
 
 
 def _wordcount_batch(texts: List[str]) -> List[Dict[str, Any]]:
@@ -156,7 +163,7 @@ class SentimentServer:
         # of recomputing.  None = the historical non-durable behavior.
         self.journal = journal
         # Optional ContinuousScheduler hosting the ``generate`` op; None
-        # when the backend has no slot runtime (e.g. --mock) — generate
+        # when no decode runtime can host the backend (e.g. --mock) — generate
         # requests then settle as bad_request instead of crashing.
         self.decode = decode
         # Scale-out mode (serving/router.py): the ReplicaRouter sitting in
@@ -786,18 +793,19 @@ def run_server(
             response_cache=response_cache,
         ).start()
         # Continuous decode runtime for the ``generate`` op — only when
-        # the backend exposes a slot runtime (capability probe) and slots
-        # weren't explicitly disabled with --slots=0.
+        # a runtime can host the backend (the one question asked of it)
+        # and slots weren't explicitly disabled with --slots=0.
+        from music_analyst_tpu.serving.decode_runtime import (
+            decode_runtime_refusal,
+        )
+
         decode = None
-        refusal = getattr(clf, "decode_runtime_refusal", None)
+        refusal = decode_runtime_refusal(clf, "continuous decode")
         if refusal and not quiet:
             # The batched ops (``sentiment``) serve; ``generate`` needs a
-            # decode runtime this model cannot have yet.
-            print("serve: generate op off: "
-                  + refusal.format(runtime="continuous decode"),
-                  file=sys.stderr)
-        if (hasattr(clf, "slot_runtime") and not refusal
-                and (slots is None or slots > 0)):
+            # decode runtime this backend cannot have (yet).
+            print("serve: generate op off: " + refusal, file=sys.stderr)
+        if not refusal and (slots is None or slots > 0):
             from music_analyst_tpu.serving.decode_loop import (
                 ContinuousScheduler,
             )

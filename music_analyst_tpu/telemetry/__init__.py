@@ -29,6 +29,7 @@ from music_analyst_tpu.telemetry.introspect import (
     collect_device_info,
     git_describe,
     install_jax_listeners,
+    register_manifest_section,
     write_run_manifest,
 )
 
@@ -42,5 +43,6 @@ __all__ = [
     "collect_device_info",
     "git_describe",
     "install_jax_listeners",
+    "register_manifest_section",
     "write_run_manifest",
 ]

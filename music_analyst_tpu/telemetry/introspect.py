@@ -19,11 +19,14 @@ import json
 import os
 import subprocess
 import sys
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 from music_analyst_tpu.telemetry.core import Telemetry, get_telemetry
 
 _LISTENERS_INSTALLED = False
+# name -> () -> section (falsy = nothing to say): what a layer above
+# telemetry adds to every run manifest, registered by that layer.
+_SECTIONS: Dict[str, Callable[[], Any]] = {}
 _GIT_DESCRIBE: Optional[str] = None
 _GIT_PROBED = False
 
@@ -53,6 +56,14 @@ def install_jax_listeners() -> bool:
     monitoring.register_event_duration_secs_listener(_on_duration)
     _LISTENERS_INSTALLED = True
     return True
+
+
+def register_manifest_section(name: str, section: Callable[[], Any]) -> None:
+    """Have ``section()`` written under ``name`` in every run manifest of
+    this process, whenever it returns something.  For the layers telemetry
+    may not import (``serving``, the weight store): they call this when
+    they are imported, so the arrow points down."""
+    _SECTIONS[name] = section
 
 
 def git_describe() -> Optional[str]:
@@ -220,23 +231,6 @@ def write_run_manifest(
     except Exception:
         pass
     try:
-        # Quantized-checkpoint cache hit/miss/stores/bytes-saved plus the
-        # most recent streaming load's peak-host-staging digest — same
-        # only-when-consulted posture as corpus_cache above.
-        from music_analyst_tpu.engines.checkpoint import last_load_stats
-        from music_analyst_tpu.engines.wq_cache import (
-            cache_stats as wq_stats,
-        )
-
-        stats = wq_stats()
-        load = last_load_stats()
-        if any(stats.values()) or load:
-            manifest["wq_cache"] = dict(stats)
-            if load:
-                manifest["wq_cache"]["last_load"] = load
-    except Exception:
-        pass
-    try:
         # Process-lifetime compile records (memoized engine callables
         # outlive a single run) — guarded so a jax-free manifest path or
         # a partial install never blocks the write.
@@ -248,18 +242,17 @@ def write_run_manifest(
         }
     except Exception:
         pass
-    try:
-        # Serving-layer snapshot (protocol, admission counters, batch
-        # occupancy, latency quantiles, residency/warmup state) — present
-        # only when a server ran in this process, so batch runs keep the
-        # original key set.
-        from music_analyst_tpu.serving.server import serving_stats
-
-        serving = serving_stats()
-        if serving:
-            manifest["serving"] = serving
-    except Exception:
-        pass
+    # What the layers above say of themselves (the server's snapshot, the
+    # weight store's cache and load digest): each registered its section
+    # when it was imported, so a run that never loaded the layer pays no
+    # import for it here and keeps the original key set.
+    for name, section in list(_SECTIONS.items()):
+        try:
+            value = section()
+            if value:
+                manifest[name] = value
+        except Exception:
+            pass
     try:
         # Request-trace recorder digest + tail exemplars: quantile trace
         # ids a reader can resolve against request_traces.jsonl — only

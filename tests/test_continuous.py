@@ -30,6 +30,9 @@ from music_analyst_tpu.serving.batcher import (
     resolve_prefill_chunk,
     resolve_slots,
 )
+from music_analyst_tpu.serving.decode_loop import generate_batch_continuous
+from music_analyst_tpu.serving.decode_runtime import slot_runtime
+from music_analyst_tpu.utils.labels import normalise_label
 
 
 @pytest.fixture(scope="module")
@@ -119,8 +122,8 @@ def test_runtime_rejects_geometry_beyond_max_seq_len(clf):
     # prompt_region clamps to max_prompt_len, so the overflow has to come
     # from the decode budget: 64 + 2048 > tiny's max_seq_len of 2048.
     with pytest.raises(ValueError):
-        clf.slot_runtime(n_slots=2, prefill_chunk=64,
-                         prompt_region=64, max_new_tokens=2048)
+        slot_runtime(clf, n_slots=2, prefill_chunk=64,
+                     prompt_region=64, max_new_tokens=2048)
 
 
 # ----------------------------------------------------------- equivalence
@@ -146,8 +149,7 @@ def test_continuous_matches_static_greedy(clf, n_slots):
 
 def test_generate_batch_continuous_wrapper_matches_static(clf):
     static = clf.generate_batch(PROMPTS, max_new_tokens=6)
-    cont = clf.generate_batch_continuous(
-        PROMPTS, max_new_tokens=6, n_slots=2, prefill_chunk=16
+    cont = generate_batch_continuous(clf, PROMPTS, max_new_tokens=6, n_slots=2, prefill_chunk=16
     )
     assert cont == static
 
@@ -158,11 +160,20 @@ def test_early_exit_scan_matches_full_scan(clf):
     assert early == full
 
 
-def test_zero_shot_labels_agree_static_vs_continuous(clf, monkeypatch):
+def test_zero_shot_labels_agree_static_vs_continuous(clf):
     texts = ["I love this sunny day", "so sad and lonely", "whatever"]
     static = clf.classify_batch_by_generation(texts)
-    monkeypatch.setattr(clf, "continuous_slots", 2)
-    continuous = clf.classify_batch_by_generation(texts)
+    from music_analyst_tpu.models.llama import zero_shot_prompt
+
+    # the same prompts, budget and normaliser, decoded by the continuous
+    # runtime
+    generations = generate_batch_continuous(
+        clf, [zero_shot_prompt(t) for t in texts], max_new_tokens=16,
+        n_slots=2)
+    continuous = [
+        "Neutral" if not text.strip() else normalise_label(gen)
+        for text, gen in zip(texts, generations)
+    ]
     assert continuous == static
 
 

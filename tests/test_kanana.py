@@ -497,8 +497,10 @@ def test_generate_batch_logits_step_by_step_match_full_forward(clf):
 
 @pytest.mark.parametrize("runtime", ["paged_runtime", "slot_runtime"])
 def test_decode_runtimes_refuse_the_latent_cache(clf, runtime):
+    from music_analyst_tpu.serving import decode_runtime
+
     with pytest.raises(NotImplementedError, match="latent cache"):
-        getattr(clf, runtime)()
+        getattr(decode_runtime, runtime)(clf)
     assert "latent cache" in clf.decode_runtime_refusal
     assert LlamaZeroShotClassifier(
         config=LlamaConfig.tiny()).decode_runtime_refusal is None
@@ -558,7 +560,7 @@ def test_sentiment_cli_end_to_end(tmp_path, fixture_csv):
 
 
 def test_serve_sentiment_op_runs_and_generate_is_refused(clf):
-    from music_analyst_tpu.serving.residency import ModelResidency
+    from music_analyst_tpu.models.backend import ModelResidency
     from music_analyst_tpu.serving.server import build_resident_ops
 
     ops = build_resident_ops(ModelResidency(model="kanana-tiny", backend=clf))

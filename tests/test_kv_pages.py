@@ -28,6 +28,7 @@ from music_analyst_tpu.ops.kv_pages import (
     PagePool,
     RadixIndex,
 )
+from music_analyst_tpu.serving.decode_runtime import paged_runtime
 from music_analyst_tpu.serving.batcher import (
     resolve_kv_pages,
     resolve_page_size,
@@ -123,8 +124,8 @@ def test_resolve_page_size_and_kv_pages(monkeypatch):
 
 def test_runtime_rejects_geometry_beyond_max_seq_len(clf):
     with pytest.raises(ValueError):
-        clf.paged_runtime(n_slots=2, prefill_chunk=64,
-                          prompt_region=64, max_new_tokens=2048)
+        paged_runtime(clf, n_slots=2, prefill_chunk=64,
+                      prompt_region=64, max_new_tokens=2048)
 
 
 # ------------------------------------------------------- host structures

@@ -26,7 +26,7 @@ if os.path.join(REPO, "perfbench") not in sys.path:
 
 from reference import deepseek_v3_f32 as ref  # noqa: E402
 
-from music_analyst_tpu.models import mla  # noqa: E402
+from music_analyst_tpu.models import llama, mla  # noqa: E402
 from music_analyst_tpu.models.layers import causal_mask, padding_mask  # noqa: E402
 from music_analyst_tpu.models.llama import (  # noqa: E402
     PRESETS,
@@ -228,7 +228,8 @@ def test_scoring_program_through_the_kernel_agrees_with_the_blocked_path(
     got, chosen, prefer, record = _scores(served, ids, lens)
     assert record.attention_paths == {"mla_flash": 3}
     assert record.traced_paths["mla.expanded"] == 3
-    assert served._prefill_lengths(lens) is lens    # one device: handed on
+    # one device: the lengths are handed on to the latent layers
+    assert llama._prefill_lengths(served.mesh, lens) is lens
 
     monkeypatch.setattr(mla, "prefill_block", lambda n_queries: 0)
     fallback = LlamaZeroShotClassifier(config=PRESETS["kanana-tiny"](), seed=0)

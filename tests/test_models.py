@@ -370,9 +370,9 @@ def test_generate_scan_matches_step_loop():
     assert batched == singles
 
 
-def test_generation_decode_mode():
-    """decode_mode='generate' classifies via batched free-text decode +
-    the shared normalizer, honoring the empty-lyric rule."""
+def test_classify_batch_by_generation():
+    """classify_batch_by_generation classifies via batched free-text decode
+    + the shared normalizer, honoring the empty-lyric rule."""
     from music_analyst_tpu.models.llama import (
         LlamaConfig,
         LlamaZeroShotClassifier,
@@ -382,10 +382,8 @@ def test_generation_decode_mode():
         vocab_size=300, dim=32, n_layers=1, n_heads=4, n_kv_heads=2,
         hidden_dim=64, rope_theta=1e4, max_seq_len=128, dtype="float32",
     )
-    clf = LlamaZeroShotClassifier(
-        config=cfg, max_prompt_len=32, decode_mode="generate"
-    )
-    labels = clf.classify_batch(["some lyrics", ""])
+    clf = LlamaZeroShotClassifier(config=cfg, max_prompt_len=32)
+    labels = clf.classify_batch_by_generation(["some lyrics", ""])
     assert labels[1] == "Neutral"
     assert all(l in ("Positive", "Neutral", "Negative") for l in labels)
     singles = [clf.classify_by_generation("some lyrics")]

@@ -431,6 +431,9 @@ def test_int8_sharded_label_agreement():
         LlamaZeroShotClassifier,
     )
     from music_analyst_tpu.parallel.mesh import build_mesh, factor_devices
+    from music_analyst_tpu.serving.decode_loop import (
+        generate_batch_continuous,
+    )
 
     mesh = build_mesh(factor_devices(8, ("dp", "tp"), fixed={"tp": 2}))
     clf = LlamaZeroShotClassifier(
@@ -438,8 +441,8 @@ def test_int8_sharded_label_agreement():
     )
     kw = dict(max_new_tokens=8, n_slots=4, prefill_chunk=16,
               speculate_k=2)
-    plain = clf.generate_batch_continuous(PROMPTS, kv_quant="none", **kw)
-    quant = clf.generate_batch_continuous(PROMPTS, kv_quant="int8", **kw)
+    plain = generate_batch_continuous(clf, PROMPTS, kv_quant="none", **kw)
+    quant = generate_batch_continuous(clf, PROMPTS, kv_quant="int8", **kw)
     labels = [normalise_label(t) for t in quant]
     want = [normalise_label(t) for t in plain]
     agreement = np.mean([a == b for a, b in zip(labels, want)])

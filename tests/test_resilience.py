@@ -422,7 +422,7 @@ def test_residency_reload_swaps_poisoned_backend_mid_session():
     backend-loss error is replaced under the live batcher; the request that hit
     it still gets an answer from the fresh backend."""
     from music_analyst_tpu.serving.batcher import DynamicBatcher
-    from music_analyst_tpu.serving.residency import ModelResidency
+    from music_analyst_tpu.models.backend import ModelResidency
     from music_analyst_tpu.serving.server import build_resident_ops
 
     class PoisonedBackend:

@@ -43,7 +43,7 @@ from music_analyst_tpu.serving.response_cache import (
 
 @pytest.fixture(scope="module")
 def mock_backend():
-    from music_analyst_tpu.serving.residency import ModelResidency
+    from music_analyst_tpu.models.backend import ModelResidency
 
     return ModelResidency(model="mock", mock=True).acquire()
 

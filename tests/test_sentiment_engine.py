@@ -99,15 +99,3 @@ def test_injected_backend_guard_matches_get_backend_unset(fixture_csv,
     with pytest.raises(TypeError, match="sequence of ints"):
         get_backend("distilbert-tiny", length_buckets=32)
 
-
-def test_mesh_capability_gate():
-    """mesh= must reach only the on-device model families; the keyword
-    kernel and the Ollama HTTP passthrough take no mesh kwarg."""
-    from music_analyst_tpu.engines.sentiment import _mesh_capable
-
-    assert _mesh_capable("distilbert", False)
-    assert _mesh_capable("distilbert-tiny-int8", False)
-    assert _mesh_capable("llama3-tiny", False)
-    assert not _mesh_capable("mock", False)
-    assert not _mesh_capable("distilbert", True)  # --mock wins
-    assert not _mesh_capable("ollama:llama3", False)

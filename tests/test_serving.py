@@ -30,7 +30,7 @@ from music_analyst_tpu.serving.batcher import (
     resolve_max_queue,
     resolve_max_wait_ms,
 )
-from music_analyst_tpu.serving.residency import ModelResidency, warmup_sizes
+from music_analyst_tpu.models.backend import ModelResidency, warmup_sizes
 from music_analyst_tpu.serving.server import SentimentServer, build_ops
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

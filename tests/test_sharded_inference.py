@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from music_analyst_tpu.parallel.mesh import MeshSpec, build_mesh
+from music_analyst_tpu.serving.decode_loop import generate_batch_continuous
 
 TEXTS = [
     "love and sunshine all day",
@@ -115,8 +116,8 @@ def test_slot_decode_tp_byte_identical(plain_gen_clf, tp2_gen_clf):
     (``page_size=0`` pins the monolithic slot cache)."""
     kwargs = dict(max_new_tokens=8, n_slots=4, prefill_chunk=16,
                   page_size=0)
-    plain = plain_gen_clf.generate_batch_continuous(GEN_PROMPTS, **kwargs)
-    tp = tp2_gen_clf.generate_batch_continuous(GEN_PROMPTS, **kwargs)
+    plain = generate_batch_continuous(plain_gen_clf, GEN_PROMPTS, **kwargs)
+    tp = generate_batch_continuous(tp2_gen_clf, GEN_PROMPTS, **kwargs)
     assert tp == plain
 
 
@@ -124,8 +125,8 @@ def test_paged_decode_tp_byte_identical(plain_gen_clf, tp2_gen_clf):
     """tp=2 paged runtime (prefix sharing on, the serving default) is
     byte-identical to tp=1 paged and to the tp=1 slot route."""
     kwargs = dict(max_new_tokens=8, n_slots=4, prefill_chunk=16)
-    plain = plain_gen_clf.generate_batch_continuous(GEN_PROMPTS, **kwargs)
-    tp = tp2_gen_clf.generate_batch_continuous(GEN_PROMPTS, **kwargs)
+    plain = generate_batch_continuous(plain_gen_clf, GEN_PROMPTS, **kwargs)
+    tp = generate_batch_continuous(tp2_gen_clf, GEN_PROMPTS, **kwargs)
     assert tp == plain
 
 
@@ -135,8 +136,8 @@ def test_tp4_decode_byte_identical(plain_gen_clf):
     mesh = build_mesh(MeshSpec((("tp", 4),)), devices=jax.devices()[:4])
     tp4 = _gen_clf(mesh=mesh)
     kwargs = dict(max_new_tokens=6, n_slots=2, prefill_chunk=16)
-    plain = plain_gen_clf.generate_batch_continuous(GEN_PROMPTS, **kwargs)
-    assert tp4.generate_batch_continuous(GEN_PROMPTS, **kwargs) == plain
+    plain = generate_batch_continuous(plain_gen_clf, GEN_PROMPTS, **kwargs)
+    assert generate_batch_continuous(tp4, GEN_PROMPTS, **kwargs) == plain
 
 
 @pytest.mark.parametrize("page_size", [0, None])
@@ -166,8 +167,10 @@ def test_tp_runtime_kv_cache_is_head_sharded(tp2_gen_clf):
     silently replicated): 4 kv heads over tp=2."""
     from jax.sharding import PartitionSpec as P
 
-    rt = tp2_gen_clf.slot_runtime(n_slots=2, prefill_chunk=16,
-                                  max_new_tokens=4, prompt_region=32)
+    from music_analyst_tpu.serving.decode_runtime import slot_runtime
+
+    rt = slot_runtime(tp2_gen_clf, n_slots=2, prefill_chunk=16,
+                      max_new_tokens=4, prompt_region=32)
     caches = rt.init_caches()
     spec = caches[0].keys.sharding.spec
     assert tuple(spec) == (None, None, "tp", None)

@@ -258,11 +258,13 @@ class LlamaBlock(nn.Module):
     def __call__(self, x, mask, positions, cache: Optional[KVCache],
                  lengths: Optional[jax.Array] = None,
                  segment_ids: Optional[jax.Array] = None,
-                 prefill_lengths: Optional[jax.Array] = None):
+                 prefill_lengths: Optional[jax.Array] = None,
+                 prefill_capacity: Optional[int] = None):
         cfg = self.config
         if cfg.attention == "mla":
             return self._latent_block(x, mask, positions, cache,
-                                      prefill_lengths, segment_ids)
+                                      prefill_lengths, prefill_capacity,
+                                      segment_ids)
         if segment_ids is not None and (
             cache is not None or cfg.attn_impl != "flash"
         ):
@@ -332,11 +334,18 @@ class LlamaBlock(nn.Module):
 
 
     def _latent_block(self, x, mask, positions, cache, prefill_lengths,
-                      segment_ids):
+                      prefill_capacity, segment_ids):
         """Pre-norm block of the ``mla`` kind: latent attention, then the
         dense SwiGLU in the leading layers and routed + shared experts in
-        the rest."""
+        the rest.
+
+        A prefill that declares its rows' lengths and a ``prefill_capacity``
+        under the step's positions runs the feed-forward half on the real
+        positions alone (``models/moe.RealPositions``: gathered into that
+        many token slots, the result put back at their places); positions
+        at or behind a row's length then receive the residual alone."""
         from music_analyst_tpu.models.mla import MLAttention
+        from music_analyst_tpu.models.moe import RealPositions
 
         cfg = self.config
         if segment_ids is not None:
@@ -361,6 +370,11 @@ class LlamaBlock(nn.Module):
                 new_cache = None
         x = x + attn_out
         h = RMSNorm(epsilon=cfg.rms_norm_eps, name="ffn_norm")(x)
+        compact = None
+        if (prefill_lengths is not None and prefill_capacity is not None
+                and prefill_capacity < h.shape[0] * h.shape[1]):
+            compact = RealPositions.of(prefill_lengths, h.shape[1],
+                                       prefill_capacity)
         if cfg.routed_layer(self.layer_index):
             from music_analyst_tpu.models.moe import SigmoidRoutedMoE
 
@@ -371,9 +385,13 @@ class LlamaBlock(nn.Module):
                 norm_topk_prob=cfg.norm_topk_prob, dtype=dtype,
                 param_dtype=param_dtype, name="feed_forward_moe",
             )
+            if compact is not None:  # it puts the experts it chose back too
+                return x + ffn(h, compact), new_cache
         else:
             ffn = SwiGLU(cfg.hidden_dim, dtype=dtype,
                          param_dtype=param_dtype, name="feed_forward")
+        if compact is not None:
+            return x + compact.put_back(ffn(compact.gather(h))), new_cache
         return x + ffn(h), new_cache
 
 
@@ -391,12 +409,21 @@ class LlamaModel(nn.Module):
         last_position: Optional[jax.Array] = None,  # [B] — see below
         segment_ids: Optional[jax.Array] = None,   # [B, S] — packed docs
         prefill_lengths: Optional[jax.Array] = None,  # [B] — see below
+        prefill_capacity: Optional[int] = None,    # static — see below
     ):
-        # ``prefill_lengths`` is read by latent-attention layers alone and
-        # is a promise about THIS call (models/mla.MLAttention): a causal
-        # prefill from position 0 on empty caches, ``mask`` = causal and
-        # key padding by these lengths, one device.  ``lengths`` keeps its
-        # one meaning, the flash path's key padding:
+        # ``prefill_lengths`` is read by the latent (``mla``) blocks alone
+        # and is a promise about THIS call (models/mla.MLAttention): a
+        # causal prefill from position 0 on empty caches, ``mask`` = causal
+        # and key padding by these lengths, one device, and nothing reads
+        # a position at or behind its row's length.  The attention takes
+        # the kernel that reads the lengths in place of the mask; with a
+        # ``prefill_capacity`` (static, ``models/moe.compact_capacity`` of
+        # these lengths: sum(lengths) <= capacity) under B*S the
+        # feed-forward halves run on the real positions alone, so what
+        # the layers return at or behind a row's length (hidden state,
+        # cache entries, the sown ``chosen``) is neither computed as the
+        # layer would nor defined.  ``lengths`` keeps its one meaning, the
+        # flash path's key padding:
         # CONTRACT: with cfg.attn_impl == "flash" (and no caches), the
         # `mask` argument is NOT applied — attention is causal + key-
         # padding-by-`lengths` + optional same-segment (packed documents,
@@ -418,6 +445,7 @@ class LlamaModel(nn.Module):
             x, new_cache = LlamaBlock(cfg, i, name=f"layer_{i}")(
                 x, mask, positions, cache_i, lengths,
                 segment_ids=segment_ids, prefill_lengths=prefill_lengths,
+                prefill_capacity=prefill_capacity,
             )
             if new_cache is not None:
                 new_caches.append(new_cache)
@@ -681,6 +709,10 @@ def init_params_by_layer(cfg: LlamaConfig, seed: int = 0):
     return params
 
 
+def _partitioned(mesh) -> bool:
+    return mesh is not None and mesh.size > 1
+
+
 def _prefill_lengths(mesh, prompt_lens):
     """What a prefill from position 0 on empty caches hands the
     latent-attention layers beside the mask (``models/mla.py``: the
@@ -688,9 +720,22 @@ def _prefill_lengths(mesh, prompt_lens):
     one device, nothing under a mesh.  The kernel's call is opaque to
     the partitioner, which would gather its operands and give every
     chip all the work, where the XLA form is partitioned."""
-    if mesh is not None and mesh.size > 1:
+    return None if _partitioned(mesh) else prompt_lens
+
+
+def _prefill_capacity(config: LlamaConfig, mesh, prompt_lens,
+                      shape) -> Optional[int]:
+    """The static ``prefill_capacity`` of a step of ``shape`` (rows,
+    width) whose rows have ``prompt_lens`` (host array): the rung of
+    ``models/moe.compact_capacity`` that holds the real tokens, where the
+    blocks read ``prefill_lengths`` (latent blocks, one device); ``None``
+    where they are withheld or unread, so such a step has one program."""
+    if not config.latent_cache or _partitioned(mesh):
         return None
-    return prompt_lens
+    from music_analyst_tpu.models.moe import compact_capacity
+
+    return compact_capacity(int(np.asarray(prompt_lens, np.int64).sum()),
+                            int(shape[0]) * int(shape[1]))
 
 
 # The decoder's three programs, built from ``(model, config, …)``: whoever
@@ -705,14 +750,17 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
     name ``llama_score_labels``)."""
 
     def _score_labels(params, prompt_ids, prompt_lens, label_ids,
-                      label_lens):
+                      label_lens, prefill_capacity=None):
         """Log-likelihood of each label continuation per batch row.
 
-        prompt_ids [B, S]; label_ids [3, L].  Returns ``(scores [B, 3],
-        stats)``: ``stats`` holds the small device-side reductions that
-        ride back with the scores (``expert_load_max`` /
-        ``expert_load_mean`` ``[routed layers]`` of the prefill, for a
-        model with routed experts; else empty).
+        prompt_ids [B, S]; label_ids [3, L]; ``prefill_capacity`` (static)
+        the token slots the prefill's feed-forward layers run, from
+        ``models/moe.compact_capacity`` of these lengths (``None`` or
+        ``B * S``: every position).  Returns ``(scores [B, 3], stats)``:
+        ``stats`` holds the small device-side reductions that ride back
+        with the scores (``expert_load_max`` / ``expert_load_mean``
+        ``[routed layers]`` of the prefill, for a model with routed
+        experts; else empty).
         """
         B, S = prompt_ids.shape
         n_labels, L = label_ids.shape
@@ -733,6 +781,7 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
             {"params": params}, prompt_ids, positions, mask, caches,
             last_position=prompt_lens - 1,
             prefill_lengths=_prefill_lengths(mesh, prompt_lens),
+            prefill_capacity=prefill_capacity,
             mutable=["intermediates"],
         )
         stats = {}
@@ -797,7 +846,8 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
             stats["chosen_labels"] = label_chosen  # [3, layers, B, L, k]
         return scores, stats  # [B, 3]
 
-    return profiled_jit(_score_labels, name="llama_score_labels")
+    return profiled_jit(_score_labels, name="llama_score_labels",
+                        static_argnames=("prefill_capacity",))
 
 
 def decode_step_program(model: LlamaModel):
@@ -822,9 +872,10 @@ def generate_scan_program(model: LlamaModel, config: LlamaConfig,
                           eos_id: int, mesh=None):
     """Prefill and every decode step of a batch as one jitted program."""
 
-    @partial(jax.jit, static_argnames=("max_new_tokens", "early_exit"))
+    @partial(jax.jit, static_argnames=("max_new_tokens", "early_exit",
+                                       "prefill_capacity"))
     def _generate_scan(params, prompt_ids, prompt_lens, max_new_tokens,
-                       early_exit=True):
+                       early_exit=True, prefill_capacity=None):
         """Batched greedy decode as ONE compiled program.
 
         The reference's generation is a remote server call per song
@@ -853,6 +904,7 @@ def generate_scan_program(model: LlamaModel, config: LlamaConfig,
             {"params": params}, prompt_ids, positions, mask, caches,
             last_position=prompt_lens - 1,
             prefill_lengths=_prefill_lengths(mesh, prompt_lens),
+            prefill_capacity=prefill_capacity,
         )
         caches = [c.with_length(S) for c in caches]
         first = jnp.argmax(logits[:, 0], axis=-1)  # [B]
@@ -1157,8 +1209,11 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         texts, prompt_ids, prompt_lens = prepared
         count_h2d_bytes([prompt_ids, prompt_lens])
         lens = prompt_lens.astype(np.int64)
-        # real prompt tokens, and their causal (query, key) pairs
-        real = (int(lens.sum()), int((lens * (lens + 1) // 2).sum()))
+        # real prompt tokens, their causal (query, key) pairs, and the
+        # token slots the prefill's feed-forward layers run for them
+        real = (int(lens.sum()), int((lens * (lens + 1) // 2).sum()),
+                _prefill_capacity(self.config, self.mesh, lens,
+                                  prompt_ids.shape))
         return (texts, jnp.asarray(prompt_ids), jnp.asarray(prompt_lens),
                 real)
 
@@ -1169,6 +1224,7 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         scores, stats = self._score_labels(
             self.params, prompt_ids, prompt_lens,
             jnp.asarray(self._label_ids), jnp.asarray(self._label_lens),
+            prefill_capacity=real[2],
         )
         return texts, scores, stats, prompt_ids.shape, real
 
@@ -1194,11 +1250,15 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         tokens but its last, whose forward pass nothing reads; computed
         includes padding), the routed layers' load, the latent cache's
         size, and the step's shape and real token counts on the span the
-        engine has open (``compute``)."""
+        engine has open (``compute``).  ``moe.assignments`` and the load
+        count the real positions' assignments where the prefill ran
+        compact (``moe_capacity`` token slots under ``rows * width``);
+        ``moe.rows_computed`` counts the rows its grouped matmuls ran,
+        fillers and padding included."""
         from music_analyst_tpu.telemetry import get_telemetry
 
         tel = get_telemetry()
-        tokens_real, token_pairs = real
+        tokens_real, token_pairs, capacity = real
         n_labels, label_width = self._label_ids.shape
         label_real = int(np.maximum(self._label_lens - 1, 0).sum())
         tel.count("decoder.tokens_real", tokens_real + rows * label_real)
@@ -1213,6 +1273,10 @@ class LlamaZeroShotClassifier(ClassifierBackend):
             load_mean = np.asarray(stats["expert_load_mean"], np.float64)
             tel.count("moe.assignments",
                       int(load_mean.sum() * self.config.n_experts))
+            slots = rows * width if capacity is None else capacity
+            tel.count("moe.rows_computed",
+                      slots * self.config.moe_top_k * len(load_max))
+            attrs["moe_capacity"] = slots
             tel.count("moe.expert_load_max", int(load_max.sum()))
             tel.count("moe.expert_load_mean", int(load_mean.sum()))
             attrs["expert_load_max_over_mean"] = [
@@ -1281,6 +1345,8 @@ class LlamaZeroShotClassifier(ClassifierBackend):
             self._generate_scan(
                 self.params, jnp.asarray(ids), jnp.asarray(lens),
                 max_new_tokens, early_exit=early_exit,
+                prefill_capacity=_prefill_capacity(
+                    self.config, self.mesh, lens, ids.shape),
             )
         )
         eos = self.tokenizer.eos_id

@@ -30,6 +30,7 @@ hosts — the multi-host regime ``parallel/distributed.py`` owns.
 from __future__ import annotations
 
 import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -217,6 +218,65 @@ def route_sigmoid_noaux(
     return chosen.astype(jnp.int32), weights * routed_scaling_factor
 
 
+COMPACT_RUNGS = 8
+
+
+def compact_capacity(real: int, positions: int) -> int:
+    """Token slots a prefill's feed-forward layers run when ``real`` of
+    the step's ``positions`` (rows x width) lie inside a row's length: the
+    smallest multiple of an eighth of the step that holds them, so a step
+    shape has at most ``COMPACT_RUNGS`` compiled programs and a step of
+    full rows runs the uncompacted one (``capacity == positions``).  Host
+    arithmetic on the lengths the caller already has."""
+    rung = -(-positions // COMPACT_RUNGS)
+    return min(positions, rung * max(1, -(-real // rung)))
+
+
+class RealPositions(NamedTuple):
+    """The real positions (``pos < lengths[row]``) of a ``[B, S]`` step,
+    in row-major order, as slots ``0 .. N-1`` of a compact token set of a
+    static ``capacity >= N``; the ``capacity - N`` filler slots behind
+    them hold a copy of the last real position and are never read back."""
+
+    source: jax.Array  # [C] int32  flat position ``row * S + pos`` of a slot
+    valid: jax.Array   # [C] bool   the slot holds a real position
+    slot: jax.Array    # [B, S] int32  a real position's slot (else clamped)
+    real: jax.Array    # [B, S] bool   ``pos < lengths[row]``
+
+    @classmethod
+    def of(cls, lengths: jax.Array, width: int,
+           capacity: int) -> "RealPositions":
+        """From the rows' lengths by arithmetic: the rows' first slots are
+        the exclusive running sum of the lengths, a slot's row is the
+        number of rows that end at or before it, its position the rest.
+        The caller promises ``sum(lengths) <= capacity``."""
+        lengths = lengths.astype(jnp.int32)
+        ends = jnp.cumsum(lengths)
+        starts = ends - lengths
+        slots = jnp.arange(capacity, dtype=jnp.int32)
+        valid = slots < ends[-1]
+        last = jnp.maximum(ends[-1] - 1, 0)
+        held = jnp.where(valid, slots, last)
+        row = jnp.searchsorted(ends, held, side="right",
+                               method="compare_all").astype(jnp.int32)
+        row = jnp.minimum(row, lengths.shape[0] - 1)
+        pos = jnp.arange(width, dtype=jnp.int32)[None, :]
+        real = pos < lengths[:, None]
+        return cls(
+            source=row * width + (held - starts[row]), valid=valid,
+            slot=jnp.where(real, starts[:, None] + pos, 0), real=real)
+
+    def gather(self, x: jax.Array) -> jax.Array:
+        """``x [B, S, ...]`` at the slots' positions: ``[C, ...]``."""
+        return x.reshape((-1,) + x.shape[2:])[self.source]
+
+    def put_back(self, y: jax.Array) -> jax.Array:
+        """``y [C, ...]`` at its positions' places in ``[B, S, ...]``,
+        zeros at and behind every row's length."""
+        real = self.real.reshape(self.real.shape + (1,) * (y.ndim - 1))
+        return jnp.where(real, y[self.slot], jnp.zeros((), y.dtype))
+
+
 @jax.custom_batching.custom_vmap
 def grouped_experts(xt, chosen, weights, gate_w, up_w, down_w):
     """``sum_k weights[t, k] * expert_{chosen[t, k]}(xt[t])`` for every
@@ -224,13 +284,18 @@ def grouped_experts(xt, chosen, weights, gate_w, up_w, down_w):
     ``jax.lax.ragged_dot`` a projection over the ragged groups, results
     returned to token order and combined in float32.  ``xt [T, D]``,
     ``chosen``/``weights [T, k]``, expert stacks ``[E, D, H]`` /
-    ``[E, H, D]``.  Returns ``[T, D]`` float32."""
+    ``[E, H, D]``.  Returns ``[T, D]`` float32.
+
+    A token whose ``chosen`` is the number of experts (one past the last)
+    is a filler of a compact token set (:class:`RealPositions`): its
+    assignments sort behind the last group and belong to none, so no
+    expert multiplies them, and its row of the result is undefined."""
     T, D = xt.shape
     k = chosen.shape[-1]
     flat_expert = chosen.reshape(T * k)
     order = jnp.argsort(flat_expert, stable=True)
     group_sizes = jnp.zeros((gate_w.shape[0],), jnp.int32).at[
-        flat_expert].add(1)
+        flat_expert].add(1, mode="drop")
     xs = xt[order // k]                                           # [A, D]
     gate = jax.lax.ragged_dot(xs, gate_w, group_sizes)
     up = jax.lax.ragged_dot(xs, up_w, group_sizes)
@@ -270,8 +335,18 @@ class SigmoidRoutedMoE(nn.Module):
     router's weights in float32.  ``n_shared`` shared experts are one
     SwiGLU of width ``n_shared * hidden_dim``.
 
-    Sows ``expert_load`` (assignments each expert received, ``[E]`` int32)
-    and ``chosen`` (``[B, S, k]``) into the ``intermediates`` collection for
+    Given ``compact`` (a prefill that declared its rows' lengths:
+    ``models/llama.LlamaBlock._latent_block``), router, sort, grouped
+    matmuls, weighted sum and shared experts run on the real positions
+    alone, gathered into ``compact``'s token set, and the result is put
+    back at their places: every real position is routed by the same scores
+    to the same experts and summed with the same weights as without it,
+    and positions at or behind a row's length are NOT computed; their
+    output is zero and their ``chosen`` 0, not what the layer would give.
+
+    Sows ``expert_load`` (assignments each expert received, ``[E]`` int32;
+    with ``compact`` those of real positions alone, fillers uncounted) and
+    ``chosen`` (``[B, S, k]``) into the ``intermediates`` collection for
     callers that ask for it.
     """
 
@@ -285,13 +360,13 @@ class SigmoidRoutedMoE(nn.Module):
     param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array,
+                 compact: Optional[RealPositions] = None) -> jax.Array:
         from music_analyst_tpu.models.layers import SwiGLU, fan_in_normal
         from music_analyst_tpu.profiling.compile import note_traced_path
 
         B, S, D = x.shape
         E, H, k = self.n_experts, self.hidden_dim, self.top_k
-        T, A = B * S, B * S * k
         gate_w = self.param("gate_experts", fan_in_normal(D), (E, D, H),
                             self.param_dtype)
         up_w = self.param("up_experts", fan_in_normal(D), (E, D, H),
@@ -309,7 +384,10 @@ class SigmoidRoutedMoE(nn.Module):
                 key, shape, dtype),
             (E,), jnp.float32,
         )
-        xt = x.reshape(T, D).astype(self.dtype)
+        if compact is None:
+            xt = x.reshape(B * S, D).astype(self.dtype)
+        else:
+            xt = compact.gather(x).astype(self.dtype)
 
         with jax.named_scope("moe.route"):
             logits = jnp.dot(xt.astype(jnp.float32), router_w,
@@ -320,9 +398,16 @@ class SigmoidRoutedMoE(nn.Module):
 
         with jax.named_scope("moe.experts"):
             note_traced_path("moe.grouped")
+            placed = chosen
+            if compact is not None:
+                note_traced_path("moe.compact")
+                placed = compact.put_back(chosen)
+                # a filler belongs to no expert (grouped_experts)
+                chosen = jnp.where(compact.valid[:, None], chosen, E)
             self.sow("intermediates", "expert_load",
-                     jnp.zeros((E,), jnp.int32).at[chosen.reshape(A)].add(1))
-            self.sow("intermediates", "chosen", chosen.reshape(B, S, k))
+                     jnp.zeros((E,), jnp.int32).at[chosen.reshape(-1)].add(
+                         1, mode="drop"))
+            self.sow("intermediates", "chosen", placed.reshape(B, S, k))
             out = grouped_experts(
                 xt, chosen, weights, gate_w.astype(self.dtype),
                 up_w.astype(self.dtype), down_w.astype(self.dtype))
@@ -333,4 +418,6 @@ class SigmoidRoutedMoE(nn.Module):
                     self.n_shared * H, dtype=self.dtype,
                     param_dtype=self.param_dtype, name="shared_experts",
                 )(xt).astype(jnp.float32)
-        return out.reshape(B, S, D).astype(x.dtype)
+        if compact is None:
+            return out.reshape(B, S, D).astype(x.dtype)
+        return compact.put_back(out.astype(x.dtype))

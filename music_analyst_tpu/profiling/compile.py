@@ -179,6 +179,12 @@ class ProfiledFunction:
         self._fn = fn
         self.name = name or getattr(fn, "__name__", None) or "jit_fn"
         self._jit = jax.jit(fn, **jit_kwargs)
+        # Static arguments given by keyword key the compile like every
+        # other argument and are left out of the call of the executable,
+        # which was specialised on them (``jax.stages.Compiled``).
+        static = jit_kwargs.get("static_argnames") or ()
+        self._static = frozenset(
+            (static,) if isinstance(static, str) else static)
         self._lock = threading.Lock()
         self._compiled: Dict[str, Any] = {}  # aval_key -> executable | None
         self.records: Dict[str, CompileRecord] = {}
@@ -296,7 +302,9 @@ class ProfiledFunction:
                 self._compiled[key] = executable
         if executable is not None:
             try:
-                return executable(*args, **kwargs)
+                return executable(*args, **{
+                    name: value for name, value in kwargs.items()
+                    if name not in self._static})
             except Exception:
                 # Executable/argument mismatch (layout, weak type, …):
                 # permanently fall back for this key.
@@ -307,6 +315,9 @@ class ProfiledFunction:
     # Parity helpers so a ProfiledFunction drops in where jax.jit was.
     def lower(self, *args: Any, **kwargs: Any):
         return self._jit.lower(*args, **kwargs)
+
+    def trace(self, *args: Any, **kwargs: Any):
+        return self._jit.trace(*args, **kwargs)
 
     def _cache_size(self) -> int:
         """Compiled-variant count (jit cache + AOT executables): the

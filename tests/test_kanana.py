@@ -37,6 +37,7 @@ from music_analyst_tpu.models.llama import (  # noqa: E402
 from music_analyst_tpu.models.mla import LatentCache, MLAttention  # noqa: E402
 from music_analyst_tpu.models.moe import (  # noqa: E402
     SigmoidRoutedMoE,
+    compact_capacity,
     route_sigmoid_noaux,
 )
 
@@ -493,6 +494,192 @@ def test_generate_batch_logits_step_by_step_match_full_forward(clf):
         assert (want[:, t].max(-1) - chosen < 4 * tol).all(), t
 
 
+# ------------------------------------- the prefill's compact feed-forward
+
+def _ragged_step(clf, lengths, width=64, seed=11):
+    """Token ids of a ``[rows, width]`` step whose rows have ``lengths``
+    (BOS first, pad behind), as the tokenizer would hand them over."""
+    rng = np.random.default_rng(seed)
+    ids = np.zeros((len(lengths), width), np.int32)
+    for row, n in enumerate(lengths):
+        ids[row, :n] = rng.integers(16, clf.config.vocab_size, size=n)
+        ids[row, 0] = clf.tokenizer.bos_id
+    return ids, np.asarray(lengths, np.int32)
+
+
+# a full row and a one-token row (N = 125 of 256, rung 128); real tokens
+# that are exactly a rung (96); one token past a rung (97 -> 128)
+_STEPS = {"ragged": [64, 1, 23, 37], "exact-rung": [40, 9, 30, 17],
+          "one-past-a-rung": [40, 9, 30, 18]}
+
+
+def _bf16_steps(got, want, steps=1):
+    """Whether ``got`` is within ``steps`` bfloat16 steps of ``want``."""
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return (np.abs(got - want)
+            <= steps * 2.0 ** -7 * np.maximum(np.abs(want), 1.0)).all()
+
+
+@pytest.mark.parametrize("lengths", list(_STEPS.values()), ids=list(_STEPS))
+def test_compact_prefill_equals_the_full_one_on_every_real_position(
+        clf, lengths):
+    """The model's forward with ``prefill_capacity`` (feed-forward halves
+    on the real positions) against the same call without: the blocks'
+    output and the latent cache on every real position, the experts they
+    chose, and through the scoring program the label scores."""
+    ids, lens = _ragged_step(clf, lengths)
+    rows, width = ids.shape
+    capacity = compact_capacity(int(lens.sum()), rows * width)
+    assert capacity == {125: 128, 96: 96, 97: 128}[int(lens.sum())]
+    positions = jnp.broadcast_to(jnp.arange(width), (rows, width))
+    lens_j = jnp.asarray(lens)
+    kv = jnp.arange(width + 8)[None, None, None, :]
+    mask = causal_mask(width, width + 8, 0) & (
+        kv < lens_j[:, None, None, None])
+
+    def forward(**declared):
+        (logits, caches), sown = clf.model.apply(
+            {"params": clf.params}, jnp.asarray(ids), positions, mask,
+            init_caches(clf.config, rows, width + 8),
+            mutable=["intermediates"], **declared)
+        chosen = np.stack([
+            np.asarray(sown["intermediates"][f"layer_{i}"]
+                       ["feed_forward_moe"]["chosen"][0]) for i in (1, 2)])
+        return np.asarray(logits), caches, chosen
+
+    full, full_caches, full_chosen = forward(prefill_lengths=lens_j)
+    got, caches, chosen = forward(prefill_lengths=lens_j,
+                                  prefill_capacity=capacity)
+    real = np.arange(width)[None, :] < lens[:, None]
+    # the final norm and the head of every real position read the blocks'
+    # output there: equal logits are equal block outputs
+    assert _bf16_steps(got[real], full[real])
+    assert np.abs(got[real] - full[real]).max() < 0.05
+    for layer, (a, b) in enumerate(zip(caches, full_caches)):
+        assert _bf16_steps(np.asarray(a.latents)[:, :width][real],
+                           np.asarray(b.latents)[:, :width][real]), layer
+        assert _bf16_steps(np.asarray(a.rope_keys)[:, :width][real],
+                           np.asarray(b.rope_keys)[:, :width][real]), layer
+    assert (chosen[:, real] == full_chosen[:, real]).all()
+    assert (chosen[:, ~real] == 0).all()
+
+    labels = (jnp.asarray(clf._label_ids), jnp.asarray(clf._label_lens))
+    want, want_stats = clf._score_labels(
+        clf.params, jnp.asarray(ids), lens_j, *labels)
+    scores, stats = clf._score_labels(
+        clf.params, jnp.asarray(ids), lens_j, *labels,
+        prefill_capacity=capacity)
+    assert _bf16_steps(scores, want)
+    assert (np.asarray(stats["chosen"])[:, real]
+            == np.asarray(want_stats["chosen"])[:, real]).all()
+    assert (np.asarray(stats["chosen_labels"])
+            == np.asarray(want_stats["chosen_labels"])).all()
+    # (b) the load the step reports is the real positions': N * top_k
+    assert (np.asarray(stats["expert_load_mean"]) * clf.config.n_experts
+            ).tolist() == [lens.sum() * 2] * 2
+    assert (np.asarray(want_stats["expert_load_mean"])
+            * clf.config.n_experts).tolist() == [rows * width * 2] * 2
+
+
+def test_a_step_of_full_rows_runs_the_program_without_lengths(clf):
+    """The rung of a step whose rows are all full is the step itself, and
+    at that capacity the traced program is the uncompacted one, text for
+    text: the one a caller that declares no capacity, or no lengths at
+    all, compiles (at this width the prefill kernel takes no part)."""
+    ids, lens = _ragged_step(clf, [64, 64, 64, 64])
+    args = (clf.params, jnp.asarray(ids), jnp.asarray(lens),
+            jnp.asarray(clf._label_ids), jnp.asarray(clf._label_lens))
+    assert compact_capacity(int(lens.sum()), ids.size) == ids.size
+
+    def text(**static):
+        return clf._score_labels.lower(*args, **static).as_text()
+
+    assert text(prefill_capacity=ids.size) == text()
+    assert text(prefill_capacity=ids.size // 2) != text()
+    # the staged hooks hand the program that rung
+    assert clf.transfer(clf.prepare(["la"] * 2))[3][2] < 2 * 64
+
+    positions = jnp.broadcast_to(jnp.arange(64), (4, 64))
+    mask = causal_mask(64, 64, 0)
+
+    def block_text(**declared):
+        return jax.jit(lambda p, x: clf.model.apply(
+            {"params": p}, x, positions, mask, **declared)[0]).lower(
+                clf.params, jnp.asarray(ids)).as_text()
+
+    assert block_text(prefill_lengths=jnp.asarray(lens),
+                      prefill_capacity=ids.size) == block_text()
+
+
+def _traced_growth(fn):
+    """How often a trace took ``moe.grouped`` and ``moe.compact`` while
+    ``fn`` ran."""
+    from music_analyst_tpu.telemetry import get_telemetry
+
+    tel = get_telemetry()
+    names = ("traced.moe.grouped", "traced.moe.compact")
+    before = [tel.counters.get(name, 0) for name in names]
+    fn()
+    return tuple(tel.counters.get(name, 0) - b
+                 for name, b in zip(names, before))
+
+
+@pytest.mark.parametrize("path", ["label_passes", "decode_step", "mesh",
+                                  "no_capacity", "generate_prefill"])
+def test_where_the_compact_path_must_and_must_not_engage(clf, path):
+    """Only a prefill that declares lengths and a capacity runs compact:
+    the vmapped label continuations, a decode step, a forward under a mesh
+    of more than one device (lengths withheld) and a caller that declares
+    no capacity trace ``moe.grouped`` without ``moe.compact``."""
+    # a width no other test traces: a traced program is not traced again
+    ids, lens = _ragged_step(clf, [48, 1, 23, 37], width=48)
+    assert compact_capacity(int(lens.sum()), ids.size) == 120
+    labels = (jnp.asarray(clf._label_ids), jnp.asarray(clf._label_lens))
+    if path == "label_passes":
+        # two routed layers: the prefill's two take it, the label passes'
+        # two (one vmapped trace of three continuations) do not
+        grouped, compact = _traced_growth(lambda: clf._score_labels.lower(
+            clf.params, jnp.asarray(ids), jnp.asarray(lens), *labels,
+            prefill_capacity=120))
+        assert (grouped, compact) == (4, 2)
+    elif path == "no_capacity":
+        grouped, compact = _traced_growth(lambda: clf._score_labels.lower(
+            clf.params, jnp.asarray(ids), jnp.asarray(lens), *labels))
+        assert (grouped, compact) == (4, 0)
+    elif path == "decode_step":
+        caches = init_caches(clf.config, 4, 56)
+        grouped, compact = _traced_growth(lambda: clf._decode_step.lower(
+            clf.params, jnp.asarray(ids[:, :1]), jnp.asarray(lens), caches))
+        assert (grouped, compact) == (2, 0)
+    elif path == "generate_prefill":
+        # the scan program's prefill declares both; its decode steps (one
+        # traced scan body) neither
+        grouped, compact = _traced_growth(lambda: clf._generate_scan.lower(
+            clf.params, jnp.asarray(ids), jnp.asarray(lens), 4,
+            early_exit=False, prefill_capacity=120))
+        assert (grouped, compact) == (4, 2)
+    else:
+        from music_analyst_tpu.parallel.mesh import MeshSpec, build_mesh
+
+        mesh = build_mesh(MeshSpec((("dp", 1), ("ep", 2), ("tp", 1))),
+                          devices=jax.devices()[:2])
+        meshed = LlamaZeroShotClassifier(
+            config=clf.config, mesh=mesh, max_prompt_len=clf.max_prompt_len)
+        transferred = meshed.transfer(meshed.prepare(LYRICS[:4]))
+        assert transferred[3][2] is None  # no capacity where none is read
+        grouped, compact = _traced_growth(
+            lambda: meshed.collect(meshed.launch(transferred)))
+        assert (grouped, compact) == (4, 0)
+        record = list(meshed._score_labels.records.values())[-1]
+        assert "moe.compact" not in record.traced_paths
+        # and even handed a capacity, withheld lengths keep the full path
+        grouped, compact = _traced_growth(
+            lambda: meshed._score_labels.lower(
+                meshed.params, jnp.asarray(ids), jnp.asarray(lens), *labels,
+                prefill_capacity=120))
+        assert (grouped, compact) == (4, 0)
+
+
 # ------------------------------------------------------ runtimes, entry points
 
 @pytest.mark.parametrize("runtime", ["paged_runtime", "slot_runtime"])
@@ -533,9 +720,17 @@ def test_staged_hooks_equal_classify_batch_and_count_the_step(clf):
     ratios = span.attrs["expert_load_max_over_mean"]
     assert len(ratios) == 2 and all(1.0 <= r <= 8.0 for r in ratios)
     assert tel.gauges["latent_cache_bytes"] == rows * (width + 8) * 3 * 2 * 24
-    # every assignment of the prefill is counted: rows * width * top_k a layer
-    assert (tel.counters["moe.assignments"] - before.get("moe.assignments", 0)
-            == 2 * 2 * rows * width * 2)
+    # two steps of one shape: the prefill's feed-forward halves ran on the
+    # rung that holds the real tokens, every REAL position's assignments
+    # are counted (top_k a routed layer) and the rows the grouped matmuls
+    # ran are the rung's, fillers included
+    capacity = compact_capacity(int(lens.sum()), rows * width)
+    assert int(lens.sum()) <= capacity < rows * width
+    assert span.attrs["moe_capacity"] == capacity
+    grew = {name: tel.counters[name] - before.get(name, 0)
+            for name in ("moe.assignments", "moe.rows_computed")}
+    assert grew == {"moe.assignments": 2 * 2 * int(lens.sum()) * 2,
+                    "moe.rows_computed": 2 * 2 * capacity * 2}
 
 
 def test_sentiment_cli_end_to_end(tmp_path, fixture_csv):

@@ -420,8 +420,8 @@ class TestLlamaPromptTrimming:
         clf = self._clf()
         seen = []
         real = clf._score_labels
-        clf._score_labels = lambda p, ids, lens, li, ll: (
-            seen.append(ids.shape), real(p, ids, lens, li, ll)
+        clf._score_labels = lambda p, ids, lens, li, ll, **static: (
+            seen.append(ids.shape), real(p, ids, lens, li, ll, **static)
         )[1]
         clf.classify_batch(["la la", "short one"])
         assert seen and seen[0][1] < 512

@@ -198,3 +198,135 @@ def test_sparse_moe_inside_classifier_forward():
     assert all(l in ("Positive", "Neutral", "Negative") for l in labels)
     outs = clf.generate_batch(["say hi", "la"], max_new_tokens=4)
     assert len(outs) == 2
+
+
+# ---------------------------------------------------------------------------
+# The compact token set of a prefill that knows its rows' lengths
+# (``RealPositions``, ``compact_capacity``, ``SigmoidRoutedMoE(x, compact)``)
+
+# (lengths of a [rows, 16] step, the rung that holds them)
+_RAGGED = ([16, 1, 9, 5], 32)       # a full row and a one-token row: N = 31
+_EXACT = ([8, 3, 16, 5], 32)        # the real tokens are exactly a rung
+_SPARSE = ([1, 1, 2, 1], 8)         # one rung holds them all
+_FULL = ([16, 16, 16, 16], 64)      # every row full: the uncompacted step
+_WIDTH = 16
+
+
+@pytest.mark.parametrize("lengths,rung", [
+    _RAGGED, _EXACT, _SPARSE, _FULL, ([16, 16, 16, 15], 64),
+    ([9, 8, 8, 8], 40)], ids=["ragged", "exact", "sparse", "full",
+                              "one-short-of-full", "one-past-a-rung"])
+def test_compact_capacity_is_the_smallest_eighth_that_holds_the_tokens(
+        lengths, rung):
+    from music_analyst_tpu.models.moe import compact_capacity
+
+    positions = len(lengths) * _WIDTH
+    assert compact_capacity(sum(lengths), positions) == rung
+    assert sum(lengths) <= rung <= positions
+    assert rung % (positions // 8) == 0
+
+
+def test_compact_capacity_never_passes_the_step_and_holds_an_empty_one():
+    from music_analyst_tpu.models.moe import compact_capacity
+
+    assert compact_capacity(0, 64) == 8        # one rung, never zero slots
+    assert compact_capacity(64, 64) == 64
+    assert compact_capacity(20, 20) == 20      # 20 positions: rungs of 3
+    assert compact_capacity(7, 20) == 9
+    assert {compact_capacity(n, 32 * 1024) for n in range(1, 32 * 1024 + 1,
+                                                          97)} == {
+        4096 * i for i in range(1, 9)}         # at most eight programs
+
+
+@pytest.mark.parametrize("lengths,capacity", [
+    _RAGGED, _EXACT, _SPARSE, ([16, 1, 9, 5], 48)],
+    ids=["ragged", "exact", "sparse", "a-rung-too-many"])
+def test_real_positions_index_is_row_major_and_its_own_inverse(
+        lengths, capacity):
+    from music_analyst_tpu.models.moe import RealPositions
+
+    index = RealPositions.of(jnp.asarray(lengths, jnp.int16), _WIDTH,
+                             capacity)
+    want = [row * _WIDTH + pos for row, n in enumerate(lengths)
+            for pos in range(n)]
+    n_real = len(want)
+    assert np.asarray(index.source)[:n_real].tolist() == want
+    assert np.asarray(index.valid).tolist() == (
+        [True] * n_real + [False] * (capacity - n_real))
+    # fillers hold a real position's copy: any index a gather may read
+    assert set(np.asarray(index.source)[n_real:].tolist()) <= {want[-1]}
+    x = jax.random.normal(jax.random.key(0), (len(lengths), _WIDTH, 3))
+    back = np.asarray(index.put_back(index.gather(x)))
+    real = np.arange(_WIDTH)[None, :] < np.asarray(lengths)[:, None]
+    assert (np.asarray(index.real) == real).all()
+    assert (back[real] == np.asarray(x)[real]).all()
+    assert (back[~real] == 0).all()
+
+
+def _routed(dtype):
+    from music_analyst_tpu.models.moe import SigmoidRoutedMoE
+
+    return SigmoidRoutedMoE(8, 16, 2, n_shared=1, routed_scaling_factor=2.0,
+                            dtype=dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("lengths,capacity", [
+    _RAGGED, _EXACT, _SPARSE, ([16, 1, 9, 5], 48)],
+    ids=["ragged", "exact", "sparse", "a-rung-too-many"])
+def test_compact_experts_equal_the_full_layer_on_every_real_position(
+        lengths, capacity, dtype):
+    """Router, sort, grouped matmuls, weighted sum and shared experts on
+    the real positions alone give what the full layer gives there (a row
+    of a grouped matmul depends on that row and its expert alone), the
+    same experts chosen; padding receives zeros and is counted nowhere."""
+    from music_analyst_tpu.models.moe import RealPositions
+
+    layer = _routed(dtype)
+    rows = len(lengths)
+    x = jax.random.normal(jax.random.key(3), (rows, _WIDTH, 12), dtype)
+    params = layer.init(jax.random.key(4), x[:1, :2])
+    full, sown_full = layer.apply(params, x, mutable=["intermediates"])
+    index = RealPositions.of(jnp.asarray(lengths), _WIDTH, capacity)
+    got, sown = layer.apply(params, x, index, mutable=["intermediates"])
+    assert got.shape == full.shape and got.dtype == full.dtype
+    real = np.asarray(index.real)
+    full, got = np.asarray(full, np.float32), np.asarray(got, np.float32)
+    step = 2.0 ** -7 if dtype == jnp.bfloat16 else 1e-6
+    assert (np.abs(got - full)[real]
+            <= step * np.maximum(np.abs(full)[real], 1.0)).all()
+    assert (got[~real] == 0).all() and np.abs(got[real]).max() > 0.1
+    chosen_full = np.asarray(sown_full["intermediates"]["chosen"][0])
+    chosen = np.asarray(sown["intermediates"]["chosen"][0])
+    assert chosen.shape == (rows, _WIDTH, 2)
+    assert (chosen[real] == chosen_full[real]).all()
+    assert (chosen[~real] == 0).all()
+    # (b) the load counts the real positions' assignments, fillers and
+    # padding uncounted: exactly the full layer's load over those positions
+    load = np.asarray(sown["intermediates"]["expert_load"][0])
+    assert load.sum() == sum(lengths) * 2
+    assert load.tolist() == np.bincount(
+        chosen_full[real].reshape(-1), minlength=8).tolist()
+    assert np.asarray(
+        sown_full["intermediates"]["expert_load"][0]).sum() == rows * 16 * 2
+
+
+def test_fillers_belong_to_no_expert_and_cannot_reach_a_real_position():
+    """A filler's assignments sort behind the last group: poisoning what
+    the filler slots hold changes no real position's result."""
+    from music_analyst_tpu.models.moe import RealPositions
+
+    layer = _routed(jnp.float32)
+    lengths, capacity = _RAGGED
+    x = jax.random.normal(jax.random.key(5), (4, _WIDTH, 12), jnp.float32)
+    params = layer.init(jax.random.key(6), x[:1, :2])
+    index = RealPositions.of(jnp.asarray(lengths), _WIDTH, capacity)
+    want = layer.apply(params, x, index)
+    # the filler now copies a padding position that holds an infinity
+    poisoned = x.at[1, 5].set(jnp.inf)
+    moved = index._replace(source=jnp.where(index.valid, index.source,
+                                            1 * _WIDTH + 5))
+    got = layer.apply(params, poisoned, moved)
+    assert bool(jnp.isfinite(got).all())
+    assert (np.asarray(got) == np.asarray(want)).all()

@@ -14,7 +14,8 @@ corpus job's two batch shapes (4,096 and the 306-row tail x 12 x 128 x 64),
 at the longest rows its VMEM arithmetic admits, and at ``distilbert-tiny``;
 the latent-attention prefill kernel at the decoder cell's step (32 x 1,024,
 32 heads of 192 | 128, the cache's 1,032-key buffer), at its smallest
-admitted width and at ``kanana-tiny``'s widths.
+admitted width and at ``kanana-tiny``'s widths; the whole scoring step of
+that cell at the rungs its compact feed-forward meets.
 """
 
 from __future__ import annotations
@@ -234,6 +235,66 @@ def test_projections_feed_the_prefill_kernel_without_a_copy(
         rf"^\s*(?:ROOT )?%[\w.-]+ = \S+ ([a-z-]+)\([^)]*%{re.escape(name)}[,)]",
         program, re.MULTILINE)
     assert readers and set(readers) <= {"bitcast", "fusion"}, readers
+
+
+@pytest.mark.parametrize("capacity", [12288, 16384])
+def test_compact_scoring_step_keeps_the_kernel_fed_and_no_padded_assignment(
+    tpu_sharding, monkeypatch, capacity
+):
+    """``llama_score_labels`` at the decoder cell's step (32 x 1,024, the
+    published widths, abstract parameters) compiled for a v5e at the rungs
+    the cell's jobs meet (``models/moe.compact_capacity`` of about 10.3k
+    real tokens a step).  The feed-forward halves run on ``capacity``
+    token slots, so nothing of the 196,608 assignments of the padded step
+    (32 x 1,024 x 6) is left, in any array; and the gather and put-back
+    around them do not come between the projections and the prefill
+    kernel, whose large operands are still the projections' own
+    ``[B, S, H*D]`` fusions in every one of the seven layers."""
+    from music_analyst_tpu.models import llama
+    from music_analyst_tpu.ops import flash_attention
+
+    monkeypatch.setattr(flash_attention, "interpret_default", lambda: False)
+    config = llama.PRESETS["kanana-2-30b-a3b"]()
+    rows, width, top_k = 32, 1024, config.moe_top_k
+    assert (config.n_layers, config.dim, top_k) == (7, 2048, 6)
+    program = llama.score_labels_program(llama.LlamaModel(config), config)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=tpu_sharding), tree)
+
+    compiled = program.trace(
+        placed(jax.eval_shape(lambda: llama.init_params_by_layer(config))),
+        placed(jnp.zeros((rows, width), jnp.int32)),
+        placed(jnp.zeros((rows,), jnp.int16)),
+        placed(jnp.zeros((3, 8), jnp.int32)),
+        placed(jnp.zeros((3,), jnp.int32)),
+        prefill_capacity=capacity,
+    ).lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+
+    assert str(rows * width * top_k) not in text
+    shapes = set(re.findall(
+        r"%ragged-dot-none[.\d]* = (\w+\[\d+,\d+\])", text))
+    # the prefill's grouped matmuls at capacity * top_k rows, the label
+    # continuations' (3 x 32 x 8 positions, uncompacted) at theirs
+    assert shapes == {
+        f"bf16[{capacity * top_k},{n}]" for n in (768, 2048)} | {
+        f"bf16[{3 * rows * 8 * top_k},{n}]" for n in (768, 2048)}, shapes
+    # 1.46 and 1.81 GB of temporaries where the padded step has 3.4
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+
+    calls = re.findall(
+        r"^\s*%(_prefill_call[.\d]*) = \S+ custom-call\(([^)]*)\)", text,
+        re.MULTILINE)
+    assert len(calls) == config.n_layers
+    for _, operands in calls:
+        lengths, q_nope, q_rope, kv, k_rope = re.findall(r"%([\w.-]+)",
+                                                         operands)
+        assert _opcode(text, q_nope) == "fusion"
+        assert _opcode(text, kv) == "fusion"
+        assert _opcode(text, q_rope) in ("copy", "fusion")
 
 
 def test_unservable_geometry_is_refused_by_name():

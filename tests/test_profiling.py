@@ -157,6 +157,27 @@ def test_compile_record_fields():
         assert d[key] is None or isinstance(d[key], (int, float))
 
 
+def test_static_keyword_keys_a_compile_and_reaches_the_executable():
+    """A static argument given by keyword is part of the compile's key (one
+    record a value) and is left out of the executable's call, which was
+    specialised on it: the compiled program itself runs, not a second
+    compile on the plain-jit fallback."""
+    import jax.numpy as jnp
+
+    from music_analyst_tpu.profiling.compile import profiled_jit
+
+    fn = profiled_jit(lambda x, width=None: x[: (width or x.shape[0])] * 2,
+                      name="static_probe", static_argnames=("width",))
+    x = jnp.arange(8, dtype=jnp.float32)
+    assert np.asarray(fn(x, width=3)).tolist() == [0.0, 2.0, 4.0]
+    assert np.asarray(fn(x, width=5)).shape == (5,)
+    assert np.asarray(fn(x)).shape == (8,)
+    assert np.asarray(fn(x, width=3)).shape == (3,)  # cached
+    assert len(fn.records) == 3
+    assert all(exe is not None for exe in fn._compiled.values())
+    assert fn._jit._cache_size() == 0  # the fallback never compiled
+
+
 def test_profiled_jit_under_outer_jit_defers_to_plain_jit():
     """jit-of-jit (the shard_map local fns): tracers must pass through."""
     import jax

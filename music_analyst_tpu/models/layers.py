@@ -313,6 +313,12 @@ class MultiHeadAttention(nn.Module):
     # The mesh a meshed forward runs under: a Pallas call is opaque to the
     # partitioner, so the whole-row kernel needs it to run per shard.
     mesh: Any = None
+    # RMSNorm over ``head_dim`` on every query and key head before RoPE,
+    # one learned scale of ``head_dim`` for all heads (QK-norm).
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+    # What the float projection kernels are stored in.
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(
@@ -328,16 +334,23 @@ class MultiHeadAttention(nn.Module):
         n_kv = self.n_kv_heads or self.n_heads
         head_dim = self.head_dim or features // self.n_heads
         dense_cls = pick_dense_cls(self.weight_quant, self.quant)
+        # only the float class has a storage dtype to choose
+        stored = ({"param_dtype": self.param_dtype}
+                  if dense_cls is nn.DenseGeneral else {})
         dense = lambda feats, name: dense_cls(  # noqa: E731
             features=feats,
             axis=-1,
             use_bias=self.use_bias,
             dtype=self.dtype,
             name=name,
+            **stored,
         )
         q = dense((self.n_heads, head_dim), "q_proj")(x)
         k = dense((n_kv, head_dim), "k_proj")(x)
         v = dense((n_kv, head_dim), "v_proj")(x)
+        if self.qk_norm:
+            q = RMSNorm(epsilon=self.norm_eps, name="q_norm")(q)
+            k = RMSNorm(epsilon=self.norm_eps, name="k_norm")(k)
 
         if self.use_rope:
             if positions is None:
@@ -408,6 +421,7 @@ class MultiHeadAttention(nn.Module):
             use_bias=self.use_bias,
             dtype=self.dtype,
             name="o_proj",
+            **stored,
         )(out)
         if cache is not None:
             return out, new_cache
@@ -478,6 +492,15 @@ def causal_mask(q_len: int, kv_len: int, offset) -> jax.Array:
     q_pos = jnp.arange(q_len)[:, None] + offset
     kv_pos = jnp.arange(kv_len)[None, :]
     return (kv_pos <= q_pos)[None, None, :, :]
+
+
+def block_causal_mask(q_len: int, kv_len: int, block: int) -> jax.Array:
+    """``[1, 1, q_len, kv_len]`` mask of a block-diffusion decoder: a query
+    sees every key up to the end of its own block of ``block`` positions
+    (bidirectional inside a block, causal across blocks)."""
+    q_block = jnp.arange(q_len)[:, None] // block
+    kv_block = jnp.arange(kv_len)[None, :] // block
+    return (kv_block <= q_block)[None, None, :, :]
 
 
 def padding_mask(lengths: jax.Array, max_len: int) -> jax.Array:

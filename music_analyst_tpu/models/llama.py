@@ -95,9 +95,11 @@ class LlamaConfig:
     v_head_dim: int = 0
     rope_interleave: bool = False
     rms_norm_eps: float = 1e-5
-    # "sigmoid_noaux" = sigmoid scores, bias-corrected choice, no dropped
-    # token, shared experts (models/moe.SigmoidRoutedMoE; experts of width
-    # ``moe_hidden_dim``); "softmax_capacity" = MoESwiGLU as above.
+    # "sigmoid_noaux" = sigmoid scores, bias-corrected choice, shared
+    # experts; "softmax_topk" = softmax over all experts, the top k
+    # renormalised, nothing else: both are models/moe.RoutedMoE (experts of
+    # width ``moe_hidden_dim``, no dropped token) with that router;
+    # "softmax_capacity" = MoESwiGLU as above.
     moe_router: str = "softmax_capacity"
     moe_hidden_dim: int = 0
     n_shared_experts: int = 0
@@ -118,12 +120,45 @@ class LlamaConfig:
     # whose scoring step compiles for most of a minute sets it to its
     # prompt cap and runs one shape.
     prompt_width_floor: int = 64
+    # Grouped-query attention beyond Llama's own: a head width that is not
+    # ``dim / n_heads`` (0 = that), RMSNorm over it on queries and keys.
+    head_dim: int = 0
+    qk_norm: bool = False
+    # How new tokens come: "autoregressive" (one a row a step) or
+    # "block_diffusion": blocks of ``block_length`` positions, attention
+    # bidirectional inside a block and causal across blocks, a block
+    # denoised from ``mask_token_id`` by passes that unmask every position
+    # whose confidence exceeds ``confidence_threshold`` and at least
+    # ``block_length / denoising_steps`` of them
+    # (``diffusion_prefill_program`` / ``diffusion_denoise_program``).
+    generation: str = "autoregressive"
+    block_length: int = 0
+    denoising_steps: int = 0
+    confidence_threshold: float = 1.0
+    mask_token_id: int = -1
 
     def __post_init__(self):
         if self.attention not in ("gqa", "mla"):
             raise ValueError(f"unknown attention kind {self.attention!r}")
-        if self.moe_router not in ("softmax_capacity", "sigmoid_noaux"):
+        if self.moe_router not in ("softmax_capacity", "sigmoid_noaux",
+                                   "softmax_topk"):
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
+        if (self.attention == "mla" and self.n_experts > 0
+                and not self.routed_experts):
+            raise ValueError(
+                "latent attention's expert layers are RoutedMoE: moe_router "
+                "must be sigmoid_noaux or softmax_topk")
+        if self.generation not in ("autoregressive", "block_diffusion"):
+            raise ValueError(f"unknown generation kind {self.generation!r}")
+        if self.block_diffusion and (
+                self.attention != "gqa" or self.block_length < 1
+                or self.denoising_steps < 1
+                or self.block_length % self.denoising_steps
+                or not 0 <= self.mask_token_id < self.vocab_size):
+            raise ValueError(
+                "block_diffusion needs grouped-query attention, a "
+                "block_length that denoising_steps divides and a "
+                "mask_token_id inside the vocabulary")
         if self.weight_quant not in ("none", "int8", "int4"):
             raise ValueError(
                 f"weight_quant must be none/int8/int4, got "
@@ -144,6 +179,27 @@ class LlamaConfig:
     def latent_cache(self) -> bool:
         return self.attention == "mla"
 
+    @property
+    def block_diffusion(self) -> bool:
+        return self.generation == "block_diffusion"
+
+    @property
+    def attn_head_dim(self) -> int:
+        return self.head_dim or self.dim // self.n_heads
+
+    @property
+    def offline_vocab_size(self) -> int:
+        """The ids an offline tokenizer may hash words over: a
+        block-diffusion model's mask token (high in the vocabulary, with
+        the other special tokens) and what lies behind it stay out."""
+        return (min(self.vocab_size, self.mask_token_id)
+                if self.block_diffusion else self.vocab_size)
+
+    @property
+    def routed_experts(self) -> bool:
+        """Whether the expert layers are ``models/moe.RoutedMoE``."""
+        return self.moe_router in ("sigmoid_noaux", "softmax_topk")
+
     def routed_layer(self, index: int) -> bool:
         """Whether layer ``index`` is a routed (expert) layer."""
         return self.n_experts > 0 and index >= self.first_k_dense_replace
@@ -151,8 +207,10 @@ class LlamaConfig:
     @classmethod
     def from_hf_config(cls, hf: dict, **overrides) -> "LlamaConfig":
         """The decoder a published ``config.json`` of ``model_type:
-        deepseek_v3`` describes, key by key.  What this code cannot run is
-        refused by name, not approximated."""
+        deepseek_v3`` or ``sdar_moe`` describes, key by key.  What this
+        code cannot run is refused by name, not approximated."""
+        if hf.get("model_type") == "sdar_moe":
+            return cls._from_sdar_moe(hf, overrides)
         unsupported = {
             "model_type": hf.get("model_type") != "deepseek_v3",
             "q_lora_rank": hf.get("q_lora_rank") is not None,
@@ -195,6 +253,49 @@ class LlamaConfig:
             routed_scaling_factor=float(hf["routed_scaling_factor"]),
             norm_topk_prob=bool(hf["norm_topk_prob"]),
             first_k_dense_replace=hf["first_k_dense_replace"],
+        )
+        fields.update(overrides)
+        return cls(**fields)
+
+    @classmethod
+    def _from_sdar_moe(cls, hf: dict, overrides: dict) -> "LlamaConfig":
+        """``model_type: sdar_moe``: a Qwen3-MoE-shaped layer (grouped-query
+        attention with QK-norm, a published ``head_dim``, every layer
+        softmax-routed experts) generating by diffusion over blocks.  The
+        sampler's sizes (``block_length``, ``denoising_steps``,
+        ``confidence_threshold``, ``mask_token_id``) are not in
+        ``config.json``: the caller's ``overrides`` state them."""
+        unsupported = {
+            "use_sliding_window": bool(hf.get("use_sliding_window", False)),
+            "mlp_only_layers": bool(hf.get("mlp_only_layers")),
+            "decoder_sparse_step": hf.get("decoder_sparse_step", 1) != 1,
+            "rope_scaling": hf.get("rope_scaling") is not None,
+            "attention_bias": bool(hf.get("attention_bias", False)),
+            "tie_word_embeddings": bool(hf.get("tie_word_embeddings", False)),
+            "hidden_act": hf.get("hidden_act", "silu") != "silu",
+        }
+        bad = sorted(k for k, v in unsupported.items() if v)
+        if bad:
+            raise ValueError(
+                "this decoder does not implement the configuration's "
+                + ", ".join(f"{k}={hf.get(k)!r}" for k in bad)
+            )
+        fields = dict(
+            vocab_size=hf["vocab_size"], dim=hf["hidden_size"],
+            n_layers=hf["num_hidden_layers"],
+            n_heads=hf["num_attention_heads"],
+            n_kv_heads=hf["num_key_value_heads"],
+            head_dim=hf["head_dim"], qk_norm=True,
+            # unused while every layer is routed (``mlp_only_layers`` [])
+            hidden_dim=hf["intermediate_size"],
+            rope_theta=float(hf["rope_theta"]),
+            max_seq_len=hf["max_position_embeddings"],
+            rms_norm_eps=float(hf["rms_norm_eps"]),
+            moe_router="softmax_topk", n_experts=hf["num_experts"],
+            moe_top_k=hf["num_experts_per_tok"],
+            moe_hidden_dim=hf["moe_intermediate_size"],
+            norm_topk_prob=bool(hf["norm_topk_prob"]),
+            generation="block_diffusion",
         )
         fields.update(overrides)
         return cls(**fields)
@@ -280,7 +381,7 @@ class LlamaBlock(nn.Module):
         attn = MultiHeadAttention(
             n_heads=cfg.n_heads,
             n_kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.dim // cfg.n_heads,
+            head_dim=cfg.attn_head_dim,
             use_rope=True,
             rope_theta=cfg.rope_theta,
             max_positions=cfg.max_seq_len,
@@ -289,9 +390,12 @@ class LlamaBlock(nn.Module):
             flash_causal=True,
             quant=cfg.quant,
             weight_quant=cfg.weight_quant,
+            qk_norm=cfg.qk_norm,
+            norm_eps=cfg.rms_norm_eps,
+            param_dtype=jnp.dtype(cfg.param_dtype),
             name="attention",
         )
-        h = RMSNorm(name="attention_norm")(x)
+        h = RMSNorm(epsilon=cfg.rms_norm_eps, name="attention_norm")(x)
         if cache is not None:
             attn_out, new_cache = attn(
                 h, mask=mask, positions=positions, cache=cache
@@ -311,7 +415,10 @@ class LlamaBlock(nn.Module):
             )
             new_cache = None
         x = x + attn_out
-        h = RMSNorm(name="ffn_norm")(x)
+        h = RMSNorm(epsilon=cfg.rms_norm_eps, name="ffn_norm")(x)
+        if cfg.n_experts > 0 and cfg.routed_experts:
+            return (x + self._feed_forward(h, prefill_lengths,
+                                           prefill_capacity), new_cache)
         if cfg.n_experts > 0:
             from music_analyst_tpu.models.moe import MoESwiGLU
 
@@ -332,20 +439,50 @@ class LlamaBlock(nn.Module):
         x = x + ffn(h)
         return x, new_cache
 
+    def _feed_forward(self, h, prefill_lengths, prefill_capacity):
+        """The feed-forward half of a block whose expert layers are
+        ``models/moe.RoutedMoE``, on the normed ``h [B, S, D]``: the dense
+        SwiGLU in the leading layers, routed (+ shared) experts in the
+        rest, with the router the configuration names.
+
+        A prefill that declares its rows' lengths and a ``prefill_capacity``
+        under the step's positions runs it on the real positions alone
+        (``models/moe.RealPositions``: gathered into that many token slots,
+        the result put back at their places); positions at or behind a
+        row's length then receive zeros (the residual alone)."""
+        from music_analyst_tpu.models.moe import RealPositions, RoutedMoE
+
+        cfg = self.config
+        dtype, param_dtype = jnp.dtype(cfg.dtype), jnp.dtype(cfg.param_dtype)
+        compact = None
+        if (prefill_lengths is not None and prefill_capacity is not None
+                and prefill_capacity < h.shape[0] * h.shape[1]):
+            compact = RealPositions.of(prefill_lengths, h.shape[1],
+                                       prefill_capacity)
+        if cfg.routed_layer(self.layer_index):
+            ffn = RoutedMoE(
+                cfg.n_experts, cfg.moe_hidden_dim, cfg.moe_top_k,
+                n_shared=cfg.n_shared_experts,
+                routed_scaling_factor=cfg.routed_scaling_factor,
+                norm_topk_prob=cfg.norm_topk_prob, dtype=dtype,
+                param_dtype=param_dtype,
+                router=cfg.moe_router,
+                name="feed_forward_moe",
+            )
+            if compact is not None:  # it puts the experts it chose back too
+                return ffn(h, compact)
+        else:
+            ffn = SwiGLU(cfg.hidden_dim, dtype=dtype,
+                         param_dtype=param_dtype, name="feed_forward")
+        if compact is not None:
+            return compact.put_back(ffn(compact.gather(h)))
+        return ffn(h)
 
     def _latent_block(self, x, mask, positions, cache, prefill_lengths,
                       prefill_capacity, segment_ids):
-        """Pre-norm block of the ``mla`` kind: latent attention, then the
-        dense SwiGLU in the leading layers and routed + shared experts in
-        the rest.
-
-        A prefill that declares its rows' lengths and a ``prefill_capacity``
-        under the step's positions runs the feed-forward half on the real
-        positions alone (``models/moe.RealPositions``: gathered into that
-        many token slots, the result put back at their places); positions
-        at or behind a row's length then receive the residual alone."""
+        """Pre-norm block of the ``mla`` kind: latent attention, then
+        :meth:`_feed_forward`."""
         from music_analyst_tpu.models.mla import MLAttention
-        from music_analyst_tpu.models.moe import RealPositions
 
         cfg = self.config
         if segment_ids is not None:
@@ -370,29 +507,8 @@ class LlamaBlock(nn.Module):
                 new_cache = None
         x = x + attn_out
         h = RMSNorm(epsilon=cfg.rms_norm_eps, name="ffn_norm")(x)
-        compact = None
-        if (prefill_lengths is not None and prefill_capacity is not None
-                and prefill_capacity < h.shape[0] * h.shape[1]):
-            compact = RealPositions.of(prefill_lengths, h.shape[1],
-                                       prefill_capacity)
-        if cfg.routed_layer(self.layer_index):
-            from music_analyst_tpu.models.moe import SigmoidRoutedMoE
-
-            ffn = SigmoidRoutedMoE(
-                cfg.n_experts, cfg.moe_hidden_dim, cfg.moe_top_k,
-                n_shared=cfg.n_shared_experts,
-                routed_scaling_factor=cfg.routed_scaling_factor,
-                norm_topk_prob=cfg.norm_topk_prob, dtype=dtype,
-                param_dtype=param_dtype, name="feed_forward_moe",
-            )
-            if compact is not None:  # it puts the experts it chose back too
-                return x + ffn(h, compact), new_cache
-        else:
-            ffn = SwiGLU(cfg.hidden_dim, dtype=dtype,
-                         param_dtype=param_dtype, name="feed_forward")
-        if compact is not None:
-            return x + compact.put_back(ffn(compact.gather(h))), new_cache
-        return x + ffn(h), new_cache
+        return (x + self._feed_forward(h, prefill_lengths, prefill_capacity),
+                new_cache)
 
 
 class LlamaModel(nn.Module):
@@ -410,8 +526,11 @@ class LlamaModel(nn.Module):
         segment_ids: Optional[jax.Array] = None,   # [B, S] — packed docs
         prefill_lengths: Optional[jax.Array] = None,  # [B] — see below
         prefill_capacity: Optional[int] = None,    # static — see below
+        with_head: bool = True,  # False: no logits (``None`` in their place)
     ):
-        # ``prefill_lengths`` is read by the latent (``mla``) blocks alone
+        # ``prefill_lengths`` is read by the blocks whose expert layers are
+        # ``RoutedMoE`` (the latent blocks hand it to their attention too,
+        # a grouped-query block's cache view knows the lengths itself)
         # and is a promise about THIS call (models/mla.MLAttention): a
         # causal prefill from position 0 on empty caches, ``mask`` = causal
         # and key padding by these lengths, one device, and nothing reads
@@ -449,6 +568,10 @@ class LlamaModel(nn.Module):
             )
             if new_cache is not None:
                 new_caches.append(new_cache)
+        if not with_head:
+            # a pass whose logits nobody reads (a diffusion prefill has no
+            # next token; a commit pass only writes keys and values)
+            return None, (new_caches if caches is not None else None)
         x = RMSNorm(epsilon=cfg.rms_norm_eps, name="norm")(x)
         if last_position is not None:
             # Gather ONE position per row BEFORE the vocab projection:
@@ -486,9 +609,9 @@ def init_caches(
                               cfg.qk_rope_head_dim, dtype)
             for _ in range(cfg.n_layers)
         ]
-    head_dim = cfg.dim // cfg.n_heads
     return [
-        KVCache.zeros(batch, max_len, cfg.n_kv_heads, head_dim, dtype)
+        KVCache.zeros(batch, max_len, cfg.n_kv_heads, cfg.attn_head_dim,
+                      dtype)
         for _ in range(cfg.n_layers)
     ]
 
@@ -670,9 +793,29 @@ def _sown_by_layer(sown, name: str) -> list:
     return [moe[name][0] for _, moe in layers]
 
 
+def _expert_id_dtype(n_experts: int):
+    return jnp.uint8 if n_experts <= 256 else jnp.int32
+
+
 def _expert_ids(chosen: jax.Array, n_experts: int) -> jax.Array:
     """Expert indices in the narrowest type that holds them."""
-    return chosen.astype(jnp.uint8 if n_experts <= 256 else jnp.int32)
+    return chosen.astype(_expert_id_dtype(n_experts))
+
+
+def _routing_stats(sown, config: "LlamaConfig") -> dict:
+    """The small device-side reductions of a prefill's routed layers that
+    ride back with a step's result: ``expert_load_max`` / ``_mean``
+    ``[routed layers]`` and ``chosen [layers, B, S, k]`` (which experts
+    every position ran, for whoever compares against a reference); empty
+    for a model without routed layers."""
+    loads = _sown_by_layer(sown, "expert_load")
+    if not loads:
+        return {}
+    load = jnp.stack(loads).astype(jnp.float32)  # [layers, E]
+    return {"expert_load_max": load.max(axis=-1),
+            "expert_load_mean": load.mean(axis=-1),
+            "chosen": _expert_ids(jnp.stack(_sown_by_layer(sown, "chosen")),
+                                  config.n_experts)}
 
 
 def init_params_by_layer(cfg: LlamaConfig, seed: int = 0):
@@ -730,7 +873,8 @@ def _prefill_capacity(config: LlamaConfig, mesh, prompt_lens,
     ``models/moe.compact_capacity`` that holds the real tokens, where the
     blocks read ``prefill_lengths`` (latent blocks, one device); ``None``
     where they are withheld or unread, so such a step has one program."""
-    if not config.latent_cache or _partitioned(mesh):
+    if not (config.latent_cache or config.block_diffusion) \
+            or _partitioned(mesh):
         return None
     from music_analyst_tpu.models.moe import compact_capacity
 
@@ -784,17 +928,7 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
             prefill_capacity=prefill_capacity,
             mutable=["intermediates"],
         )
-        stats = {}
-        loads = _sown_by_layer(sown, "expert_load")
-        if loads:
-            load = jnp.stack(loads).astype(jnp.float32)  # [layers, E]
-            stats = {"expert_load_max": load.max(axis=-1),
-                     "expert_load_mean": load.mean(axis=-1),
-                     # [layers, B, S, k]: which experts every position
-                     # ran, for whoever compares against a reference
-                     "chosen": _expert_ids(
-                         jnp.stack(_sown_by_layer(sown, "chosen")),
-                         config.n_experts)}
+        stats = _routing_stats(sown, config)
         # Force every cache to report the true prompt length so label
         # positions line up even though the buffer was written at 0..S.
         caches = [c.with_length(S) for c in caches]
@@ -1086,7 +1220,7 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         self.config = config or LlamaConfig.tiny()
         self.max_prompt_len = max_prompt_len
         self.tokenizer = resolve_llama_tokenizer(
-            self.config.vocab_size, kind=self.config.tokenizer)
+            self.config.offline_vocab_size, kind=self.config.tokenizer)
         # Ids above vocab_size would be silently clamped by nn.Embed's
         # gather, producing garbage labels with no diagnostic.  With real
         # weights that's fatal; on random-weight smoke runs (labels are
@@ -1160,6 +1294,10 @@ class LlamaZeroShotClassifier(ClassifierBackend):
                 "llama3-8b needs a checkpoint (set MUSICAAL_LLAMA_CKPT) and "
                 "a multi-chip mesh; use --model llama3-tiny for smoke runs "
                 "or --mock for the keyword kernel"
+            )
+        if config.block_diffusion:
+            from music_analyst_tpu.models.block_diffusion import (
+                BlockDiffusionClassifier as cls,
             )
         return cls(config=config, checkpoint_path=ckpt, **kwargs)
 
@@ -1269,25 +1407,36 @@ class LlamaZeroShotClassifier(ClassifierBackend):
                      label_positions=n_labels * label_width,
                      label_positions_real=label_real)
         if stats:
-            load_max = np.asarray(stats["expert_load_max"], np.float64)
-            load_mean = np.asarray(stats["expert_load_mean"], np.float64)
-            tel.count("moe.assignments",
-                      int(load_mean.sum() * self.config.n_experts))
             slots = rows * width if capacity is None else capacity
-            tel.count("moe.rows_computed",
-                      slots * self.config.moe_top_k * len(load_max))
-            attrs["moe_capacity"] = slots
-            tel.count("moe.expert_load_max", int(load_max.sum()))
-            tel.count("moe.expert_load_mean", int(load_mean.sum()))
-            attrs["expert_load_max_over_mean"] = [
-                round(float(m / max(a, 1e-9)), 4)
-                for m, a in zip(load_max, load_mean)]
+            attrs.update(self._count_expert_load(stats, slots))
         if self.config.latent_cache:
             cfg = self.config
             tel.gauge("latent_cache_bytes", int(
                 rows * (width + label_width) * cfg.n_layers * 2
                 * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)))
         tel.current_span().set(**attrs)
+
+    def _count_expert_load(self, stats, slots: int,
+                           pass_assignments: int = 0) -> dict:
+        """The ``moe.*`` counters of one step whose prefill ran its expert
+        layers on ``slots`` token slots (``stats``: its routed layers'
+        load), ``pass_assignments`` more having run uncompacted behind it;
+        returns what goes on the ``compute`` span."""
+        from music_analyst_tpu.telemetry import get_telemetry
+
+        tel = get_telemetry()
+        load_max = np.asarray(stats["expert_load_max"], np.float64)
+        load_mean = np.asarray(stats["expert_load_mean"], np.float64)
+        tel.count("moe.assignments", int(
+            load_mean.sum() * self.config.n_experts) + pass_assignments)
+        tel.count("moe.rows_computed",
+                  slots * self.config.moe_top_k * len(load_max)
+                  + pass_assignments)
+        tel.count("moe.expert_load_max", int(load_max.sum()))
+        tel.count("moe.expert_load_mean", int(load_mean.sum()))
+        return {"moe_capacity": slots, "expert_load_max_over_mean": [
+            round(float(m / max(a, 1e-9)), 4)
+            for m, a in zip(load_max, load_mean)]}
 
     def classify_batch(self, texts: Sequence[str]) -> List[str]:
         return self.collect(self.submit(texts))

@@ -218,6 +218,25 @@ def route_sigmoid_noaux(
     return chosen.astype(jnp.int32), weights * routed_scaling_factor
 
 
+def route_softmax_topk(logits: jax.Array, top_k: int,
+                       norm_topk_prob: bool = True):
+    """``softmax`` scores over ALL experts, the ``top_k`` largest chosen,
+    no bias, no scaling, no group stage.  Weights: the chosen
+    probabilities, divided by their sum where ``norm_topk_prob``.
+    ``logits [T, E]`` float32; returns ``(indices [T, k] int32, weights
+    [T, k] float32)``."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, chosen = jax.lax.top_k(probs, top_k)
+    if norm_topk_prob:
+        weights = weights / weights.sum(axis=-1, keepdims=True)
+    return chosen.astype(jnp.int32), weights
+
+
+# The routers :class:`RoutedMoE` can be given, by the configuration's name
+# for them (``LlamaConfig.moe_router``).
+ROUTERS = ("sigmoid_noaux", "softmax_topk")
+
+
 COMPACT_RUNGS = 8
 
 
@@ -323,9 +342,14 @@ def _grouped_experts_vmap(axis_size, in_batched, xt, chosen, weights,
     return out.reshape((axis_size, -1) + out.shape[1:]), True
 
 
-class SigmoidRoutedMoE(nn.Module):
-    """Sigmoid-routed experts with **no capacity and no dropped token**,
-    plus shared experts every token passes through.
+class RoutedMoE(nn.Module):
+    """Routed experts with **no capacity and no dropped token**, plus the
+    shared experts (if any) every token passes through.  ``router`` names
+    the function that scores and chooses (:data:`ROUTERS`):
+    ``sigmoid_noaux`` (:func:`route_sigmoid_noaux`, with its
+    ``e_score_correction_bias`` parameter and ``routed_scaling_factor``) or
+    ``softmax_topk`` (:func:`route_softmax_topk`, no parameter beside the
+    router's matrix).
 
     The assignments (token, expert) are sorted by expert and each of the
     three projections is one grouped matrix multiplication over the ragged
@@ -336,7 +360,7 @@ class SigmoidRoutedMoE(nn.Module):
     SwiGLU of width ``n_shared * hidden_dim``.
 
     Given ``compact`` (a prefill that declared its rows' lengths:
-    ``models/llama.LlamaBlock._latent_block``), router, sort, grouped
+    ``models/llama.LlamaBlock._feed_forward``), router, sort, grouped
     matmuls, weighted sum and shared experts run on the real positions
     alone, gathered into ``compact``'s token set, and the result is put
     back at their places: every real position is routed by the same scores
@@ -358,6 +382,7 @@ class SigmoidRoutedMoE(nn.Module):
     norm_topk_prob: bool = True
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
+    router: str = "sigmoid_noaux"
 
     @nn.compact
     def __call__(self, x: jax.Array,
@@ -378,12 +403,15 @@ class SigmoidRoutedMoE(nn.Module):
         # run, not how precisely.
         router_w = self.param("router", fan_in_normal(D), (D, E),
                               jnp.float32)
-        bias = self.param(
-            "e_score_correction_bias",
-            lambda key, shape, dtype: 0.01 * jax.random.normal(
-                key, shape, dtype),
-            (E,), jnp.float32,
-        )
+        if self.router not in ROUTERS:
+            raise ValueError(f"unknown router {self.router!r}")
+        if self.router == "sigmoid_noaux":
+            bias = self.param(
+                "e_score_correction_bias",
+                lambda key, shape, dtype: 0.01 * jax.random.normal(
+                    key, shape, dtype),
+                (E,), jnp.float32,
+            )
         if compact is None:
             xt = x.reshape(B * S, D).astype(self.dtype)
         else:
@@ -392,9 +420,14 @@ class SigmoidRoutedMoE(nn.Module):
         with jax.named_scope("moe.route"):
             logits = jnp.dot(xt.astype(jnp.float32), router_w,
                              precision=jax.lax.Precision.HIGHEST)
-            chosen, weights = route_sigmoid_noaux(
-                logits, bias, k, self.routed_scaling_factor,
-                self.norm_topk_prob)
+            if self.router == "sigmoid_noaux":
+                chosen, weights = route_sigmoid_noaux(
+                    logits, bias, k, self.routed_scaling_factor,
+                    self.norm_topk_prob)
+            else:
+                note_traced_path("moe.softmax_topk")
+                chosen, weights = route_softmax_topk(
+                    logits, k, self.norm_topk_prob)
 
         with jax.named_scope("moe.experts"):
             note_traced_path("moe.grouped")
@@ -421,3 +454,7 @@ class SigmoidRoutedMoE(nn.Module):
         if compact is None:
             return out.reshape(B, S, D).astype(x.dtype)
         return compact.put_back(out.astype(x.dtype))
+
+
+# The name the layer had while the sigmoid router was its only one.
+SigmoidRoutedMoE = RoutedMoE

@@ -59,6 +59,7 @@ def _flash_kernel(
     scale: float,
     residuals: bool,
     segmented: bool,
+    block_causal: int = 0,
 ):
     if segmented:
         # VMEM [1, bq, 1] / [1, 1, bkv] — per-token segment ids (block-
@@ -88,10 +89,18 @@ def _flash_kernel(
 
     if causal:
         # Skip kv blocks whose every (offset-adjusted) position is above
-        # the diagonal: they can't contribute to the online softmax.
+        # the diagonal: they can't contribute to the online softmax.  (A
+        # ``block_causal`` length divides both tile sizes, so the last
+        # query of a tile also ends its block and the rule is the same.)
         run = kv_off + ki * block_kv <= q_off + qi * block_q + block_q - 1
     else:
         run = ki >= 0
+    if block_causal:
+        # A block-causal prefill is self-attention of rows ``kv_len``
+        # long: key tiles at or past a row's length add nothing, and
+        # query tiles there are read by nobody (written as zeros).
+        run = run & (kv_off + ki * block_kv < kv_len) & (
+            q_off + qi * block_q < kv_len)
 
     @pl.when(run)
     def _compute():
@@ -115,6 +124,10 @@ def _flash_kernel(
             q_pos = q_off + qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, dimension=0
             )
+            if block_causal:
+                # a query sees every key up to the end of its own block
+                q_pos = q_pos // block_causal * block_causal + (
+                    block_causal - 1)
             valid = valid & (kv_pos <= q_pos)
         if segmented:
             valid = valid & (qseg_ref[0] == kvseg_ref[0])  # [bq,1]==[1,bkv]
@@ -149,7 +162,7 @@ def _flash_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "block_q", "block_kv", "interpret",
-                     "residuals"),
+                     "residuals", "block_causal"),
 )
 def _flash_call(
     q: jax.Array,       # [B, S, H, D]
@@ -164,6 +177,7 @@ def _flash_call(
     residuals: bool,
     q_seg: jax.Array | None = None,   # [B, S] int32 segment ids
     kv_seg: jax.Array | None = None,  # [B, KV]
+    block_causal: int = 0,
 ):
     B, S, H, D = q.shape
     KV = k.shape[1]
@@ -188,6 +202,7 @@ def _flash_call(
         scale=scale,
         residuals=residuals,
         segmented=segmented,
+        block_causal=block_causal,
     )
     qblock_spec = pl.BlockSpec(
         (1, 1, block_q, D),
@@ -291,6 +306,7 @@ def flash_attention(
     return_residuals: bool = False,
     q_segment_ids: jax.Array | None = None,
     kv_segment_ids: jax.Array | None = None,
+    block_causal: int = 0,
 ):
     """Attention over ``[B, S, H, D]`` without materializing logits.
 
@@ -313,6 +329,15 @@ def flash_attention(
     formulation's uniform-over-masked behavior in effect (neither is ever
     gathered).
 
+    ``block_causal`` = ``n`` > 0 (with ``causal``) is the block-diffusion
+    prefill's rule: a query at position ``i`` sees key ``j`` iff ``j <
+    lengths[row]`` and ``j // n <= i // n`` (bidirectional inside a block
+    of ``n`` positions, causal across blocks).  ``n`` must divide both
+    tiles, so tile skipping stays the causal rule; it declares
+    self-attention of rows ``lengths`` long, so tiles of keys AND of
+    queries at or past a row's length are skipped and such queries come
+    back as zeros.
+
     ``q_offset``/``kv_offset`` shift the global positions used by the
     causal/length masks — the hook that lets a sequence-parallel caller
     (ring attention) run this kernel on one K/V shard at a time.  With
@@ -326,6 +351,11 @@ def flash_attention(
     block_kv = _fit_block(block_kv, KV)
     if H % k.shape[2]:
         raise ValueError(f"q heads {H} not a multiple of kv heads {k.shape[2]}")
+    if block_causal and (not causal or block_q % block_causal
+                         or block_kv % block_causal):
+        raise ValueError(
+            f"block_causal={block_causal} needs causal=True and a length "
+            f"that divides the tiles ({block_q} x {block_kv})")
     if lengths is None:
         # Lengths are *global* positions: with a kv_offset the local shard
         # covers [kv_offset, kv_offset + KV).
@@ -363,4 +393,5 @@ def flash_attention(
     return _flash_call(
         q, k, v, lengths.astype(jnp.int32), offsets, causal, block_q,
         block_kv, interpret, return_residuals, q_seg=q_seg, kv_seg=kv_seg,
+        block_causal=block_causal,
     )

@@ -82,3 +82,147 @@ class KVCache:
 jax.tree_util.register_dataclass(
     KVCache, data_fields=["keys", "values", "length"], meta_fields=[]
 )
+
+
+# ---------------------------------------------------------------------------
+# Block-diffusion views.  A block-diffusion decoder prefills its prompt's
+# whole blocks under a block-causal mask, then runs one block of positions
+# over the cache again and again (denoising: the cache is read, nothing is
+# written) and once more to keep it (commit: the block's keys and values
+# are written at each row's own offset).  Each of the three is a view the
+# attention layer treats as a cache that attends for itself
+# (``models/layers.MultiHeadAttention``: ``cache.update(k, v).attend(q,
+# mask)``, the seam the paged cache uses; the ``mask`` argument is unread,
+# a view knows its rule).
+
+NEG_INF = -1e30
+
+
+def block_causal_tile(n_queries: int) -> int:
+    """The flash kernel's tile for a block-causal prefill of this many
+    positions (``ops/flash_attention.py``), 0 = outside its regime: whole
+    tiles of 512 or 256 positions; anything else keeps the masked XLA
+    form.  The one place the limit is written down."""
+    for tile in (512, 256):
+        if n_queries % tile == 0:
+            return tile
+    return 0
+
+
+def _grouped_scores(q, k, scale):
+    """``q [B, n, H, D]`` against ``k [B, L, Hkv, D]`` without repeating
+    the key heads: ``[B, Hkv, G, n, L]`` float32."""
+    B, n, H, D = q.shape
+    qg = q.reshape(B, n, k.shape[2], H // k.shape[2], D)
+    return jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
+                      preferred_element_type=jnp.float32) * scale
+
+
+def _grouped_values(p, v, dtype):
+    """``p [B, Hkv, G, n, L]`` over ``v [B, L, Hkv, D]``: ``[B, n, H, D]``."""
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(out.shape[:2] + (-1, out.shape[-1]))
+
+
+@dataclasses.dataclass
+class BlockCausalPrefill:
+    """View of an EMPTY cache for the prefill of ``lengths`` positions a
+    row (whole blocks of ``block``): attention is self-attention of the
+    new keys under the block-causal rule, by the flash kernel where the
+    width admits it (``kernel``; :func:`block_causal_tile`) and by the
+    masked XLA form elsewhere; ``update`` lays the new keys and values at
+    the head of the cache's buffers and reports ``lengths`` filled.  What
+    the view returns at or behind a row's length is not defined."""
+
+    cache: KVCache
+    lengths: jax.Array  # [B] int32, a multiple of ``block`` a row
+    block: int
+    kernel: bool = True
+    k_new: jax.Array | None = None
+    v_new: jax.Array | None = None
+
+    def update(self, k_new: jax.Array, v_new: jax.Array):
+        cache = self.cache
+        keys = jax.lax.dynamic_update_slice(
+            cache.keys, k_new.astype(cache.keys.dtype), (0, 0, 0, 0))
+        values = jax.lax.dynamic_update_slice(
+            cache.values, v_new.astype(cache.values.dtype), (0, 0, 0, 0))
+        return dataclasses.replace(
+            self, cache=KVCache(keys, values, self.lengths.astype(jnp.int32)),
+            k_new=k_new, v_new=v_new)
+
+    def attend(self, q: jax.Array, mask=None) -> jax.Array:
+        from music_analyst_tpu.profiling.compile import note_attention_path
+
+        n = q.shape[1]
+        tile = block_causal_tile(n) if self.kernel else 0
+        if tile:
+            from music_analyst_tpu.ops.flash_attention import flash_attention
+
+            note_attention_path("block_causal")
+            return flash_attention(
+                q, self.k_new, self.v_new, lengths=self.lengths,
+                causal=True, block_causal=self.block, block_q=tile,
+                block_kv=tile)
+        note_attention_path("block_causal_dense")
+        scores = _grouped_scores(q, self.k_new, q.shape[-1] ** -0.5)
+        pos = jnp.arange(n)
+        seen = (pos[None, :] // self.block <= pos[:, None] // self.block)
+        seen = seen[None] & (pos[None, None, :]
+                             < self.lengths[:, None, None])    # [B, n, n]
+        scores = jnp.where(seen[:, None, None], scores, NEG_INF)
+        return _grouped_values(jax.nn.softmax(scores, axis=-1), self.v_new,
+                               q.dtype).astype(q.dtype)
+
+
+@dataclasses.dataclass
+class BlockPass:
+    """View of a filled cache for one pass of one block: the block's
+    queries see the row's cached keys (``j < filled[row]``, the cache's
+    length before the pass) and the block's own, all of them
+    (bidirectional), computed as two score parts under one softmax so the
+    cache is never copied.  ``commit`` (static) says whether ``update``
+    writes the block's keys and values at each row's own offset and
+    advances its length (the last pass of a block) or leaves the cache as
+    it is (a denoising pass)."""
+
+    cache: KVCache  # ``length`` a ``[B]`` vector
+    commit: bool = False
+    filled: jax.Array | None = None
+    k_new: jax.Array | None = None
+    v_new: jax.Array | None = None
+
+    def update(self, k_new: jax.Array, v_new: jax.Array):
+        cache = self.cache.update(k_new, v_new) if self.commit else self.cache
+        return dataclasses.replace(self, cache=cache,
+                                   filled=self.cache.length, k_new=k_new,
+                                   v_new=v_new)
+
+    def attend(self, q: jax.Array, mask=None) -> jax.Array:
+        from music_analyst_tpu.profiling.compile import note_attention_path
+
+        note_attention_path("block_over_cache")
+        scale = q.shape[-1] ** -0.5
+        keys, values = self.cache.keys, self.cache.values
+        cached = _grouped_scores(q, keys, scale)           # [B,Hkv,G,n,L]
+        seen = (jnp.arange(keys.shape[1])[None, :]
+                < self.filled[:, None])[:, None, None, None, :]
+        cached = jnp.where(seen, cached, NEG_INF)
+        own = _grouped_scores(q, self.k_new.astype(keys.dtype), scale)
+        probs = jax.nn.softmax(
+            jnp.concatenate([cached, own], axis=-1), axis=-1)
+        split = keys.shape[1]
+        out = (_grouped_values(probs[..., :split], values, q.dtype)
+               + _grouped_values(probs[..., split:],
+                                 self.v_new.astype(values.dtype), q.dtype))
+        return out.astype(q.dtype)
+
+
+for _view, _data, _meta in (
+    (BlockCausalPrefill, ["cache", "lengths", "k_new", "v_new"],
+     ["block", "kernel"]),
+    (BlockPass, ["cache", "filled", "k_new", "v_new"], ["commit"]),
+):
+    jax.tree_util.register_dataclass(_view, data_fields=_data,
+                                     meta_fields=_meta)

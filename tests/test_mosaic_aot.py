@@ -15,7 +15,9 @@ at the longest rows its VMEM arithmetic admits, and at ``distilbert-tiny``;
 the latent-attention prefill kernel at the decoder cell's step (32 x 1,024,
 32 heads of 192 | 128, the cache's 1,032-key buffer), at its smallest
 admitted width and at ``kanana-tiny``'s widths; the whole scoring step of
-that cell at the rungs its compact feed-forward meets.
+that cell at the rungs its compact feed-forward meets; flash attention
+under the block-causal rule at the diffusion cell's prefill (32 x 1,024, 32
+query heads on 4 key heads of 128) and both programs of that cell's step.
 """
 
 from __future__ import annotations
@@ -295,6 +297,89 @@ def test_compact_scoring_step_keeps_the_kernel_fed_and_no_padded_assignment(
         assert _opcode(text, q_nope) == "fusion"
         assert _opcode(text, kv) == "fusion"
         assert _opcode(text, q_rope) in ("copy", "fusion")
+
+
+@pytest.mark.parametrize("rows,width,heads,kv_heads,head_dim", [
+    (32, 1024, 32, 4, 128),  # the diffusion cell's prefill
+    (8, 256, 4, 2, 32),      # sdar-tiny at its smallest admitted width
+], ids=["sdar-30b-a3b-chat", "sdar-tiny"])
+def test_block_causal_flash_attention_compiles_under_mosaic(
+    tpu_sharding, rows, width, heads, kv_heads, head_dim
+):
+    from music_analyst_tpu.ops.kv_cache import block_causal_tile
+
+    tile = block_causal_tile(width)
+    assert tile in (256, 512)
+
+    def fn(q, k, v, lengths):
+        return flash_attention(
+            q, k, v, lengths=lengths, causal=True, block_causal=4,
+            block_q=tile, block_kv=tile, interpret=False)
+
+    _compile_for_tpu(
+        fn, tpu_sharding,
+        ((rows, width, heads, head_dim), jnp.bfloat16),
+        ((rows, width, kv_heads, head_dim), jnp.bfloat16),
+        ((rows, width, kv_heads, head_dim), jnp.bfloat16),
+        ((rows,), jnp.int32))
+
+
+@pytest.mark.parametrize("capacity", [12288, 16384])
+def test_diffusion_step_compiles_for_the_chip_at_the_cells_shapes(
+    tpu_sharding, monkeypatch, capacity
+):
+    """Both programs of ``sdar-30b-a3b-chat``'s step (32 x 1,024, the
+    published widths, abstract parameters) compiled for a v5e at the rungs
+    the cell's jobs meet: the prefill holds one block-causal kernel call a
+    layer and its expert layers run ``capacity`` token slots, not the
+    padded step's 32,768; the block loop's
+    grouped matmuls run the 1,024 assignments of a pass, and the donated
+    caches come back in place (aliased, not copied)."""
+    from music_analyst_tpu.models import block_diffusion, llama
+    from music_analyst_tpu.ops import flash_attention as flash
+
+    monkeypatch.setattr(flash, "interpret_default", lambda: False)
+    config = llama.PRESETS["sdar-30b-a3b-chat"]()
+    rows, width, blocks = 32, 1024, 4
+    top_k, n = config.moe_top_k, config.block_length
+    assert (config.n_layers, config.dim, top_k, n) == (7, 2048, 8, 4)
+    model = llama.LlamaModel(config)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=tpu_sharding), tree)
+
+    params = placed(jax.eval_shape(
+        lambda: llama.init_params_by_layer(config)))
+    ids = placed(jnp.zeros((rows, width), jnp.int32))
+    lens = placed(jnp.zeros((rows,), jnp.int16))
+    prefill = block_diffusion.diffusion_prefill_program(model, config).trace(
+        params, ids, lens, gen_blocks=blocks, prefill_capacity=capacity,
+    ).lower(lowering_platforms=("tpu",)).compile()
+    text = prefill.as_text()
+    assert set(re.findall(
+        r"%ragged-dot-none[.\d]* = (\w+\[\d+,\d+\])", text)) == {
+        f"bf16[{capacity * top_k},{w}]" for w in (768, 2048)}
+    assert len(re.findall(r"%_flash_call[.\d]* = \S+ custom-call\(", text)) == (
+        config.n_layers)
+    assert prefill.memory_analysis().temp_size_in_bytes < 2.4e9
+
+    caches = placed(jax.eval_shape(lambda: [
+        llama.KVCache(c.keys, c.values, jnp.zeros((rows,), jnp.int32))
+        for c in llama.init_caches(config, rows, width + blocks * n)]))
+    denoise = block_diffusion.diffusion_denoise_program(model, config).trace(
+        params, caches, ids, lens, gen_blocks=blocks,
+    ).lower(lowering_platforms=("tpu",)).compile()
+    text = denoise.as_text()
+    assert set(re.findall(
+        r"%ragged-dot-none[.\d]* = (\w+\[\d+,\d+\])", text)) == {
+        f"bf16[{rows * n * top_k},{w}]" for w in (768, 2048)}
+    memory = denoise.memory_analysis()
+    cache_bytes = (2 * config.n_layers * rows * (width + blocks * n)
+                   * config.n_kv_heads * config.attn_head_dim * 2)
+    assert memory.alias_size_in_bytes >= cache_bytes
+    assert memory.temp_size_in_bytes < 1.0e9
 
 
 def test_unservable_geometry_is_refused_by_name():

@@ -15,7 +15,8 @@
 #         one comparison); "solo": every leg has its own.
 # Each leg's result line is printed and kept, with its warm-up job's manifest
 # (`profiling.compiles`: the `hlo_fingerprint` of every compiled program) and
-# a traced leg's reduced trace, under chiprun_out/ab/.
+# a traced leg's reduced trace, and the count of steps a compact rung
+# (`<leg>.rungs.txt`), under chiprun_out/ab/.
 wl=$1; order=$2; seed=$3; mode=${4:-pair}
 root=$PWD
 mkdir -p chiprun_out/ab
@@ -31,9 +32,14 @@ for leg in $order; do
     echo "rc=$?" >> $out.out
     cp perfbench/out/$wl/run/warmup/sentiment/run_manifest.json \
        $out.manifest.json
+    # the compact rungs the leg's steps met (`moe_capacity` on `compute`)
+    cat perfbench/out/$wl/run/job*/*/telemetry.jsonl 2>/dev/null \
+      | grep -o '"moe_capacity": [0-9]*' | sort | uniq -c | tr '\n' ';' \
+      > $out.rungs.txt
     if [ "$tr" = "1" ]; then
       cp perfbench/out/$wl/trace_reduced.json $out.trace_reduced.json
     fi )
-  echo "== $leg seed $s"; tail -n 2 $out.out | cut -c1-2500
+  echo "== $leg seed $s rungs: $(cat $out.rungs.txt)"
+  tail -n 2 $out.out | cut -c1-2500
   i=$((i+1))
 done

@@ -8,7 +8,10 @@ buffer, bfloat16, with the prompt lengths of the benchmark's own corpus
 generator parameters, four batches of 32).  ``blocked`` is
 ``models/mla.blocked_attention`` under the causal-and-padding mask;
 ``kernel`` is ``ops/mla_prefill_attention.py`` at its own block, at the
-prompts' lengths and with every row full (the causal half alone).
+prompts' lengths and with every row full (the causal half alone);
+``packed`` is its packed form on the same rows laid one behind the other
+in ``models/moe.compact_capacity`` slots (PR 32: what the compact prefill
+calls), compared with the padded form on the real positions.
 ``executed_share`` is the part of the ``S x S`` square the kernel's blocks
 cover, ``tflops`` counts those blocks' multiply-adds.
 
@@ -69,14 +72,17 @@ def run() -> dict:
 
     from music_analyst_tpu.models.layers import causal_mask, padding_mask
     from music_analyst_tpu.models.mla import blocked_attention
+    from music_analyst_tpu.models.moe import RealPositions, compact_capacity
     from music_analyst_tpu.ops.mla_prefill_attention import (
         mla_prefill_attention,
+        mla_prefill_attention_packed,
         prefill_block,
     )
 
     if smoke():
-        rows, seq, heads, nope, rope, v_dim, n_batches = 2, 512, 4, 16, 8, 16, 1
-        lens_all = np.asarray([300, 41])
+        rows, seq, heads, nope, rope, v_dim, n_batches = (
+            4, 512, 4, 16, 8, 16, 1)
+        lens_all = np.asarray([300, 41, 256, 1])
     else:
         rows, seq, heads, nope, rope, v_dim, n_batches = (
             32, 1024, 32, 128, 64, 128, 4)
@@ -108,11 +114,26 @@ def run() -> dict:
     def kernel(lens):
         return mla_prefill_attention(*flat, lens, heads, scale)
 
-    def ms(fn, lens_list):
-        """Mean milliseconds a call over ``lens_list``, one readback."""
+    # the packed form's operands: the same rows' real positions, gathered
+    # outside the timed call (in the model the projections write them so)
+    capacity = max(compact_capacity(int(np.asarray(b).sum()), rows * seq)
+                   for b in batches)
+    compact = [RealPositions.of(b, seq, capacity) for b in batches]
+    gathered = [tuple(c.gather(a[:, :seq]) for a in flat) for c in compact]
+
+    @jax.jit
+    def packed(operands, lens):
+        return mla_prefill_attention_packed(*operands, lens, seq, heads,
+                                            scale)
+
+    def ms(fn, lens_list, operands=None):
+        """Mean milliseconds a call over ``lens_list`` (``fn(lens)``, or
+        ``fn(operands[i], lens)``), one readback."""
+        calls = ([(lens,) for lens in lens_list] if operands is None
+                 else list(zip(operands, lens_list)))
+
         def go():
-            out = [fn(lens) for lens in lens_list][-1]
-            return out[0, 0, :8]
+            return [fn(*args) for args in calls][-1].reshape(-1)[:8]
         go()
         return timed(go)[0] / len(lens_list) * 1e3
 
@@ -121,6 +142,10 @@ def run() -> dict:
     lens0 = np.asarray(batches[0])
     error = max(float(np.abs(got[b, :n] - want[b, :n]).max())
                 for b, n in enumerate(lens0))
+    got_packed = np.asarray(compact[0].put_back(
+        packed(gathered[0], batches[0])), np.float32).reshape(want.shape)
+    error_packed = max(float(np.abs(got_packed[b, :n] - got[b, :n]).max())
+                       for b, n in enumerate(lens0))
     flops_a_pair = heads * 2 * (nope + rope + v_dim)
     pairs = float(np.mean([_executed_pairs(b, seq, block) for b in batches]))
     pairs_full = _executed_pairs(full, seq, block)
@@ -135,6 +160,9 @@ def run() -> dict:
         "blocked_ms": round(ms(blocked, batches), 3),
         "kernel_ms": round(kernel_ms, 3),
         "kernel_full_rows_ms": round(full_ms, 3),
+        "packed_ms": round(ms(packed, batches, gathered), 3),
+        "packed_capacity": capacity,
+        "packed_max_abs_error_against_kernel": error_packed,
         "executed_share": round(pairs / (rows * seq * seq), 4),
         "kernel_tflops": round(pairs * flops_a_pair / kernel_ms / 1e9, 2),
         "kernel_full_rows_tflops": round(

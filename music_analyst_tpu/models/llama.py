@@ -360,12 +360,13 @@ class LlamaBlock(nn.Module):
                  lengths: Optional[jax.Array] = None,
                  segment_ids: Optional[jax.Array] = None,
                  prefill_lengths: Optional[jax.Array] = None,
-                 prefill_capacity: Optional[int] = None):
+                 prefill_capacity: Optional[int] = None,
+                 packed=None):
         cfg = self.config
         if cfg.attention == "mla":
             return self._latent_block(x, mask, positions, cache,
                                       prefill_lengths, prefill_capacity,
-                                      segment_ids)
+                                      segment_ids, packed)
         if segment_ids is not None and (
             cache is not None or cfg.attn_impl != "flash"
         ):
@@ -439,7 +440,8 @@ class LlamaBlock(nn.Module):
         x = x + ffn(h)
         return x, new_cache
 
-    def _feed_forward(self, h, prefill_lengths, prefill_capacity):
+    def _feed_forward(self, h, prefill_lengths, prefill_capacity,
+                      packed=None):
         """The feed-forward half of a block whose expert layers are
         ``models/moe.RoutedMoE``, on the normed ``h [B, S, D]``: the dense
         SwiGLU in the leading layers, routed (+ shared) experts in the
@@ -449,13 +451,17 @@ class LlamaBlock(nn.Module):
         under the step's positions runs it on the real positions alone
         (``models/moe.RealPositions``: gathered into that many token slots,
         the result put back at their places); positions at or behind a
-        row's length then receive zeros (the residual alone)."""
+        row's length then receive zeros (the residual alone).  Where the
+        caller's stream is that token set already (``packed``: ``h [1, C,
+        D]``, the latent blocks under ``LlamaModel``'s compact prefill) it
+        is taken and returned as it is."""
         from music_analyst_tpu.models.moe import RealPositions, RoutedMoE
 
         cfg = self.config
         dtype, param_dtype = jnp.dtype(cfg.dtype), jnp.dtype(cfg.param_dtype)
-        compact = None
-        if (prefill_lengths is not None and prefill_capacity is not None
+        compact = packed
+        if (packed is None and prefill_lengths is not None
+                and prefill_capacity is not None
                 and prefill_capacity < h.shape[0] * h.shape[1]):
             compact = RealPositions.of(prefill_lengths, h.shape[1],
                                        prefill_capacity)
@@ -470,18 +476,19 @@ class LlamaBlock(nn.Module):
                 name="feed_forward_moe",
             )
             if compact is not None:  # it puts the experts it chose back too
-                return ffn(h, compact)
+                return ffn(h, compact, packed=packed is not None)
         else:
             ffn = SwiGLU(cfg.hidden_dim, dtype=dtype,
                          param_dtype=param_dtype, name="feed_forward")
-        if compact is not None:
+        if compact is not None and packed is None:
             return compact.put_back(ffn(compact.gather(h)))
         return ffn(h)
 
     def _latent_block(self, x, mask, positions, cache, prefill_lengths,
-                      prefill_capacity, segment_ids):
+                      prefill_capacity, segment_ids, packed=None):
         """Pre-norm block of the ``mla`` kind: latent attention, then
-        :meth:`_feed_forward`."""
+        :meth:`_feed_forward`.  With ``packed`` the stream ``x [1, C, D]``
+        is the real positions' compact set from norm to residual."""
         from music_analyst_tpu.models.mla import MLAttention
 
         cfg = self.config
@@ -500,15 +507,16 @@ class LlamaBlock(nn.Module):
         with jax.named_scope("mla"):
             if cache is not None:
                 attn_out, new_cache = attn(h, mask, positions, cache,
-                                           prefill_lengths)
+                                           prefill_lengths, packed)
             else:
                 attn_out = attn(h, mask, positions,
-                                prefill_lengths=prefill_lengths)
+                                prefill_lengths=prefill_lengths,
+                                packed=packed)
                 new_cache = None
         x = x + attn_out
         h = RMSNorm(epsilon=cfg.rms_norm_eps, name="ffn_norm")(x)
-        return (x + self._feed_forward(h, prefill_lengths, prefill_capacity),
-                new_cache)
+        return (x + self._feed_forward(h, prefill_lengths, prefill_capacity,
+                                       packed), new_cache)
 
 
 class LlamaModel(nn.Module):
@@ -535,14 +543,25 @@ class LlamaModel(nn.Module):
         # causal prefill from position 0 on empty caches, ``mask`` = causal
         # and key padding by these lengths, one device, and nothing reads
         # a position at or behind its row's length.  The attention takes
-        # the kernel that reads the lengths in place of the mask; with a
+        # the kernel that reads the lengths in place of the mask.  With a
         # ``prefill_capacity`` (static, ``models/moe.compact_capacity`` of
-        # these lengths: sum(lengths) <= capacity) under B*S the
-        # feed-forward halves run on the real positions alone, so what
-        # the layers return at or behind a row's length (hidden state,
-        # cache entries, the sown ``chosen``) is neither computed as the
-        # layer would nor defined.  ``lengths`` keeps its one meaning, the
-        # flash path's key padding:
+        # these lengths: sum(lengths) <= capacity) under B*S the step runs
+        # on the real positions alone.  Latent blocks
+        # (:func:`runs_compact`): the hidden state is ``[1, capacity,
+        # dim]`` from the embedding of the real positions' ids to the
+        # position the head reads, each row's real positions one behind
+        # the other (``models/moe.RealPositions``); norms, projections,
+        # RoPE, the prefill kernel's packed form, the feed-forward halves
+        # and the residual adds see nothing else, and ONLY the latent
+        # cache (``latents``, ``k_rope``) and the sown ``chosen`` are put
+        # back at ``[B, S]``, zeros at and behind a row's length.  A
+        # hidden state at a padding position does not exist; logits
+        # without ``last_position`` are the head's of zeros there.
+        # Grouped-query blocks: the feed-forward halves alone gather the
+        # real positions and put their result back.  Either way what the
+        # layers return at or behind a row's length is neither computed
+        # as the layer would nor defined.  ``lengths`` keeps its one
+        # meaning, the flash path's key padding:
         # CONTRACT: with cfg.attn_impl == "flash" (and no caches), the
         # `mask` argument is NOT applied — attention is causal + key-
         # padding-by-`lengths` + optional same-segment (packed documents,
@@ -555,6 +574,15 @@ class LlamaModel(nn.Module):
         cfg = self.config
         dtype = jnp.dtype(cfg.dtype)
         param_dtype = jnp.dtype(cfg.param_dtype)
+        packed = None
+        if prefill_lengths is not None and runs_compact(
+                cfg, token_ids.shape, prefill_capacity):
+            from music_analyst_tpu.models.moe import RealPositions
+
+            packed = RealPositions.of(prefill_lengths, token_ids.shape[1],
+                                      prefill_capacity)
+            token_ids = packed.gather(token_ids)[None]
+            positions = packed.gather(positions)[None]
         x = nn.Embed(cfg.vocab_size, cfg.dim, dtype=dtype,
                      param_dtype=param_dtype,
                      name="tok_embeddings")(token_ids)
@@ -564,7 +592,7 @@ class LlamaModel(nn.Module):
             x, new_cache = LlamaBlock(cfg, i, name=f"layer_{i}")(
                 x, mask, positions, cache_i, lengths,
                 segment_ids=segment_ids, prefill_lengths=prefill_lengths,
-                prefill_capacity=prefill_capacity,
+                prefill_capacity=prefill_capacity, packed=packed,
             )
             if new_cache is not None:
                 new_caches.append(new_cache)
@@ -572,6 +600,13 @@ class LlamaModel(nn.Module):
             # a pass whose logits nobody reads (a diffusion prefill has no
             # next token; a commit pass only writes keys and values)
             return None, (new_caches if caches is not None else None)
+        if packed is not None and last_position is not None:
+            # the one slot a row's head reads, before the last norm
+            x = x[0][packed.start + last_position.astype(jnp.int32),
+                     None]
+            last_position = None
+        elif packed is not None:
+            x = packed.put_back(x[0])
         x = RMSNorm(epsilon=cfg.rms_norm_eps, name="norm")(x)
         if last_position is not None:
             # Gather ONE position per row BEFORE the vocab projection:
@@ -880,6 +915,22 @@ def _prefill_capacity(config: LlamaConfig, mesh, prompt_lens,
 
     return compact_capacity(int(np.asarray(prompt_lens, np.int64).sum()),
                             int(shape[0]) * int(shape[1]))
+
+
+def runs_compact(config: LlamaConfig, shape, capacity) -> bool:
+    """Whether a prefill of ``shape`` (rows, width) that declares its rows'
+    lengths and this ``prefill_capacity`` keeps its hidden state on the
+    compact token set from the embedding to the head (``LlamaModel``):
+    latent blocks, fewer slots than positions, and a width and a slot
+    count the packed prefill kernel takes.  The one place that decides
+    it, for the model and for whoever counts what a step computed."""
+    from music_analyst_tpu.ops.mla_prefill_attention import (
+        packed_prefill_block,
+    )
+
+    return (config.attention == "mla" and capacity is not None
+            and capacity < int(shape[0]) * int(shape[1])
+            and bool(packed_prefill_block(int(shape[1]), capacity)))
 
 
 # The decoder's three programs, built from ``(model, config, …)``: whoever
@@ -1386,7 +1437,9 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         ``decoder.tokens_real`` / ``decoder.tokens_computed`` (positions
         that went through the layers: the prompt's, and each label's
         tokens but its last, whose forward pass nothing reads; computed
-        includes padding), the routed layers' load, the latent cache's
+        includes padding, and of a prefill that ran on the compact token
+        set its ``capacity`` slots in place of ``rows * width``), the
+        routed layers' load, the latent cache's
         size, and the step's shape and real token counts on the span the
         engine has open (``compute``).  ``moe.assignments`` and the load
         count the real positions' assignments where the prefill ran
@@ -1400,8 +1453,11 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         n_labels, label_width = self._label_ids.shape
         label_real = int(np.maximum(self._label_lens - 1, 0).sum())
         tel.count("decoder.tokens_real", tokens_real + rows * label_real)
+        through_layers = (
+            capacity if runs_compact(self.config, (rows, width), capacity)
+            else rows * width)
         tel.count("decoder.tokens_computed",
-                  rows * (width + n_labels * label_width))
+                  through_layers + rows * n_labels * label_width)
         attrs = dict(rows=rows, width=width, tokens_real=tokens_real,
                      token_pairs=token_pairs,
                      label_positions=n_labels * label_width,

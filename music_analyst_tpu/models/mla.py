@@ -50,6 +50,7 @@ from music_analyst_tpu.models.layers import (
 )
 from music_analyst_tpu.ops.mla_prefill_attention import (
     mla_prefill_attention,
+    mla_prefill_attention_packed,
     prefill_block,
 )
 from music_analyst_tpu.profiling.compile import (
@@ -214,7 +215,8 @@ class MLAttention(nn.Module):
     @nn.compact
     def __call__(self, x, mask=None, positions=None,
                  cache: Optional[LatentCache] = None,
-                 prefill_lengths: Optional[jax.Array] = None):
+                 prefill_lengths: Optional[jax.Array] = None,
+                 packed=None):
         """``prefill_lengths [B]`` is a promise only the caller can make
         (``cache.length`` is traced, nothing here can check it): this call
         is a causal prefill from position 0 (query ``i`` is key ``i``, on
@@ -225,16 +227,29 @@ class MLAttention(nn.Module):
         (:func:`prefill_block` decides from the number of queries).  A
         continuation on a filled cache, a chunked prefill, any other mask,
         training (the kernel has no gradient) and a meshed forward withhold
-        it, and ``mask`` is applied as given."""
+        it, and ``mask`` is applied as given.
+
+        ``packed`` (a ``models/moe.RealPositions`` of these lengths, from
+        ``models/llama.LlamaModel`` alone) says ``x [1, C, dim]`` and
+        ``positions [1, C]`` are that compact token set, each row's real
+        positions one behind the other, and not ``[B, S, ...]``: the
+        projections, RoPE, the latent expansion, the kernel (its packed
+        form, which finds a row at its first slot) and ``o_proj`` run on
+        the ``C`` slots and the result is ``[1, C, dim]``.  Only what the
+        cache holds, ``latents`` and ``k_rope``, is put back at ``[B, S]``
+        (zeros at and behind a row's length); a hidden state at a padding
+        position does not exist."""
         dim = x.shape[-1]
         batch, n_q = x.shape[:2]
         heads, nope, rope = (self.n_heads, self.qk_nope_head_dim,
                              self.qk_rope_head_dim)
         rank, v_dim = self.kv_lora_rank, self.v_head_dim
         scale = (nope + rope) ** -0.5
-        absorbed = cache is not None and n_q <= self.absorb_max_queries
-        flash = (not absorbed and prefill_lengths is not None
-                 and bool(prefill_block(n_q)))
+        absorbed = (packed is None and cache is not None
+                    and n_q <= self.absorb_max_queries)
+        flash = packed is not None or (
+            not absorbed and prefill_lengths is not None
+            and bool(prefill_block(n_q)))
 
         x = x.astype(self.dtype)
         w_q = Kernel((dim, heads, nope + rope), dim, self.param_dtype,
@@ -267,7 +282,12 @@ class MLAttention(nn.Module):
         k_rope = rotate(kv_a[..., None, rank:], cos, sin, positions)[:, :, 0]
 
         new_cache = None
-        if cache is not None:
+        if cache is not None and packed is not None:
+            # the kernel reads the compact set; the label passes read the
+            # cache by row
+            new_cache = cache.update(packed.put_back(latents[0]),
+                                     packed.put_back(k_rope[0]))
+        elif cache is not None:
             new_cache = cache.update(latents, k_rope)
             latents, k_rope = new_cache.latents, new_cache.rope_keys
 
@@ -287,12 +307,20 @@ class MLAttention(nn.Module):
             out = jnp.einsum("bqhr,rhd->bqhd", ctx, w_kvb[..., nope:])
         elif flash:
             note_traced_path("mla.expanded")
-            note_attention_path("mla_flash")
             kv = latents @ w_kvb.reshape(rank, heads * (nope + v_dim))
-            out = mla_prefill_attention(
-                q_nope, q_rope.reshape(batch, n_q, heads * rope), kv, k_rope,
-                prefill_lengths, heads, scale,
-            ).reshape(batch, n_q, heads, v_dim)
+            q_rope = q_rope.reshape(batch, n_q, heads * rope)
+            if packed is not None:
+                note_traced_path("mla.compact")
+                note_attention_path("mla_flash_packed")
+                out = mla_prefill_attention_packed(
+                    q_nope[0], q_rope[0], kv[0], k_rope[0], prefill_lengths,
+                    packed.real.shape[1], heads, scale)
+            else:
+                note_attention_path("mla_flash")
+                out = mla_prefill_attention(
+                    q_nope, q_rope, kv, k_rope, prefill_lengths, heads,
+                    scale)
+            out = out.reshape(batch, n_q, heads, v_dim)
         else:
             note_traced_path("mla.expanded")
             note_attention_path("mla_blocked")

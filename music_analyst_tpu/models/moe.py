@@ -259,6 +259,7 @@ class RealPositions(NamedTuple):
 
     source: jax.Array  # [C] int32  flat position ``row * S + pos`` of a slot
     valid: jax.Array   # [C] bool   the slot holds a real position
+    start: jax.Array   # [B] int32  a row's first slot
     slot: jax.Array    # [B, S] int32  a real position's slot (else clamped)
     real: jax.Array    # [B, S] bool   ``pos < lengths[row]``
 
@@ -283,6 +284,7 @@ class RealPositions(NamedTuple):
         real = pos < lengths[:, None]
         return cls(
             source=row * width + (held - starts[row]), valid=valid,
+            start=starts,
             slot=jnp.where(real, starts[:, None] + pos, 0), real=real)
 
     def gather(self, x: jax.Array) -> jax.Array:
@@ -367,6 +369,10 @@ class RoutedMoE(nn.Module):
     to the same experts and summed with the same weights as without it,
     and positions at or behind a row's length are NOT computed; their
     output is zero and their ``chosen`` 0, not what the layer would give.
+    With ``packed`` the caller's ``x [1, C, D]`` IS that token set (the
+    latent blocks' compact stream) and so is the result: nothing is
+    gathered or put back but the sown ``chosen``, and a filler's routed
+    output is zero (the shared experts' alone is its result).
 
     Sows ``expert_load`` (assignments each expert received, ``[E]`` int32;
     with ``compact`` those of real positions alone, fillers uncounted) and
@@ -386,11 +392,13 @@ class RoutedMoE(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array,
-                 compact: Optional[RealPositions] = None) -> jax.Array:
+                 compact: Optional[RealPositions] = None,
+                 packed: bool = False) -> jax.Array:
         from music_analyst_tpu.models.layers import SwiGLU, fan_in_normal
         from music_analyst_tpu.profiling.compile import note_traced_path
 
-        B, S, D = x.shape
+        D = x.shape[-1]
+        B, S = x.shape[:2] if not packed else compact.real.shape
         E, H, k = self.n_experts, self.hidden_dim, self.top_k
         gate_w = self.param("gate_experts", fan_in_normal(D), (E, D, H),
                             self.param_dtype)
@@ -412,8 +420,8 @@ class RoutedMoE(nn.Module):
                     key, shape, dtype),
                 (E,), jnp.float32,
             )
-        if compact is None:
-            xt = x.reshape(B * S, D).astype(self.dtype)
+        if compact is None or packed:
+            xt = x.reshape(-1, D).astype(self.dtype)
         else:
             xt = compact.gather(x).astype(self.dtype)
 
@@ -444,6 +452,13 @@ class RoutedMoE(nn.Module):
             out = grouped_experts(
                 xt, chosen, weights, gate_w.astype(self.dtype),
                 up_w.astype(self.dtype), down_w.astype(self.dtype))
+            if packed:
+                # The fillers stay in the caller's stream, and their rows
+                # of the grouped matmuls belong to no group: undefined.
+                # They have to be finite: the prefill kernel multiplies a
+                # masked key's value by a probability of zero, and the
+                # fillers share the last real slots' block.
+                out = jnp.where(compact.valid[:, None], out, 0.0)
 
         if self.n_shared:
             with jax.named_scope("moe.shared"):
@@ -451,8 +466,8 @@ class RoutedMoE(nn.Module):
                     self.n_shared * H, dtype=self.dtype,
                     param_dtype=self.param_dtype, name="shared_experts",
                 )(xt).astype(jnp.float32)
-        if compact is None:
-            return out.reshape(B, S, D).astype(x.dtype)
+        if compact is None or packed:
+            return out.reshape(x.shape).astype(x.dtype)
         return compact.put_back(out.astype(x.dtype))
 
 

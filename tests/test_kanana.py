@@ -27,6 +27,7 @@ if os.path.join(REPO, "perfbench") not in sys.path:
 
 from reference import deepseek_v3_f32 as ref  # noqa: E402
 
+from music_analyst_tpu.models import llama  # noqa: E402
 from music_analyst_tpu.models.layers import causal_mask  # noqa: E402
 from music_analyst_tpu.models.llama import (  # noqa: E402
     PRESETS,
@@ -581,6 +582,111 @@ def test_compact_prefill_equals_the_full_one_on_every_real_position(
             * clf.config.n_experts).tolist() == [rows * width * 2] * 2
 
 
+# ------------------- the prefill's compact stream, embedding to last norm
+
+# 4 x 512: a width the packed kernel takes, rungs of 256 slots.  A full row,
+# a one-token row, a block and a block + 1 (698 real of 768 slots); rows
+# that fill their rung to the last slot (1,024 of 1,024)
+_WIDE_STEPS = {"ragged": [300, 1, 256, 141],
+               "fills-the-capacity": [512, 255, 1, 256]}
+
+
+@pytest.mark.parametrize("lengths", list(_WIDE_STEPS.values()),
+                         ids=list(_WIDE_STEPS))
+def test_compact_stream_equals_the_padded_prefill_on_every_real_position(
+        clf, lengths):
+    """At a width and a rung the packed prefill kernel takes, a prefill
+    that declares a ``prefill_capacity`` keeps its hidden state on the
+    compact token set from the embedding to the last norm
+    (``llama.runs_compact``).  Against the same call with lengths alone
+    (the padded kernel, every position through every layer): the head's
+    logits at each row's last position, the cached ``latents`` and
+    ``rope_keys`` and the chosen experts on every real position, zeros in
+    the cache behind a row's length; through the scoring program the label
+    scores, and the compile record names what ran; through
+    ``generate_scan_program`` the same tokens."""
+    ids, lens = _ragged_step(clf, lengths, width=512)
+    rows, width = ids.shape
+    capacity = compact_capacity(int(lens.sum()), rows * width)
+    assert capacity == {698: 768, 1024: 1024}[int(lens.sum())]
+    assert llama.runs_compact(clf.config, ids.shape, capacity)
+    positions = jnp.broadcast_to(jnp.arange(width), (rows, width))
+    lens_j = jnp.asarray(lens)
+    kv = jnp.arange(width + 8)[None, None, None, :]
+    mask = causal_mask(width, width + 8, 0) & (
+        kv < lens_j[:, None, None, None])
+
+    def forward(**declared):
+        (logits, caches), sown = clf.model.apply(
+            {"params": clf.params}, jnp.asarray(ids), positions, mask,
+            init_caches(clf.config, rows, width + 8),
+            last_position=lens_j - 1, prefill_lengths=lens_j,
+            mutable=["intermediates"], **declared)
+        chosen = np.stack([
+            np.asarray(sown["intermediates"][f"layer_{i}"]
+                       ["feed_forward_moe"]["chosen"][0]) for i in (1, 2)])
+        return np.asarray(logits), caches, chosen
+
+    full, full_caches, full_chosen = forward()
+    got, caches, chosen = forward(prefill_capacity=capacity)
+    real = np.arange(width)[None, :] < lens[:, None]
+    assert got.shape == full.shape == (rows, 1, clf.config.vocab_size)
+    # a row that starts inside a kernel block sums its tiles in another
+    # order: bfloat16 roundings apart, layer after layer
+    assert np.abs(got - full).max() < 0.05
+    for layer, (a, b) in enumerate(zip(caches, full_caches)):
+        for name in ("latents", "rope_keys"):
+            mine = np.asarray(getattr(a, name), np.float32)[:, :width]
+            apart = np.abs(mine[real] - np.asarray(
+                getattr(b, name), np.float32)[:, :width][real])
+            assert apart.max() < 0.1 and apart.mean() < 0.002, (layer, name)
+            assert not mine[~real].any()
+    # a tie between two experts may fall the other way after a rounding
+    assert (chosen[:, real] == full_chosen[:, real]).mean() > 0.99
+    assert (chosen[:, ~real] == 0).all()
+
+    labels = (jnp.asarray(clf._label_ids), jnp.asarray(clf._label_lens))
+    want, _ = clf._score_labels(clf.params, jnp.asarray(ids), lens_j,
+                                *labels)
+    scores, stats = clf._score_labels(
+        clf.params, jnp.asarray(ids), lens_j, *labels,
+        prefill_capacity=capacity)
+    record = list(clf._score_labels.records.values())[-1]
+    assert np.abs(np.asarray(scores) - np.asarray(want)).max() < 0.03
+    assert record.attention_paths == {"mla_flash_packed": 3}
+    assert record.traced_paths["mla.compact"] == 3
+    assert record.traced_paths["moe.compact"] == 2
+    padded = [r for r in clf._score_labels.records.values()
+              if r.attention_paths == {"mla_flash": 3}]
+    assert padded and all(
+        "mla.compact" not in r.traced_paths for r in padded)
+    assert (np.asarray(stats["expert_load_mean"]) * clf.config.n_experts
+            ).tolist() == [lens.sum() * 2] * 2
+
+    def tokens(**static):
+        return np.asarray(clf._generate_scan(
+            clf.params, jnp.asarray(ids), lens_j, 4, early_exit=False,
+            **static))
+
+    assert (tokens(prefill_capacity=capacity) == tokens()).all()
+
+
+def test_who_keeps_the_padded_stream():
+    """``runs_compact`` is decided by what the call shows: latent blocks,
+    fewer slots than positions, a width and a slot count the packed kernel
+    takes.  Everyone else keeps the ``[B, S, dim]`` stream."""
+    tiny = PRESETS["kanana-tiny"]()
+    assert llama.runs_compact(tiny, (32, 1024), 12288)
+    assert llama.runs_compact(tiny, (4, 512), 768)
+    assert not llama.runs_compact(tiny, (32, 1024), None)        # a mesh
+    assert not llama.runs_compact(tiny, (32, 1024), 32 * 1024)   # full rows
+    assert not llama.runs_compact(tiny, (4, 64), 128)     # blocked width
+    assert not llama.runs_compact(tiny, (13, 512), 832)   # not whole blocks
+    assert not llama.runs_compact(                        # grouped-query
+        PRESETS["sdar-tiny"](), (32, 1024), 12288)
+    assert not llama.runs_compact(LlamaConfig.tiny(), (32, 1024), 12288)
+
+
 def test_a_step_of_full_rows_runs_the_program_without_lengths(clf):
     """The rung of a step whose rows are all full is the step itself, and
     at that capacity the traced program is the uncompacted one, text for
@@ -710,8 +816,13 @@ def test_staged_hooks_equal_classify_batch_and_count_the_step(clf):
     real = int(lens.sum()) + rows * 3
     assert tel.counters["decoder.tokens_real"] - before.get(
         "decoder.tokens_real", 0) == 2 * real
+    # what went through the layers: the compact token set's slots (the
+    # step's width and rung are ones the packed prefill takes), not the
+    # step's rows x width, then every label position
+    capacity = compact_capacity(int(lens.sum()), rows * width)
+    assert llama.runs_compact(clf.config, (rows, width), capacity)
     assert tel.counters["decoder.tokens_computed"] - before.get(
-        "decoder.tokens_computed", 0) == 2 * rows * (width + 3 * 8)
+        "decoder.tokens_computed", 0) == 2 * (capacity + rows * 3 * 8)
     assert span.attrs["rows"] == rows and span.attrs["width"] == width
     assert span.attrs["tokens_real"] == int(lens.sum())
     assert span.attrs["token_pairs"] == int((lens * (lens + 1) // 2).sum())
@@ -724,7 +835,6 @@ def test_staged_hooks_equal_classify_batch_and_count_the_step(clf):
     # rung that holds the real tokens, every REAL position's assignments
     # are counted (top_k a routed layer) and the rows the grouped matmuls
     # ran are the rung's, fillers included
-    capacity = compact_capacity(int(lens.sum()), rows * width)
     assert int(lens.sum()) <= capacity < rows * width
     assert span.attrs["moe_capacity"] == capacity
     grew = {name: tel.counters[name] - before.get(name, 0)
@@ -797,8 +907,8 @@ def test_sharded_equals_unsharded(clf):
     # the call would be opaque to the partitioner (every chip all the
     # heads), so the meshed program keeps the XLA form, which it splits
     assert ids.shape[1] == 512
-    one, meshed = (list(c._score_labels.records.values())[-1]
-                   for c in (clf, sharded))
-    assert one.attention_paths == {"mla_flash": 3}
+    meshed = list(sharded._score_labels.records.values())[-1]
+    assert any(r.attention_paths == {"mla_flash": 3}
+               for r in clf._score_labels.records.values())
     assert meshed.attention_paths == {"mla_blocked": 3}
     assert meshed.traced_paths["mla.expanded"] == 3

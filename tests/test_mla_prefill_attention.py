@@ -37,9 +37,12 @@ from music_analyst_tpu.models.mla import (  # noqa: E402
     MLAttention,
     blocked_attention,
 )
+from music_analyst_tpu.models.moe import RealPositions  # noqa: E402
 from music_analyst_tpu.ops.mla_prefill_attention import (  # noqa: E402
     BLOCK,
     mla_prefill_attention,
+    mla_prefill_attention_packed,
+    packed_prefill_block,
     prefill_block,
 )
 from music_analyst_tpu.profiling.compile import profiled_jit  # noqa: E402
@@ -130,6 +133,97 @@ def test_shapes_outside_the_regime_are_refused_not_served():
         mla_prefill_attention(*short_keys, jnp.full((4,), 9), 4, 0.2)
 
 
+# --------------------------------------------------------- the packed form
+
+# rows laid one behind the other in a token set of ``capacity`` slots
+# (lengths, capacity): a row of one token, exactly a block, a block + 1
+# and the full width, fillers behind them sharing the last row's block;
+# rows that fill the capacity to the last slot; two whole blocks of
+# fillers behind the last row; rows with no token at all among the others
+PACKED = {
+    "one_block_block+1_full": ([1, BLOCK, BLOCK + 1, SEQ], 6 * BLOCK),
+    "fills_the_capacity": ([SEQ, BLOCK - 1, 1, 2 * BLOCK], 6 * BLOCK),
+    "dead_blocks_behind": ([5, 100, BLOCK - 1, 17], 4 * BLOCK),
+    "empty_rows": ([0, BLOCK + 44, 0, 7], 2 * BLOCK),
+}
+
+
+def _packed_and_padded(widths, dtype, lengths, capacity, seed=0):
+    """The packed kernel's output put back at ``[rows, SEQ]`` and the
+    padded kernel's on the same operands, with the packed form's own
+    ``[capacity, H*v]`` result."""
+    heads, nope, rope, _ = WIDTHS[widths]
+    q_nope, q_rope, kv, k_rope = _operands(widths, dtype, seed)
+    rows = q_nope.shape[0]
+    flat = (q_nope.reshape(rows, SEQ, -1), q_rope.reshape(rows, SEQ, -1),
+            kv[:, :SEQ].reshape(rows, SEQ, -1), k_rope[:, :SEQ])
+    scale = (nope + rope) ** -0.5
+    lens = jnp.asarray(lengths, jnp.int32)
+    want = mla_prefill_attention(*flat, lens, heads, scale)
+    compact = RealPositions.of(lens, SEQ, capacity)
+    got = mla_prefill_attention_packed(
+        *(compact.gather(a) for a in flat), lens, SEQ, heads, scale)
+    assert got.shape == (capacity, want.shape[-1])
+    return (np.asarray(compact.put_back(got), np.float32),
+            np.asarray(want, np.float32), np.asarray(got, np.float32))
+
+
+@pytest.mark.parametrize("case", list(PACKED), ids=list(PACKED))
+@pytest.mark.parametrize("widths", list(WIDTHS), ids=list(WIDTHS))
+def test_packed_kernel_equals_the_padded_one_on_real_positions(widths, case):
+    """bfloat16 as served: every real slot reads its own row's slots up to
+    itself and nothing of its neighbours', wherever in a block its row
+    starts (a bfloat16 step apart where the tiles are summed in another
+    order); fillers are finite, and zeros in the blocks behind the last
+    real slot.  That every real slot is right also says no query block
+    wrote over another's slots."""
+    lengths, capacity = PACKED[case]
+    back, want, got = _packed_and_padded(widths, jnp.bfloat16, lengths,
+                                         capacity)
+    assert np.isfinite(got).all()
+    for row, n in enumerate(lengths):
+        np.testing.assert_allclose(back[row, :n], want[row, :n],
+                                   rtol=0, atol=0.02)
+        assert not back[row, n:].any()        # put_back's zeros
+    dead = -(-sum(lengths) // BLOCK) * BLOCK  # first block with no real slot
+    assert not got[dead:].any()
+    if sum(lengths) == capacity:
+        assert dead == capacity               # no filler at all
+
+
+@pytest.mark.parametrize("widths", list(WIDTHS), ids=list(WIDTHS))
+def test_packed_kernel_holds_the_equations_in_float32(widths):
+    """float32 operands: nothing but the order of the sums differs, with
+    rows that start inside a block and end inside another."""
+    lengths, capacity = PACKED["one_block_block+1_full"]
+    back, want, _ = _packed_and_padded(widths, jnp.float32, lengths,
+                                       capacity, seed=1)
+    for row, n in enumerate(lengths):
+        np.testing.assert_allclose(back[row, :n], want[row, :n],
+                                   rtol=0, atol=2e-5)
+
+
+def test_packed_shapes_outside_the_regime_are_refused_not_served():
+    assert packed_prefill_block(1024, 12288) == BLOCK
+    assert packed_prefill_block(2 * BLOCK, 3 * BLOCK) == BLOCK
+    # rows of a width the padded kernel refuses; slots that are not blocks
+    assert [packed_prefill_block(*shape) for shape in (
+        (BLOCK, 4 * BLOCK), (2 * BLOCK + 128, 4 * BLOCK),
+        (2 * BLOCK, 3 * BLOCK + 64), (64, 128))] == [0, 0, 0, 0]
+    q_nope, q_rope, kv, k_rope = _operands("24|16", jnp.float32)
+    flat = (q_nope.reshape(4 * SEQ, -1), q_rope.reshape(4 * SEQ, -1),
+            kv[:, :SEQ].reshape(4 * SEQ, -1), k_rope[:, :SEQ].reshape(
+                4 * SEQ, -1))
+    lens = jnp.full((4,), 9)
+    with pytest.raises(ValueError, match="packed_prefill_block"):
+        mla_prefill_attention_packed(
+            *(a[:3 * BLOCK + 64] for a in flat), lens, SEQ, 4, 0.2)
+    with pytest.raises(ValueError, match="not the queries'"):
+        mla_prefill_attention_packed(
+            flat[0], flat[1], flat[2][:BLOCK], flat[3][:BLOCK], lens, SEQ,
+            4, 0.2)
+
+
 # ------------------------------------------------------------ the choice
 
 def _tiny_attention():
@@ -140,6 +234,53 @@ def _tiny_attention():
         kv_lora_rank=cfg.kv_lora_rank, rope_theta=cfg.rope_theta,
         rope_interleave=cfg.rope_interleave, max_positions=cfg.max_seq_len,
         norm_eps=cfg.rms_norm_eps, dtype=jnp.float32)
+
+
+def test_packed_attention_layer_equals_the_padded_layer_on_real_positions():
+    """``MLAttention`` handed the compact token set (``packed``): its
+    output put back equals the padded call's on every real position, the
+    cache holds the real positions' ``latents`` / ``rope_keys`` and zeros
+    behind them, and the compile record names the packed kernel."""
+    cfg, attention = _tiny_attention()
+    n_queries, rows = 2 * BLOCK, 4
+    x = jax.random.normal(jax.random.key(5), (rows, n_queries, cfg.dim),
+                          jnp.float32)
+    params = attention.init(jax.random.key(3), x[:, :8])
+    lens = jnp.asarray([n_queries, 1, BLOCK + 1, 100], jnp.int32)
+    capacity = 4 * BLOCK
+    buffer = n_queries + PAD
+    positions = jnp.broadcast_to(jnp.arange(n_queries), (rows, n_queries))
+
+    def forward(params, x, lens, packed):
+        cache = LatentCache.zeros(rows, buffer, cfg.kv_lora_rank,
+                                  cfg.qk_rope_head_dim, jnp.float32)
+        if not packed:
+            return attention.apply(params, x, None, positions, cache,
+                                   prefill_lengths=lens)
+        compact = RealPositions.of(lens, n_queries, capacity)
+        out, cache = attention.apply(
+            params, compact.gather(x)[None], None,
+            compact.gather(positions)[None], cache, prefill_lengths=lens,
+            packed=compact)
+        return compact.put_back(out[0]), cache
+
+    program = profiled_jit(forward, name="mla_packed", static_argnums=(3,))
+    got, got_cache = program(params, x, lens, True)
+    (record,) = program.records.values()
+    assert record.attention_paths == {"mla_flash_packed": 1}
+    assert record.traced_paths == {"mla.expanded": 1, "mla.compact": 1}
+    want, want_cache = program(params, x, lens, False)
+    real = np.arange(n_queries)[None, :] < np.asarray(lens)[:, None]
+    np.testing.assert_allclose(np.asarray(got)[real], np.asarray(want)[real],
+                               rtol=0, atol=2e-5)
+    for name in ("latents", "rope_keys"):
+        mine = np.asarray(getattr(got_cache, name))[:, :n_queries]
+        np.testing.assert_allclose(
+            mine[real],
+            np.asarray(getattr(want_cache, name))[:, :n_queries][real],
+            rtol=0, atol=2e-5)
+        assert not mine[~real].any()
+    assert int(got_cache.length) == n_queries
 
 
 @pytest.mark.parametrize("n_queries,lengths,cache,paths,traced", [

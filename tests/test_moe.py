@@ -330,3 +330,39 @@ def test_fillers_belong_to_no_expert_and_cannot_reach_a_real_position():
     got = layer.apply(params, poisoned, moved)
     assert bool(jnp.isfinite(got).all())
     assert (np.asarray(got) == np.asarray(want)).all()
+
+
+@pytest.mark.parametrize("lengths,capacity", [
+    _RAGGED, _EXACT, ([16, 1, 9, 5], 48)],
+    ids=["ragged", "exact", "a-rung-too-many"])
+def test_packed_experts_take_and_return_the_token_set_itself(
+        lengths, capacity):
+    """``packed``: the caller's stream IS the compact token set (the
+    latent blocks under ``LlamaModel``'s compact prefill).  Its result on
+    the real slots is the gathered form's before the put-back, nothing is
+    ``[B, S, dim]`` but the sown ``chosen``, and a filler (whose rows of
+    the grouped matmuls belong to no group and are undefined) comes out
+    with the shared experts' output alone: it stays in the stream, and the
+    prefill kernel needs it finite."""
+    from music_analyst_tpu.models.layers import SwiGLU
+    from music_analyst_tpu.models.moe import RealPositions
+
+    layer = _routed(jnp.float32)
+    rows, n_real = len(lengths), sum(lengths)
+    x = jax.random.normal(jax.random.key(7), (rows, _WIDTH, 12), jnp.float32)
+    params = layer.init(jax.random.key(8), x[:1, :2])
+    index = RealPositions.of(jnp.asarray(lengths), _WIDTH, capacity)
+    want, sown_want = layer.apply(params, x, index, mutable=["intermediates"])
+    stream = index.gather(x)[None]
+    got, sown = layer.apply(params, stream, index, packed=True,
+                            mutable=["intermediates"])
+    assert got.shape == stream.shape == (1, capacity, 12)
+    np.testing.assert_allclose(np.asarray(index.put_back(got[0])),
+                               np.asarray(want), rtol=0, atol=1e-6)
+    for name in ("chosen", "expert_load"):
+        assert (np.asarray(sown["intermediates"][name][0]) == np.asarray(
+            sown_want["intermediates"][name][0])).all()
+    shared = SwiGLU(16, dtype=jnp.float32, name="shared_experts").apply(
+        {"params": params["params"]["shared_experts"]}, stream[0, n_real:])
+    np.testing.assert_allclose(np.asarray(got[0, n_real:]),
+                               np.asarray(shared), rtol=0, atol=1e-6)

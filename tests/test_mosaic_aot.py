@@ -14,8 +14,9 @@ corpus job's two batch shapes (4,096 and the 306-row tail x 12 x 128 x 64),
 at the longest rows its VMEM arithmetic admits, and at ``distilbert-tiny``;
 the latent-attention prefill kernel at the decoder cell's step (32 x 1,024,
 32 heads of 192 | 128, the cache's 1,032-key buffer), at its smallest
-admitted width and at ``kanana-tiny``'s widths; the whole scoring step of
-that cell at the rungs its compact feed-forward meets; flash attention
+admitted width and at ``kanana-tiny``'s widths, and its packed form on the
+compact token sets of those steps; the whole scoring step of that cell at
+the rungs its compact prefill meets; flash attention
 under the block-causal rule at the diffusion cell's prefill (32 x 1,024, 32
 query heads on 4 key heads of 128) and both programs of that cell's step.
 """
@@ -32,6 +33,8 @@ from jax.sharding import SingleDeviceSharding
 from music_analyst_tpu.ops.flash_attention import flash_attention
 from music_analyst_tpu.ops.mla_prefill_attention import (
     mla_prefill_attention,
+    mla_prefill_attention_packed,
+    packed_prefill_block,
     prefill_block,
 )
 from music_analyst_tpu.ops.paged_attention import (
@@ -178,6 +181,37 @@ def test_mla_prefill_attention_compiles_under_mosaic(
     )
 
 
+@pytest.mark.parametrize(
+    "capacity,rows,seq,heads,nope,rope,v_dim",
+    [
+        (12288, 32, 1024, 32, 128, 64, 128),  # the cell's usual rung
+        (16384, 32, 1024, 32, 128, 64, 128),  # and the one above
+        (1024, 4, 512, 32, 128, 64, 128),     # the smallest admitted width
+        (2048, 8, 512, 4, 16, 8, 16),         # kanana-tiny's widths
+    ],
+)
+def test_packed_mla_prefill_attention_compiles_under_mosaic(
+    tpu_sharding, capacity, rows, seq, heads, nope, rope, v_dim
+):
+    """The packed form: ``[capacity, H*D]`` operands, five scalar-prefetched
+    tables, a loop over a block's rows with traced bounds."""
+    assert packed_prefill_block(seq, capacity)
+
+    def fn(q_nope, q_rope, kv, k_rope, lengths):
+        return mla_prefill_attention_packed(
+            q_nope, q_rope, kv, k_rope, lengths, seq, heads,
+            (nope + rope) ** -0.5, interpret=False)
+
+    _compile_for_tpu(
+        fn, tpu_sharding,
+        ((capacity, heads * nope), jnp.bfloat16),
+        ((capacity, heads * rope), jnp.bfloat16),
+        ((capacity, heads * (nope + v_dim)), jnp.bfloat16),
+        ((capacity, rope), jnp.bfloat16),
+        ((rows,), jnp.int32),
+    )
+
+
 def _opcode(program: str, name: str) -> str:
     """Opcode of the instruction ``%name`` in a compiled program's text."""
     (line,) = re.findall(rf"^\s*(?:ROOT )?%{re.escape(name)} = .*$", program,
@@ -239,19 +273,36 @@ def test_projections_feed_the_prefill_kernel_without_a_copy(
     assert readers and set(readers) <= {"bitcast", "fusion"}, readers
 
 
+def _traced(fn):
+    """``{path: layers}`` the traces under ``fn`` noted
+    (``profiling.compile.note_traced_path``)."""
+    from music_analyst_tpu.telemetry import get_telemetry
+
+    tel = get_telemetry()
+    before = dict(tel.counters)
+    fn()
+    return {name[len("traced."):]: count - before.get(name, 0)
+            for name, count in tel.counters.items()
+            if name.startswith("traced.") and count > before.get(name, 0)}
+
+
 @pytest.mark.parametrize("capacity", [12288, 16384])
-def test_compact_scoring_step_keeps_the_kernel_fed_and_no_padded_assignment(
+def test_compact_scoring_step_keeps_the_kernel_fed_and_no_padded_position(
     tpu_sharding, monkeypatch, capacity
 ):
     """``llama_score_labels`` at the decoder cell's step (32 x 1,024, the
     published widths, abstract parameters) compiled for a v5e at the rungs
     the cell's jobs meet (``models/moe.compact_capacity`` of about 10.3k
-    real tokens a step).  The feed-forward halves run on ``capacity``
-    token slots, so nothing of the 196,608 assignments of the padded step
-    (32 x 1,024 x 6) is left, in any array; and the gather and put-back
-    around them do not come between the projections and the prefill
-    kernel, whose large operands are still the projections' own
-    ``[B, S, H*D]`` fusions in every one of the seven layers."""
+    real tokens a step).  The prefill runs on ``capacity`` token slots
+    from the embedding to the last norm: nothing of the 196,608
+    assignments of the padded step (32 x 1,024 x 6) is left, in any
+    array, and no array of the step's 32 x 1,024 positions wider than the
+    latent cache's (``latents`` 512, ``k_rope`` 64).  The packed prefill
+    kernel's large operands are the projections' own ``[capacity, H*D]``
+    fusions in every one of the seven layers, with no copy, slice or
+    transpose between, and its result reaches ``o_proj`` as a bitcast.
+    The trace says which form it took; a program that declares no
+    capacity takes neither compact path."""
     from music_analyst_tpu.models import llama
     from music_analyst_tpu.ops import flash_attention
 
@@ -266,17 +317,35 @@ def test_compact_scoring_step_keeps_the_kernel_fed_and_no_padded_assignment(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                            sharding=tpu_sharding), tree)
 
-    compiled = program.trace(
-        placed(jax.eval_shape(lambda: llama.init_params_by_layer(config))),
-        placed(jnp.zeros((rows, width), jnp.int32)),
-        placed(jnp.zeros((rows,), jnp.int16)),
-        placed(jnp.zeros((3, 8), jnp.int32)),
-        placed(jnp.zeros((3,), jnp.int32)),
-        prefill_capacity=capacity,
-    ).lower(lowering_platforms=("tpu",)).compile()
+    params = placed(jax.eval_shape(
+        lambda: llama.init_params_by_layer(config)))
+
+    def trace(**static):
+        return program.trace(
+            params, placed(jnp.zeros((rows, width), jnp.int32)),
+            placed(jnp.zeros((rows,), jnp.int16)),
+            placed(jnp.zeros((3, 8), jnp.int32)),
+            placed(jnp.zeros((3,), jnp.int32)), **static)
+
+    traced = []
+    paths = _traced(lambda: traced.append(trace(prefill_capacity=capacity)))
+    assert (paths["mla.compact"], paths["moe.compact"]) == (7, 6)
+    if capacity == 12288:
+        padded = _traced(trace)       # lengths, no capacity
+        assert padded["mla.expanded"] == 7
+        assert not {"mla.compact", "moe.compact"} & set(padded)
+    compiled = traced[0].lower(lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
 
-    assert str(rows * width * top_k) not in text
+    # as an array's dimension (a reshape's ``integer_config`` may hold the
+    # number for its own reasons: 12,288 x 16 is the same)
+    assert not re.search(rf"[\[,]{rows * width * top_k}[,\]]", text)
+    # the widest array over the step's positions is the cache's latents
+    # (the chosen experts and the mask of real positions are narrower
+    # still); the residual stream alone is 2,048 wide
+    by_position = re.findall(
+        rf"\[{rows},10\d\d,(\d+)\]|\w\[{rows * width},(\d+)\]", text)
+    assert max(int(a or b) for a, b in by_position) == 512
     shapes = set(re.findall(
         r"%ragged-dot-none[.\d]* = (\w+\[\d+,\d+\])", text))
     # the prefill's grouped matmuls at capacity * top_k rows, the label
@@ -284,19 +353,25 @@ def test_compact_scoring_step_keeps_the_kernel_fed_and_no_padded_assignment(
     assert shapes == {
         f"bf16[{capacity * top_k},{n}]" for n in (768, 2048)} | {
         f"bf16[{3 * rows * 8 * top_k},{n}]" for n in (768, 2048)}, shapes
-    # 1.46 and 1.81 GB of temporaries where the padded step has 3.4
+    # 1.45 and 1.8 GB of temporaries where the padded step has 3.4
     assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
 
     calls = re.findall(
-        r"^\s*%(_prefill_call[.\d]*) = \S+ custom-call\(([^)]*)\)", text,
-        re.MULTILINE)
+        r"^\s*%(_packed_prefill_call[.\d]*) = \S+ custom-call\(([^)]*)\)",
+        text, re.MULTILINE)
     assert len(calls) == config.n_layers
-    for _, operands in calls:
-        lengths, q_nope, q_rope, kv, k_rope = re.findall(r"%([\w.-]+)",
+    assert "%_prefill_call" not in text
+    for name, operands in calls:
+        *tables, q_nope, q_rope, kv, k_rope = re.findall(r"%([\w.-]+)",
                                                          operands)
+        assert len(tables) == 5
         assert _opcode(text, q_nope) == "fusion"
         assert _opcode(text, kv) == "fusion"
         assert _opcode(text, q_rope) in ("copy", "fusion")
+        readers = re.findall(
+            rf"^\s*(?:ROOT )?%[\w.-]+ = \S+ ([a-z-]+)\([^)]*"
+            rf"%{re.escape(name)}[,)]", text, re.MULTILINE)
+        assert readers and set(readers) <= {"bitcast", "fusion"}, readers
 
 
 @pytest.mark.parametrize("rows,width,heads,kv_heads,head_dim", [

@@ -26,6 +26,7 @@ _SUITE_MODULES = (
     "benchmarks.roofline",
     "benchmarks.flash_sweep",
     "benchmarks.mla_prefill",
+    "benchmarks.kda_prefill",
     "benchmarks.generation",
     "benchmarks.coldstart",
     "benchmarks.ingest",

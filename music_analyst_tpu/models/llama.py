@@ -136,6 +136,26 @@ class LlamaConfig:
     denoising_steps: int = 0
     confidence_threshold: float = 1.0
     mask_token_id: int = -1
+    # Two kinds of mixer in one model: with ``mixer_period`` p > 0 layer
+    # ``i`` runs ``attention`` where ``(i + 1) % p == 0`` and Kimi Delta
+    # Attention (models/kda.py: a recurrent state a row, not a cache)
+    # everywhere else; 0 = ``attention`` in every layer.
+    mixer_period: int = 0
+    # The published indices of the layers kept, where a cut keeps others
+    # than the first ``n_layers`` (a leading dense layer and a whole period
+    # from further on): the period is counted on these.  ``None`` = 0, 1, ..
+    layer_ids: Optional[tuple] = None
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 4
+    kda_lower_bound: float = -5.0
+    # One sigmoid gate a head on latent attention's output (models/mla.py).
+    mla_output_gate: bool = False
+    # Group-limited expert choice of the sigmoid router (models/moe.py).
+    n_group: int = 1
+    topk_group: int = 1
+    # ``(first, count)``: the experts of each routed layer THIS chip holds
+    # of the layer's ``n_experts`` (the router's width); ``None`` = all.
+    experts_held: Optional[tuple] = None
 
     def __post_init__(self):
         if self.attention not in ("gqa", "mla"):
@@ -148,6 +168,17 @@ class LlamaConfig:
             raise ValueError(
                 "latent attention's expert layers are RoutedMoE: moe_router "
                 "must be sigmoid_noaux or softmax_topk")
+        if self.mixer_period and (self.attention != "mla"
+                                  or self.kda_head_dim < 1):
+            raise ValueError(
+                "mixer_period puts KDA layers between latent-attention "
+                "ones: attention must be mla and kda_head_dim set")
+        for name in ("experts_held", "layer_ids"):  # lists in a preset file
+            if getattr(self, name) is not None:
+                object.__setattr__(
+                    self, name, tuple(int(n) for n in getattr(self, name)))
+        if self.layer_ids is not None and len(self.layer_ids) != self.n_layers:
+            raise ValueError("layer_ids does not name n_layers layers")
         if self.generation not in ("autoregressive", "block_diffusion"):
             raise ValueError(f"unknown generation kind {self.generation!r}")
         if self.block_diffusion and (
@@ -183,6 +214,28 @@ class LlamaConfig:
     def block_diffusion(self) -> bool:
         return self.generation == "block_diffusion"
 
+    def mixer(self, index: int) -> str:
+        """Layer ``index``'s mixer: ``"kda"``, or the ``attention`` kind."""
+        if self.layer_ids is not None:
+            index = self.layer_ids[index]
+        if self.mixer_period and (index + 1) % self.mixer_period:
+            return "kda"
+        return self.attention
+
+    @property
+    def kda_layers(self) -> int:
+        return sum(self.mixer(i) == "kda" for i in range(self.n_layers))
+
+    @property
+    def recurrent_state(self) -> bool:
+        """Whether some layer carries ``models/kda.RecurrentState``."""
+        return self.kda_layers > 0
+
+    @property
+    def experts_held_count(self) -> int:
+        return (self.experts_held[1] if self.experts_held is not None
+                else self.n_experts)
+
     @property
     def attn_head_dim(self) -> int:
         return self.head_dim or self.dim // self.n_heads
@@ -207,15 +260,16 @@ class LlamaConfig:
     @classmethod
     def from_hf_config(cls, hf: dict, **overrides) -> "LlamaConfig":
         """The decoder a published ``config.json`` of ``model_type:
-        deepseek_v3`` or ``sdar_moe`` describes, key by key.  What this
-        code cannot run is refused by name, not approximated."""
+        deepseek_v3``, ``sdar_moe`` or ``ling_hybrid`` describes, key by
+        key.  What this code cannot run is refused by name, not
+        approximated."""
         if hf.get("model_type") == "sdar_moe":
             return cls._from_sdar_moe(hf, overrides)
+        if hf.get("model_type") == "ling_hybrid":
+            return cls._from_ling_hybrid(hf, overrides)
         unsupported = {
             "model_type": hf.get("model_type") != "deepseek_v3",
             "q_lora_rank": hf.get("q_lora_rank") is not None,
-            "n_group": hf.get("n_group", 1) != 1,
-            "topk_group": hf.get("topk_group", 1) != 1,
             "scoring_func": hf.get("scoring_func") != "sigmoid",
             "topk_method": hf.get("topk_method") != "noaux_tc",
             "rope_scaling": hf.get("rope_scaling") is not None,
@@ -253,6 +307,94 @@ class LlamaConfig:
             routed_scaling_factor=float(hf["routed_scaling_factor"]),
             norm_topk_prob=bool(hf["norm_topk_prob"]),
             first_k_dense_replace=hf["first_k_dense_replace"],
+            n_group=hf.get("n_group", 1), topk_group=hf.get("topk_group", 1),
+        )
+        fields.update(overrides)
+        return cls(**fields)
+
+    @classmethod
+    def _from_ling_hybrid(cls, hf: dict, overrides: dict) -> "LlamaConfig":
+        """``model_type: ling_hybrid`` (the name is the preset's own: the
+        catalog's row of Ling-3.0-flash-VL's language model has none): a
+        period of ``layer_group_size`` layers is KDA mixers then one latent
+        attention with a head-wise output gate; leading dense layers, then
+        ``num_experts`` sigmoid-routed experts chosen inside the best
+        ``topk_group`` of ``n_group`` groups, one shared expert.  How many
+        of the experts this chip holds is not the source's to say: the
+        caller's ``overrides`` (``experts_held``) state it."""
+        layers = hf["num_hidden_layers"]
+        kept = overrides.get("layer_ids") or range(layers)
+
+        def nonzero(key):
+            limits = hf.get(key, ())
+            return any(limits[i] for i in kept if i < len(limits))
+
+        heads = hf["num_attention_heads"]
+        unsupported = {
+            "expert_swiglu_limit_list": nonzero("expert_swiglu_limit_list"),
+            "share_expert_swiglu_limit_list": nonzero(
+                "share_expert_swiglu_limit_list"),
+            "use_nGPT": bool(hf.get("use_nGPT", False)),
+            "value_norm": bool(hf.get("value_norm", False)),
+            "up_proj_norm": bool(hf.get("up_proj_norm", False)),
+            "scale_router_input": bool(hf.get("scale_router_input", False)),
+            "use_kda_lora": bool(hf.get("use_kda_lora", False)),
+            "no_kda_lora": not hf.get("no_kda_lora", True),
+            "mtp_use_kda": bool(hf.get("mtp_use_kda", False)),
+            "use_mla_nope": bool(hf.get("use_mla_nope", False)),
+            "q_lora_rank": hf.get("q_lora_rank") is not None,
+            "score_function": hf.get("score_function") != "sigmoid",
+            "moe_router_enable_expert_bias": not hf.get(
+                "moe_router_enable_expert_bias", False),
+            "gated_attention_proj_granularity_type": hf.get(
+                "gated_attention_proj_granularity_type") != "head_wise",
+            "linear_silu": not hf.get("linear_silu", False),
+            "kda_safe_gate": not hf.get("kda_safe_gate", False),
+            "group_norm_size": hf.get("group_norm_size", 1) != 1,
+            "num_kv_heads_for_linear_attn": hf.get(
+                "num_kv_heads_for_linear_attn", 0) not in (0, heads),
+            "use_qk_norm": not hf.get("use_qk_norm", False),
+            "rotary_dim": hf.get("rotary_dim") != hf["qk_rope_head_dim"],
+            "rope_scaling": hf.get("rope_scaling") is not None,
+            "tie_word_embeddings": bool(hf.get("tie_word_embeddings", False)),
+        }
+        bad = sorted(k for k, v in unsupported.items() if v)
+        if bad:
+            raise ValueError(
+                "this decoder does not implement the configuration's "
+                + ", ".join(f"{k}={hf.get(k)!r}" for k in bad)
+            )
+        width = hf["moe_intermediate_size"]
+        shared = hf["moe_shared_expert_intermediate_size"]
+        if shared % width:
+            raise ValueError(
+                "moe_shared_expert_intermediate_size is not whole experts "
+                "of moe_intermediate_size")
+        fields = dict(
+            vocab_size=hf["vocab_size"], dim=hf["hidden_size"],
+            n_layers=layers, n_heads=heads,
+            n_kv_heads=hf["num_key_value_heads"],
+            hidden_dim=hf["intermediate_size"],
+            rope_theta=float(hf["rope_theta"]),
+            max_seq_len=hf["max_position_embeddings"],
+            attention="mla", kv_lora_rank=hf["kv_lora_rank"],
+            qk_nope_head_dim=hf["qk_nope_head_dim"],
+            qk_rope_head_dim=hf["qk_rope_head_dim"],
+            v_head_dim=hf["v_head_dim"],
+            # the pairing of the existing latent path (``assumed``)
+            rope_interleave=True, mla_output_gate=True,
+            rms_norm_eps=float(hf["rms_norm_eps"]),
+            mixer_period=hf["layer_group_size"],
+            kda_head_dim=hf["head_dim"],
+            kda_conv_kernel=hf["short_conv_kernel_size"],
+            kda_lower_bound=float(hf["kda_lower_bound"]),
+            moe_router="sigmoid_noaux", n_experts=hf["num_experts"],
+            moe_top_k=hf["num_experts_per_tok"], moe_hidden_dim=width,
+            n_shared_experts=shared // width,
+            routed_scaling_factor=float(hf["routed_scaling_factor"]),
+            norm_topk_prob=bool(hf["norm_topk_prob"]),
+            first_k_dense_replace=hf["first_k_dense_replace"],
+            n_group=hf["n_group"], topk_group=hf["topk_group"],
         )
         fields.update(overrides)
         return cls(**fields)
@@ -349,6 +491,14 @@ LATENT_CACHE_REFUSAL = (
 )
 
 
+RECURRENT_STATE_REFUSAL = (
+    "most of this model's layers carry a recurrent state a row "
+    "(models/kda.RecurrentState: a float32 matrix a head and the "
+    "convolutions' last inputs), not keys and values a token; the {runtime} "
+    "runtime has slots or pages of per-head keys and values only"
+)
+
+
 class LlamaBlock(nn.Module):
     config: LlamaConfig
     # Only a configuration whose layers differ in kind reads it
@@ -361,12 +511,12 @@ class LlamaBlock(nn.Module):
                  segment_ids: Optional[jax.Array] = None,
                  prefill_lengths: Optional[jax.Array] = None,
                  prefill_capacity: Optional[int] = None,
-                 packed=None):
+                 packed=None, row_lengths: Optional[jax.Array] = None):
         cfg = self.config
         if cfg.attention == "mla":
             return self._latent_block(x, mask, positions, cache,
                                       prefill_lengths, prefill_capacity,
-                                      segment_ids, packed)
+                                      segment_ids, packed, row_lengths)
         if segment_ids is not None and (
             cache is not None or cfg.attn_impl != "flash"
         ):
@@ -473,6 +623,8 @@ class LlamaBlock(nn.Module):
                 norm_topk_prob=cfg.norm_topk_prob, dtype=dtype,
                 param_dtype=param_dtype,
                 router=cfg.moe_router,
+                n_group=cfg.n_group, topk_group=cfg.topk_group,
+                experts_held=cfg.experts_held,
                 name="feed_forward_moe",
             )
             if compact is not None:  # it puts the experts it chose back too
@@ -485,23 +637,44 @@ class LlamaBlock(nn.Module):
         return ffn(h)
 
     def _latent_block(self, x, mask, positions, cache, prefill_lengths,
-                      prefill_capacity, segment_ids, packed=None):
-        """Pre-norm block of the ``mla`` kind: latent attention, then
-        :meth:`_feed_forward`.  With ``packed`` the stream ``x [1, C, D]``
-        is the real positions' compact set from norm to residual."""
+                      prefill_capacity, segment_ids, packed=None,
+                      row_lengths=None):
+        """Pre-norm block of the ``mla`` kind: latent attention (or, in
+        the layers ``LlamaConfig.mixer`` gives it, Kimi Delta Attention on a
+        recurrent state in the cache's place), then :meth:`_feed_forward`.
+        With ``packed`` the stream ``x [1, C, D]`` is the real positions'
+        compact set from norm to residual."""
         from music_analyst_tpu.models.mla import MLAttention
 
         cfg = self.config
         if segment_ids is not None:
             raise ValueError("latent attention takes no segment_ids")
         dtype, param_dtype = jnp.dtype(cfg.dtype), jnp.dtype(cfg.param_dtype)
+        if cfg.mixer(self.layer_index) == "kda":
+            from music_analyst_tpu.models.kda import KimiDeltaAttention
+
+            mixer = KimiDeltaAttention(
+                n_heads=cfg.n_heads, head_dim=cfg.kda_head_dim,
+                conv_kernel=cfg.kda_conv_kernel,
+                lower_bound=cfg.kda_lower_bound, norm_eps=cfg.rms_norm_eps,
+                dtype=dtype, param_dtype=param_dtype, name="attention",
+            )
+            h = RMSNorm(epsilon=cfg.rms_norm_eps, name="attention_norm")(x)
+            mixed = mixer(h, positions, cache, prefill_lengths, row_lengths,
+                          packed)
+            mixed, new_cache = mixed if cache is not None else (mixed, None)
+            x = x + mixed
+            h = RMSNorm(epsilon=cfg.rms_norm_eps, name="ffn_norm")(x)
+            return (x + self._feed_forward(
+                h, prefill_lengths, prefill_capacity, packed), new_cache)
         attn = MLAttention(
             n_heads=cfg.n_heads, qk_nope_head_dim=cfg.qk_nope_head_dim,
             qk_rope_head_dim=cfg.qk_rope_head_dim,
             v_head_dim=cfg.v_head_dim, kv_lora_rank=cfg.kv_lora_rank,
             rope_theta=cfg.rope_theta, rope_interleave=cfg.rope_interleave,
             max_positions=cfg.max_seq_len, norm_eps=cfg.rms_norm_eps,
-            dtype=dtype, param_dtype=param_dtype, name="attention",
+            dtype=dtype, param_dtype=param_dtype,
+            output_gate=cfg.mla_output_gate, name="attention",
         )
         h = RMSNorm(epsilon=cfg.rms_norm_eps, name="attention_norm")(x)
         with jax.named_scope("mla"):
@@ -535,6 +708,7 @@ class LlamaModel(nn.Module):
         prefill_lengths: Optional[jax.Array] = None,  # [B] — see below
         prefill_capacity: Optional[int] = None,    # static — see below
         with_head: bool = True,  # False: no logits (``None`` in their place)
+        row_lengths: Optional[jax.Array] = None,   # [B] — see below
     ):
         # ``prefill_lengths`` is read by the blocks whose expert layers are
         # ``RoutedMoE`` (the latent blocks hand it to their attention too,
@@ -560,7 +734,11 @@ class LlamaModel(nn.Module):
         # Grouped-query blocks: the feed-forward halves alone gather the
         # real positions and put their result back.  Either way what the
         # layers return at or behind a row's length is neither computed
-        # as the layer would nor defined.  ``lengths`` keeps its one
+        # as the layer would nor defined.  ``row_lengths`` is read by the
+        # layers that carry a recurrent state (``models/kda.py``) where
+        # ``prefill_lengths`` is withheld: how many of this call's tokens
+        # exist a row (a mask cannot tell a state when to stop), a fact
+        # that promises nothing.  ``lengths`` keeps its one
         # meaning, the flash path's key padding:
         # CONTRACT: with cfg.attn_impl == "flash" (and no caches), the
         # `mask` argument is NOT applied — attention is causal + key-
@@ -593,6 +771,7 @@ class LlamaModel(nn.Module):
                 x, mask, positions, cache_i, lengths,
                 segment_ids=segment_ids, prefill_lengths=prefill_lengths,
                 prefill_capacity=prefill_capacity, packed=packed,
+                row_lengths=row_lengths,
             )
             if new_cache is not None:
                 new_caches.append(new_cache)
@@ -635,14 +814,22 @@ class LlamaModel(nn.Module):
 def init_caches(
     cfg: LlamaConfig, batch: int, max_len: int, dtype=jnp.bfloat16
 ) -> List[KVCache]:
-    """One empty cache a layer, of the layer's attention kind."""
+    """One empty cache a layer, of the layer's mixer kind: a ``KVCache``
+    (grouped-query attention), a ``LatentCache`` (latent attention) or,
+    for a KDA layer, a ``RecurrentState``, which does not grow with
+    ``max_len``.  The slot and paged decode runtimes hold the first kind
+    alone (``LlamaZeroShotClassifier.decode_runtime_refusal``)."""
     if cfg.latent_cache:
+        from music_analyst_tpu.models.kda import RecurrentState
         from music_analyst_tpu.models.mla import LatentCache
 
         return [
+            RecurrentState.zeros(batch, cfg.n_heads, cfg.kda_head_dim,
+                                 cfg.kda_conv_kernel, dtype)
+            if cfg.mixer(i) == "kda" else
             LatentCache.zeros(batch, max_len, cfg.kv_lora_rank,
                               cfg.qk_rope_head_dim, dtype)
-            for _ in range(cfg.n_layers)
+            for i in range(cfg.n_layers)
         ]
     return [
         KVCache.zeros(batch, max_len, cfg.n_kv_heads, cfg.attn_head_dim,
@@ -846,11 +1033,15 @@ def _routing_stats(sown, config: "LlamaConfig") -> dict:
     loads = _sown_by_layer(sown, "expert_load")
     if not loads:
         return {}
-    load = jnp.stack(loads).astype(jnp.float32)  # [layers, E]
-    return {"expert_load_max": load.max(axis=-1),
-            "expert_load_mean": load.mean(axis=-1),
-            "chosen": _expert_ids(jnp.stack(_sown_by_layer(sown, "chosen")),
-                                  config.n_experts)}
+    load = jnp.stack(loads).astype(jnp.float32)  # [layers, E held]
+    stats = {"expert_load_max": load.max(axis=-1),
+             "expert_load_mean": load.mean(axis=-1),
+             "chosen": _expert_ids(jnp.stack(_sown_by_layer(sown, "chosen")),
+                                   config.n_experts)}
+    if config.experts_held is not None:
+        # the real positions' assignments, held here or not
+        stats["assignments"] = jnp.stack(_sown_by_layer(sown, "assigned"))
+    return stats
 
 
 def init_params_by_layer(cfg: LlamaConfig, seed: int = 0):
@@ -868,14 +1059,14 @@ def init_params_by_layer(cfg: LlamaConfig, seed: int = 0):
     def program(module, *args):
         return jax.jit(lambda key: module.init(key, *args)["params"])
 
-    inits = {}  # routed? -> jitted init of that layer kind
+    inits = {}  # (routed?, mixer) -> jitted init of that layer kind
     params = {}
     embed = nn.Embed(cfg.vocab_size, cfg.dim, dtype=dtype,
                      param_dtype=jnp.dtype(cfg.param_dtype))
     params["tok_embeddings"] = program(embed, positions)(
         jax.random.fold_in(root, 0))
     for i in range(cfg.n_layers):
-        kind = cfg.routed_layer(i)
+        kind = (cfg.routed_layer(i), cfg.mixer(i))
         if kind not in inits:
             inits[kind] = program(LlamaBlock(cfg, i), x, mask, positions, None)
         params[f"layer_{i}"] = inits[kind](jax.random.fold_in(root, 1 + i))
@@ -921,8 +1112,10 @@ def runs_compact(config: LlamaConfig, shape, capacity) -> bool:
     """Whether a prefill of ``shape`` (rows, width) that declares its rows'
     lengths and this ``prefill_capacity`` keeps its hidden state on the
     compact token set from the embedding to the head (``LlamaModel``):
-    latent blocks, fewer slots than positions, and a width and a slot
-    count the packed prefill kernel takes.  The one place that decides
+    latent blocks (KDA layers among them or not: both kernels find a row
+    at its own slot), fewer slots than positions, and a width and a slot
+    count the packed prefill kernel takes (whole 256-slot blocks, which
+    are whole chunks of the KDA kernel too).  The one place that decides
     it, for the model and for whoever counts what a step computed."""
     from music_analyst_tpu.ops.mla_prefill_attention import (
         packed_prefill_block,
@@ -945,7 +1138,7 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
     name ``llama_score_labels``)."""
 
     def _score_labels(params, prompt_ids, prompt_lens, label_ids,
-                      label_lens, prefill_capacity=None):
+                      label_lens, prefill_capacity=None, probe_rows=None):
         """Log-likelihood of each label continuation per batch row.
 
         prompt_ids [B, S]; label_ids [3, L]; ``prefill_capacity`` (static)
@@ -955,7 +1148,12 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
         ``stats`` holds the small device-side reductions that ride back
         with the scores (``expert_load_max`` / ``expert_load_mean``
         ``[routed layers]`` of the prefill, for a model with routed
-        experts; else empty).
+        experts; else empty).  ``probe_rows [P]`` (a model with recurrent
+        state alone): the rows whose state after the prefill rides back
+        too (``stats["probe"]``: every KDA layer's ``state [layers, P, H,
+        dk, dv]``, every latent layer's ``latents`` and ``rope_keys``
+        ``[layers, P, S, .]``), for whoever compares them with a
+        reference; the values choose rows, not a program.
         """
         B, S = prompt_ids.shape
         n_labels, L = label_ids.shape
@@ -976,10 +1174,12 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
             {"params": params}, prompt_ids, positions, mask, caches,
             last_position=prompt_lens - 1,
             prefill_lengths=_prefill_lengths(mesh, prompt_lens),
-            prefill_capacity=prefill_capacity,
+            prefill_capacity=prefill_capacity, row_lengths=prompt_lens,
             mutable=["intermediates"],
         )
         stats = _routing_stats(sown, config)
+        if probe_rows is not None:
+            stats["probe"] = _probe(config, caches, probe_rows, S)
         # Force every cache to report the true prompt length so label
         # positions line up even though the buffer was written at 0..S.
         caches = [c.with_length(S) for c in caches]
@@ -1024,15 +1224,46 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
                 if chosen2 else None,
             )
 
-        scores, label_chosen = jax.vmap(
-            score_one, in_axes=(0, 0), out_axes=(1, 0)
-        )(label_ids, label_lens)
+        if config.recurrent_state:
+            # One label after the other: each continuation advances its own
+            # copy of every KDA layer's state, and under ``vmap`` the copies
+            # of all labels and layers are made up front (2.4 GB at 64 rows
+            # x 6 layers x 3 labels), beside the prefill's temporaries.
+            by_label, label_chosen = jax.lax.map(
+                lambda label: score_one(*label), (label_ids, label_lens))
+            scores = by_label.T
+        else:
+            scores, label_chosen = jax.vmap(
+                score_one, in_axes=(0, 0), out_axes=(1, 0)
+            )(label_ids, label_lens)
         if label_chosen is not None:
             stats["chosen_labels"] = label_chosen  # [3, layers, B, L, k]
+        if label_chosen is not None and config.experts_held is not None:
+            # of the label positions whose forward is read, the
+            # assignments to experts held here
+            first, count = config.experts_held
+            read = (jnp.arange(L)[None, :] < label_lens[:, None] - 1)
+            here = (label_chosen >= first) & (label_chosen < first + count)
+            stats["label_assignments_held"] = jnp.sum(
+                here & read[:, None, None, :, None])
         return scores, stats  # [B, 3]
 
     return profiled_jit(_score_labels, name="llama_score_labels",
                         static_argnames=("prefill_capacity",))
+
+
+def _probe(config: LlamaConfig, caches, rows, width: int) -> dict:
+    """What ``rows`` of a prefill's caches hold, by mixer kind."""
+    states = [c.state[rows] for i, c in enumerate(caches)
+              if config.mixer(i) == "kda"]
+    latent = [c for i, c in enumerate(caches) if config.mixer(i) != "kda"]
+    kept = {"state": jnp.stack(states)}
+    if latent:
+        kept["latents"] = jnp.stack(
+            [c.latents[rows, :width] for c in latent])
+        kept["rope_keys"] = jnp.stack(
+            [c.rope_keys[rows, :width] for c in latent])
+    return kept
 
 
 def decode_step_program(model: LlamaModel):
@@ -1041,8 +1272,8 @@ def decode_step_program(model: LlamaModel):
 
     @jax.jit
     def _decode_step(params, token, position, caches):
-        B = token.shape[0]
-        kv_len = caches[0].max_len
+        # a recurrent state has no length: the first cache that has one
+        kv_len = next(c.max_len for c in caches if hasattr(c, "max_len"))
         kv_pos = jnp.arange(kv_len)[None, None, None, :]
         mask = kv_pos <= position[:, None, None, None]
         logits, caches = model.apply(
@@ -1089,7 +1320,7 @@ def generate_scan_program(model: LlamaModel, config: LlamaConfig,
             {"params": params}, prompt_ids, positions, mask, caches,
             last_position=prompt_lens - 1,
             prefill_lengths=_prefill_lengths(mesh, prompt_lens),
-            prefill_capacity=prefill_capacity,
+            prefill_capacity=prefill_capacity, row_lengths=prompt_lens,
         )
         caches = [c.with_length(S) for c in caches]
         first = jnp.argmax(logits[:, 0], axis=-1)  # [B]
@@ -1308,6 +1539,11 @@ class LlamaZeroShotClassifier(ClassifierBackend):
 
             self.params = shard_params(self.params, mesh)
         self._label_ids, self._label_lens = _label_table(self.tokenizer)
+        # The rows of a step whose recurrent state rides back with the
+        # scores (``_score_labels``' ``probe_rows``: a model with KDA
+        # layers alone); whoever compares states with a reference sets the
+        # rows it sampled.  Values, not a program.
+        self.probe_rows = np.arange(8, dtype=np.int32)
         self._score_labels = score_labels_program(
             self.model, self.config, mesh)
         self._decode_step = decode_step_program(self.model)
@@ -1318,7 +1554,11 @@ class LlamaZeroShotClassifier(ClassifierBackend):
     def decode_runtime_refusal(self) -> Optional[str]:
         """Why the continuous decode runtimes (``serving/
         decode_runtime.py``) cannot host this model, or ``None`` where
-        they can.  ``serve`` reads it to leave the ``generate`` op off."""
+        they can: a latent cache, a step that yields a block
+        (``models/block_diffusion.py``'s own), or a recurrent state beside
+        the cache.  ``serve`` reads it to leave the ``generate`` op off."""
+        if self.config.recurrent_state:
+            return RECURRENT_STATE_REFUSAL
         return LATENT_CACHE_REFUSAL if self.config.latent_cache else None
 
     @classmethod
@@ -1410,10 +1650,14 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         """Dispatch the scoring program (JAX async dispatch: the handle
         holds device arrays, nothing blocks)."""
         texts, prompt_ids, prompt_lens, real = transferred
+        extra = {}
+        if self.config.recurrent_state:
+            extra["probe_rows"] = jnp.asarray(
+                np.minimum(self.probe_rows, prompt_ids.shape[0] - 1))
         scores, stats = self._score_labels(
             self.params, prompt_ids, prompt_lens,
             jnp.asarray(self._label_ids), jnp.asarray(self._label_lens),
-            prefill_capacity=real[2],
+            prefill_capacity=real[2], **extra,
         )
         return texts, scores, stats, prompt_ids.shape, real
 
@@ -1465,11 +1709,23 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         if stats:
             slots = rows * width if capacity is None else capacity
             attrs.update(self._count_expert_load(stats, slots))
-        if self.config.latent_cache:
-            cfg = self.config
+        cfg = self.config
+        if cfg.latent_cache:
             tel.gauge("latent_cache_bytes", int(
-                rows * (width + label_width) * cfg.n_layers * 2
+                rows * (width + label_width)
+                * (cfg.n_layers - cfg.kda_layers) * 2
                 * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)))
+        if cfg.recurrent_state:
+            kda = cfg.kda_layers
+            # float32 state a head, and the convolutions' last inputs
+            state_bytes = rows * kda * cfg.n_heads * cfg.kda_head_dim * (
+                4 * cfg.kda_head_dim + 2 * 3 * (cfg.kda_conv_kernel - 1))
+            tel.gauge("recurrent_state_bytes", state_bytes)
+            tel.count("kda.tokens", tokens_real * kda)
+            tel.count("kda.state_steps",
+                      rows * n_labels * label_width * kda)
+            attrs.update(kda_layers=kda, mla_layers=cfg.n_layers - kda,
+                         state_bytes=state_bytes)
         tel.current_span().set(**attrs)
 
     def _count_expert_load(self, stats, slots: int,
@@ -1483,16 +1739,29 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         tel = get_telemetry()
         load_max = np.asarray(stats["expert_load_max"], np.float64)
         load_mean = np.asarray(stats["expert_load_mean"], np.float64)
-        tel.count("moe.assignments", int(
-            load_mean.sum() * self.config.n_experts) + pass_assignments)
+        # the load is the held experts' (all of them, but for a chip that
+        # holds a share of each layer's)
+        held = int(load_mean.sum() * self.config.experts_held_count)
+        attrs = {"moe_capacity": slots, "expert_load_max_over_mean": [
+            round(float(m / max(a, 1e-9)), 4)
+            for m, a in zip(load_max, load_mean)]}
+        if self.config.experts_held is None:
+            assignments = held
+        else:
+            # every real position's choices, held here or not; of the label
+            # continuations the positions whose forward is read
+            assignments = int(np.asarray(stats["assignments"]).sum())
+            held_labels = int(stats.get("label_assignments_held", 0))
+            tel.count("moe.assignments_held", held + held_labels)
+            attrs.update(assignments=assignments, assignments_held=held,
+                         label_assignments_held=held_labels)
+        tel.count("moe.assignments", assignments + pass_assignments)
         tel.count("moe.rows_computed",
                   slots * self.config.moe_top_k * len(load_max)
                   + pass_assignments)
         tel.count("moe.expert_load_max", int(load_max.sum()))
         tel.count("moe.expert_load_mean", int(load_mean.sum()))
-        return {"moe_capacity": slots, "expert_load_max_over_mean": [
-            round(float(m / max(a, 1e-9)), 4)
-            for m, a in zip(load_max, load_mean)]}
+        return attrs
 
     def classify_batch(self, texts: Sequence[str]) -> List[str]:
         return self.collect(self.submit(texts))
@@ -1512,6 +1781,7 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         logits, caches = self.model.apply(
             {"params": self.params}, jnp.asarray(ids), positions, mask, caches,
             last_position=jnp.asarray(lens, jnp.int32) - 1,
+            row_lengths=jnp.asarray(lens, jnp.int32),
         )
         caches = [c.with_length(int(lens[0])) for c in caches]
         token = jnp.argmax(logits[:, 0], axis=-1)

@@ -211,6 +211,11 @@ class MLAttention(nn.Module):
     # published 512 / 128 / 128).
     absorb_max_queries: int = 128
     block_q: int = 128
+    # One sigmoid gate a head on the attention output, before ``o_proj``
+    # (``gated_attention_proj_granularity_type: head_wise``): ``o_h <- o_h
+    # * sigmoid(W_gate x)_h``, ``W_gate [dim, heads]``; the same in the
+    # expanded and the absorbed form, which differ in how ``o_h`` is made.
+    output_gate: bool = False
 
     @nn.compact
     def __call__(self, x, mask=None, positions=None,
@@ -329,6 +334,12 @@ class MLAttention(nn.Module):
                 q_nope, q_rope, kv[..., :nope], k_rope, kv[..., nope:],
                 mask, scale, self.block_q,
             )
+        if self.output_gate:
+            note_traced_path("mla.gated")
+            gate = nn.Dense(heads, use_bias=False, dtype=self.dtype,
+                            param_dtype=self.param_dtype, name="gate_proj")(x)
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))[..., None]).astype(self.dtype)
         out = nn.DenseGeneral(
             features=dim, axis=(-2, -1), use_bias=False, dtype=self.dtype,
             param_dtype=self.param_dtype, name="o_proj",

@@ -199,19 +199,33 @@ class MoESwiGLU(nn.Module):
 def route_sigmoid_noaux(
     logits: jax.Array, bias: jax.Array, top_k: int,
     routed_scaling_factor: float, norm_topk_prob: bool = True,
+    n_group: int = 1, topk_group: int = 1,
 ):
-    """``sigmoid`` scores with ``noaux_tc`` selection and no group stage
-    (``n_group = topk_group = 1``).
+    """``sigmoid`` scores with ``noaux_tc`` selection, group-limited where
+    ``n_group > 1``.
 
     Choice: the ``top_k`` largest of ``s + bias`` where ``s =
     sigmoid(logits)`` and ``bias`` is the per-expert
-    ``e_score_correction_bias``.  Weights: ``s`` itself (without the bias)
-    at the chosen experts, divided by their sum, times
-    ``routed_scaling_factor``.  ``logits [T, E]`` float32; returns
-    ``(indices [T, k] int32, weights [T, k] float32)``.
+    ``e_score_correction_bias``.  With groups the experts are ``n_group``
+    runs of ``E / n_group`` neighbours, a group's score is the sum of its
+    two largest corrected scores, the best ``topk_group`` groups are kept
+    and the corrected scores of the others count as 0 in the choice.
+    Weights: ``s`` itself (without the bias) at the chosen experts, divided
+    by their sum, times ``routed_scaling_factor``.  ``logits [T, E]``
+    float32; returns ``(indices [T, k] int32, weights [T, k] float32)``.
     """
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
-    _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    corrected = scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        grouped = corrected.reshape(corrected.shape[:-1] + (n_group, -1))
+        group_scores = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)
+        _, kept = jax.lax.top_k(group_scores, topk_group)
+        keep = jax.nn.one_hot(kept, n_group, dtype=bool).any(axis=-2)
+        corrected = jnp.where(keep[..., None], grouped, 0.0).reshape(
+            corrected.shape)
+    elif topk_group != 1:
+        raise ValueError("topk_group without n_group")
+    _, chosen = jax.lax.top_k(corrected, top_k)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
     if norm_topk_prob:
         weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
@@ -238,6 +252,9 @@ ROUTERS = ("sigmoid_noaux", "softmax_topk")
 
 
 COMPACT_RUNGS = 8
+# Tokens a pass of a layer that holds a share of its experts
+# (``RoutedMoE(experts_held=...)``) sorts to them at a time.
+HELD_CHUNK = 8192
 
 
 def compact_capacity(real: int, positions: int) -> int:
@@ -298,8 +315,8 @@ class RealPositions(NamedTuple):
         return jnp.where(real, y[self.slot], jnp.zeros((), y.dtype))
 
 
-@jax.custom_batching.custom_vmap
-def grouped_experts(xt, chosen, weights, gate_w, up_w, down_w):
+def _grouped_experts(xt, chosen, weights, gate_w, up_w, down_w,
+                     partial: bool):
     """``sum_k weights[t, k] * expert_{chosen[t, k]}(xt[t])`` for every
     token, no capacity: assignments sorted by expert, one
     ``jax.lax.ragged_dot`` a projection over the ragged groups, results
@@ -310,7 +327,10 @@ def grouped_experts(xt, chosen, weights, gate_w, up_w, down_w):
     A token whose ``chosen`` is the number of experts (one past the last)
     is a filler of a compact token set (:class:`RealPositions`): its
     assignments sort behind the last group and belong to none, so no
-    expert multiplies them, and its row of the result is undefined."""
+    expert multiplies them, and its row of the result is undefined.
+    ``partial`` (:func:`grouped_experts_held`): single assignments may name
+    that one-past-the-last expert too (an expert another chip holds); they
+    go where a filler's go and count as zero in the token's sum."""
     T, D = xt.shape
     k = chosen.shape[-1]
     flat_expert = chosen.reshape(T * k)
@@ -322,26 +342,62 @@ def grouped_experts(xt, chosen, weights, gate_w, up_w, down_w):
     up = jax.lax.ragged_dot(xs, up_w, group_sizes)
     ys = jax.lax.ragged_dot(nn.silu(gate) * up, down_w, group_sizes)
     # back to assignment order (token-major), weighted sum in float32
+    if partial:
+        # one choice at a time: three quarters of the assignments are other
+        # chips' at four chips a layer, and ``[T, k, D]`` in float32 is the
+        # step's largest array by far (2 GB at 24,576 slots x 8 x 2,560)
+        back = jnp.argsort(order).reshape(T, k)
+        here = chosen < gate_w.shape[0]
+        out = jnp.zeros((T, D), jnp.float32)
+        for j in range(k):
+            out = out + jnp.where(
+                here[:, j, None],
+                ys[back[:, j]].astype(jnp.float32)
+                * weights[:, j, None].astype(jnp.float32), 0.0)
+        return out
     y = ys[jnp.argsort(order)].reshape(T, k, D).astype(jnp.float32)
     return jnp.einsum("tkd,tk->td", y, weights.astype(jnp.float32))
 
 
-@grouped_experts.def_vmap
-def _grouped_experts_vmap(axis_size, in_batched, xt, chosen, weights,
-                          gate_w, up_w, down_w):
-    """Tokens are independent, so a batch of token sets is one larger set
-    (``ragged_dot`` has no batching rule over its token axis): the label
-    continuations of ``_score_labels`` run as one grouped matmul."""
-    if any(in_batched[3:]):
-        raise NotImplementedError("grouped_experts: batched expert weights")
-    def merge(x, batched):
-        if not batched:
-            x = jnp.broadcast_to(x[None], (axis_size,) + x.shape)
-        return x.reshape((axis_size * x.shape[1],) + x.shape[2:])
-    out = grouped_experts(
-        merge(xt, in_batched[0]), merge(chosen, in_batched[1]),
-        merge(weights, in_batched[2]), gate_w, up_w, down_w)
-    return out.reshape((axis_size, -1) + out.shape[1:]), True
+def _batched_over_tokens(partial: bool):
+    """:func:`_grouped_experts` with its batching rule: tokens are
+    independent, so a batch of token sets is one larger set (``ragged_dot``
+    has no batching rule over its token axis): the label continuations of
+    ``_score_labels`` run as one grouped matmul."""
+
+    @jax.custom_batching.custom_vmap
+    def grouped_experts(xt, chosen, weights, gate_w, up_w, down_w):
+        return _grouped_experts(xt, chosen, weights, gate_w, up_w, down_w,
+                                partial)
+
+    grouped = grouped_experts
+
+    @grouped.def_vmap
+    def _vmap(axis_size, in_batched, xt, chosen, weights, gate_w, up_w,
+              down_w):
+        if any(in_batched[3:]):
+            raise NotImplementedError(
+                "grouped_experts: batched expert weights")
+
+        def merge(x, batched):
+            if not batched:
+                x = jnp.broadcast_to(x[None], (axis_size,) + x.shape)
+            return x.reshape((axis_size * x.shape[1],) + x.shape[2:])
+
+        out = grouped(
+            merge(xt, in_batched[0]), merge(chosen, in_batched[1]),
+            merge(weights, in_batched[2]), gate_w, up_w, down_w)
+        return out.reshape((axis_size, -1) + out.shape[1:]), True
+
+    grouped.__doc__ = _grouped_experts.__doc__
+    return grouped
+
+
+grouped_experts = _batched_over_tokens(partial=False)
+# The same over the experts THIS chip holds of a layer that has more
+# (``RoutedMoE(experts_held=...)``): ``chosen`` in local ids, an absent
+# expert's as one past the last.
+grouped_experts_held = _batched_over_tokens(partial=True)
 
 
 class RoutedMoE(nn.Module):
@@ -374,10 +430,21 @@ class RoutedMoE(nn.Module):
     gathered or put back but the sown ``chosen``, and a filler's routed
     output is zero (the shared experts' alone is its result).
 
-    Sows ``expert_load`` (assignments each expert received, ``[E]`` int32;
-    with ``compact`` those of real positions alone, fillers uncounted) and
-    ``chosen`` (``[B, S, k]``) into the ``intermediates`` collection for
-    callers that ask for it.
+    ``experts_held = (first, count)`` says this chip holds experts ``first
+    .. first + count - 1`` of the layer's ``n_experts`` (expert parallelism:
+    the others live on the chips that share the layer).  The router keeps
+    its ``n_experts`` outputs and its ``top_k`` a token; ``count`` expert
+    stacks exist; an assignment to an absent expert goes where a filler's
+    goes (no group of the grouped matmul: zero, not computed); and the
+    result is THIS chip's part of the layer's: the held experts' share of
+    the weighted sum plus the shared experts, which every chip of the layer
+    computes alike.  The exchange that would add the parts is not here, and
+    nothing stands in for it.
+
+    Sows ``expert_load`` (assignments each held expert received, ``[E]``
+    int32; with ``compact`` those of real positions alone, fillers
+    uncounted) and ``chosen`` (``[B, S, k]``, the router's ids, held or
+    not) into the ``intermediates`` collection for callers that ask for it.
     """
 
     n_experts: int
@@ -389,6 +456,10 @@ class RoutedMoE(nn.Module):
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
     router: str = "sigmoid_noaux"
+    # group-limited choice (``route_sigmoid_noaux``); 1, 1 = none
+    n_group: int = 1
+    topk_group: int = 1
+    experts_held: Optional[tuple] = None
 
     @nn.compact
     def __call__(self, x: jax.Array,
@@ -400,11 +471,15 @@ class RoutedMoE(nn.Module):
         D = x.shape[-1]
         B, S = x.shape[:2] if not packed else compact.real.shape
         E, H, k = self.n_experts, self.hidden_dim, self.top_k
-        gate_w = self.param("gate_experts", fan_in_normal(D), (E, D, H),
+        first, held = self.experts_held or (0, E)
+        if not 0 <= first <= first + held <= E:
+            raise ValueError(
+                f"experts_held {self.experts_held} outside 0..{E}")
+        gate_w = self.param("gate_experts", fan_in_normal(D), (held, D, H),
                             self.param_dtype)
-        up_w = self.param("up_experts", fan_in_normal(D), (E, D, H),
+        up_w = self.param("up_experts", fan_in_normal(D), (held, D, H),
                           self.param_dtype)
-        down_w = self.param("down_experts", fan_in_normal(H), (E, H, D),
+        down_w = self.param("down_experts", fan_in_normal(H), (held, H, D),
                             self.param_dtype)
         # The router stays float32 at the highest matmul precision: its
         # cost is a sliver, and a rounding there changes *which* experts
@@ -428,7 +503,12 @@ class RoutedMoE(nn.Module):
         with jax.named_scope("moe.route"):
             logits = jnp.dot(xt.astype(jnp.float32), router_w,
                              precision=jax.lax.Precision.HIGHEST)
-            if self.router == "sigmoid_noaux":
+            if self.router == "sigmoid_noaux" and self.n_group > 1:
+                note_traced_path("moe.group_limited")
+                chosen, weights = route_sigmoid_noaux(
+                    logits, bias, k, self.routed_scaling_factor,
+                    self.norm_topk_prob, self.n_group, self.topk_group)
+            elif self.router == "sigmoid_noaux":
                 chosen, weights = route_sigmoid_noaux(
                     logits, bias, k, self.routed_scaling_factor,
                     self.norm_topk_prob)
@@ -440,18 +520,46 @@ class RoutedMoE(nn.Module):
         with jax.named_scope("moe.experts"):
             note_traced_path("moe.grouped")
             placed = chosen
+            if self.experts_held is not None:
+                note_traced_path("moe.experts_held")
+                # local ids; an expert another chip holds is one past the
+                # last, as a filler's is (grouped_experts_held)
+                local = chosen - first
+                chosen = jnp.where((local >= 0) & (local < held), local,
+                                   held)
             if compact is not None:
                 note_traced_path("moe.compact")
-                placed = compact.put_back(chosen)
+                placed = compact.put_back(placed)
                 # a filler belongs to no expert (grouped_experts)
-                chosen = jnp.where(compact.valid[:, None], chosen, E)
+                chosen = jnp.where(compact.valid[:, None], chosen, held)
             self.sow("intermediates", "expert_load",
-                     jnp.zeros((E,), jnp.int32).at[chosen.reshape(-1)].add(
-                         1, mode="drop"))
+                     jnp.zeros((held,), jnp.int32).at[
+                         chosen.reshape(-1)].add(1, mode="drop"))
             self.sow("intermediates", "chosen", placed.reshape(B, S, k))
-            out = grouped_experts(
-                xt, chosen, weights, gate_w.astype(self.dtype),
-                up_w.astype(self.dtype), down_w.astype(self.dtype))
+            if self.experts_held is not None:
+                real = (compact.valid.sum() if compact is not None
+                        else xt.shape[0])
+                self.sow("intermediates", "assigned",
+                         jnp.asarray(real * k, jnp.int32))
+            stacks = (gate_w.astype(self.dtype), up_w.astype(self.dtype),
+                      down_w.astype(self.dtype))
+            n_tok = xt.shape[0]
+            if self.experts_held is None:
+                out = grouped_experts(xt, chosen, weights, *stacks)
+            elif n_tok > HELD_CHUNK and n_tok % HELD_CHUNK == 0:
+                # Every assignment is sorted and gathered, held or not
+                # (how many are held is the router's to say, and no token
+                # is dropped), so the sorted copies are sized by all of
+                # them: a stretch of the tokens at a time.
+                def stretch(a):
+                    return a.reshape((-1, HELD_CHUNK) + a.shape[1:])
+
+                out = jax.lax.map(
+                    lambda part: grouped_experts_held(*part, *stacks),
+                    (stretch(xt), stretch(chosen), stretch(weights)),
+                ).reshape(n_tok, D)
+            else:
+                out = grouped_experts_held(xt, chosen, weights, *stacks)
             if packed:
                 # The fillers stay in the caller's stream, and their rows
                 # of the grouped matmuls belong to no group: undefined.

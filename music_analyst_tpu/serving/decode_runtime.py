@@ -24,7 +24,9 @@ def decode_runtime_refusal(backend, runtime: str) -> Optional[str]:
     """Why no ``runtime`` ("slot", "paged", "continuous decode") can host
     ``backend``, or ``None`` where one can — the backend's own answer
     (``ClassifierBackend.decode_runtime_refusal``; a model answers for its
-    layers, e.g. a latent cache no page layout holds yet)."""
+    layers: a latent cache no page layout holds yet, a step that yields a
+    block and not a token, or a recurrent state a row beside the cache,
+    which slots and pages of keys and values have no place for)."""
     reason = getattr(backend, "decode_runtime_refusal",
                      ClassifierBackend.decode_runtime_refusal)
     return reason.format(runtime=runtime) if reason else None

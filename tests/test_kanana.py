@@ -135,7 +135,7 @@ def test_presets_are_built_from_their_files(clf):
 
 
 @pytest.mark.parametrize("key,value", [
-    ("q_lora_rank", 1536), ("n_group", 8), ("scoring_func", "softmax"),
+    ("q_lora_rank", 1536), ("moe_layer_freq", 2), ("scoring_func", "softmax"),
     ("topk_method", "greedy"), ("rope_scaling", {"type": "yarn"}),
     ("model_type", "llama"),
 ])

@@ -16,7 +16,9 @@ the latent-attention prefill kernel at the decoder cell's step (32 x 1,024,
 32 heads of 192 | 128, the cache's 1,032-key buffer), at its smallest
 admitted width and at ``kanana-tiny``'s widths, and its packed form on the
 compact token sets of those steps; the whole scoring step of that cell at
-the rungs its compact prefill meets; flash attention
+the rungs its compact prefill meets; the KDA prefill kernel at the hybrid
+cell's step (64 x 1,024, 32 heads of 128 | 128; compact and padded) and the
+whole scoring step of that cell; flash attention
 under the block-causal rule at the diffusion cell's prefill (32 x 1,024, 32
 query heads on 4 key heads of 128) and both programs of that cell's step.
 """
@@ -455,6 +457,97 @@ def test_diffusion_step_compiles_for_the_chip_at_the_cells_shapes(
                    * config.n_kv_heads * config.attn_head_dim * 2)
     assert memory.alias_size_in_bytes >= cache_bytes
     assert memory.temp_size_in_bytes < 1.0e9
+
+
+@pytest.mark.parametrize("slots,rows,width,heads,dim", [
+    (24576, 64, 1024, 32, 128),   # the hybrid cell's step, compact
+    (65536, 64, 1024, 32, 128),   # the same rows padded to [B, S]
+    (768, 4, 512, 4, 16),         # ling-tiny's compact 512-wide step
+], ids=["ling-compact", "ling-padded", "ling-tiny"])
+def test_kda_chunked_compiles_under_mosaic(tpu_sharding, slots, rows, width,
+                                           heads, dim):
+    """The KDA prefill kernel (``ops/kda_attention.py``) at the hybrid
+    cell's step (64 rows of up to 1,024 slots, 32 heads of 128 | 128) on
+    the compact token stream and on padded rows, with the norms it takes a
+    head at a time, and at the test size."""
+    from music_analyst_tpu.ops.kda_attention import kda_chunked
+
+    def fn(q, k, v, g, beta, starts, ends, valid):
+        return kda_chunked(q, k, v, g, beta, starts, ends, valid, heads,
+                           width, normalize=True, out_norm_eps=1e-6,
+                           interpret=False)
+
+    wide = (slots, heads * dim)
+    compiled = _compile_for_tpu(
+        fn, tpu_sharding, (wide, jnp.bfloat16), (wide, jnp.bfloat16),
+        (wide, jnp.bfloat16), (wide, jnp.float32),
+        ((slots, heads), jnp.float32), ((rows,), jnp.int32),
+        ((rows,), jnp.int32), ((slots,), jnp.bool_))
+    assert re.search(r"%_kda_chunk_call[.\d]* = ", compiled.as_text())
+
+
+def test_hybrid_scoring_step_compiles_for_the_chip_at_the_cells_shape(
+    tpu_sharding, monkeypatch
+):
+    """``llama_score_labels`` of ``ling-3.0-flash-vl`` at the hybrid cell's
+    step (64 x 1,024, the published widths, abstract parameters) compiled
+    for a v5e at the rung its jobs meet (24,576 slots for about 21,000 real
+    tokens): one KDA kernel call in each of the six KDA layers and one
+    packed latent-attention call in the seventh, all on the compact stream;
+    the held experts' grouped matmuls run a stretch of the tokens at a time
+    (8,192 slots x 8 choices) over 128 expert stacks, not the router's 512;
+    no KDA layer holds the stream as ``[slots, heads, 128]`` (a head's
+    norms ride in the kernel), and the temporaries stay under what one chip
+    has beside 10.5 GB of weights."""
+    from music_analyst_tpu.models import llama
+    from music_analyst_tpu.ops import flash_attention
+
+    monkeypatch.setattr(flash_attention, "interpret_default", lambda: False)
+    config = llama.PRESETS["ling-3.0-flash-vl"]()
+    rows, width, capacity = 64, 1024, 24576
+    assert (config.n_layers, config.kda_layers, config.moe_top_k) == (7, 6, 8)
+    program = llama.score_labels_program(llama.LlamaModel(config), config)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=tpu_sharding), tree)
+
+    params = placed(jax.eval_shape(
+        lambda: llama.init_params_by_layer(config)))
+    assert params["layer_1"]["feed_forward_moe"]["gate_experts"].shape == (
+        128, 2560, 768)
+    assert params["layer_1"]["feed_forward_moe"]["router"].shape == (
+        2560, 512)
+    traced = []
+    paths = _traced(lambda: traced.append(program.trace(
+        params, placed(jnp.zeros((rows, width), jnp.int32)),
+        placed(jnp.zeros((rows,), jnp.int16)),
+        placed(jnp.zeros((3, 8), jnp.int32)),
+        placed(jnp.zeros((3,), jnp.int32)), prefill_capacity=capacity,
+        probe_rows=placed(jnp.zeros((8,), jnp.int32)))))
+    assert (paths["kda.compact"], paths["mla.compact"]) == (6, 1)
+    assert paths["moe.experts_held"] == paths["moe.group_limited"] == 12
+    compiled = traced[0].lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    # (two results a call: the outputs and the rows' final states)
+    assert len(re.findall(
+        r"%_kda_chunk_call[.\d]* = \([^=]*\) custom-call\(", text)) == 6
+    assert len(re.findall(
+        r"%_packed_prefill_call[.\d]* = \S+ custom-call\(", text)) == 1
+    shapes = set(re.findall(
+        r"%ragged-dot-none[.\d]* = (\w+\[\d+,\d+\])", text))
+    # (a label's continuation at a time: 64 rows x 8 positions x 8 choices)
+    assert shapes == {f"bf16[{8192 * 8},{n}]" for n in (768, 2560)} | {
+        f"bf16[{rows * 8 * 8},{n}]" for n in (768, 2560)}, shapes
+    # a head's norms ride in the KDA kernel: no KDA layer holds the stream
+    # as [slots, heads, 128] in float32 (0.4 GB and a re-tiling copy each)
+    by_head = re.findall(
+        rf'f32\[{capacity},32,128\][^\n]*op_name="([^"]*)"', text)
+    assert by_head and not any("kda" in name for name in by_head)
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < 10.6e9
+    assert memory.temp_size_in_bytes < 3.6e9
 
 
 def test_unservable_geometry_is_refused_by_name():

@@ -12,6 +12,11 @@ normalization with *constrained label scoring*: one shared prompt prefill,
 then teacher-forced log-likelihood of each candidate label continuation —
 three tiny decode passes instead of an unbounded generation loop, which is
 both deterministic and TPU-shaped (static shapes, no dynamic stopping).
+A label's first token is scored by the prompt's last logits and token
+``i > 0`` by the continuation's logits at position ``i - 1``, so a
+continuation runs the label's tokens but the last, whose forward pass
+nothing reads: ``max(label_lens) - 1`` positions (one for ``word + EOS``,
+none where every label is one token), not a table padded to a fixed width.
 A ``generate`` + ``normalise_label`` path (the reference's semantics,
 empty-output crash fixed) is kept for API parity.
 """
@@ -1132,23 +1137,43 @@ def runs_compact(config: LlamaConfig, shape, capacity) -> bool:
 # functions' names are part of what a run records (a device trace finds
 # the scoring step by ``score_labels``).
 
+# The most tokens a label keeps, and the label slots every scoring step's
+# caches have behind the prompt's ``S`` (a multiple of 8 keeps the cache's
+# key axis one: 1,032 keys at a 1,024-wide step), whatever the table's width.
+MAX_LABEL_TOKENS = 8
+
+
 def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
     """The jitted scoring step: one prompt prefill a row, then the
     teacher-forced label continuations on its cache (``profiled_jit``
-    name ``llama_score_labels``)."""
+    name ``llama_score_labels``).
+
+    A continuation runs the positions whose forward pass is read and no
+    other.  Token 0 of a label is scored by the PROMPT's last logits and
+    token ``i > 0`` by the continuation's logits at position ``i - 1``, so
+    the forward of a label's last token (the EOS of ``word + EOS``) scores
+    nothing: of a table ``L`` wide the continuation forwards
+    ``label_ids[:, :L - 1]`` and gathers at ``label_ids[:, 1:]``.  ``L`` is
+    a shape of the program (:func:`_label_table`: the longest label), so
+    with one-token labels there is no continuation at all.  Attention is
+    causal and a recurrent state runs forward: the positions left out
+    never reached the ones that are read."""
 
     def _score_labels(params, prompt_ids, prompt_lens, label_ids,
                       label_lens, prefill_capacity=None, probe_rows=None):
         """Log-likelihood of each label continuation per batch row.
 
-        prompt_ids [B, S]; label_ids [3, L]; ``prefill_capacity`` (static)
+        prompt_ids [B, S]; label_ids [3, L], ``L <= MAX_LABEL_TOKENS``;
+        ``prefill_capacity`` (static)
         the token slots the prefill's feed-forward layers run, from
         ``models/moe.compact_capacity`` of these lengths (``None`` or
         ``B * S``: every position).  Returns ``(scores [B, 3], stats)``:
         ``stats`` holds the small device-side reductions that ride back
         with the scores (``expert_load_max`` / ``expert_load_mean``
         ``[routed layers]`` of the prefill, for a model with routed
-        experts; else empty).  ``probe_rows [P]`` (a model with recurrent
+        experts; else empty; ``chosen_labels [3, layers, B, L, k]``: the
+        experts the continuations' positions ran, ``-1`` at the last,
+        which ran none).  ``probe_rows [P]`` (a model with recurrent
         state alone): the rows whose state after the prefill rides back
         too (``stats["probe"]``: every KDA layer's ``state [layers, P, H,
         dk, dv]``, every latent layer's ``latents`` and ``rope_keys``
@@ -1157,17 +1182,24 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
         """
         B, S = prompt_ids.shape
         n_labels, L = label_ids.shape
+        if L > MAX_LABEL_TOKENS:
+            raise ValueError(
+                f"a label table {L} wide: the caches hold "
+                f"{MAX_LABEL_TOKENS} label slots")
+        W = L - 1  # the positions a continuation runs
         # prompt_lens may arrive int16 (wire narrowing) — widen once
         # on device before the arithmetic/broadcast uses below.
         prompt_lens = prompt_lens.astype(jnp.int32)
         positions = jnp.arange(S)[None, :].repeat(B, 0)
-        # kv length is S+L (the cache buffer); the label slots are
-        # causally unreachable during prefill and masked out anyway.
-        mask = causal_mask(S, S + L, 0) & jnp.pad(
+        # kv length is the cache buffer's, S + MAX_LABEL_TOKENS whatever
+        # the labels' width; the label slots are causally unreachable
+        # during prefill and masked out anyway.
+        kv_len = S + MAX_LABEL_TOKENS
+        mask = causal_mask(S, kv_len, 0) & jnp.pad(
             padding_mask(prompt_lens, S),
-            ((0, 0), (0, 0), (0, 0), (0, L)),
+            ((0, 0), (0, 0), (0, 0), (0, MAX_LABEL_TOKENS)),
         )
-        caches = init_caches(config, B, S + L)
+        caches = init_caches(config, B, kv_len)
         # last_position: only the final prompt logits are consumed, so
         # the [B,S,V] prefill logits are never materialized.
         (logits, caches), sown = model.apply(
@@ -1183,45 +1215,49 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
         # Force every cache to report the true prompt length so label
         # positions line up even though the buffer was written at 0..S.
         caches = [c.with_length(S) for c in caches]
-        last_logits = logits[:, 0]  # [B, V]
+        # token 0 of every label is scored from the prompt's last logits
+        first_logp = jax.nn.log_softmax(logits[:, 0], axis=-1)  # [B, V]
 
         def score_one(label_row, label_len):
             lab = jnp.broadcast_to(label_row[None, :], (B, L))
-            pos = prompt_lens[:, None] + jnp.arange(L)[None, :]
-            # decode attends to the full prompt (masked by its length)
-            # plus the causal prefix of the label tokens
-            kv_len = S + L
-            kv_pos = jnp.arange(kv_len)[None, None, None, :]
-            prompt_part = kv_pos < prompt_lens[:, None, None, None]
-            label_part = (kv_pos >= S) & (
-                kv_pos - S <= jnp.arange(L)[None, None, :, None]
-            )
-            mask2 = prompt_part | label_part
-            (logits2, _), sown2 = model.apply(
-                {"params": params}, lab, pos, mask2, caches,
-                mutable=["intermediates"],
-            )
-            # token 0 scored from the prompt's last logits; tokens i>0
-            # from the label forward pass
-            logp_all = jax.nn.log_softmax(logits2, axis=-1)
-            first_lp = jnp.take_along_axis(
-                jax.nn.log_softmax(last_logits, axis=-1),
-                lab[:, :1], axis=1,
-            )[:, 0]
-            rest_lp = jnp.take_along_axis(
-                logp_all[:, :-1], lab[:, 1:, None], axis=2
-            )[:, :, 0]
-            idx = jnp.arange(L - 1)[None, :]
-            rest_lp = jnp.where(idx < label_len - 1, rest_lp, 0.0)
+            total = jnp.take_along_axis(first_logp, lab[:, :1], axis=1)[:, 0]
+            label_chosen = None
+            if W:
+                # tokens i > 0 from the forward of the label's tokens
+                # before them: positions 0 .. W-1, written at slots S ..
+                steps = jnp.arange(W)
+                pos = prompt_lens[:, None] + steps[None, :]
+                # decode attends to the full prompt (masked by its length)
+                # plus the causal prefix of the label tokens
+                kv_pos = jnp.arange(kv_len)[None, None, None, :]
+                prompt_part = kv_pos < prompt_lens[:, None, None, None]
+                label_part = (kv_pos >= S) & (
+                    kv_pos - S <= steps[None, None, :, None]
+                )
+                (logits2, _), sown2 = model.apply(
+                    {"params": params}, lab[:, :-1], pos,
+                    prompt_part | label_part, caches,
+                    mutable=["intermediates"],
+                )
+                rest_lp = jnp.take_along_axis(
+                    jax.nn.log_softmax(logits2, axis=-1),
+                    lab[:, 1:, None], axis=2,
+                )[:, :, 0]
+                total = total + jnp.where(
+                    steps[None, :] < label_len - 1, rest_lp, 0.0).sum(axis=1)
+                chosen2 = _sown_by_layer(sown2, "chosen")
+                if chosen2:
+                    # signed: the last position states no expert
+                    label_chosen = jnp.pad(
+                        jnp.stack(chosen2).astype(jnp.int32),
+                        ((0, 0), (0, 0), (0, 1), (0, 0)),
+                        constant_values=-1)
             # Length-normalize: summed log-probs otherwise favor the
             # shortest label ("Neutral" is one byte shorter than the
             # other two under the byte tokenizer).
-            total = first_lp + rest_lp.sum(axis=1)
-            chosen2 = _sown_by_layer(sown2, "chosen")
             return (
                 total / jnp.maximum(label_len.astype(jnp.float32), 1.0),
-                _expert_ids(jnp.stack(chosen2), config.n_experts)
-                if chosen2 else None,
+                label_chosen,
             )
 
         if config.recurrent_state:
@@ -1236,6 +1272,11 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
             scores, label_chosen = jax.vmap(
                 score_one, in_axes=(0, 0), out_axes=(1, 0)
             )(label_ids, label_lens)
+        if label_chosen is None and "chosen" in stats:
+            # no continuation ran: nothing stated at the one label position
+            layers, _, _, k = stats["chosen"].shape
+            label_chosen = jnp.full((n_labels, layers, B, L, k), -1,
+                                    jnp.int32)
         if label_chosen is not None:
             stats["chosen_labels"] = label_chosen  # [3, layers, B, L, k]
         if label_chosen is not None and config.experts_held is not None:
@@ -1451,9 +1492,16 @@ def build_params(model: LlamaModel, config: LlamaConfig,
 
 
 def _label_table(tokenizer):
-    """``(ids [3, 8], lens [3])``: the label continuations scored
-    teacher-forced after a shared prompt prefill, padded to one fixed
-    length so a single jitted function scores them as a batch dimension."""
+    """``(ids [3, L], lens [3])``: the label continuations scored
+    teacher-forced after a shared prompt prefill, padded to the longest
+    label's ``L`` tokens (at most ``MAX_LABEL_TOKENS``) so a single jitted
+    function scores them as a batch dimension.  ``L`` is the tokenizer's
+    own: 2 where a label is ``word + EOS``, 8 for the byte tokenizer's
+    ``Positive`` / ``Negative``, 1 where every label is one token and the
+    tokenizer closes none.  It is a host constant when the classifier is
+    built and so a shape of the scoring program, which runs ``L - 1``
+    positions a continuation (:func:`score_labels_program`: the last
+    token's forward is never taken) and not a fixed 8."""
     bos_id = getattr(tokenizer, "bos_id", None)
     label_rows, label_lens = [], []
     for label in SUPPORTED_LABELS:
@@ -1468,9 +1516,11 @@ def _label_table(tokenizer):
             # pass: score "label, then stop" (EOS) as the answer.
             row = np.insert(row, n, tokenizer.eos_id)
             n += 1
-        label_rows.append(row[skip:skip + 8])  # fixed len 8
-        label_lens.append(min(n - skip, 8))
-    return np.stack(label_rows), np.array(label_lens, dtype=np.int32)
+        label_rows.append(row[skip:skip + MAX_LABEL_TOKENS])
+        label_lens.append(min(n - skip, MAX_LABEL_TOKENS))
+    width = max(1, *label_lens)
+    return (np.stack([row[:width] for row in label_rows]),
+            np.array(label_lens, dtype=np.int32))
 
 
 def zero_shot_prompt(lyrics: str) -> str:
@@ -1680,31 +1730,36 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         """What one scoring step computed, into the run's telemetry:
         ``decoder.tokens_real`` / ``decoder.tokens_computed`` (positions
         that went through the layers: the prompt's, and each label's
-        tokens but its last, whose forward pass nothing reads; computed
-        includes padding, and of a prefill that ran on the compact token
+        tokens but its last, whose forward pass nothing reads and nothing
+        runs; computed includes padding: a shorter label's positions up to
+        the longest's, and of a prefill that ran on the compact token
         set its ``capacity`` slots in place of ``rows * width``), the
-        routed layers' load, the latent cache's
-        size, and the step's shape and real token counts on the span the
-        engine has open (``compute``).  ``moe.assignments`` and the load
-        count the real positions' assignments where the prefill ran
-        compact (``moe_capacity`` token slots under ``rows * width``);
+        routed layers' load, the latent cache's size as allocated, and the
+        step's shape and real token counts on the span the engine has open
+        (``compute``: ``label_positions`` a row ran, ``label_positions_real``
+        of them read; equal where the labels are equally long).
+        ``moe.assignments`` and the load count the real positions'
+        assignments where the prefill ran compact (``moe_capacity`` token
+        slots under ``rows * width``);
         ``moe.rows_computed`` counts the rows its grouped matmuls ran,
         fillers and padding included."""
         from music_analyst_tpu.telemetry import get_telemetry
 
         tel = get_telemetry()
         tokens_real, token_pairs, capacity = real
+        # a continuation runs the table's width less one: the positions
+        # whose forward some label reads (``score_labels_program``)
         n_labels, label_width = self._label_ids.shape
+        label_run = n_labels * (label_width - 1)
         label_real = int(np.maximum(self._label_lens - 1, 0).sum())
         tel.count("decoder.tokens_real", tokens_real + rows * label_real)
         through_layers = (
             capacity if runs_compact(self.config, (rows, width), capacity)
             else rows * width)
         tel.count("decoder.tokens_computed",
-                  through_layers + rows * n_labels * label_width)
+                  through_layers + rows * label_run)
         attrs = dict(rows=rows, width=width, tokens_real=tokens_real,
-                     token_pairs=token_pairs,
-                     label_positions=n_labels * label_width,
+                     token_pairs=token_pairs, label_positions=label_run,
                      label_positions_real=label_real)
         if stats:
             slots = rows * width if capacity is None else capacity
@@ -1712,7 +1767,7 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         cfg = self.config
         if cfg.latent_cache:
             tel.gauge("latent_cache_bytes", int(
-                rows * (width + label_width)
+                rows * (width + MAX_LABEL_TOKENS)
                 * (cfg.n_layers - cfg.kda_layers) * 2
                 * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)))
         if cfg.recurrent_state:
@@ -1722,8 +1777,7 @@ class LlamaZeroShotClassifier(ClassifierBackend):
                 4 * cfg.kda_head_dim + 2 * 3 * (cfg.kda_conv_kernel - 1))
             tel.gauge("recurrent_state_bytes", state_bytes)
             tel.count("kda.tokens", tokens_real * kda)
-            tel.count("kda.state_steps",
-                      rows * n_labels * label_width * kda)
+            tel.count("kda.state_steps", rows * label_run * kda)
             attrs.update(kda_layers=kda, mla_layers=cfg.n_layers - kda,
                          state_bytes=state_bytes)
         tel.current_span().set(**attrs)

@@ -12,6 +12,7 @@ is tight enough that int8 experts or a dropped shared expert fail it.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
@@ -76,11 +77,16 @@ def _lyrics(seed: int, rows: int):
 LYRICS = _lyrics(1, 12) + [""]
 
 
-@pytest.fixture(scope="module")
-def clf():
+@functools.lru_cache(maxsize=None)
+def _backend(name: str):
     from music_analyst_tpu.engines.sentiment import get_backend
 
-    return get_backend("kanana-tiny")
+    return get_backend(name)
+
+
+@pytest.fixture(scope="module")
+def clf():
+    return _backend("kanana-tiny")
 
 
 def _f32(tree):
@@ -364,7 +370,9 @@ def test_label_scores_through_the_latent_cache_match_reference(clf, scored):
     ids, lens, got, want = scored
     tol = ref.TEST_TOLERANCE
     routing = want["routing"]
-    assert routing["compared"] == 2 * 3 * int((lens + 8).sum())
+    # a prompt's positions and the two of ``word + EOS``, in every label
+    assert clf._label_ids.shape == (3, 2)
+    assert routing["compared"] == 2 * 3 * int((lens + 2).sum())
     assert 0 < routing["differ"] < 0.05 * routing["compared"]
     assert routing["wrong"] == tol["wrong_choices"]
     assert routing["deepest_tie"] < tol["route_margin"]
@@ -818,18 +826,20 @@ def test_staged_hooks_equal_classify_batch_and_count_the_step(clf):
         "decoder.tokens_real", 0) == 2 * real
     # what went through the layers: the compact token set's slots (the
     # step's width and rung are ones the packed prefill takes), not the
-    # step's rows x width, then every label position
+    # step's rows x width, then the one position a label whose forward is
+    # read (the word's; the table is two wide, not padded to 8)
     capacity = compact_capacity(int(lens.sum()), rows * width)
     assert llama.runs_compact(clf.config, (rows, width), capacity)
     assert tel.counters["decoder.tokens_computed"] - before.get(
-        "decoder.tokens_computed", 0) == 2 * (capacity + rows * 3 * 8)
+        "decoder.tokens_computed", 0) == 2 * (capacity + rows * 3 * 1)
     assert span.attrs["rows"] == rows and span.attrs["width"] == width
     assert span.attrs["tokens_real"] == int(lens.sum())
     assert span.attrs["token_pairs"] == int((lens * (lens + 1) // 2).sum())
     assert (span.attrs["label_positions"],
-            span.attrs["label_positions_real"]) == (24, 3)
+            span.attrs["label_positions_real"]) == (3, 3)
     ratios = span.attrs["expert_load_max_over_mean"]
     assert len(ratios) == 2 and all(1.0 <= r <= 8.0 for r in ratios)
+    # the cache as allocated: 8 label slots behind the prompt's width
     assert tel.gauges["latent_cache_bytes"] == rows * (width + 8) * 3 * 2 * 24
     # two steps of one shape: the prefill's feed-forward halves ran on the
     # rung that holds the real tokens, every REAL position's assignments
@@ -841,6 +851,117 @@ def test_staged_hooks_equal_classify_batch_and_count_the_step(clf):
             for name in ("moe.assignments", "moe.rows_computed")}
     assert grew == {"moe.assignments": 2 * 2 * int(lens.sum()) * 2,
                     "moe.rows_computed": 2 * 2 * capacity * 2}
+
+
+# ------------------------------------------- the label continuations' width
+
+def _tables(vocab_size: int) -> dict:
+    """Label tables by their lengths: a word then EOS (the hash-word
+    tokenizer's), the byte tokenizer's ``Positive`` / ``Neutral`` /
+    ``Negative``, and one-token labels that nothing closes."""
+    from music_analyst_tpu.models.tokenization import (
+        ByteTokenizer,
+        HashWordLMTokenizer,
+    )
+
+    words = llama._label_table(HashWordLMTokenizer(vocab_size))
+    return {"2-2-2": words,
+            "8-7-8": llama._label_table(ByteTokenizer(vocab_size)),
+            "1-1-1": (words[0][:, :1], np.ones(3, np.int32))}
+
+
+@pytest.mark.parametrize("lengths", ["2-2-2", "8-7-8", "1-1-1"])
+@pytest.mark.parametrize("model", ["kanana-tiny", "ling-tiny", "llama3-tiny"])
+def test_label_scores_equal_those_of_the_table_padded_to_eight(model,
+                                                               lengths):
+    """A continuation runs the table's width less one (the positions
+    whose forward some label reads: 1 for ``word + EOS``, 7 for the byte
+    tokenizer, none for one-token labels) and scores as the same model
+    does on the table padded to 8, through a latent cache
+    (``kanana-tiny``), recurrent states beside one (``ling-tiny``: one
+    label after the other) and a key-value cache (``llama3-tiny``); the
+    experts the positions ran are the same, and ``-1`` stands at the last
+    position, which ran none."""
+    served = _backend(model)
+    ids, lens = _prompts(served, _lyrics(3, 5) + [""])
+    table, label_lens = _tables(served.config.offline_vocab_size)[lengths]
+    assert label_lens.tolist() == [int(n) for n in lengths.split("-")]
+    width = table.shape[1]
+    assert width == label_lens.max()
+    padded = np.zeros((3, 8), table.dtype)
+    padded[:, :width] = table
+
+    def run(label_ids):
+        scores, stats = served._score_labels(
+            served.params, jnp.asarray(ids), jnp.asarray(lens),
+            jnp.asarray(label_ids), jnp.asarray(label_lens))
+        return np.asarray(scores, np.float64), stats
+
+    got, stats = run(table)
+    want, want_stats = run(padded)
+    assert np.isfinite(got).all() and got.shape == (len(lens), 3)
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-6)
+    if served.config.routed_experts:
+        chosen = np.asarray(stats["chosen_labels"])
+        wide = np.asarray(want_stats["chosen_labels"])
+        layers = chosen.shape[1]
+        assert chosen.shape == (3, layers, len(lens), width,
+                                served.config.moe_top_k)
+        assert (chosen[:, :, :, :width - 1]
+                == wide[:, :, :, :width - 1]).all()
+        assert (chosen[:, :, :, width - 1] == -1).all()
+        assert (wide[:, :, :, 7] == -1).all() and (wide[..., :7, :] >= 0).all()
+    else:
+        assert "chosen_labels" not in stats
+    if served.config.experts_held is not None:
+        assert int(stats["label_assignments_held"]) == int(
+            want_stats["label_assignments_held"])
+    with pytest.raises(ValueError, match="label slots"):
+        run(np.zeros((3, 9), table.dtype))
+
+
+@pytest.mark.parametrize("model,ran,read", [
+    ("kanana-tiny", 3, 3), ("ling-tiny", 3, 3), ("llama3-tiny", 21, 20)])
+def test_a_step_counts_the_label_positions_it_ran(model, ran, read):
+    """The ``compute`` span and the counters say what a continuation ran:
+    ``label_positions`` = labels x (the table's width - 1), equal to
+    ``label_positions_real`` where the labels are equally long (the
+    cells' ``word + EOS``; the byte tokenizer's ``Neutral`` is a byte
+    shorter), and one state step a KDA layer a position; two steps of one
+    shape are one scoring program."""
+    from music_analyst_tpu.telemetry import get_telemetry
+
+    served = _backend(model)
+    lyrics = _lyrics(5, 6)
+    ids, lens = _prompts(served, lyrics)
+    rows, width = ids.shape
+    tel = get_telemetry()
+    before = dict(tel.counters)
+    programs = set(served._score_labels.records)
+    with tel.span("compute") as span:
+        for _ in range(2):
+            served.collect(served.launch(served.transfer(
+                served.prepare(lyrics))))
+    assert len(set(served._score_labels.records) - programs) <= 1
+    assert (span.attrs["label_positions"],
+            span.attrs["label_positions_real"]) == (ran, read)
+    assert ran == 3 * (served._label_ids.shape[1] - 1)
+
+    def grew(name):
+        return tel.counters.get(name, 0) - before.get(name, 0)
+
+    capacity = compact_capacity(int(lens.sum()), rows * width)
+    through = (capacity if llama.runs_compact(
+        served.config, (rows, width), capacity) else rows * width)
+    assert grew("decoder.tokens_computed") == 2 * (through + rows * ran)
+    assert grew("decoder.tokens_real") == 2 * (int(lens.sum()) + rows * read)
+    kda = served.config.kda_layers
+    assert grew("kda.state_steps") == 2 * rows * ran * kda
+    if served.config.latent_cache:
+        cfg = served.config
+        assert tel.gauges["latent_cache_bytes"] == (
+            rows * (width + llama.MAX_LABEL_TOKENS) * (cfg.n_layers - kda)
+            * 2 * (cfg.kv_lora_rank + cfg.qk_rope_head_dim))
 
 
 def test_sentiment_cli_end_to_end(tmp_path, fixture_csv):

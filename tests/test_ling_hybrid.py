@@ -755,7 +755,8 @@ def test_cli_writes_the_jobs_files_and_counts_what_a_step_did(tmp_path):
     counters, gauges = manifest["counters"], manifest["gauges"]
     assert counters["kda.tokens"] == 3 * (
         counters["decoder.tokens_real"] - 8 * 3)   # 3 KDA layers, 2 steps
-    assert counters["kda.state_steps"] == 8 * 3 * 8 * 3
+    # 8 rows x 3 labels x the one position a label runs x 3 KDA layers
+    assert counters["kda.state_steps"] == 8 * 3 * 1 * 3
     assert 0 < counters["moe.assignments_held"] < counters["moe.assignments"]
     assert counters["moe.assignments"] % (3 * 4) == 0
     assert gauges["recurrent_state_bytes"] == 4 * 3 * 4 * 16 * (64 + 18)

@@ -326,7 +326,7 @@ def test_compact_scoring_step_keeps_the_kernel_fed_and_no_padded_position(
         return program.trace(
             params, placed(jnp.zeros((rows, width), jnp.int32)),
             placed(jnp.zeros((rows,), jnp.int16)),
-            placed(jnp.zeros((3, 8), jnp.int32)),
+            placed(jnp.zeros((3, 2), jnp.int32)),   # word + EOS
             placed(jnp.zeros((3,), jnp.int32)), **static)
 
     traced = []
@@ -351,10 +351,11 @@ def test_compact_scoring_step_keeps_the_kernel_fed_and_no_padded_position(
     shapes = set(re.findall(
         r"%ragged-dot-none[.\d]* = (\w+\[\d+,\d+\])", text))
     # the prefill's grouped matmuls at capacity * top_k rows, the label
-    # continuations' (3 x 32 x 8 positions, uncompacted) at theirs
+    # continuations' at theirs: 3 x 32 rows x the ONE position whose
+    # forward is read (the label word's; not a table padded to 8)
     assert shapes == {
         f"bf16[{capacity * top_k},{n}]" for n in (768, 2048)} | {
-        f"bf16[{3 * rows * 8 * top_k},{n}]" for n in (768, 2048)}, shapes
+        f"bf16[{3 * rows * top_k},{n}]" for n in (768, 2048)}, shapes
     # 1.45 and 1.8 GB of temporaries where the padded step has 3.4
     assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
 
@@ -523,7 +524,7 @@ def test_hybrid_scoring_step_compiles_for_the_chip_at_the_cells_shape(
     paths = _traced(lambda: traced.append(program.trace(
         params, placed(jnp.zeros((rows, width), jnp.int32)),
         placed(jnp.zeros((rows,), jnp.int16)),
-        placed(jnp.zeros((3, 8), jnp.int32)),
+        placed(jnp.zeros((3, 2), jnp.int32)),   # word + EOS
         placed(jnp.zeros((3,), jnp.int32)), prefill_capacity=capacity,
         probe_rows=placed(jnp.zeros((8,), jnp.int32)))))
     assert (paths["kda.compact"], paths["mla.compact"]) == (6, 1)
@@ -537,9 +538,10 @@ def test_hybrid_scoring_step_compiles_for_the_chip_at_the_cells_shape(
         r"%_packed_prefill_call[.\d]* = \S+ custom-call\(", text)) == 1
     shapes = set(re.findall(
         r"%ragged-dot-none[.\d]* = (\w+\[\d+,\d+\])", text))
-    # (a label's continuation at a time: 64 rows x 8 positions x 8 choices)
+    # (a label's continuation at a time: 64 rows x the one position whose
+    # forward is read x 8 choices)
     assert shapes == {f"bf16[{8192 * 8},{n}]" for n in (768, 2560)} | {
-        f"bf16[{rows * 8 * 8},{n}]" for n in (768, 2560)}, shapes
+        f"bf16[{rows * 8},{n}]" for n in (768, 2560)}, shapes
     # a head's norms ride in the KDA kernel: no KDA layer holds the stream
     # as [slots, heads, 128] in float32 (0.4 GB and a re-tiling copy each)
     by_head = re.findall(

@@ -15,8 +15,10 @@
 #         one comparison); "solo": every leg has its own.
 # Each leg's result line is printed and kept, with its warm-up job's manifest
 # (`profiling.compiles`: the `hlo_fingerprint` of every compiled program) and
-# a traced leg's reduced trace, and the count of steps a compact rung
-# (`<leg>.rungs.txt`), under chiprun_out/ab/.
+# a traced leg's reduced trace (by XLA's names, and by the program's scopes:
+# `python3 perfbench/tools/scope_table.py <leg>.scope_reduced.json` prints
+# the second), and the count of steps a compact rung (`<leg>.rungs.txt`),
+# under chiprun_out/ab/.
 wl=$1; order=$2; seed=$3; mode=${4:-pair}
 root=$PWD
 mkdir -p chiprun_out/ab
@@ -38,6 +40,8 @@ for leg in $order; do
       > $out.rungs.txt
     if [ "$tr" = "1" ]; then
       cp perfbench/out/$wl/trace_reduced.json $out.trace_reduced.json
+      cp perfbench/out/$wl/scope_reduced.json $out.scope_reduced.json \
+        2>/dev/null  # a parent older than PR 35 writes none
     fi )
   echo "== $leg seed $s rungs: $(cat $out.rungs.txt)"
   tail -n 2 $out.out | cut -c1-2500

@@ -500,6 +500,8 @@ def leg_trace(smoke: Smoke, corpus: str, model: str, songs: int,
     require(traces, f"trace: no non-empty *.xplane.pb under {profile}")
     require(os.path.exists(os.path.join(profile, "trace_spans.json")),
             "trace: no trace_spans.json")
+    require(os.path.exists(os.path.join(profile, "op_scopes.json")),
+            "trace: no op_scopes.json")
     smoke.observations["trace"] = {
         "xplane_bytes": sum(os.path.getsize(p) for p in traces)}
 

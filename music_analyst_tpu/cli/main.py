@@ -98,8 +98,9 @@ def _add_telemetry_flags(p: argparse.ArgumentParser) -> None:
                    help="Disable run telemetry entirely (no extra files)")
     p.add_argument("--profile-dir", default=None,
                    help="Capture a device profiler trace + span-level "
-                        "Chrome trace (trace_spans.json) into this dir "
-                        "(profiling/trace.py)")
+                        "Chrome trace (trace_spans.json) + the scope of "
+                        "each device operation (op_scopes.json) into this "
+                        "dir (profiling/trace.py)")
     p.add_argument("--watchdog-timeout", default=None,
                    help="Heartbeat watchdog timeout in seconds: a stage/"
                         "compile/device scope silent this long dumps a "

@@ -92,22 +92,25 @@ class TransformerBlock(nn.Module):
     def __call__(self, x, mask, lengths=None, segment_ids=None):
         cfg = self.config
         dtype = jnp.dtype(cfg.dtype)
-        attn_out = MultiHeadAttention(
-            n_heads=cfg.n_heads, dtype=dtype, attn_impl=cfg.attn_impl,
-            use_bias=True,  # HF DistilBERT q/k/v/out projections have biases
-            quant=cfg.quant, weight_quant=cfg.weight_quant,
-            mesh=self.mesh, name="attention",
-        )(x, mask=None if cfg.attn_impl == "flash" else mask,
-          lengths=lengths,
-          segment_ids=segment_ids if cfg.attn_impl == "flash" else None)
-        x = nn.LayerNorm(
-            name="sa_layer_norm", dtype=dtype, epsilon=LN_EPS
-        )(x + attn_out)
-        mlp_out = GeluMLP(cfg.hidden_dim, dtype=dtype, quant=cfg.quant,
-                          weight_quant=cfg.weight_quant, name="ffn")(x)
-        return nn.LayerNorm(
-            name="output_layer_norm", dtype=dtype, epsilon=LN_EPS
-        )(x + mlp_out)
+        with jax.named_scope("encoder.attention"):
+            attn_out = MultiHeadAttention(
+                n_heads=cfg.n_heads, dtype=dtype, attn_impl=cfg.attn_impl,
+                # HF DistilBERT q/k/v/out projections have biases
+                use_bias=True,
+                quant=cfg.quant, weight_quant=cfg.weight_quant,
+                mesh=self.mesh, name="attention",
+            )(x, mask=None if cfg.attn_impl == "flash" else mask,
+              lengths=lengths,
+              segment_ids=segment_ids if cfg.attn_impl == "flash" else None)
+            x = nn.LayerNorm(
+                name="sa_layer_norm", dtype=dtype, epsilon=LN_EPS
+            )(x + attn_out)
+        with jax.named_scope("encoder.ffn"):
+            mlp_out = GeluMLP(cfg.hidden_dim, dtype=dtype, quant=cfg.quant,
+                              weight_quant=cfg.weight_quant, name="ffn")(x)
+            return nn.LayerNorm(
+                name="output_layer_norm", dtype=dtype, epsilon=LN_EPS
+            )(x + mlp_out)
 
 
 class DistilBertEncoder(nn.Module):
@@ -134,15 +137,16 @@ class DistilBertEncoder(nn.Module):
         """
         cfg = self.config
         dtype = jnp.dtype(cfg.dtype)
-        if positions is None:
-            positions = jnp.arange(token_ids.shape[1])[None, :]
-        tok = nn.Embed(cfg.vocab_size, cfg.dim, dtype=dtype,
-                       name="word_embeddings")(token_ids)
-        pos = nn.Embed(cfg.max_positions, cfg.dim, dtype=dtype,
-                       name="position_embeddings")(positions)
-        x = nn.LayerNorm(
-            name="embed_layer_norm", dtype=dtype, epsilon=LN_EPS
-        )(tok + pos)
+        with jax.named_scope("encoder.embed"):
+            if positions is None:
+                positions = jnp.arange(token_ids.shape[1])[None, :]
+            tok = nn.Embed(cfg.vocab_size, cfg.dim, dtype=dtype,
+                           name="word_embeddings")(token_ids)
+            pos = nn.Embed(cfg.max_positions, cfg.dim, dtype=dtype,
+                           name="position_embeddings")(positions)
+            x = nn.LayerNorm(
+                name="embed_layer_norm", dtype=dtype, epsilon=LN_EPS
+            )(tok + pos)
         if segment_ids is not None:
             # Block-diagonal: token pairs attend iff same segment.  The
             # dense impl gets a mask array; the flash kernel takes the
@@ -202,15 +206,17 @@ class DistilBertForSentiment(nn.Module):
         x = DistilBertEncoder(cfg, self.mesh, name="encoder")(
             token_ids, lengths, positions=positions, segment_ids=segment_ids
         )
-        if cls_index is None:
-            cls = x[:, 0]  # [CLS]
-        else:
-            cls = jnp.take_along_axis(
-                x, cls_index[:, :, None].astype(jnp.int32), axis=1
-            )                                               # [B, K, D]
-        h = nn.Dense(cfg.dim, dtype=dtype, name="pre_classifier")(cls)
-        h = nn.relu(h)
-        return nn.Dense(cfg.n_classes, dtype=jnp.float32, name="classifier")(h)
+        with jax.named_scope("encoder.head"):
+            if cls_index is None:
+                cls = x[:, 0]  # [CLS]
+            else:
+                cls = jnp.take_along_axis(
+                    x, cls_index[:, :, None].astype(jnp.int32), axis=1
+                )                                           # [B, K, D]
+            h = nn.Dense(cfg.dim, dtype=dtype, name="pre_classifier")(cls)
+            h = nn.relu(h)
+            return nn.Dense(cfg.n_classes, dtype=jnp.float32,
+                            name="classifier")(h)
 
 
 def iter_hf_param_units(params, path: str, mmap: bool = False):
@@ -558,8 +564,9 @@ class DistilBertClassifier(ClassifierBackend):
                 token_ids.astype(jnp.int32),
                 lengths.astype(jnp.int32),
             )
-            probs = jax.nn.softmax(logits, axis=-1)
-            return jnp.argmax(logits, axis=-1), jnp.max(probs, axis=-1)
+            with jax.named_scope("encoder.head"):
+                probs = jax.nn.softmax(logits, axis=-1)
+                return jnp.argmax(logits, axis=-1), jnp.max(probs, axis=-1)
 
         # Steady-state forwards donate their input batch: the H2D staging
         # buffer is dead the moment the widened copy exists, so XLA may
@@ -602,8 +609,9 @@ class DistilBertClassifier(ClassifierBackend):
                 segment_ids=seg,
                 cls_index=jnp.minimum(st, seq - 1),
             )                                                  # [P, K, C]
-            probs = jax.nn.softmax(logits, axis=-1)
-            return jnp.argmax(logits, axis=-1), jnp.max(probs, axis=-1)
+            with jax.named_scope("encoder.head"):
+                probs = jax.nn.softmax(logits, axis=-1)
+                return jnp.argmax(logits, axis=-1), jnp.max(probs, axis=-1)
 
         self._forward_packed = profiled_jit(
             _forward_packed, name="distilbert_forward_packed",

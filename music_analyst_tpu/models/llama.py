@@ -552,24 +552,26 @@ class LlamaBlock(nn.Module):
             name="attention",
         )
         h = RMSNorm(epsilon=cfg.rms_norm_eps, name="attention_norm")(x)
-        if cache is not None:
-            attn_out, new_cache = attn(
-                h, mask=mask, positions=positions, cache=cache
-            )
-        else:
-            # Flash path: masking is fully described by flash_causal=True +
-            # lengths (+ optional packed-document segment_ids), so the
-            # (causal & padding) mask array stays out.  Dense callers fold
-            # segment masking into the mask array themselves.
-            attn_out = attn(
-                h,
-                mask=None if cfg.attn_impl == "flash" else mask,
-                positions=positions,
-                lengths=lengths,
-                segment_ids=(segment_ids if cfg.attn_impl == "flash"
-                             else None),
-            )
-            new_cache = None
+        with jax.named_scope("gqa"):
+            if cache is not None:
+                attn_out, new_cache = attn(
+                    h, mask=mask, positions=positions, cache=cache
+                )
+            else:
+                # Flash path: masking is fully described by
+                # flash_causal=True + lengths (+ optional packed-document
+                # segment_ids), so the (causal & padding) mask array stays
+                # out.  Dense callers fold segment masking into the mask
+                # array themselves.
+                attn_out = attn(
+                    h,
+                    mask=None if cfg.attn_impl == "flash" else mask,
+                    positions=positions,
+                    lengths=lengths,
+                    segment_ids=(segment_ids if cfg.attn_impl == "flash"
+                                 else None),
+                )
+                new_cache = None
         x = x + attn_out
         h = RMSNorm(epsilon=cfg.rms_norm_eps, name="ffn_norm")(x)
         if cfg.n_experts > 0 and cfg.routed_experts:
@@ -758,17 +760,18 @@ class LlamaModel(nn.Module):
         dtype = jnp.dtype(cfg.dtype)
         param_dtype = jnp.dtype(cfg.param_dtype)
         packed = None
-        if prefill_lengths is not None and runs_compact(
-                cfg, token_ids.shape, prefill_capacity):
-            from music_analyst_tpu.models.moe import RealPositions
+        with jax.named_scope("embed"):
+            if prefill_lengths is not None and runs_compact(
+                    cfg, token_ids.shape, prefill_capacity):
+                from music_analyst_tpu.models.moe import RealPositions
 
-            packed = RealPositions.of(prefill_lengths, token_ids.shape[1],
-                                      prefill_capacity)
-            token_ids = packed.gather(token_ids)[None]
-            positions = packed.gather(positions)[None]
-        x = nn.Embed(cfg.vocab_size, cfg.dim, dtype=dtype,
-                     param_dtype=param_dtype,
-                     name="tok_embeddings")(token_ids)
+                packed = RealPositions.of(
+                    prefill_lengths, token_ids.shape[1], prefill_capacity)
+                token_ids = packed.gather(token_ids)[None]
+                positions = packed.gather(positions)[None]
+            x = nn.Embed(cfg.vocab_size, cfg.dim, dtype=dtype,
+                         param_dtype=param_dtype,
+                         name="tok_embeddings")(token_ids)
         new_caches: List[KVCache] = []
         for i in range(cfg.n_layers):
             cache_i = caches[i] if caches is not None else None
@@ -1187,36 +1190,36 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
                 f"a label table {L} wide: the caches hold "
                 f"{MAX_LABEL_TOKENS} label slots")
         W = L - 1  # the positions a continuation runs
-        # prompt_lens may arrive int16 (wire narrowing) — widen once
-        # on device before the arithmetic/broadcast uses below.
-        prompt_lens = prompt_lens.astype(jnp.int32)
-        positions = jnp.arange(S)[None, :].repeat(B, 0)
-        # kv length is the cache buffer's, S + MAX_LABEL_TOKENS whatever
-        # the labels' width; the label slots are causally unreachable
-        # during prefill and masked out anyway.
-        kv_len = S + MAX_LABEL_TOKENS
-        mask = causal_mask(S, kv_len, 0) & jnp.pad(
-            padding_mask(prompt_lens, S),
-            ((0, 0), (0, 0), (0, 0), (0, MAX_LABEL_TOKENS)),
-        )
-        caches = init_caches(config, B, kv_len)
-        # last_position: only the final prompt logits are consumed, so
-        # the [B,S,V] prefill logits are never materialized.
-        (logits, caches), sown = model.apply(
-            {"params": params}, prompt_ids, positions, mask, caches,
-            last_position=prompt_lens - 1,
-            prefill_lengths=_prefill_lengths(mesh, prompt_lens),
-            prefill_capacity=prefill_capacity, row_lengths=prompt_lens,
-            mutable=["intermediates"],
-        )
-        stats = _routing_stats(sown, config)
-        if probe_rows is not None:
-            stats["probe"] = _probe(config, caches, probe_rows, S)
-        # Force every cache to report the true prompt length so label
-        # positions line up even though the buffer was written at 0..S.
-        caches = [c.with_length(S) for c in caches]
-        # token 0 of every label is scored from the prompt's last logits
-        first_logp = jax.nn.log_softmax(logits[:, 0], axis=-1)  # [B, V]
+        with jax.named_scope("prefill"):
+            # prompt_lens may arrive int16 (wire narrowing) — widen once
+            # on device before the arithmetic/broadcast uses below.
+            prompt_lens = prompt_lens.astype(jnp.int32)
+            positions = jnp.arange(S)[None, :].repeat(B, 0)
+            # kv length is the cache buffer's, S + MAX_LABEL_TOKENS
+            # whatever the labels' width; the label slots are causally
+            # unreachable during prefill and masked out anyway.
+            kv_len = S + MAX_LABEL_TOKENS
+            mask = causal_mask(S, kv_len, 0) & jnp.pad(
+                padding_mask(prompt_lens, S),
+                ((0, 0), (0, 0), (0, 0), (0, MAX_LABEL_TOKENS)),
+            )
+            caches = init_caches(config, B, kv_len)
+            # last_position: only the final prompt logits are consumed, so
+            # the [B,S,V] prefill logits are never materialized.
+            (logits, caches), sown = model.apply(
+                {"params": params}, prompt_ids, positions, mask, caches,
+                last_position=prompt_lens - 1,
+                prefill_lengths=_prefill_lengths(mesh, prompt_lens),
+                prefill_capacity=prefill_capacity, row_lengths=prompt_lens,
+                mutable=["intermediates"],
+            )
+            stats = _routing_stats(sown, config)
+            if probe_rows is not None:
+                stats["probe"] = _probe(config, caches, probe_rows, S)
+            # Force every cache to report the true prompt length so
+            # label positions line up even though the buffer was written
+            # at 0..S.
+            caches = [c.with_length(S) for c in caches]
 
         def score_one(label_row, label_len):
             lab = jnp.broadcast_to(label_row[None, :], (B, L))
@@ -1260,33 +1263,41 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
                 label_chosen,
             )
 
-        if config.recurrent_state:
-            # One label after the other: each continuation advances its own
-            # copy of every KDA layer's state, and under ``vmap`` the copies
-            # of all labels and layers are made up front (2.4 GB at 64 rows
-            # x 6 layers x 3 labels), beside the prefill's temporaries.
-            by_label, label_chosen = jax.lax.map(
-                lambda label: score_one(*label), (label_ids, label_lens))
-            scores = by_label.T
-        else:
-            scores, label_chosen = jax.vmap(
-                score_one, in_axes=(0, 0), out_axes=(1, 0)
-            )(label_ids, label_lens)
-        if label_chosen is None and "chosen" in stats:
-            # no continuation ran: nothing stated at the one label position
-            layers, _, _, k = stats["chosen"].shape
-            label_chosen = jnp.full((n_labels, layers, B, L, k), -1,
-                                    jnp.int32)
-        if label_chosen is not None:
-            stats["chosen_labels"] = label_chosen  # [3, layers, B, L, k]
-        if label_chosen is not None and config.experts_held is not None:
-            # of the label positions whose forward is read, the
-            # assignments to experts held here
-            first, count = config.experts_held
-            read = (jnp.arange(L)[None, :] < label_lens[:, None] - 1)
-            here = (label_chosen >= first) & (label_chosen < first + count)
-            stats["label_assignments_held"] = jnp.sum(
-                here & read[:, None, None, :, None])
+        with jax.named_scope("labels"):
+            # token 0 of every label is scored from the prompt's last
+            # logits
+            first_logp = jax.nn.log_softmax(logits[:, 0], axis=-1)  # [B, V]
+            if config.recurrent_state:
+                # One label after the other: each continuation advances its
+                # own copy of every KDA layer's state, and under ``vmap``
+                # the copies of all labels and layers are made up front
+                # (2.4 GB at 64 rows x 6 layers x 3 labels), beside the
+                # prefill's temporaries.
+                by_label, label_chosen = jax.lax.map(
+                    lambda label: score_one(*label),
+                    (label_ids, label_lens))
+                scores = by_label.T
+            else:
+                scores, label_chosen = jax.vmap(
+                    score_one, in_axes=(0, 0), out_axes=(1, 0)
+                )(label_ids, label_lens)
+            if label_chosen is None and "chosen" in stats:
+                # no continuation ran: nothing stated at the one label
+                # position
+                layers, _, _, k = stats["chosen"].shape
+                label_chosen = jnp.full((n_labels, layers, B, L, k), -1,
+                                        jnp.int32)
+            if label_chosen is not None:
+                stats["chosen_labels"] = label_chosen  # [3, layers, B, L, k]
+            if label_chosen is not None and config.experts_held is not None:
+                # of the label positions whose forward is read, the
+                # assignments to experts held here
+                first, count = config.experts_held
+                read = (jnp.arange(L)[None, :] < label_lens[:, None] - 1)
+                here = (label_chosen >= first) & (
+                    label_chosen < first + count)
+                stats["label_assignments_held"] = jnp.sum(
+                    here & read[:, None, None, :, None])
         return scores, stats  # [B, 3]
 
     return profiled_jit(_score_labels, name="llama_score_labels",

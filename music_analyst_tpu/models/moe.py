@@ -320,9 +320,10 @@ def _grouped_experts(xt, chosen, weights, gate_w, up_w, down_w,
     """``sum_k weights[t, k] * expert_{chosen[t, k]}(xt[t])`` for every
     token, no capacity: assignments sorted by expert, one
     ``jax.lax.ragged_dot`` a projection over the ragged groups, results
-    returned to token order and combined in float32.  ``xt [T, D]``,
-    ``chosen``/``weights [T, k]``, expert stacks ``[E, D, H]`` /
-    ``[E, H, D]``.  Returns ``[T, D]`` float32.
+    returned to token order and combined in float32 (scopes
+    ``moe.dispatch``, ``moe.matmul``, ``moe.combine``, inside the caller's
+    ``moe.experts``).  ``xt [T, D]``, ``chosen``/``weights [T, k]``, expert
+    stacks ``[E, D, H]`` / ``[E, H, D]``.  Returns ``[T, D]`` float32.
 
     A token whose ``chosen`` is the number of experts (one past the last)
     is a filler of a compact token set (:class:`RealPositions`): its
@@ -333,30 +334,34 @@ def _grouped_experts(xt, chosen, weights, gate_w, up_w, down_w,
     go where a filler's go and count as zero in the token's sum."""
     T, D = xt.shape
     k = chosen.shape[-1]
-    flat_expert = chosen.reshape(T * k)
-    order = jnp.argsort(flat_expert, stable=True)
-    group_sizes = jnp.zeros((gate_w.shape[0],), jnp.int32).at[
-        flat_expert].add(1, mode="drop")
-    xs = xt[order // k]                                           # [A, D]
-    gate = jax.lax.ragged_dot(xs, gate_w, group_sizes)
-    up = jax.lax.ragged_dot(xs, up_w, group_sizes)
-    ys = jax.lax.ragged_dot(nn.silu(gate) * up, down_w, group_sizes)
+    with jax.named_scope("moe.dispatch"):
+        flat_expert = chosen.reshape(T * k)
+        order = jnp.argsort(flat_expert, stable=True)
+        group_sizes = jnp.zeros((gate_w.shape[0],), jnp.int32).at[
+            flat_expert].add(1, mode="drop")
+        xs = xt[order // k]                                       # [A, D]
+    with jax.named_scope("moe.matmul"):
+        gate = jax.lax.ragged_dot(xs, gate_w, group_sizes)
+        up = jax.lax.ragged_dot(xs, up_w, group_sizes)
+        ys = jax.lax.ragged_dot(nn.silu(gate) * up, down_w, group_sizes)
     # back to assignment order (token-major), weighted sum in float32
-    if partial:
-        # one choice at a time: three quarters of the assignments are other
-        # chips' at four chips a layer, and ``[T, k, D]`` in float32 is the
-        # step's largest array by far (2 GB at 24,576 slots x 8 x 2,560)
-        back = jnp.argsort(order).reshape(T, k)
-        here = chosen < gate_w.shape[0]
-        out = jnp.zeros((T, D), jnp.float32)
-        for j in range(k):
-            out = out + jnp.where(
-                here[:, j, None],
-                ys[back[:, j]].astype(jnp.float32)
-                * weights[:, j, None].astype(jnp.float32), 0.0)
-        return out
-    y = ys[jnp.argsort(order)].reshape(T, k, D).astype(jnp.float32)
-    return jnp.einsum("tkd,tk->td", y, weights.astype(jnp.float32))
+    with jax.named_scope("moe.combine"):
+        if partial:
+            # one choice at a time: three quarters of the assignments are
+            # other chips' at four chips a layer, and ``[T, k, D]`` in
+            # float32 is the step's largest array by far (2 GB at 24,576
+            # slots x 8 x 2,560)
+            back = jnp.argsort(order).reshape(T, k)
+            here = chosen < gate_w.shape[0]
+            out = jnp.zeros((T, D), jnp.float32)
+            for j in range(k):
+                out = out + jnp.where(
+                    here[:, j, None],
+                    ys[back[:, j]].astype(jnp.float32)
+                    * weights[:, j, None].astype(jnp.float32), 0.0)
+            return out
+        y = ys[jnp.argsort(order)].reshape(T, k, D).astype(jnp.float32)
+        return jnp.einsum("tkd,tk->td", y, weights.astype(jnp.float32))
 
 
 def _batched_over_tokens(partial: bool):

@@ -46,11 +46,12 @@ def _token_histogram(ids: jax.Array, vocab_size: int) -> jax.Array:
     One fused masked scatter-add; int32 counts (the per-word corpus bound is
     well under 2^31 even for the 1M-song dataset).
     """
-    valid = ids >= 0
-    clipped = jnp.where(valid, ids, 0)
-    return jnp.zeros((vocab_size,), jnp.int32).at[clipped].add(
-        valid.astype(jnp.int32), mode="drop"
-    )
+    with jax.named_scope("histogram"):
+        valid = ids >= 0
+        clipped = jnp.where(valid, ids, 0)
+        return jnp.zeros((vocab_size,), jnp.int32).at[clipped].add(
+            valid.astype(jnp.int32), mode="drop"
+        )
 
 
 token_histogram = profiled_jit(
@@ -276,7 +277,8 @@ def _stream_accum(mesh: Mesh, axis: str, padded_vocab: int):
     independent until the final ``_psum_rows`` merge."""
 
     def local(hist, ids):
-        return hist + _token_histogram(ids, padded_vocab)[None, :]
+        with jax.named_scope("histogram"):
+            return hist + _token_histogram(ids, padded_vocab)[None, :]
 
     return profiled_jit(
         shard_map(

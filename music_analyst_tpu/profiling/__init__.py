@@ -6,7 +6,10 @@ Four pieces (PERFORMANCE.md §"Profiling a run"):
   lower/compile boundary: per-compile ``cost_analysis()`` FLOPs/bytes,
   ``memory_analysis()``, an HLO fingerprint, and a recompile detector
   keyed on abstract avals (``profiling.compiles`` / ``.recompiles``
-  counters + a ``compile``/``recompile`` event per occurrence).
+  counters + a ``compile``/``recompile`` event per occurrence);
+  :func:`op_scopes`, derived when asked: each instruction of every
+  executable held, with the ``jax.named_scope`` path it was traced under
+  (what names the operations of a device trace).
 * ``profiling/collectives.py`` — analytic per-step byte estimates for
   ``psum`` / ``all_gather`` / all-to-all / ``ppermute`` from mesh shape
   + payload shape (``collectives.*_bytes`` counters + one ``collective``
@@ -48,13 +51,16 @@ __all__ = [
     "run_profile_diff",
     "profiled_jit",
     "compile_records",
+    "op_scopes",
 ]
 
 
 def __getattr__(name):
-    # profiled_jit/compile_records live in a jax-importing module; resolve
-    # them lazily so `import music_analyst_tpu.profiling` stays jax-free.
-    if name in ("profiled_jit", "compile_records", "ProfiledFunction"):
+    # profiled_jit/compile_records/op_scopes live in a jax-importing module;
+    # resolve them lazily so `import music_analyst_tpu.profiling` stays
+    # jax-free.
+    if name in ("profiled_jit", "compile_records", "op_scopes",
+                "ProfiledFunction"):
         from music_analyst_tpu.profiling import compile as _compile
 
         return getattr(_compile, name)

@@ -1,18 +1,34 @@
 """Compile-boundary introspection: :func:`profiled_jit`.
 
 Wraps the jit lower/compile boundary the engines use so every compiled
-program records what it costs before it ever runs:
+program records what it costs before it ever runs.
+
+**Recorded at every compile** (a :class:`CompileRecord`: the ``compile``
+event, :func:`compile_records`, the manifest's ``profiling`` section):
 
 * ``cost_analysis()`` — FLOPs and bytes-accessed per execution,
 * ``memory_analysis()`` — temp/argument/output allocation bytes (TPU
   backends implement it; CPU returns nothing and the field stays null),
 * an HLO fingerprint (sha256 of the lowered StableHLO text) so two runs
-  can prove they executed the same program, and
+  can prove they executed the same program,
+* the compile's seconds, a weight-quantized call's parameter bytes, and the
+  ``attention_paths`` / ``traced_paths`` noted while the program was traced
+  (:func:`note_attention_path`, :func:`note_traced_path`), and
 * a **recompile detector**: calls are keyed on their abstract avals
   (shape/dtype of every array leaf + values of everything static); a new
   key after the first compile bumps ``profiling.recompiles`` and emits a
   ``recompile`` event naming the offending shape change — the telemetry
   answer to "why is this run spending its wall-clock in XLA".
+
+**Derived when asked, and kept** (nothing at compile time, nothing in a
+record, so no manifest or event grows and a run nobody traces pays
+nothing):
+
+* :func:`op_scopes` — for every executable this process holds, each
+  instruction of the optimised program with the ``jax.named_scope`` path it
+  was traced under, read out of ``compiled.as_text()``: what names the
+  operations of a device trace (``profile_run`` writes it as
+  ``op_scopes.json``; ``perfbench/scope_reduce.py`` sums a trace by it).
 
 The wrapper is a fallback-safe veneer over ``jax.jit``: the AOT
 ``lower(...).compile()`` path feeds the records, and any AOT-ineligible
@@ -25,9 +41,10 @@ never waits on a result (synchronisation stays the caller's readback).
 from __future__ import annotations
 
 import hashlib
+import re
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 
@@ -126,6 +143,136 @@ def _scalar(analysis: Any, key: str) -> Optional[float]:
         return None
 
 
+# ``  [ROOT ]%name = <shape> opcode(operands), attr=..., metadata={...}``
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?(\S+) = (.*)$")
+_COMPUTATION = re.compile(r"^(ENTRY )?%?(\S+) \(.*\{$")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_OPCODE = re.compile(r"\s*([\w\-]+)\(")
+_OPERAND = re.compile(r"%([^\s,()]+)")
+# The instructions whose computations run as operations of their own (a
+# fused computation is one operation on the chip: not followed).
+_FOLLOWED = ("while", "conditional", "call")
+_CALLEE = re.compile(
+    r"\b(?:condition|body|true_computation|false_computation|to_apply)"
+    r"=%?([^\s,}]+)")
+_BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+
+
+def _balanced(text: str) -> int:
+    """Where the parenthesis that opens ``text`` closes (-1: nowhere)."""
+    depth = 0
+    for i, char in enumerate(text):
+        depth += (char == "(") - (char == ")")
+        if depth == 0:
+            return i
+    return -1
+
+
+def _opcode_and_operands(rest: str) -> Tuple[str, List[str]]:
+    """From an instruction's text behind ``name = ``: skip the result shape
+    (one word, or a tuple in parentheses), then ``opcode(%a, %b)``."""
+    behind = (rest[_balanced(rest) + 1:] if rest.startswith("(")
+              else rest.partition(" ")[2])
+    found = _OPCODE.match(behind)
+    if not found:
+        return "", []
+    operands = behind[found.end() - 1:]
+    return found.group(1), _OPERAND.findall(
+        operands[:_balanced(operands) + 1])
+
+
+def _computations(text: str):
+    """``(module name, entry computation, {computation: [(instruction,
+    its text behind "name = ")]})`` of a module's text."""
+    module = ""
+    computations: Dict[str, List[Tuple[str, str]]] = {}
+    entry, current = None, None
+    for line in text.splitlines():
+        if line.startswith("HloModule "):
+            module = line.split()[1].rstrip(",")
+            continue
+        head = _COMPUTATION.match(line)
+        if head:
+            current = computations.setdefault(head.group(2), [])
+            if head.group(1):
+                entry = head.group(2)
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        found = _INSTRUCTION.match(line) if current is not None else None
+        if found:
+            current.append((found.group(1), found.group(2)))
+    return module, entry, computations
+
+
+def _is_path(op_name: str) -> bool:
+    """Whether an ``op_name`` is the path an operation was traced under
+    (every program here is a ``jax.jit``'s) and not an argument's name or a
+    name of XLA's own."""
+    return op_name.startswith("jit(")
+
+
+def hlo_op_scopes(text: str) -> Tuple[str, Dict[str, List[Optional[str]]]]:
+    """``(module name, {instruction: [op_name, enclosing instruction]})``
+    from an optimised module's text (``compiled.as_text()``): the
+    instructions of the entry computation and of every computation reached
+    from it as a ``while`` body or condition, a ``conditional`` branch or a
+    ``call``.  The enclosing instruction is the ``while`` / ``conditional``
+    / ``call`` whose computation holds it, ``None`` in the entry.
+
+    ``op_name`` is whole as XLA printed it where that is the path the
+    instruction was traced under (``jit(f)/prefill/mla/dot_general``).
+    Where XLA printed none (the copies it adds, tuples) or a name of its
+    own (the TPU compiler's grouped-matmul kernels are all
+    ``ragged-dot-none``), the instruction stands under the path of its
+    first operand that has one, else of its enclosing instruction, else of
+    its first user (a weight's relayout before the projection that reads
+    it), with XLA's own name, if any, as the last component
+    (``jit(f)/.../moe.dispatch/gather/ragged-dot-none``).  A parameter, and
+    what nothing leads to a path from, keeps what XLA printed."""
+    module, entry, computations = _computations(text)
+    ops: Dict[str, List[Optional[str]]] = {}
+    users: Dict[str, List[str]] = {}
+    parameters = set()  # the entry's: they keep their argument's name
+
+    def under(name: str, others: List[Optional[str]]) -> None:
+        paths = [ops[o][0] for o in others
+                 if o in ops and _is_path(ops[o][0])]
+        if paths and not _is_path(ops[name][0]):
+            ops[name][0] = "/".join(filter(None, (paths[0], ops[name][0])))
+
+    todo = [(entry, None)] if entry is not None else []
+    seen = set()
+    while todo:
+        computation, enclosing = todo.pop()
+        if computation in seen:
+            continue
+        seen.add(computation)
+        for name, rest in computations.get(computation, ()):
+            printed = _OP_NAME.search(rest)
+            opcode, operands = _opcode_and_operands(rest)
+            ops[name] = [printed.group(1) if printed else "", enclosing]
+            if opcode == "parameter" and enclosing is None:
+                parameters.add(name)
+                continue
+            # operands stand before their users in the text
+            under(name, operands + [enclosing])
+            for operand in operands:
+                users.setdefault(operand, []).append(name)
+            if opcode not in _FOLLOWED:
+                continue
+            callees = _CALLEE.findall(rest)
+            for branches in _BRANCHES.findall(rest):
+                callees += [b.strip().lstrip("%")
+                            for b in branches.split(",")]
+            todo += [(callee, name) for callee in callees]
+    for name in reversed(list(ops)):  # users first, then their operands
+        if name not in parameters:
+            under(name, users.get(name, []))
+    return module, ops
+
+
 class CompileRecord:
     """One compiled program's cost/memory/fingerprint digest."""
 
@@ -187,6 +334,7 @@ class ProfiledFunction:
             (static,) if isinstance(static, str) else static)
         self._lock = threading.Lock()
         self._compiled: Dict[str, Any] = {}  # aval_key -> executable | None
+        self._op_scopes: Dict[str, Any] = {}  # aval_key -> op_scopes() entry
         self.records: Dict[str, CompileRecord] = {}
         with _REGISTRY_LOCK:
             _REGISTRY.append(self)
@@ -312,6 +460,24 @@ class ProfiledFunction:
                     self._compiled[key] = None
         return self._jit(*args, **kwargs)
 
+    def op_scopes(self) -> List[Dict[str, Any]]:
+        """This function's entries of :func:`op_scopes`, one a compiled
+        shape; an executable's text is read once."""
+        with self._lock:
+            compiled = dict(self._compiled)
+        out = []
+        for key, executable in compiled.items():
+            if executable is None:  # fell back to plain jit: no text
+                out.append({"fn": self.name, "aval_key": key,
+                            "module": None, "ops": None})
+                continue
+            if key not in self._op_scopes:
+                module, ops = hlo_op_scopes(executable.as_text())
+                self._op_scopes[key] = {"fn": self.name, "aval_key": key,
+                                        "module": module, "ops": ops}
+            out.append(self._op_scopes[key])
+        return out
+
     # Parity helpers so a ProfiledFunction drops in where jax.jit was.
     def lower(self, *args: Any, **kwargs: Any):
         return self._jit.lower(*args, **kwargs)
@@ -352,4 +518,28 @@ def compile_records() -> List[Dict[str, Any]]:
     out: List[Dict[str, Any]] = []
     for fn in fns:
         out.extend(rec.as_dict() for rec in fn.records.values())
+    return out
+
+
+def op_scopes() -> List[Dict[str, Any]]:
+    """Which scope each device operation belongs to, for every executable
+    :func:`profiled_jit` holds in this process: a list of ``{"fn", "aval_key",
+    "module", "ops"}``.  ``module`` is the program's name as a device trace
+    prints it (``HloModule jit__score_labels`` -> ``jit__score_labels``) and
+    ``ops`` is :func:`hlo_op_scopes`'s ``{instruction: [op_name, enclosing
+    instruction or None]}``.  A shape that fell back to plain ``jit``
+    (``compile_introspection_failed``) is listed with ``module`` and ``ops``
+    ``None``: its time in a trace is unmapped, not absent.
+
+    Computed from ``compiled.as_text()`` when asked, not when compiling,
+    and kept: a run that nobody traces never pays for it.  The paths are
+    the executable's own: one loaded from the persistent compilation cache
+    (its key leaves metadata out) carries the scopes of the tree that
+    compiled it.
+    """
+    with _REGISTRY_LOCK:
+        fns = list(_REGISTRY)
+    out: List[Dict[str, Any]] = []
+    for fn in fns:
+        out.extend(fn.op_scopes())
     return out

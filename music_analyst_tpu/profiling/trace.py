@@ -11,7 +11,10 @@ removed in PR 4).  Two granularities:
   a profile that was asked for and silently not taken is worse than no
   run) **and** renders this run's telemetry spans into
   ``<dir>/trace_spans.json``, a self-contained Chrome-trace artifact
-  (``chrome://tracing`` / Perfetto).
+  (``chrome://tracing`` / Perfetto), and, beside a device trace,
+  ``<dir>/op_scopes.json``: which ``jax.named_scope`` path each device
+  operation of each compiled program was traced under
+  (``profiling.compile.op_scopes``), to read the trace by scope.
 
 Timing discipline: wall timings everywhere end in a host readback
 (``np.asarray``) at the engines' sync points — the host needs the bytes
@@ -104,7 +107,9 @@ def profile_run(
     touch the backend (the replica-router parent — starting the profiler
     initialises every backend, and only the process that holds a chip can
     trace it); it still gets the span-level ``trace_spans.json``, which
-    is rendered purely from host-side telemetry.
+    is rendered purely from host-side telemetry.  A device trace gets
+    ``op_scopes.json`` beside it: the trace names operations as XLA does
+    (``fusion.315``), the file says under which scope each was traced.
     """
     if not profile_dir:
         yield
@@ -123,6 +128,11 @@ def profile_run(
         try:
             if device_trace:
                 jax.profiler.stop_trace()
+                from music_analyst_tpu.profiling.compile import op_scopes
+
+                with open(os.path.join(profile_dir, "op_scopes.json"), "w",
+                          encoding="utf-8") as fh:
+                    json.dump(op_scopes(), fh)
         finally:
             write_chrome_trace(
                 tel, os.path.join(profile_dir, "trace_spans.json")

@@ -319,11 +319,18 @@ def _grouped_experts(xt, chosen, weights, gate_w, up_w, down_w,
                      partial: bool):
     """``sum_k weights[t, k] * expert_{chosen[t, k]}(xt[t])`` for every
     token, no capacity: assignments sorted by expert, one
-    ``jax.lax.ragged_dot`` a projection over the ragged groups, results
-    returned to token order and combined in float32 (scopes
-    ``moe.dispatch``, ``moe.matmul``, ``moe.combine``, inside the caller's
-    ``moe.experts``).  ``xt [T, D]``, ``chosen``/``weights [T, k]``, expert
-    stacks ``[E, D, H]`` / ``[E, H, D]``.  Returns ``[T, D]`` float32.
+    ``jax.lax.ragged_dot`` a projection over the ragged groups, and the
+    results back in token order one choice at a time: ``k`` gathers of
+    the down projection's bfloat16 rows, each row converted to float32,
+    multiplied by its float32 weight and added in float32 in ONE fusion
+    behind the gathers (scopes ``moe.dispatch``, ``moe.matmul``,
+    ``moe.combine``, inside the caller's ``moe.experts``).  No ``[T, k, D]``
+    float32 array exists for any ``k``: it would be the step's largest by
+    far (2 GB at 24,576 slots x 8 x 2,560), and where ``k`` is no multiple
+    of the float32 tile's 8 rows the TPU pads it and lays it out anew
+    besides (6 to 8: 0.8 GB written and read a layer at 12,288 x 6 x
+    2,048).  ``xt [T, D]``, ``chosen``/``weights [T, k]``, expert stacks
+    ``[E, D, H]`` / ``[E, H, D]``.  Returns ``[T, D]`` float32.
 
     A token whose ``chosen`` is the number of experts (one past the last)
     is a filler of a compact token set (:class:`RealPositions`): its
@@ -331,7 +338,8 @@ def _grouped_experts(xt, chosen, weights, gate_w, up_w, down_w,
     expert multiplies them, and its row of the result is undefined.
     ``partial`` (:func:`grouped_experts_held`): single assignments may name
     that one-past-the-last expert too (an expert another chip holds); they
-    go where a filler's go and count as zero in the token's sum."""
+    go where a filler's go and the sum masks them (``chosen < E``): each
+    counts as exactly zero in its token's sum, whatever its row holds."""
     T, D = xt.shape
     k = chosen.shape[-1]
     with jax.named_scope("moe.dispatch"):
@@ -344,24 +352,22 @@ def _grouped_experts(xt, chosen, weights, gate_w, up_w, down_w,
         gate = jax.lax.ragged_dot(xs, gate_w, group_sizes)
         up = jax.lax.ragged_dot(xs, up_w, group_sizes)
         ys = jax.lax.ragged_dot(nn.silu(gate) * up, down_w, group_sizes)
-    # back to assignment order (token-major), weighted sum in float32
     with jax.named_scope("moe.combine"):
+        back = jnp.argsort(order).reshape(T, k)
         if partial:
-            # one choice at a time: three quarters of the assignments are
-            # other chips' at four chips a layer, and ``[T, k, D]`` in
-            # float32 is the step's largest array by far (2 GB at 24,576
-            # slots x 8 x 2,560)
-            back = jnp.argsort(order).reshape(T, k)
             here = chosen < gate_w.shape[0]
-            out = jnp.zeros((T, D), jnp.float32)
-            for j in range(k):
-                out = out + jnp.where(
-                    here[:, j, None],
-                    ys[back[:, j]].astype(jnp.float32)
-                    * weights[:, j, None].astype(jnp.float32), 0.0)
-            return out
-        y = ys[jnp.argsort(order)].reshape(T, k, D).astype(jnp.float32)
-        return jnp.einsum("tkd,tk->td", y, weights.astype(jnp.float32))
+
+        def weighted(j):
+            return (ys[back[:, j]].astype(jnp.float32)
+                    * weights[:, j, None].astype(jnp.float32))
+
+        out = jnp.zeros((T, D), jnp.float32)
+        for j in range(k):
+            if partial:
+                out = out + jnp.where(here[:, j, None], weighted(j), 0.0)
+            else:
+                out = out + weighted(j)
+        return out
 
 
 def _batched_over_tokens(partial: bool):
@@ -418,9 +424,11 @@ class RoutedMoE(nn.Module):
     three projections is one grouped matrix multiplication over the ragged
     groups (``jax.lax.ragged_dot``: on the TPU XLA lowers it to its grouped
     matmul kernel, the work is ``top_k`` experts a token however uneven the
-    routing).  The results go back to token order and are combined with the
-    router's weights in float32.  ``n_shared`` shared experts are one
-    SwiGLU of width ``n_shared * hidden_dim``.
+    routing).  The results go back to token order one choice at a time
+    (``top_k`` gathers of bfloat16 rows) and are combined with the router's
+    weights in one fused float32 multiply-add: no ``[T, top_k, D]`` array
+    lies between (:func:`grouped_experts`).  ``n_shared`` shared experts
+    are one SwiGLU of width ``n_shared * hidden_dim``.
 
     Given ``compact`` (a prefill that declared its rows' lengths:
     ``models/llama.LlamaBlock._feed_forward``), router, sort, grouped

@@ -366,3 +366,80 @@ def test_packed_experts_take_and_return_the_token_set_itself(
         {"params": params["params"]["shared_experts"]}, stream[0, n_real:])
     np.testing.assert_allclose(np.asarray(got[0, n_real:]),
                                np.asarray(shared), rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("partial", [False, True], ids=["whole", "held"])
+@pytest.mark.parametrize("top_k", [2, 6, 8])
+def test_grouped_rows_return_to_token_order_and_are_summed_in_float32(
+        top_k, partial, monkeypatch):
+    """The way back from the grouped matmuls, one form for every
+    ``top_k``: a real token's result is ``sum_j w[t, j] * expert(x[t])``
+    over the choices held here, each expert's bfloat16 row taken to
+    float32 and weighted and added there, as a plain per-token loop gives
+    it.  Rows of the grouped matmuls that belong to no group are undefined:
+    they are NaN here, and with ``partial`` an assignment to an absent
+    expert still counts as exactly zero (a token with none held comes out
+    zero), while a filler's own row of the whole layer's form stays
+    undefined."""
+    import flax.linen as nn
+
+    from music_analyst_tpu.models import moe
+
+    n_experts, dim, hidden, tokens, fillers = 16, 16, 8, 37, 5
+
+    def few_bits(key, shape, scale):
+        # quarter steps: every matmul's float32 sum is exact in any order,
+        # so its bfloat16 row is the same one row at a time
+        return (jnp.round(jax.random.normal(key, shape) * scale * 4) / 4
+                ).astype(jnp.bfloat16)
+
+    keys = jax.random.split(jax.random.key(top_k), 7)
+    x = few_bits(keys[0], (tokens, dim), 1.0)
+    gate_w = few_bits(keys[1], (n_experts, dim, hidden), 0.5)
+    up_w = few_bits(keys[2], (n_experts, dim, hidden), 0.5)
+    down_w = few_bits(keys[3], (n_experts, hidden, dim), 0.5)
+    chosen = jnp.argsort(jax.random.uniform(keys[4], (tokens, n_experts)),
+                         axis=-1)[:, :top_k].astype(jnp.int32)
+    if partial:  # about a third of the assignments are another chip's
+        absent = jax.random.uniform(keys[5], chosen.shape) < 0.35
+        absent = absent.at[0].set(True).at[1, 1:].set(True)
+        chosen = jnp.where(absent, n_experts, chosen)
+    chosen = chosen.at[tokens - fillers:].set(n_experts)
+    weights = jax.random.uniform(keys[6], (tokens, top_k), jnp.float32,
+                                 0.05, 1.0)
+
+    ragged_dot = jax.lax.ragged_dot
+
+    def undefined_behind_the_groups(lhs, rhs, group_sizes, **kwargs):
+        out = ragged_dot(lhs, rhs, group_sizes, **kwargs)
+        grouped = jnp.arange(lhs.shape[0]) < group_sizes.sum()
+        return jnp.where(grouped[:, None], out, jnp.nan)
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", undefined_behind_the_groups)
+    grouped = moe.grouped_experts_held if partial else moe.grouped_experts
+    got = np.asarray(grouped(x, chosen, weights, gate_w, up_w, down_w))
+    assert got.shape == (tokens, dim) and got.dtype == np.float32
+
+    def expert_row(t, e):
+        gate, up = jnp.dot(x[t], gate_w[e]), jnp.dot(x[t], up_w[e])
+        assert gate.dtype == jnp.bfloat16
+        return np.asarray(jnp.dot(nn.silu(gate) * up, down_w[e]), np.float32)
+
+    chosen, weights = np.asarray(chosen), np.asarray(weights)
+    real = tokens - fillers
+    for t in range(real):
+        want, size = np.zeros(dim, np.float32), np.zeros(dim, np.float32)
+        for j in range(top_k):
+            if chosen[t, j] < n_experts:
+                term = weights[t, j] * expert_row(t, chosen[t, j])
+                want, size = want + term, size + np.abs(term)
+        assert np.isfinite(got[t]).all()
+        # the order of the k float32 additions is free, nothing else is
+        assert (np.abs(got[t] - want) <= top_k * 2.0 ** -23 * size).all()
+    assert np.abs(got[:real]).max() > 0.1
+    if partial:
+        assert (got[0] == 0).all() and (got[real:] == 0).all()
+        held_once = weights[1, 0] * expert_row(1, chosen[1, 0])
+        assert (got[1] == held_once).all()
+    else:
+        assert np.isnan(got[real:]).all()

@@ -21,10 +21,15 @@ cell's step (64 x 1,024, 32 heads of 128 | 128; compact and padded) and the
 whole scoring step of that cell; flash attention
 under the block-causal rule at the diffusion cell's prefill (32 x 1,024, 32
 query heads on 4 key heads of 128) and both programs of that cell's step.
+One compile holds no kernel of ours: the grouped experts of a routed layer
+(``models/moe.grouped_experts``, XLA's own grouped matmul) at the decoder
+cells' compact token set, with 6 and with 8 choices a token, for what XLA
+puts between the kernels' result and the weighted sum.
 """
 
 from __future__ import annotations
 
+import math
 import re
 
 import jax
@@ -60,13 +65,14 @@ def tpu_sharding():
     return SingleDeviceSharding(topology.devices[0])
 
 
-def _compile_for_tpu(fn, sharding, *shapes):
+def _compile_for_tpu(fn, sharding, *shapes, mosaic=True):
     args = [
         jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
         for shape, dtype in shapes
     ]
     lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
-    assert "tpu_custom_call" in lowered.as_text()  # Mosaic, not interpreted
+    if mosaic:  # a kernel of ours: Mosaic, not interpreted
+        assert "tpu_custom_call" in lowered.as_text()
     return lowered.compile()
 
 
@@ -275,6 +281,50 @@ def test_projections_feed_the_prefill_kernel_without_a_copy(
     assert readers and set(readers) <= {"bitcast", "fusion"}, readers
 
 
+def _largest_float32(program: str) -> int:
+    """Elements of the largest float32 array the compiled text names,
+    a fusion's inner values among them."""
+    return max(math.prod(map(int, dims.split(",")))
+               for dims in re.findall(r"\bf32\[([\d,]+)\]", program))
+
+
+@pytest.mark.parametrize("top_k", [6, 8])
+def test_grouped_results_return_to_token_order_with_no_float32_copy(
+    tpu_sharding, top_k
+):
+    """``models/moe.grouped_experts`` at a routed layer of the decoder
+    cells' prefill (12,288 token slots, 128 experts of 2,048 x 768; 6
+    choices a token as ``kanana-2-30b-a3b``, 8 as ``sdar-30b-a3b-chat``)
+    compiled for a v5e.  The down projection's rows come back one choice
+    at a time, ``top_k`` bfloat16 gathers of ``[T, D]``, and one fusion
+    sums them in float32: no float32 array of ``T x top_k x D`` elements
+    exists (at 6 choices the TPU padded it to 8 and laid it out anew,
+    0.8 GB a layer), and the temporaries are the sorted rows in bfloat16
+    twice over (the kernels' result and its gathered copy), 0.55 GB at 6
+    choices where that array made them 1.1 GB."""
+    from music_analyst_tpu.models.moe import grouped_experts
+
+    tokens, dim, hidden, experts = 12288, 2048, 768, 128
+    compiled = _compile_for_tpu(
+        grouped_experts, tpu_sharding,
+        ((tokens, dim), jnp.bfloat16), ((tokens, top_k), jnp.int32),
+        ((tokens, top_k), jnp.float32),
+        ((experts, dim, hidden), jnp.bfloat16),
+        ((experts, dim, hidden), jnp.bfloat16),
+        ((experts, hidden, dim), jnp.bfloat16), mosaic=False)
+    text = compiled.as_text()
+    assert "ragged-dot-none" in text            # XLA's grouped matmul
+    # not even inside a fusion (the widest is the activation's, which
+    # never leaves its fusion: ``tokens x top_k x hidden``)
+    assert _largest_float32(text) < tokens * top_k * dim
+    back = re.findall(
+        r"= bf16\[(\d+),(\d+)\]\S* fusion\(.*moe\.combine/gather", text)
+    assert back == [(str(tokens), str(dim))] * top_k, back
+    sorted_rows = tokens * top_k * dim * 2      # bfloat16
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        2 * sorted_rows + 64 * 2 ** 20)
+
+
 def _traced(fn):
     """``{path: layers}`` the traces under ``fn`` noted
     (``profiling.compile.note_traced_path``)."""
@@ -356,8 +406,12 @@ def test_compact_scoring_step_keeps_the_kernel_fed_and_no_padded_position(
     assert shapes == {
         f"bf16[{capacity * top_k},{n}]" for n in (768, 2048)} | {
         f"bf16[{3 * rows * top_k},{n}]" for n in (768, 2048)}, shapes
-    # 1.45 and 1.8 GB of temporaries where the padded step has 3.4
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+    # the experts' results return to token order with no float32 copy of
+    # the ``capacity x top_k`` sorted rows, in a fusion or out of one
+    assert _largest_float32(text) < capacity * top_k * config.dim
+    # 0.99 and 1.29 GB of temporaries (1.45 and 1.8 with that copy; the
+    # padded step has 3.4)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
     calls = re.findall(
         r"^\s*%(_packed_prefill_call[.\d]*) = \S+ custom-call\(([^)]*)\)",

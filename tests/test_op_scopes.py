@@ -38,6 +38,24 @@ def _lyrics(rows: int = 6):
             for n in rng.integers(5, 300, size=rows)]
 
 
+@pytest.fixture(scope="module", autouse=True)
+def compiled_here():
+    """Every test of this file reads scopes out of a compiled program's
+    text, so every program it reads is compiled by it: a persistent
+    compile cache (``utils/cache.py``: any test that enters through the
+    CLI turns on ``<repo>/.jax_cache`` for its whole worker) keys an entry
+    with the metadata left out, and hands back the executable of whichever
+    tree wrote it, with that tree's scopes in its text."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
 @pytest.fixture
 def text_reads(monkeypatch):
     """The calls of ``compiled.as_text()`` made while the test runs."""

@@ -27,6 +27,7 @@ _SUITE_MODULES = (
     "benchmarks.flash_sweep",
     "benchmarks.mla_prefill",
     "benchmarks.kda_prefill",
+    "benchmarks.ssd_prefill",
     "benchmarks.generation",
     "benchmarks.coldstart",
     "benchmarks.ingest",

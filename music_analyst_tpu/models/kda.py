@@ -160,6 +160,38 @@ def causal_conv(u, weight, history=None, positions=None):
     return out
 
 
+def conv_tails(before, history, lengths, packed, keep: int):
+    """The last ``keep`` pre-convolution inputs of every row after a call's
+    tokens, the streams of ``before`` (a list of ``[B, T, W]``, or ``[1, C,
+    W]`` on the compact stream ``packed``) side by side on the last axis;
+    ``history [B, keep, sum W]`` holds what came before the call (zeros
+    without), ``lengths [B]`` how many of the call's tokens exist a row
+    (all of them without)."""
+    back = jnp.arange(keep, dtype=jnp.int32)
+    if packed is not None:
+        lens = lengths.astype(jnp.int32)
+        offset = lens[:, None] - keep + back[None, :]       # [B, K-1]
+        slot = jnp.clip(packed.start[:, None] + offset, 0,
+                        before[0].shape[1] - 1)
+        tails = jnp.concatenate([u[0][slot] for u in before], axis=-1)
+        return jnp.where((offset >= 0)[..., None], tails,
+                         jnp.zeros((), tails.dtype))
+    batch, n_tok = before[0].shape[:2]
+    count = (jnp.full((batch,), n_tok, jnp.int32) if lengths is None
+             else lengths.astype(jnp.int32))
+    at = (count[:, None] + back[None, :])[..., None]
+    width = before[0].shape[2]
+    tails = []
+    for i, u in enumerate(before):
+        past = (jnp.zeros((batch, keep, width), u.dtype)
+                if history is None
+                else history[..., i * width:(i + 1) * width
+                             ].astype(u.dtype))
+        tails.append(jnp.take_along_axis(
+            jnp.concatenate([past, u], axis=1), at, axis=1))
+    return jnp.concatenate(tails, axis=-1)
+
+
 class KimiDeltaAttention(nn.Module):
     """The KDA mixer.  Returns ``out`` without a state, ``(out, new_state)``
     with one, as ``MLAttention`` does with its cache."""
@@ -287,32 +319,5 @@ class KimiDeltaAttention(nn.Module):
         if state is None:
             return out
         return out, RecurrentState(
-            new_state, self._tails(before, history, lengths, packed))
-
-    def _tails(self, before, history, lengths, packed):
-        """The last ``K - 1`` pre-convolution inputs ``[q~ | k~ | v~]`` of
-        every row after this call's tokens."""
-        keep = self.conv_kernel - 1
-        back = jnp.arange(keep, dtype=jnp.int32)
-        if packed is not None:
-            lens = lengths.astype(jnp.int32)
-            offset = lens[:, None] - keep + back[None, :]       # [B, K-1]
-            slot = jnp.clip(packed.start[:, None] + offset, 0,
-                            before[0].shape[1] - 1)
-            tails = jnp.concatenate([u[0][slot] for u in before], axis=-1)
-            return jnp.where((offset >= 0)[..., None], tails,
-                             jnp.zeros((), tails.dtype))
-        batch, n_tok = before[0].shape[:2]
-        count = (jnp.full((batch,), n_tok, jnp.int32) if lengths is None
-                 else lengths.astype(jnp.int32))
-        at = (count[:, None] + back[None, :])[..., None]
-        width = before[0].shape[2]
-        tails = []
-        for i, u in enumerate(before):
-            past = (jnp.zeros((batch, keep, width), u.dtype)
-                    if history is None
-                    else history[..., i * width:(i + 1) * width
-                                 ].astype(u.dtype))
-            tails.append(jnp.take_along_axis(
-                jnp.concatenate([past, u], axis=1), at, axis=1))
-        return jnp.concatenate(tails, axis=-1)
+            new_state, conv_tails(before, history, lengths, packed,
+                                  self.conv_kernel - 1))

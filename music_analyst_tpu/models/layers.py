@@ -96,11 +96,13 @@ def dot_product_attention(
     k: jax.Array,
     v: jax.Array,
     mask: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Plain attention ``[B, S, H, D]`` with fp32 softmax accumulation.
 
     Grouped-query support: when ``k``/``v`` carry fewer heads than ``q``,
     KV heads are broadcast over the query-head groups (Llama-3 GQA).
+    ``scale`` multiplies the scores (``None`` = ``D ** -0.5``).
     """
     n_q_heads = q.shape[2]
     n_kv_heads = k.shape[2]
@@ -108,7 +110,8 @@ def dot_product_attention(
         group = n_q_heads // n_kv_heads
         k = jnp.repeat(k, group, axis=2)
         v = jnp.repeat(v, group, axis=2)
-    scale = q.shape[-1] ** -0.5
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if mask is not None:
         logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
@@ -319,6 +322,9 @@ class MultiHeadAttention(nn.Module):
     norm_eps: float = 1e-6
     # What the float projection kernels are stored in.
     param_dtype: jnp.dtype = jnp.float32
+    # What multiplies the scores before the softmax where a configuration
+    # publishes its own; ``None`` = ``head_dim ** -0.5``.
+    scale: Optional[float] = None
 
     @nn.compact
     def __call__(
@@ -329,7 +335,14 @@ class MultiHeadAttention(nn.Module):
         cache: Optional[KVCache] = None,
         lengths: Optional[jax.Array] = None,
         segment_ids: Optional[jax.Array] = None,
+        packed=None,
     ):
+        # ``packed`` (a ``models/moe.RealPositions``): ``x [1, C, D]`` is
+        # the compact token stream of a ``[B, S]`` step.  The projections
+        # run on it; queries, keys and values are put back at their
+        # ``[B, S]`` places for the cache and the attention this layer has
+        # (zeros at and behind a row's length), and the result is gathered
+        # onto the stream again before ``o_proj``.
         features = x.shape[-1]
         n_kv = self.n_kv_heads or self.n_heads
         head_dim = self.head_dim or features // self.n_heads
@@ -363,6 +376,9 @@ class MultiHeadAttention(nn.Module):
             q = apply_rope(q, cos, sin, positions)
             k = apply_rope(k, cos, sin, positions)
 
+        if packed is not None:
+            q, k, v = (packed.put_back(a[0]) for a in (q, k, v))
+
         new_cache = None
         paged = False
         if cache is not None:
@@ -375,7 +391,16 @@ class MultiHeadAttention(nn.Module):
             if not paged:
                 k, v = new_cache.keys, new_cache.values
 
+        def no_scale(attention: str):
+            if self.scale is not None:
+                raise ValueError(
+                    "a published softmax scale reaches the dense form, the "
+                    f"flash kernel and a cache view that carries it; {attention} "
+                    "has none")
+
         if paged:
+            if getattr(new_cache, "scale", None) != self.scale:
+                no_scale("this cache view")
             out = new_cache.attend(q, mask)
         elif self.attn_impl == "flash" and cache is None:
             from music_analyst_tpu.ops.flash_attention import flash_attention
@@ -393,7 +418,7 @@ class MultiHeadAttention(nn.Module):
                 )
             out = flash_attention(
                 q, k, v, lengths=lengths, causal=self.flash_causal,
-                q_segment_ids=segment_ids,
+                q_segment_ids=segment_ids, scale=self.scale,
             )
         else:
             if segment_ids is not None:
@@ -410,11 +435,14 @@ class MultiHeadAttention(nn.Module):
                     whole_row_attention,
                 )
 
+                no_scale("the whole-row kernel")
                 note_attention_path("whole_row")
                 out = whole_row_attention(q, k, v, lengths, mesh=self.mesh)
             else:
                 note_attention_path("dense")
-                out = dot_product_attention(q, k, v, mask)
+                out = dot_product_attention(q, k, v, mask, self.scale)
+        if packed is not None:
+            out = packed.gather(out)[None]
         out = dense_cls(
             features=features,
             axis=(-2, -1),

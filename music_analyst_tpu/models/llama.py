@@ -45,8 +45,11 @@ from music_analyst_tpu.models.tokenization import (
     ByteTokenizer,
     resolve_llama_tokenizer,
 )
-from music_analyst_tpu.ops.kv_cache import KVCache
-from music_analyst_tpu.profiling.compile import profiled_jit
+from music_analyst_tpu.ops.kv_cache import BlockCausalPrefill, KVCache
+from music_analyst_tpu.profiling.compile import (
+    note_traced_path,
+    profiled_jit,
+)
 from music_analyst_tpu.utils.labels import SUPPORTED_LABELS, normalise_label
 
 # Reference prompt, scripts/sentiment_classifier.py:32-36 (behavioral
@@ -161,6 +164,26 @@ class LlamaConfig:
     # ``(first, count)``: the experts of each routed layer THIS chip holds
     # of the layer's ``n_experts`` (the router's width); ``None`` = all.
     experts_held: Optional[tuple] = None
+    # The mixer a layer, as a published list (``"mamba"`` = a Mamba-2
+    # state-space layer, models/mamba2.py: a recurrent state a row;
+    # ``"attention"`` = the ``attention`` kind); ``None`` = by
+    # ``mixer_period``.  The four widths below are Mamba-2's.
+    layer_types: Optional[tuple] = None
+    mamba_n_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_d_state: int = 0
+    mamba_conv_kernel: int = 4
+    # Grouped-query attention without rotary positions, and with a
+    # published softmax scale (0 = ``head_dim ** -0.5``).
+    use_rope: bool = True
+    attention_scale: float = 0.0
+    # Scalars on the embedding, on both residual branches of every layer
+    # and under the logits (``logits / logits_scaling``); 1 = none.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # The head is the embedding's transpose: no ``lm_head`` parameter.
+    tie_embeddings: bool = False
 
     def __post_init__(self):
         if self.attention not in ("gqa", "mla"):
@@ -174,14 +197,32 @@ class LlamaConfig:
                 "latent attention's expert layers are RoutedMoE: moe_router "
                 "must be sigmoid_noaux or softmax_topk")
         if self.mixer_period and (self.attention != "mla"
-                                  or self.kda_head_dim < 1):
+                                  or self.kda_head_dim < 1
+                                  or self.layer_types is not None):
             raise ValueError(
                 "mixer_period puts KDA layers between latent-attention "
-                "ones: attention must be mla and kda_head_dim set")
+                "ones: attention must be mla, kda_head_dim set, and the "
+                "layers' kinds not given as a list besides (layer_types)")
         for name in ("experts_held", "layer_ids"):  # lists in a preset file
             if getattr(self, name) is not None:
                 object.__setattr__(
                     self, name, tuple(int(n) for n in getattr(self, name)))
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            if (len(self.layer_types) != self.n_layers
+                    or set(self.layer_types) - {"mamba", "attention"}):
+                raise ValueError(
+                    "layer_types names n_layers layers, each mamba or "
+                    "attention")
+            if self.attention != "gqa" or min(
+                    self.mamba_n_heads, self.mamba_head_dim,
+                    self.mamba_d_state) < 1:
+                raise ValueError(
+                    "layer_types puts Mamba-2 layers between grouped-query "
+                    "ones: attention must be gqa and the mamba widths set")
+        if self.tie_embeddings and self.weight_quant != "none":
+            raise ValueError(
+                "weight_quant stores a head of its own: no tied embeddings")
         if self.layer_ids is not None and len(self.layer_ids) != self.n_layers:
             raise ValueError("layer_ids does not name n_layers layers")
         if self.generation not in ("autoregressive", "block_diffusion"):
@@ -220,21 +261,41 @@ class LlamaConfig:
         return self.generation == "block_diffusion"
 
     def mixer(self, index: int) -> str:
-        """Layer ``index``'s mixer: ``"kda"``, or the ``attention`` kind."""
+        """Layer ``index``'s mixer: ``"kda"``, ``"mamba"``, or the
+        ``attention`` kind.  The one place that answers, from the list
+        (``layer_types``) or the period (``mixer_period``)."""
+        if self.layer_types is not None:
+            return ("mamba" if self.layer_types[index] == "mamba"
+                    else self.attention)
         if self.layer_ids is not None:
             index = self.layer_ids[index]
         if self.mixer_period and (index + 1) % self.mixer_period:
             return "kda"
         return self.attention
 
+    def _layers_of(self, mixer: str) -> int:
+        return sum(self.mixer(i) == mixer for i in range(self.n_layers))
+
     @property
     def kda_layers(self) -> int:
-        return sum(self.mixer(i) == "kda" for i in range(self.n_layers))
+        return self._layers_of("kda")
+
+    @property
+    def ssm_layers(self) -> int:
+        return self._layers_of("mamba")
 
     @property
     def recurrent_state(self) -> bool:
-        """Whether some layer carries ``models/kda.RecurrentState``."""
-        return self.kda_layers > 0
+        """Whether some layer carries a recurrent state a row
+        (``models/kda.RecurrentState`` or ``models/mamba2.SSMState``)."""
+        return self.kda_layers + self.ssm_layers > 0
+
+    @property
+    def compact_stream(self) -> bool:
+        """Whether every block takes the compact token stream
+        (:func:`runs_compact`): the latent blocks, and grouped-query blocks
+        between state-space layers."""
+        return self.attention == "mla" or self.ssm_layers > 0
 
     @property
     def experts_held_count(self) -> int:
@@ -265,11 +326,13 @@ class LlamaConfig:
     @classmethod
     def from_hf_config(cls, hf: dict, **overrides) -> "LlamaConfig":
         """The decoder a published ``config.json`` of ``model_type:
-        deepseek_v3``, ``sdar_moe`` or ``ling_hybrid`` describes, key by
-        key.  What this code cannot run is refused by name, not
-        approximated."""
+        deepseek_v3``, ``sdar_moe``, ``ling_hybrid`` or ``granitemoehybrid``
+        describes, key by key.  What this code cannot run is refused by
+        name, not approximated."""
         if hf.get("model_type") == "sdar_moe":
             return cls._from_sdar_moe(hf, overrides)
+        if hf.get("model_type") == "granitemoehybrid":
+            return cls._from_granitemoehybrid(hf, overrides)
         if hf.get("model_type") == "ling_hybrid":
             return cls._from_ling_hybrid(hf, overrides)
         unsupported = {
@@ -405,6 +468,77 @@ class LlamaConfig:
         return cls(**fields)
 
     @classmethod
+    def _from_granitemoehybrid(cls, hf: dict,
+                               overrides: dict) -> "LlamaConfig":
+        """``model_type: granitemoehybrid``: ``layer_types`` says which
+        layers are Mamba-2 and which grouped-query attention without
+        positions (``position_embedding_type: "nope"``) under the published
+        softmax scale ``attention_multiplier``; every layer's feed-forward
+        half is ``num_local_experts`` softmax-routed experts of width
+        ``intermediate_size`` (the softmax over the chosen
+        ``num_experts_per_tok`` logits) plus one shared SwiGLU of
+        ``shared_intermediate_size``; the embedding is multiplied by
+        ``embedding_multiplier``, both residual branches by
+        ``residual_multiplier``, the logits divided by ``logits_scaling``,
+        and the head is the embedding's transpose.  How many of the experts
+        this chip holds is not the source's to say: the caller's
+        ``overrides`` (``experts_held``) state it."""
+        width = hf["intermediate_size"]
+        shared = hf["shared_intermediate_size"]
+        unsupported = {
+            "position_embedding_type": hf.get(
+                "position_embedding_type") != "nope",
+            "attention_bias": bool(hf.get("attention_bias", False)),
+            "mamba_proj_bias": bool(hf.get("mamba_proj_bias", False)),
+            "mamba_conv_bias": not hf.get("mamba_conv_bias", True),
+            "mamba_n_groups": hf.get("mamba_n_groups", 1) != 1,
+            "rope_scaling": hf.get("rope_scaling") is not None,
+            "hidden_act": hf.get("hidden_act", "silu") != "silu",
+            "normalization_function": hf.get(
+                "normalization_function", "rmsnorm") != "rmsnorm",
+            "mamba_expand": (hf["mamba_expand"] * hf["hidden_size"]
+                             != hf["mamba_n_heads"] * hf["mamba_d_head"]),
+            "shared_intermediate_size": bool(shared % width),
+            "num_local_experts": hf.get("num_local_experts", 0) < 1,
+        }
+        bad = sorted(k for k, v in unsupported.items() if v)
+        if bad:
+            raise ValueError(
+                "this decoder does not implement the configuration's "
+                + ", ".join(f"{k}={hf.get(k)!r}" for k in bad)
+            )
+        fields = dict(
+            vocab_size=hf["vocab_size"], dim=hf["hidden_size"],
+            n_layers=hf["num_hidden_layers"],
+            n_heads=hf["num_attention_heads"],
+            n_kv_heads=hf["num_key_value_heads"],
+            # no dense layer: the width is one expert's (the catalog's note)
+            hidden_dim=width, moe_hidden_dim=width,
+            rope_theta=float(hf.get("rope_theta", 10_000.0)),
+            max_seq_len=hf["max_position_embeddings"],
+            rms_norm_eps=float(hf["rms_norm_eps"]),
+            layer_types=tuple(hf["layer_types"]),
+            mamba_n_heads=hf["mamba_n_heads"],
+            mamba_head_dim=hf["mamba_d_head"],
+            mamba_d_state=hf["mamba_d_state"],
+            mamba_conv_kernel=hf["mamba_d_conv"],
+            use_rope=False,
+            attention_scale=float(hf["attention_multiplier"]),
+            embedding_multiplier=float(hf["embedding_multiplier"]),
+            residual_multiplier=float(hf["residual_multiplier"]),
+            logits_scaling=float(hf["logits_scaling"]),
+            tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+            # softmax over the chosen logits = softmax over all, the chosen
+            # renormalised (``route_softmax_topk`` with ``norm_topk_prob``)
+            moe_router="softmax_topk", norm_topk_prob=True,
+            n_experts=hf["num_local_experts"],
+            moe_top_k=hf["num_experts_per_tok"],
+            n_shared_experts=shared // width,
+        )
+        fields.update(overrides)
+        return cls(**fields)
+
+    @classmethod
     def _from_sdar_moe(cls, hf: dict, overrides: dict) -> "LlamaConfig":
         """``model_type: sdar_moe``: a Qwen3-MoE-shaped layer (grouped-query
         attention with QK-norm, a published ``head_dim``, every layer
@@ -498,9 +632,10 @@ LATENT_CACHE_REFUSAL = (
 
 RECURRENT_STATE_REFUSAL = (
     "most of this model's layers carry a recurrent state a row "
-    "(models/kda.RecurrentState: a float32 matrix a head and the "
-    "convolutions' last inputs), not keys and values a token; the {runtime} "
-    "runtime has slots or pages of per-head keys and values only"
+    "(models/kda.RecurrentState or models/mamba2.SSMState: a float32 matrix "
+    "a head and the convolutions' last inputs), not keys and values a "
+    "token; the {runtime} runtime has slots or pages of per-head keys and "
+    "values only"
 )
 
 
@@ -522,6 +657,10 @@ class LlamaBlock(nn.Module):
             return self._latent_block(x, mask, positions, cache,
                                       prefill_lengths, prefill_capacity,
                                       segment_ids, packed, row_lengths)
+        if cfg.mixer(self.layer_index) == "mamba":
+            return self._state_space_block(
+                x, positions, cache, prefill_lengths, prefill_capacity,
+                segment_ids, packed, row_lengths)
         if segment_ids is not None and (
             cache is not None or cfg.attn_impl != "flash"
         ):
@@ -538,7 +677,7 @@ class LlamaBlock(nn.Module):
             n_heads=cfg.n_heads,
             n_kv_heads=cfg.n_kv_heads,
             head_dim=cfg.attn_head_dim,
-            use_rope=True,
+            use_rope=cfg.use_rope,
             rope_theta=cfg.rope_theta,
             max_positions=cfg.max_seq_len,
             dtype=dtype,
@@ -549,14 +688,30 @@ class LlamaBlock(nn.Module):
             qk_norm=cfg.qk_norm,
             norm_eps=cfg.rms_norm_eps,
             param_dtype=jnp.dtype(cfg.param_dtype),
+            scale=cfg.attention_scale or None,
             name="attention",
         )
         h = RMSNorm(epsilon=cfg.rms_norm_eps, name="attention_norm")(x)
         with jax.named_scope("gqa"):
             if cache is not None:
+                # A declared prefill (``prefill_lengths``: causal, from
+                # position 0, the cache empty, one device) of a
+                # configuration that asks for the flash kernel takes it
+                # through the cache's causal view, which reads the lengths
+                # in the mask's place; anything else is the masked form.
+                view = (cfg.attn_impl == "flash"
+                        and prefill_lengths is not None
+                        and isinstance(cache, KVCache))
+                if view:
+                    cache = BlockCausalPrefill(
+                        cache, prefill_lengths.astype(jnp.int32), 1,
+                        scale=cfg.attention_scale or None)
                 attn_out, new_cache = attn(
-                    h, mask=mask, positions=positions, cache=cache
+                    h, mask=mask, positions=positions, cache=cache,
+                    packed=packed,
                 )
+                if view:
+                    new_cache = new_cache.cache
             else:
                 # Flash path: masking is fully described by
                 # flash_causal=True + lengths (+ optional packed-document
@@ -570,13 +725,14 @@ class LlamaBlock(nn.Module):
                     lengths=lengths,
                     segment_ids=(segment_ids if cfg.attn_impl == "flash"
                                  else None),
+                    packed=packed,
                 )
                 new_cache = None
-        x = x + attn_out
+        x = self._residual(x, attn_out)
         h = RMSNorm(epsilon=cfg.rms_norm_eps, name="ffn_norm")(x)
         if cfg.n_experts > 0 and cfg.routed_experts:
-            return (x + self._feed_forward(h, prefill_lengths,
-                                           prefill_capacity), new_cache)
+            return (self._residual(x, self._feed_forward(
+                h, prefill_lengths, prefill_capacity, packed)), new_cache)
         if cfg.n_experts > 0:
             from music_analyst_tpu.models.moe import MoESwiGLU
 
@@ -594,8 +750,43 @@ class LlamaBlock(nn.Module):
         else:
             ffn = SwiGLU(cfg.hidden_dim, dtype=dtype, quant=cfg.quant,
                          weight_quant=cfg.weight_quant, name="feed_forward")
-        x = x + ffn(h)
+        x = self._residual(x, ffn(h))
         return x, new_cache
+
+    def _residual(self, x, branch):
+        """``x + residual_multiplier * branch`` (1 = the plain sum, and the
+        program it always was)."""
+        scale = self.config.residual_multiplier
+        if scale == 1.0:
+            return x + branch
+        return x + (branch.astype(jnp.float32) * scale).astype(x.dtype)
+
+    def _state_space_block(self, x, positions, cache, prefill_lengths,
+                           prefill_capacity, segment_ids, packed,
+                           row_lengths):
+        """Pre-norm block whose mixer is Mamba-2 on a recurrent state in
+        the cache's place (``models/mamba2.py``), then
+        :meth:`_feed_forward`.  With ``packed`` the stream ``x [1, C, D]``
+        is the real positions' compact set from norm to residual."""
+        from music_analyst_tpu.models.mamba2 import Mamba2Mixer
+
+        cfg = self.config
+        if segment_ids is not None:
+            raise ValueError("a state-space layer takes no segment_ids")
+        mixer = Mamba2Mixer(
+            n_heads=cfg.mamba_n_heads, head_dim=cfg.mamba_head_dim,
+            d_state=cfg.mamba_d_state, conv_kernel=cfg.mamba_conv_kernel,
+            norm_eps=cfg.rms_norm_eps, dtype=jnp.dtype(cfg.dtype),
+            param_dtype=jnp.dtype(cfg.param_dtype), name="attention",
+        )
+        h = RMSNorm(epsilon=cfg.rms_norm_eps, name="attention_norm")(x)
+        mixed = mixer(h, positions, cache, prefill_lengths, row_lengths,
+                      packed)
+        mixed, new_cache = mixed if cache is not None else (mixed, None)
+        x = self._residual(x, mixed)
+        h = RMSNorm(epsilon=cfg.rms_norm_eps, name="ffn_norm")(x)
+        return (self._residual(x, self._feed_forward(
+            h, prefill_lengths, prefill_capacity, packed)), new_cache)
 
     def _feed_forward(self, h, prefill_lengths, prefill_capacity,
                       packed=None):
@@ -769,9 +960,12 @@ class LlamaModel(nn.Module):
                     prefill_lengths, token_ids.shape[1], prefill_capacity)
                 token_ids = packed.gather(token_ids)[None]
                 positions = packed.gather(positions)[None]
-            x = nn.Embed(cfg.vocab_size, cfg.dim, dtype=dtype,
-                         param_dtype=param_dtype,
-                         name="tok_embeddings")(token_ids)
+            embed = nn.Embed(cfg.vocab_size, cfg.dim, dtype=dtype,
+                             param_dtype=param_dtype, name="tok_embeddings")
+            x = embed(token_ids)
+            if cfg.embedding_multiplier != 1.0:
+                x = (x.astype(jnp.float32) * cfg.embedding_multiplier
+                     ).astype(dtype)
         new_caches: List[KVCache] = []
         for i in range(cfg.n_layers):
             cache_i = caches[i] if caches is not None else None
@@ -804,7 +998,16 @@ class LlamaModel(nn.Module):
             x = jnp.take_along_axis(
                 x, last_position[:, None, None].astype(jnp.int32), axis=1
             )
-        if cfg.weight_quant != "none":
+        if cfg.tie_embeddings:
+            # the head is the embedding's transpose, as ``nn.Dense`` in
+            # float32 would compute it from a kernel of its own
+            note_traced_path("embeddings.tied")
+            with jax.named_scope("lm_head"):
+                logits = jax.lax.dot_general(
+                    x.astype(jnp.float32),
+                    embed.embedding.astype(jnp.float32),
+                    (((x.ndim - 1,), (1,)), ((), ())))
+        elif cfg.weight_quant != "none":
             from music_analyst_tpu.models.layers import WqDenseGeneral
 
             logits = WqDenseGeneral(
@@ -816,6 +1019,8 @@ class LlamaModel(nn.Module):
                 logits = nn.Dense(cfg.vocab_size, use_bias=False,
                                   dtype=jnp.float32, param_dtype=param_dtype,
                                   name="lm_head")(x)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
         return logits, (new_caches if caches is not None else None)
 
 
@@ -824,26 +1029,32 @@ def init_caches(
 ) -> List[KVCache]:
     """One empty cache a layer, of the layer's mixer kind: a ``KVCache``
     (grouped-query attention), a ``LatentCache`` (latent attention) or,
-    for a KDA layer, a ``RecurrentState``, which does not grow with
-    ``max_len``.  The slot and paged decode runtimes hold the first kind
-    alone (``LlamaZeroShotClassifier.decode_runtime_refusal``)."""
-    if cfg.latent_cache:
-        from music_analyst_tpu.models.kda import RecurrentState
-        from music_analyst_tpu.models.mla import LatentCache
+    for a layer that carries a recurrent state, that state (a KDA layer's
+    ``RecurrentState``, a Mamba-2 layer's ``SSMState``), which does not grow
+    with ``max_len``.  The slot and paged decode runtimes hold the first
+    kind alone (``LlamaZeroShotClassifier.decode_runtime_refusal``)."""
 
-        return [
-            RecurrentState.zeros(batch, cfg.n_heads, cfg.kda_head_dim,
-                                 cfg.kda_conv_kernel, dtype)
-            if cfg.mixer(i) == "kda" else
-            LatentCache.zeros(batch, max_len, cfg.kv_lora_rank,
-                              cfg.qk_rope_head_dim, dtype)
-            for i in range(cfg.n_layers)
-        ]
-    return [
-        KVCache.zeros(batch, max_len, cfg.n_kv_heads, cfg.attn_head_dim,
-                      dtype)
-        for _ in range(cfg.n_layers)
-    ]
+    def empty(mixer: str):
+        if mixer == "kda":
+            from music_analyst_tpu.models.kda import RecurrentState
+
+            return RecurrentState.zeros(batch, cfg.n_heads, cfg.kda_head_dim,
+                                        cfg.kda_conv_kernel, dtype)
+        if mixer == "mamba":
+            from music_analyst_tpu.models.mamba2 import SSMState
+
+            return SSMState.zeros(
+                batch, cfg.mamba_n_heads, cfg.mamba_head_dim,
+                cfg.mamba_d_state, cfg.mamba_conv_kernel, dtype)
+        if mixer == "mla":
+            from music_analyst_tpu.models.mla import LatentCache
+
+            return LatentCache.zeros(batch, max_len, cfg.kv_lora_rank,
+                                     cfg.qk_rope_head_dim, dtype)
+        return KVCache.zeros(batch, max_len, cfg.n_kv_heads,
+                             cfg.attn_head_dim, dtype)
+
+    return [empty(cfg.mixer(i)) for i in range(cfg.n_layers)]
 
 
 def load_torch_state_dict(path: str, mmap: bool = False) -> dict:
@@ -1079,6 +1290,8 @@ def init_params_by_layer(cfg: LlamaConfig, seed: int = 0):
             inits[kind] = program(LlamaBlock(cfg, i), x, mask, positions, None)
         params[f"layer_{i}"] = inits[kind](jax.random.fold_in(root, 1 + i))
     params["norm"] = RMSNorm(epsilon=cfg.rms_norm_eps).init(root, x)["params"]
+    if cfg.tie_embeddings:
+        return params
     head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
                     param_dtype=jnp.dtype(cfg.param_dtype))
     params["lm_head"] = program(head, x)(
@@ -1107,8 +1320,8 @@ def _prefill_capacity(config: LlamaConfig, mesh, prompt_lens,
     ``models/moe.compact_capacity`` that holds the real tokens, where the
     blocks read ``prefill_lengths`` (latent blocks, one device); ``None``
     where they are withheld or unread, so such a step has one program."""
-    if not (config.latent_cache or config.block_diffusion) \
-            or _partitioned(mesh):
+    if not (config.latent_cache or config.block_diffusion
+            or config.ssm_layers) or _partitioned(mesh):
         return None
     from music_analyst_tpu.models.moe import compact_capacity
 
@@ -1120,16 +1333,20 @@ def runs_compact(config: LlamaConfig, shape, capacity) -> bool:
     """Whether a prefill of ``shape`` (rows, width) that declares its rows'
     lengths and this ``prefill_capacity`` keeps its hidden state on the
     compact token set from the embedding to the head (``LlamaModel``):
-    latent blocks (KDA layers among them or not: both kernels find a row
-    at its own slot), fewer slots than positions, and a width and a slot
-    count the packed prefill kernel takes (whole 256-slot blocks, which
-    are whole chunks of the KDA kernel too).  The one place that decides
-    it, for the model and for whoever counts what a step computed."""
+    blocks that take the stream (``LlamaConfig.compact_stream``: latent
+    blocks, KDA layers among them or not, and grouped-query blocks between
+    state-space layers: every kernel finds a row at its own slot, and the
+    grouped-query layer puts queries, keys and values back at ``[B, S]``
+    for the kernel it has), fewer slots than positions, and a width and a
+    slot count the kernels take (whole 256-slot blocks of the packed
+    latent prefill, which are whole chunks of the KDA and the state-space
+    kernels too).  The one place that decides it, for the model and for
+    whoever counts what a step computed."""
     from music_analyst_tpu.ops.mla_prefill_attention import (
         packed_prefill_block,
     )
 
-    return (config.attention == "mla" and capacity is not None
+    return (config.compact_stream and capacity is not None
             and capacity < int(shape[0]) * int(shape[1])
             and bool(packed_prefill_block(int(shape[1]), capacity)))
 
@@ -1178,10 +1395,12 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
         experts the continuations' positions ran, ``-1`` at the last,
         which ran none).  ``probe_rows [P]`` (a model with recurrent
         state alone): the rows whose state after the prefill rides back
-        too (``stats["probe"]``: every KDA layer's ``state [layers, P, H,
-        dk, dv]``, every latent layer's ``latents`` and ``rope_keys``
-        ``[layers, P, S, .]``), for whoever compares them with a
-        reference; the values choose rows, not a program.
+        too (``stats["probe"]``: every KDA or Mamba-2 layer's ``state
+        [layers, P, H, ., .]``, the Mamba-2 layers' ``conv`` tails, every
+        latent layer's ``latents`` and ``rope_keys`` ``[layers, P, S, .]``,
+        every grouped-query layer's ``keys`` and ``values`` ``[layers, P,
+        S, Hkv, D]``), for whoever compares them with a reference; the
+        values choose rows, not a program.
         """
         B, S = prompt_ids.shape
         n_labels, L = label_ids.shape
@@ -1269,7 +1488,7 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
             first_logp = jax.nn.log_softmax(logits[:, 0], axis=-1)  # [B, V]
             if config.recurrent_state:
                 # One label after the other: each continuation advances its
-                # own copy of every KDA layer's state, and under ``vmap``
+                # own copy of every recurrent layer's state, and under ``vmap``
                 # the copies of all labels and layers are made up front
                 # (2.4 GB at 64 rows x 6 layers x 3 labels), beside the
                 # prefill's temporaries.
@@ -1306,15 +1525,23 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
 
 def _probe(config: LlamaConfig, caches, rows, width: int) -> dict:
     """What ``rows`` of a prefill's caches hold, by mixer kind."""
-    states = [c.state[rows] for i, c in enumerate(caches)
-              if config.mixer(i) == "kda"]
-    latent = [c for i, c in enumerate(caches) if config.mixer(i) != "kda"]
-    kept = {"state": jnp.stack(states)}
-    if latent:
+    by_kind = {}
+    for i, cache in enumerate(caches):
+        by_kind.setdefault(config.mixer(i), []).append(cache)
+    carried = by_kind.get("kda", []) + by_kind.get("mamba", [])
+    kept = {"state": jnp.stack([c.state[rows] for c in carried])}
+    if "mamba" in by_kind:
+        kept["conv"] = jnp.stack([c.conv[rows] for c in by_kind["mamba"]])
+    if "mla" in by_kind:
         kept["latents"] = jnp.stack(
-            [c.latents[rows, :width] for c in latent])
+            [c.latents[rows, :width] for c in by_kind["mla"]])
         kept["rope_keys"] = jnp.stack(
-            [c.rope_keys[rows, :width] for c in latent])
+            [c.rope_keys[rows, :width] for c in by_kind["mla"]])
+    if "gqa" in by_kind:
+        kept["keys"] = jnp.stack(
+            [c.keys[rows, :width] for c in by_kind["gqa"]])
+        kept["values"] = jnp.stack(
+            [c.values[rows, :width] for c in by_kind["gqa"]])
     return kept
 
 
@@ -1617,7 +1844,8 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         decode_runtime.py``) cannot host this model, or ``None`` where
         they can: a latent cache, a step that yields a block
         (``models/block_diffusion.py``'s own), or a recurrent state beside
-        the cache.  ``serve`` reads it to leave the ``generate`` op off."""
+        the cache, whatever the cache's kind.  ``serve`` reads it to leave
+        the ``generate`` op off."""
         if self.config.recurrent_state:
             return RECURRENT_STATE_REFUSAL
         return LATENT_CACHE_REFUSAL if self.config.latent_cache else None
@@ -1781,7 +2009,7 @@ class LlamaZeroShotClassifier(ClassifierBackend):
                 rows * (width + MAX_LABEL_TOKENS)
                 * (cfg.n_layers - cfg.kda_layers) * 2
                 * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)))
-        if cfg.recurrent_state:
+        if cfg.kda_layers:
             kda = cfg.kda_layers
             # float32 state a head, and the convolutions' last inputs
             state_bytes = rows * kda * cfg.n_heads * cfg.kda_head_dim * (
@@ -1790,6 +2018,22 @@ class LlamaZeroShotClassifier(ClassifierBackend):
             tel.count("kda.tokens", tokens_real * kda)
             tel.count("kda.state_steps", rows * label_run * kda)
             attrs.update(kda_layers=kda, mla_layers=cfg.n_layers - kda,
+                         state_bytes=state_bytes)
+        if cfg.ssm_layers:
+            ssm = cfg.ssm_layers
+            inner = cfg.mamba_n_heads * cfg.mamba_head_dim
+            # float32 state a head, and the convolution's last inputs
+            state_bytes = rows * ssm * (
+                4 * inner * cfg.mamba_d_state
+                + 2 * (cfg.mamba_conv_kernel - 1)
+                * (inner + 2 * cfg.mamba_d_state))
+            tel.gauge("recurrent_state_bytes", state_bytes)
+            tel.gauge("kv_cache_bytes", int(
+                rows * (width + MAX_LABEL_TOKENS) * (cfg.n_layers - ssm)
+                * 2 * 2 * cfg.n_kv_heads * cfg.attn_head_dim))
+            tel.count("ssm.tokens", tokens_real * ssm)
+            tel.count("ssm.state_steps", rows * label_run * ssm)
+            attrs.update(ssm_layers=ssm, attention_layers=cfg.n_layers - ssm,
                          state_bytes=state_bytes)
         tel.current_span().set(**attrs)
 

@@ -162,7 +162,7 @@ def _flash_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "block_q", "block_kv", "interpret",
-                     "residuals", "block_causal"),
+                     "residuals", "block_causal", "scale"),
 )
 def _flash_call(
     q: jax.Array,       # [B, S, H, D]
@@ -178,6 +178,7 @@ def _flash_call(
     q_seg: jax.Array | None = None,   # [B, S] int32 segment ids
     kv_seg: jax.Array | None = None,  # [B, KV]
     block_causal: int = 0,
+    scale: float | None = None,
 ):
     B, S, H, D = q.shape
     KV = k.shape[1]
@@ -190,7 +191,8 @@ def _flash_call(
     group = H // Hkv
     q_blocks = S // block_q
     kv_blocks = KV // block_kv
-    scale = D ** -0.5
+    if scale is None:
+        scale = D ** -0.5
 
     segmented = q_seg is not None
     kernel = functools.partial(
@@ -307,8 +309,13 @@ def flash_attention(
     q_segment_ids: jax.Array | None = None,
     kv_segment_ids: jax.Array | None = None,
     block_causal: int = 0,
+    scale: float | None = None,
 ):
     """Attention over ``[B, S, H, D]`` without materializing logits.
+
+    ``scale`` multiplies the scores before the softmax: ``D ** -0.5``
+    unless a configuration publishes its own (``None`` = that default, and
+    the program every caller without one had).
 
     ``lengths`` masks keys/values past each row's valid length (encoder
     padding); ``causal`` adds the autoregressive mask.  GQA is supported
@@ -394,4 +401,5 @@ def flash_attention(
         q, k, v, lengths.astype(jnp.int32), offsets, causal, block_q,
         block_kv, interpret, return_residuals, q_seg=q_seg, kv_seg=kv_seg,
         block_causal=block_causal,
+        scale=None if scale is None else float(scale),
     )

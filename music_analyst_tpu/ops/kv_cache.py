@@ -133,12 +133,17 @@ class BlockCausalPrefill:
     width admits it (``kernel``; :func:`block_causal_tile`) and by the
     masked XLA form elsewhere; ``update`` lays the new keys and values at
     the head of the cache's buffers and reports ``lengths`` filled.  What
-    the view returns at or behind a row's length is not defined."""
+    the view returns at or behind a row's length is not defined.
+
+    ``block`` 1 is the causal rule itself: the view of an autoregressive
+    decoder's declared prefill (``models/llama.LlamaBlock``), rows of any
+    length.  ``scale`` multiplies the scores (``None`` = ``D ** -0.5``)."""
 
     cache: KVCache
     lengths: jax.Array  # [B] int32, a multiple of ``block`` a row
     block: int
     kernel: bool = True
+    scale: float | None = None
     k_new: jax.Array | None = None
     v_new: jax.Array | None = None
 
@@ -164,9 +169,11 @@ class BlockCausalPrefill:
             return flash_attention(
                 q, self.k_new, self.v_new, lengths=self.lengths,
                 causal=True, block_causal=self.block, block_q=tile,
-                block_kv=tile)
+                block_kv=tile, scale=self.scale)
         note_attention_path("block_causal_dense")
-        scores = _grouped_scores(q, self.k_new, q.shape[-1] ** -0.5)
+        scores = _grouped_scores(
+            q, self.k_new,
+            q.shape[-1] ** -0.5 if self.scale is None else self.scale)
         pos = jnp.arange(n)
         seen = (pos[None, :] // self.block <= pos[:, None] // self.block)
         seen = seen[None] & (pos[None, None, :]
@@ -221,7 +228,7 @@ class BlockPass:
 
 for _view, _data, _meta in (
     (BlockCausalPrefill, ["cache", "lengths", "k_new", "v_new"],
-     ["block", "kernel"]),
+     ["block", "kernel", "scale"]),
     (BlockPass, ["cache", "filled", "k_new", "v_new"], ["commit"]),
 ):
     jax.tree_util.register_dataclass(_view, data_fields=_data,

@@ -19,8 +19,8 @@ def test_every_advertised_module_registers(monkeypatch):
     # Every module in the advertised tuple must have registered >= 1 suite.
     assert len(names) >= len(benchmarks._SUITE_MODULES)
     for expected in (
-        "roofline", "flash_sweep", "mla_prefill", "kda_prefill", "generation",
-        "coldstart",
+        "roofline", "flash_sweep", "mla_prefill", "kda_prefill", "ssd_prefill",
+        "generation", "coldstart",
         "ingest",
         "scaling", "joint", "llama_zeroshot", "sentiment_int8", "bucketing",
         "overlap", "streaming", "serving", "router", "slo", "crash",
@@ -30,8 +30,8 @@ def test_every_advertised_module_registers(monkeypatch):
 
 @pytest.mark.parametrize(
     "name",
-    ["roofline", "flash_sweep", "mla_prefill", "kda_prefill", "generation",
-     "ingest",
+    ["roofline", "flash_sweep", "mla_prefill", "kda_prefill", "ssd_prefill",
+     "generation", "ingest",
      "joint", "llama_zeroshot", "sentiment_int8", "bucketing", "overlap",
      "streaming", "serving"],
 )

@@ -606,6 +606,87 @@ def test_hybrid_scoring_step_compiles_for_the_chip_at_the_cells_shape(
     assert memory.temp_size_in_bytes < 3.6e9
 
 
+@pytest.mark.parametrize("slots,rows,width,heads,dim,state", [
+    (12288, 32, 1024, 128, 64, 128),   # the cell's rung, compact stream
+    (32768, 32, 1024, 128, 64, 128),   # the same rows padded to [B, S]
+    (768, 4, 512, 8, 16, 32),          # granite-tiny's compact 512-wide step
+], ids=["granite-compact", "granite-padded", "granite-tiny"])
+def test_ssd_chunked_compiles_under_mosaic(tpu_sharding, slots, rows, width,
+                                           heads, dim, state):
+    """The Mamba-2 prefill kernel (``ops/ssd_scan.py``) at the state-space
+    cell's step (32 rows of up to 1,024 slots, 128 heads of 64, state 128)
+    on the compact token stream and on padded rows, and at the test size."""
+    from music_analyst_tpu.ops.ssd_scan import ssd_chunked
+
+    def fn(x, dt, a, b, c, starts, ends, valid):
+        return ssd_chunked(x, dt, a, b, c, starts, ends, valid, heads, width,
+                           interpret=False)
+
+    compiled = _compile_for_tpu(
+        fn, tpu_sharding, ((slots, heads * dim), jnp.bfloat16),
+        ((slots, heads), jnp.float32), ((heads,), jnp.float32),
+        ((slots, state), jnp.bfloat16), ((slots, state), jnp.bfloat16),
+        ((rows,), jnp.int32), ((rows,), jnp.int32), ((slots,), jnp.bool_))
+    assert re.search(r"%_ssd_chunk_call[.\d]* = ", compiled.as_text())
+
+
+def test_mamba_layer_feeds_the_ssd_kernel_without_a_copy(tpu_sharding,
+                                                          monkeypatch):
+    """One Mamba-2 mixer of ``granite-4.0-h-small`` on the cell's compact
+    stream (12,288 slots of 32 rows, 4,096 wide, inner 8,192), compiled for a
+    v5e: one ``_ssd_`` call, fed by a fusion (what a token writes, the step
+    spread over its head's lanes inside the product), and no copy or
+    transpose of a stream-sized array anywhere in the layer: nothing is laid
+    out anew for the kernel, before it or behind it."""
+    from music_analyst_tpu.models import llama
+    from music_analyst_tpu.models.mamba2 import Mamba2Mixer, SSMState
+    from music_analyst_tpu.models.moe import RealPositions
+    from music_analyst_tpu.ops import flash_attention
+
+    monkeypatch.setattr(flash_attention, "interpret_default", lambda: False)
+    cfg = llama.PRESETS["granite-4.0-h-small"]()
+    rows, width, capacity = 32, 1024, 12288
+    mixer = Mamba2Mixer(
+        n_heads=cfg.mamba_n_heads, head_dim=cfg.mamba_head_dim,
+        d_state=cfg.mamba_d_state, conv_kernel=cfg.mamba_conv_kernel,
+        norm_eps=cfg.rms_norm_eps, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=tpu_sharding), tree)
+
+    params = placed(jax.eval_shape(
+        mixer.init, jax.random.key(0), jnp.zeros((1, 8, cfg.dim),
+                                                 jnp.bfloat16)))
+
+    def fn(params, x, lens):
+        packed = RealPositions.of(lens, width, capacity)
+        positions = packed.gather(
+            jnp.broadcast_to(jnp.arange(width), (rows, width)))[None]
+        state = SSMState.zeros(rows, cfg.mamba_n_heads, cfg.mamba_head_dim,
+                               cfg.mamba_d_state)
+        return mixer.apply(params, x, positions, state, lens, None, packed)
+
+    lowered = jax.jit(fn).trace(
+        params, placed(jnp.zeros((1, capacity, cfg.dim), jnp.bfloat16)),
+        placed(jnp.zeros((rows,), jnp.int32))).lower(
+            lowering_platforms=("tpu",))
+    text = lowered.compile().as_text()
+    calls = re.findall(
+        r"%_ssd_chunk_call[.\d]* = \([^=]*\) custom-call\(([^)]*)\)", text)
+    assert len(calls) == 1
+    fed_by = [name.strip() for name in calls[0].split(",")]
+    assert "fusion" in fed_by[2], fed_by        # what a token writes
+    # (the entry computation's own operations: inside a fusion a transpose
+    # over ``dimensions={0,1}`` is the gather's notation, not a movement)
+    moved = re.findall(
+        rf"= \w+\[(?:1,)?{capacity},(?:4096|8192|8448|16640)\]\S* "
+        r"(?:copy|transpose)\(", text[text.index("ENTRY"):])
+    assert not moved, moved
+
+
 def test_unservable_geometry_is_refused_by_name():
     """What Mosaic cannot lower is refused when the kernel (or the decode
     runtime, on a TPU) is built — not discovered at the first dispatch."""

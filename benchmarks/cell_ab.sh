@@ -18,7 +18,9 @@
 # a traced leg's reduced trace (by XLA's names, and by the program's scopes:
 # `python3 perfbench/tools/scope_table.py <leg>.scope_reduced.json` prints
 # the second), and the count of steps a compact rung (`<leg>.rungs.txt`),
-# under chiprun_out/ab/.
+# under chiprun_out/ab/.  A traced leg keeps a compile cache of its side's
+# own (ROADMAP I10: the machine's cache hands a kernel-free program back
+# with the scopes of whichever tree wrote the entry).
 wl=$1; order=$2; seed=$3; mode=${4:-pair}
 root=$PWD
 mkdir -p chiprun_out/ab
@@ -29,7 +31,10 @@ for leg in $order; do
   s=$((seed + 7919 * k + 1000003 * tr))
   if [ "$side" = "P" ]; then dir=.checkout; else dir=.scratch/final; fi
   out=$root/chiprun_out/ab/${wl}_${leg}_seed${s}
-  ( cd $dir && python3 perfbench/run.py --workload $wl --seed $s \
+  ( if [ "$tr" = "1" ]; then
+      export JAX_COMPILATION_CACHE_DIR=$root/.scratch/jax_cache_$side
+    fi
+    cd $dir && python3 perfbench/run.py --workload $wl --seed $s \
         --seconds 50 --trace $tr > $out.out 2> $out.err
     echo "rc=$?" >> $out.out
     cp perfbench/out/$wl/run/warmup/sentiment/run_manifest.json \

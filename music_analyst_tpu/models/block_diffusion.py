@@ -20,8 +20,11 @@ between them, so that a trace names them apart:
 * :func:`diffusion_prefill_program` (``llama_diffusion_prefill``): the
   prompts' whole blocks under the block-causal mask
   (``ops/kv_cache.BlockCausalPrefill``), no logits (a diffusion prefill has
-  no next token to read), the expert layers on the real positions
-  (``models/moe.RealPositions``); returns the caches;
+  no next token to read), the hidden state on the whole blocks' positions
+  alone from the embedding to the last layer (``models/llama.runs_compact``:
+  the compact token stream, ``models/moe.RealPositions``; queries, keys and
+  values put back at ``[B, S]`` for the view and the kernel); returns the
+  caches, zeros at and behind a row's whole blocks;
 * :func:`diffusion_denoise_program` (``llama_diffusion_denoise``): takes
   the caches (donated) and runs every block on the device, a ``lax.scan``
   over blocks around a ``lax.while_loop`` over passes: no host round trip
@@ -310,7 +313,8 @@ class BlockDiffusionClassifier(LlamaZeroShotClassifier):
         """What one step computed, into the run's telemetry: counters
         ``diffusion.*``, ``decoder.tokens_real`` / ``_computed`` (positions
         through the layers: the prefilled blocks' and each pass's; computed
-        includes the padding of the prefill), ``moe.*``, gauge
+        includes the prefill's padding, or its ``capacity`` slots' fillers
+        where it ran on the compact token set), ``moe.*``, gauge
         ``kv_cache_bytes``, and the step's shape and real counts on the
         span the engine has open (``compute``)."""
         from music_analyst_tpu.telemetry import get_telemetry
@@ -334,7 +338,8 @@ class BlockDiffusionClassifier(LlamaZeroShotClassifier):
         tel.count("diffusion.commit_passes", commit)
         tel.count("diffusion.tokens_unmasked", unmasked)
         tel.count("decoder.tokens_real", prefilled + pass_positions)
-        tel.count("decoder.tokens_computed", rows * width + pass_positions)
+        tel.count("decoder.tokens_computed",
+                  self._prefill_slots(rows, width, capacity) + pass_positions)
         load = self._count_expert_load(
             stats, slots,
             pass_positions * cfg.moe_top_k * len(stats["expert_load_max"]))

@@ -15,7 +15,10 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from music_analyst_tpu.ops.kv_cache import KVCache
-from music_analyst_tpu.profiling.compile import note_attention_path
+from music_analyst_tpu.profiling.compile import (
+    note_attention_path,
+    note_traced_path,
+)
 
 
 def rope_frequencies(
@@ -338,11 +341,12 @@ class MultiHeadAttention(nn.Module):
         packed=None,
     ):
         # ``packed`` (a ``models/moe.RealPositions``): ``x [1, C, D]`` is
-        # the compact token stream of a ``[B, S]`` step.  The projections
-        # run on it; queries, keys and values are put back at their
-        # ``[B, S]`` places for the cache and the attention this layer has
-        # (zeros at and behind a row's length), and the result is gathered
-        # onto the stream again before ``o_proj``.
+        # the compact token stream of a ``[B, S]`` step (traced path
+        # ``gqa.compact``).  The projections, QK-norm and RoPE run on it;
+        # queries, keys and values are put back at their ``[B, S]`` places
+        # for the cache and the attention this layer has (zeros at and
+        # behind a row's length), and the result is gathered onto the
+        # stream again before ``o_proj``.
         features = x.shape[-1]
         n_kv = self.n_kv_heads or self.n_heads
         head_dim = self.head_dim or features // self.n_heads
@@ -377,6 +381,7 @@ class MultiHeadAttention(nn.Module):
             k = apply_rope(k, cos, sin, positions)
 
         if packed is not None:
+            note_traced_path("gqa.compact")
             q, k, v = (packed.put_back(a[0]) for a in (q, k, v))
 
         new_cache = None

@@ -293,9 +293,11 @@ class LlamaConfig:
     @property
     def compact_stream(self) -> bool:
         """Whether every block takes the compact token stream
-        (:func:`runs_compact`): the latent blocks, and grouped-query blocks
-        between state-space layers."""
-        return self.attention == "mla" or self.ssm_layers > 0
+        (:func:`runs_compact`): the latent blocks, grouped-query blocks
+        between state-space layers, and the grouped-query blocks of a
+        block-diffusion prefill (its passes declare no lengths)."""
+        return (self.attention == "mla" or self.ssm_layers > 0
+                or self.block_diffusion)
 
     @property
     def experts_held_count(self) -> int:
@@ -795,14 +797,17 @@ class LlamaBlock(nn.Module):
         SwiGLU in the leading layers, routed (+ shared) experts in the
         rest, with the router the configuration names.
 
-        A prefill that declares its rows' lengths and a ``prefill_capacity``
-        under the step's positions runs it on the real positions alone
-        (``models/moe.RealPositions``: gathered into that many token slots,
-        the result put back at their places); positions at or behind a
-        row's length then receive zeros (the residual alone).  Where the
-        caller's stream is that token set already (``packed``: ``h [1, C,
-        D]``, the latent blocks under ``LlamaModel``'s compact prefill) it
-        is taken and returned as it is."""
+        Where the caller's stream is the real positions' token set
+        (``packed``: ``h [1, C, D]``, every block under ``LlamaModel``'s
+        compact prefill, :func:`runs_compact`) it is taken and returned as
+        it is.  A prefill that declares its rows' lengths and a
+        ``prefill_capacity`` under the step's positions at a shape
+        ``runs_compact`` refuses (a width under 512, a slot count that is
+        not whole 256-slot blocks: the test presets' steps, an odd number
+        of rows at 1,024) still runs this half on the real positions
+        alone (``models/moe.RealPositions``: gathered into that many token
+        slots, the result put back at their places); positions at or
+        behind a row's length then receive zeros (the residual alone)."""
         from music_analyst_tpu.models.moe import RealPositions, RoutedMoE
 
         cfg = self.config
@@ -918,19 +923,26 @@ class LlamaModel(nn.Module):
         # the kernel that reads the lengths in place of the mask.  With a
         # ``prefill_capacity`` (static, ``models/moe.compact_capacity`` of
         # these lengths: sum(lengths) <= capacity) under B*S the step runs
-        # on the real positions alone.  Latent blocks
-        # (:func:`runs_compact`): the hidden state is ``[1, capacity,
-        # dim]`` from the embedding of the real positions' ids to the
-        # position the head reads, each row's real positions one behind
+        # on the real positions alone.  Where :func:`runs_compact` says so
+        # (latent blocks, KDA and state-space layers with their
+        # grouped-query layer, the block-diffusion prefill): the hidden
+        # state is ``[1, capacity, dim]`` from the embedding of the real
+        # positions' ids to the position the head reads (to the last block
+        # where there is no head), each row's real positions one behind
         # the other (``models/moe.RealPositions``); norms, projections,
-        # RoPE, the prefill kernel's packed form, the feed-forward halves
-        # and the residual adds see nothing else, and ONLY the latent
-        # cache (``latents``, ``k_rope``) and the sown ``chosen`` are put
-        # back at ``[B, S]``, zeros at and behind a row's length.  A
+        # QK-norm, RoPE, the feed-forward halves and the residual adds see
+        # nothing else.  The latent prefill kernel takes the stream as it
+        # is (its packed form) and ONLY the latent cache (``latents``,
+        # ``k_rope``) is put back at ``[B, S]``; a grouped-query layer
+        # puts queries, keys and values back at ``[B, S]`` for the cache
+        # view and the kernel it has and gathers the result onto the
+        # stream (``MultiHeadAttention(packed=...)``); the sown ``chosen``
+        # is put back either way; zeros at and behind a row's length.  A
         # hidden state at a padding position does not exist; logits
         # without ``last_position`` are the head's of zeros there.
-        # Grouped-query blocks: the feed-forward halves alone gather the
-        # real positions and put their result back.  Either way what the
+        # Anywhere else (a width or a slot count the kernels refuse): the
+        # feed-forward halves alone gather the real positions and put
+        # their result back.  Either way what the
         # layers return at or behind a row's length is neither computed
         # as the layer would nor defined.  ``row_lengths`` is read by the
         # layers that carry a recurrent state (``models/kda.py``) where
@@ -1334,14 +1346,16 @@ def runs_compact(config: LlamaConfig, shape, capacity) -> bool:
     lengths and this ``prefill_capacity`` keeps its hidden state on the
     compact token set from the embedding to the head (``LlamaModel``):
     blocks that take the stream (``LlamaConfig.compact_stream``: latent
-    blocks, KDA layers among them or not, and grouped-query blocks between
-    state-space layers: every kernel finds a row at its own slot, and the
-    grouped-query layer puts queries, keys and values back at ``[B, S]``
-    for the kernel it has), fewer slots than positions, and a width and a
-    slot count the kernels take (whole 256-slot blocks of the packed
+    blocks, KDA layers among them or not, grouped-query blocks between
+    state-space layers, and the grouped-query blocks of a block-diffusion
+    prefill: every kernel finds a row at its own slot, and a grouped-query
+    layer puts queries, keys and values back at ``[B, S]`` for the cache
+    view and the kernel it has), fewer slots than positions, and a width
+    and a slot count the kernels take (whole 256-slot blocks of the packed
     latent prefill, which are whole chunks of the KDA and the state-space
-    kernels too).  The one place that decides it, for the model and for
-    whoever counts what a step computed."""
+    kernels too; the grouped-query layers keep the same rule, so a step
+    shape has one answer whatever its model).  The one place that decides
+    it, for the model and for whoever counts what a step computed."""
     from music_analyst_tpu.ops.mla_prefill_attention import (
         packed_prefill_block,
     )
@@ -1965,6 +1979,13 @@ class LlamaZeroShotClassifier(ClassifierBackend):
                 labels.append(SUPPORTED_LABELS[int(idx)])
         return labels
 
+    def _prefill_slots(self, rows: int, width: int, capacity) -> int:
+        """Token slots a declared prefill of ``rows x width`` put through
+        the layers: its ``capacity`` where it ran on the compact token set
+        (:func:`runs_compact`), every position where it did not."""
+        return (capacity if runs_compact(self.config, (rows, width), capacity)
+                else rows * width)
+
     def _count_step(self, rows: int, width: int, real, stats) -> None:
         """What one scoring step computed, into the run's telemetry:
         ``decoder.tokens_real`` / ``decoder.tokens_computed`` (positions
@@ -1992,11 +2013,9 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         label_run = n_labels * (label_width - 1)
         label_real = int(np.maximum(self._label_lens - 1, 0).sum())
         tel.count("decoder.tokens_real", tokens_real + rows * label_real)
-        through_layers = (
-            capacity if runs_compact(self.config, (rows, width), capacity)
-            else rows * width)
         tel.count("decoder.tokens_computed",
-                  through_layers + rows * label_run)
+                  self._prefill_slots(rows, width, capacity)
+                  + rows * label_run)
         attrs = dict(rows=rows, width=width, tokens_real=tokens_real,
                      token_pairs=token_pairs, label_positions=label_run,
                      label_positions_real=label_real)

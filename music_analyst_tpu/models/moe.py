@@ -439,9 +439,9 @@ class RoutedMoE(nn.Module):
     and positions at or behind a row's length are NOT computed; their
     output is zero and their ``chosen`` 0, not what the layer would give.
     With ``packed`` the caller's ``x [1, C, D]`` IS that token set (the
-    latent blocks' compact stream) and so is the result: nothing is
-    gathered or put back but the sown ``chosen``, and a filler's routed
-    output is zero (the shared experts' alone is its result).
+    compact stream of ``models/llama.runs_compact``) and so is the result:
+    nothing is gathered or put back but the sown ``chosen``, and a filler's
+    routed output is zero (the shared experts' alone is its result).
 
     ``experts_held = (first, count)`` says this chip holds experts ``first
     .. first + count - 1`` of the layer's ``n_experts`` (expert parallelism:

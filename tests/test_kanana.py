@@ -680,9 +680,10 @@ def test_compact_stream_equals_the_padded_prefill_on_every_real_position(
 
 
 def test_who_keeps_the_padded_stream():
-    """``runs_compact`` is decided by what the call shows: latent blocks,
-    fewer slots than positions, a width and a slot count the packed kernel
-    takes.  Everyone else keeps the ``[B, S, dim]`` stream."""
+    """``runs_compact`` is decided by what the call shows: blocks that
+    take the stream, fewer slots than positions, a width and a slot count
+    the packed kernel takes.  Everyone else keeps the ``[B, S, dim]``
+    stream."""
     tiny = PRESETS["kanana-tiny"]()
     assert llama.runs_compact(tiny, (32, 1024), 12288)
     assert llama.runs_compact(tiny, (4, 512), 768)
@@ -690,8 +691,9 @@ def test_who_keeps_the_padded_stream():
     assert not llama.runs_compact(tiny, (32, 1024), 32 * 1024)   # full rows
     assert not llama.runs_compact(tiny, (4, 64), 128)     # blocked width
     assert not llama.runs_compact(tiny, (13, 512), 832)   # not whole blocks
-    assert not llama.runs_compact(                        # grouped-query
-        PRESETS["sdar-tiny"](), (32, 1024), 12288)
+    # grouped-query blocks alone: a block-diffusion prefill takes the
+    # stream (tests/test_sdar.py), an autoregressive decoder does not
+    assert llama.runs_compact(PRESETS["sdar-tiny"](), (32, 1024), 12288)
     assert not llama.runs_compact(LlamaConfig.tiny(), (32, 1024), 12288)
 
 

@@ -463,8 +463,8 @@ def test_diffusion_step_compiles_for_the_chip_at_the_cells_shapes(
     """Both programs of ``sdar-30b-a3b-chat``'s step (32 x 1,024, the
     published widths, abstract parameters) compiled for a v5e at the rungs
     the cell's jobs meet: the prefill holds one block-causal kernel call a
-    layer and its expert layers run ``capacity`` token slots, not the
-    padded step's 32,768; the block loop's
+    layer, and its hidden state and expert layers run ``capacity`` token
+    slots, not the padded step's 32,768; the block loop's
     grouped matmuls run the 1,024 assignments of a pass, and the donated
     caches come back in place (aliased, not copied)."""
     from music_analyst_tpu.models import block_diffusion, llama
@@ -495,7 +495,12 @@ def test_diffusion_step_compiles_for_the_chip_at_the_cells_shapes(
         f"bf16[{capacity * top_k},{w}]" for w in (768, 2048)}
     assert len(re.findall(r"%_flash_call[.\d]* = \S+ custom-call\(", text)) == (
         config.n_layers)
-    assert prefill.memory_analysis().temp_size_in_bytes < 2.4e9
+    # the hidden state lives on the ``capacity`` slots from the embedding
+    # to the last layer: no array of the padded step's positions x dim
+    assert f"[{rows},{width},{config.dim}]" not in text
+    assert f"[{rows * width},{config.dim}]" not in text
+    assert f"bf16[{capacity},{config.dim}]" in text
+    assert prefill.memory_analysis().temp_size_in_bytes < 1.5e9
 
     caches = placed(jax.eval_shape(lambda: [
         llama.KVCache(c.keys, c.values, jnp.zeros((rows,), jnp.int32))

@@ -463,6 +463,180 @@ def test_compact_prefill_is_bit_equal_on_the_real_positions(steps):
     assert float(stats["expert_load_mean"][0]) * 8 == whole.sum() * 2
 
 
+# a row shorter than a block (nothing of it is prefilled), one that fills
+# the width, a block + 1 (648 whole-block positions in 768 slots); rows that
+# fill their rung to the last slot (1,024 of 1,024, no filler)
+_WIDE_STEPS = {"ragged": [3, 512, 101, 38],
+               "fills-the-capacity": [512, 256, 2, 259]}
+
+
+def _wide_step(clf, lengths, width=512):
+    ids = np.random.default_rng(5).integers(
+        16, clf.config.offline_vocab_size, (len(lengths), width))
+    return ids.astype(np.int32), np.asarray(lengths, np.int32)
+
+
+@pytest.mark.parametrize("lengths", list(_WIDE_STEPS.values()),
+                         ids=list(_WIDE_STEPS))
+def test_compact_stream_equals_the_padded_prefill_on_every_whole_block(
+        clf, lengths):
+    """At a width and a rung ``llama.runs_compact`` admits, a prefill that
+    declares a ``prefill_capacity`` keeps its hidden state on the compact
+    token set through every block (projections, QK-norm, RoPE, norms,
+    residual adds and experts on ``capacity`` slots; queries, keys and
+    values put back at ``[B, S]`` for the cache view and the kernel).
+    Against the same call with the capacity withheld (every position
+    through every layer): the caches on ``[0, whole[row])``, zeros behind
+    it, the chosen experts, and the tokens the block loop generates from
+    either."""
+    from music_analyst_tpu.models import llama
+    from music_analyst_tpu.models.moe import compact_capacity
+
+    ids, lens = _wide_step(clf, lengths)
+    rows, width = ids.shape
+    whole = lens // 4 * 4
+    assert 0 in whole and width in whole
+    capacity = compact_capacity(int(whole.sum()), rows * width)
+    assert capacity == {648: 768, 1024: 1024}[int(whole.sum())]
+    assert llama.runs_compact(clf.config, ids.shape, capacity)
+    args = (clf.params, jnp.asarray(ids), jnp.asarray(lens))
+    full, full_stats = clf._prefill(*args, gen_blocks=4)
+    compact, stats = clf._prefill(*args, gen_blocks=4,
+                                  prefill_capacity=capacity)
+    # both are the kernel's; the one that declared a capacity ran compact
+    at_shape = [(key, record) for key, record in clf._prefill.records.items()
+                if f"int32[{rows}, {width}]" in key]
+    assert len(at_shape) >= 2
+    for key, record in at_shape:
+        assert record.attention_paths == {"block_causal": 3}
+        took = 3 if "prefill_capacity" in key else 0
+        assert record.traced_paths.get("gqa.compact", 0) == took
+        assert record.traced_paths.get("moe.compact", 0) == took
+    real = np.arange(width)[None, :] < whole[:, None]
+    for a, b in zip(full, compact):
+        np.testing.assert_array_equal(b.length, whole)
+        for name in ("keys", "values"):
+            want = np.asarray(getattr(a, name), np.float32)[:, :width]
+            got = np.asarray(getattr(b, name), np.float32)[:, :width]
+            # the same sums a slot as a position: no tile is summed in
+            # another order (the kernel sees the same [B, S] operands)
+            np.testing.assert_array_equal(got[real], want[real])
+            assert not got[~real].any()
+    np.testing.assert_array_equal(
+        np.asarray(stats["chosen"])[:, real],
+        np.asarray(full_stats["chosen"])[:, real])
+    assert not np.asarray(stats["chosen"])[:, ~real].any()
+    # the load counts the whole blocks' assignments alone
+    assert float(stats["expert_load_mean"][0]) * 8 == whole.sum() * 2
+    want, _ = clf._denoise(*args[:1], full, *args[1:], gen_blocks=4)
+    got, _ = clf._denoise(*args[:1], compact, *args[1:], gen_blocks=4)
+    for name in ("tokens", "fresh", "unmask_pass"):
+        np.testing.assert_array_equal(got[name], want[name])
+
+
+@pytest.mark.parametrize("words,compact", [((300, 30, 120, 9), True),
+                                           ((30, 55, 70, 18), False)],
+                         ids=["compact", "padded"])
+def test_tokens_computed_counts_the_slots_the_prefill_ran(clf, words,
+                                                          compact):
+    """``decoder.tokens_computed`` is what went through the layers: the
+    ``capacity`` slots of a prefill that ran on the compact stream, ``rows
+    * width`` of one that did not, and the passes' positions either way;
+    ``decoder.tokens_real`` is the work, whatever implements it."""
+    from music_analyst_tpu.models import llama
+    from music_analyst_tpu.telemetry import get_telemetry
+
+    rng = np.random.default_rng(11)
+    texts = [" ".join(rng.choice(_WORDS, size=n)) for n in words]
+    transferred = clf.transfer(clf.prepare(texts))
+    rows, width = transferred[1].shape
+    prefilled, capacity = transferred[3][1], transferred[3][3]
+    assert capacity < rows * width
+    assert llama.runs_compact(clf.config, (rows, width), capacity) == compact
+    tel = get_telemetry()
+    names = ("decoder.tokens_computed", "decoder.tokens_real",
+             "diffusion.denoise_passes", "diffusion.commit_passes")
+    before = [tel.counters.get(name, 0) for name in names]
+    clf.collect(clf.launch(transferred))
+    computed, real, denoise, commit = (
+        tel.counters.get(name, 0) - b for name, b in zip(names, before))
+    pass_positions = rows * 4 * (denoise + commit)
+    assert real == prefilled + pass_positions
+    assert computed == (capacity if compact else rows * width) + pass_positions
+
+
+# ``sha256(lowered.as_text())[:16]`` (a compile record's ``hlo_fingerprint``)
+# of the scoring program at 4 x 512 with lengths 300, 41, 256, 101, padded
+# and at 768 slots, as the tree before the block-diffusion prefill took the
+# stream lowered them.  A PR that means to change one of these programs
+# replaces its pair here.
+_KEPT_PROGRAMS = {
+    "kanana-tiny": ("036fff5115d8d3d1", "0e77949cc3e2799c"),
+    "ling-tiny": ("87953e0cff93cbef", "5a49013a2523cef7"),
+    "granite-tiny": ("6b3b4af1b8a6d4a4", "003dfc3ea836d9cc"),
+    "llama3-tiny": ("858ad4f66b8792d7", "858ad4f66b8792d7"),
+}
+
+
+@pytest.mark.parametrize("preset", list(_KEPT_PROGRAMS))
+def test_the_other_decoders_answer_and_lower_as_they_did(preset):
+    """Who takes the compact stream beside the block-diffusion prefill, and
+    that their programs are text for text what they were: the latent, the
+    KDA and the state-space configurations run compact where they did, an
+    autoregressive grouped-query decoder never does (a declared capacity
+    changes nothing of its program)."""
+    import hashlib
+
+    from music_analyst_tpu.engines.sentiment import get_backend
+    from music_analyst_tpu.models import llama
+
+    clf = get_backend(preset, seed=0)
+    cfg = clf.config
+    takes = preset != "llama3-tiny"
+    assert cfg.compact_stream == takes and not cfg.block_diffusion
+    assert llama.runs_compact(cfg, (4, 512), 768) == takes
+    assert llama.runs_compact(cfg, (32, 1024), 12288) == takes
+    assert not llama.runs_compact(cfg, (4, 512), None)       # a mesh
+    assert not llama.runs_compact(cfg, (4, 512), 4 * 512)    # full rows
+    assert not llama.runs_compact(cfg, (4, 64), 128)         # narrow
+    extra = {}
+    if cfg.recurrent_state:
+        extra["probe_rows"] = jnp.asarray(np.minimum(clf.probe_rows, 3))
+    args = (clf.params, jnp.zeros((4, 512), jnp.int32),
+            jnp.asarray([300, 41, 256, 101], jnp.int32),
+            jnp.asarray(clf._label_ids), jnp.asarray(clf._label_lens))
+    lowered = tuple(
+        hashlib.sha256(clf._score_labels.lower(
+            *args, prefill_capacity=capacity, **extra).as_text().encode()
+        ).hexdigest()[:16] for capacity in (None, 768))
+    assert lowered == _KEPT_PROGRAMS[preset]
+
+
+def test_a_partitioned_prefill_keeps_the_padded_program(clf):
+    """Under a mesh of more than one device the prefill withholds the
+    lengths (the kernel's call is opaque to the partitioner) and is handed
+    no capacity: the padded program, whatever capacity a caller names."""
+    from music_analyst_tpu.models import llama
+    from music_analyst_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    mesh = build_mesh(MeshSpec((("dp", 1), ("ep", 2), ("tp", 1))),
+                      devices=jax.devices()[:2])
+    meshed = BlockDiffusionClassifier(
+        config=clf.config, mesh=mesh, max_prompt_len=clf.max_prompt_len)
+    ids, lens = _wide_step(clf, _WIDE_STEPS["ragged"])
+    assert llama.runs_compact(clf.config, ids.shape, 768)
+    transferred = meshed.transfer(("", ids, lens))
+    assert transferred[3][3] is None  # no capacity where no length is read
+    args = (meshed.params, jnp.asarray(ids), jnp.asarray(lens))
+
+    def text(program, **static):
+        return program.lower(*args, gen_blocks=4, **static).as_text()
+
+    assert text(meshed._prefill, prefill_capacity=768) == text(
+        meshed._prefill)
+    assert text(clf._prefill, prefill_capacity=768) != text(clf._prefill)
+
+
 # ----------------------------------------------------- the normal path
 
 def test_labels_come_from_the_generated_tokens(clf):

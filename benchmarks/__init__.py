@@ -28,6 +28,7 @@ _SUITE_MODULES = (
     "benchmarks.mla_prefill",
     "benchmarks.kda_prefill",
     "benchmarks.ssd_prefill",
+    "benchmarks.window_prefill",
     "benchmarks.generation",
     "benchmarks.coldstart",
     "benchmarks.ingest",

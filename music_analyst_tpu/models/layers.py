@@ -8,6 +8,7 @@ are kept divisible by the tp degree by construction in the model configs).
 
 from __future__ import annotations
 
+import math
 from typing import Any, Optional, Tuple
 
 import jax
@@ -21,33 +22,114 @@ from music_analyst_tpu.profiling.compile import (
 )
 
 
+# The most positions a RoPE table is built over: a configuration that
+# declares more (2**20 here: 268 MB a table of 64 frequencies, twice a layer
+# kind) gets the same angles computed from the positions it is given
+# (:func:`rope_at`; ``MultiHeadAttention`` decides by this number alone).
+ROPE_TABLE_POSITIONS = 1 << 17
+
+
+def rope_inverse_frequencies(
+    head_dim: int, theta: float = 10_000.0, rotary_dim: int = 0, yarn=None
+) -> Tuple[jax.Array, float]:
+    """``(inv_freq [r / 2], factor)`` of a RoPE over the first ``r`` =
+    ``rotary_dim`` dimensions of a head (0 = all ``head_dim``): ``theta **
+    (-2j / r)``, and ``factor`` 1.  With ``yarn`` (a ``rope_parameters``
+    group of ``rope_type: "yarn"``, as a dict or its items) the frequencies
+    are YaRN's blend as ``transformers``' ``_compute_yarn_parameters``
+    computes it with ``dim = r``, its defaults for the keys the group may
+    lack (``beta_fast`` 32, ``beta_slow`` 1, ``attention_factor`` the
+    paper's ``0.1 ln(factor) + 1``): ``ramp_j = clip((j - low) / (high -
+    low), 0, 1)`` between the dimensions that turn ``beta_fast`` and
+    ``beta_slow`` times over the original context, ``inv_freq_j = (f_j /
+    factor) ramp_j + f_j (1 - ramp_j)``, and ``factor`` =
+    ``attention_factor`` multiplies cos and sin."""
+    r = rotary_dim or head_dim
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r))
+    if yarn is None:
+        return inv_freq, 1.0
+    yarn = dict(yarn)
+    factor = float(yarn["factor"])
+    original = int(yarn["original_max_position_embeddings"])
+
+    def correction_dim(rotations: float) -> float:
+        return (r * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(yarn.get("beta_fast") or 32)), 0)
+    high = min(math.ceil(correction_dim(yarn.get("beta_slow") or 1)), r - 1)
+    if low == high:
+        high += 0.001  # the source's guard against a ramp of no width
+    ramp = jnp.clip(
+        (jnp.arange(r // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    attention_factor = yarn.get("attention_factor") or (
+        0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0)
+    return (inv_freq / factor * ramp + inv_freq * (1 - ramp),
+            float(attention_factor))
+
+
 def rope_frequencies(
-    head_dim: int, max_positions: int, theta: float = 10_000.0
+    head_dim: int, max_positions: int, theta: float = 10_000.0,
+    rotary_dim: int = 0, yarn=None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Precompute RoPE cos/sin tables ``[max_positions, head_dim/2]``."""
-    inv_freq = 1.0 / (
-        theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
-    )
-    positions = jnp.arange(max_positions, dtype=jnp.float32)
-    angles = jnp.outer(positions, inv_freq)
-    return jnp.cos(angles), jnp.sin(angles)
+    """Precompute RoPE cos/sin tables ``[max_positions, r / 2]``, ``r`` the
+    rotary part of the head (``rotary_dim``, 0 = ``head_dim``); with
+    ``yarn`` YaRN's frequencies, cos and sin times its ``attention_factor``
+    (:func:`rope_inverse_frequencies`)."""
+    if not rotary_dim and yarn is None:
+        inv_freq = 1.0 / (
+            theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+        )
+        positions = jnp.arange(max_positions, dtype=jnp.float32)
+        angles = jnp.outer(positions, inv_freq)
+        return jnp.cos(angles), jnp.sin(angles)
+    return rope_at(jnp.arange(max_positions), head_dim, theta, rotary_dim,
+                   yarn)
+
+
+def rope_at(
+    positions: jax.Array, head_dim: int, theta: float = 10_000.0,
+    rotary_dim: int = 0, yarn=None,
+) -> Tuple[jax.Array, jax.Array]:
+    """cos/sin ``[..., r / 2]`` at ``positions [...]``: the rows
+    :func:`rope_frequencies`' tables hold there, without the table."""
+    inv_freq, factor = rope_inverse_frequencies(
+        head_dim, theta, rotary_dim, yarn)
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq
+    if factor == 1.0:
+        return jnp.cos(angles), jnp.sin(angles)
+    return jnp.cos(angles) * factor, jnp.sin(angles) * factor
 
 
 def apply_rope(
-    x: jax.Array, cos: jax.Array, sin: jax.Array, positions: jax.Array
+    x: jax.Array, cos: jax.Array, sin: jax.Array,
+    positions: Optional[jax.Array]
 ) -> jax.Array:
     """Rotate ``x [B, S, H, D]`` by position-dependent angles.
 
     ``positions [B, S]`` indexes the precomputed tables, supporting both
-    prefill (0..S) and decode (cache_len + step) without recompilation.
+    prefill (0..S) and decode (cache_len + step) without recompilation;
+    ``None`` = cos and sin are ``[B, S, r / 2]`` already (:func:`rope_at`).
+    Tables narrower than the head (``r < D``: a partial rotary part) turn
+    the first ``r`` dimensions, half-split over those ``r``, and pass the
+    rest as they are.
     """
-    cos_p = cos[positions][:, :, None, :]  # [B, S, 1, D/2]
-    sin_p = sin[positions][:, :, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    rotated = jnp.concatenate(
-        (x1 * cos_p - x2 * sin_p, x2 * cos_p + x1 * sin_p), axis=-1
-    )
-    return rotated.astype(x.dtype)
+    if positions is None:
+        cos_p, sin_p = cos[:, :, None, :], sin[:, :, None, :]
+    else:
+        cos_p = cos[positions][:, :, None, :]  # [B, S, 1, r/2]
+        sin_p = sin[positions][:, :, None, :]
+    r = 2 * cos.shape[-1]
+    if r < x.shape[-1]:
+        note_traced_path("rope.partial")
+        turned, passed = x[..., :r], x[..., r:]
+    else:
+        turned, passed = x, None
+    x1, x2 = jnp.split(turned, 2, axis=-1)
+    parts = (x1 * cos_p - x2 * sin_p, x2 * cos_p + x1 * sin_p)
+    if passed is not None:
+        parts += (passed,)
+    return jnp.concatenate(parts, axis=-1).astype(x.dtype)
 
 
 def apply_rope_interleaved(
@@ -109,12 +191,12 @@ def dot_product_attention(
     """
     n_q_heads = q.shape[2]
     n_kv_heads = k.shape[2]
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     if n_kv_heads != n_q_heads:
         group = n_q_heads // n_kv_heads
         k = jnp.repeat(k, group, axis=2)
         v = jnp.repeat(v, group, axis=2)
-    if scale is None:
-        scale = q.shape[-1] ** -0.5
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if mask is not None:
         logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
@@ -328,6 +410,21 @@ class MultiHeadAttention(nn.Module):
     # What multiplies the scores before the softmax where a configuration
     # publishes its own; ``None`` = ``head_dim ** -0.5``.
     scale: Optional[float] = None
+    # A sliding window: the query at position ``p`` sees the keys at
+    # positions ``p - window + 1 .. p`` (``window`` keys with its own;
+    # ``transformers``' ``sliding_window_overlay``: ``kv > q - window``) of
+    # those the mask, the lengths or the cache view let it see; 0 = none.
+    window: int = 0
+    # RoPE on the first ``rotary_dim`` dimensions of a head (0 = all of
+    # it), and YaRN's parameters (``rope_frequencies``; hashable: the
+    # group's items); the defaults are plain RoPE over the head.
+    rotary_dim: int = 0
+    yarn: Any = None
+    # One gate a query head on the attention's output, from the layer's
+    # own input: ``o_h <- f(x W_g)_h * o_h`` with ``W_g [D, H]`` float32,
+    # ``f`` = ``"softplus"`` or ``"sigmoid"``, before ``o_proj``; ``"none"``
+    # = no gate and no parameter.
+    output_gate: str = "none"
 
     @nn.compact
     def __call__(
@@ -339,14 +436,22 @@ class MultiHeadAttention(nn.Module):
         lengths: Optional[jax.Array] = None,
         segment_ids: Optional[jax.Array] = None,
         packed=None,
+        key_positions: Optional[jax.Array] = None,
     ):
         # ``packed`` (a ``models/moe.RealPositions``): ``x [1, C, D]`` is
         # the compact token stream of a ``[B, S]`` step (traced path
-        # ``gqa.compact``).  The projections, QK-norm and RoPE run on it;
-        # queries, keys and values are put back at their ``[B, S]`` places
-        # for the cache and the attention this layer has (zeros at and
-        # behind a row's length), and the result is gathered onto the
-        # stream again before ``o_proj``.
+        # ``gqa.compact``).  The projections, QK-norm, RoPE and the output
+        # gate run on it; queries, keys and values are put back at their
+        # ``[B, S]`` places for the cache and the attention this layer has
+        # (zeros at and behind a row's length), and the result is gathered
+        # onto the stream again before the gate and ``o_proj``.
+        # ``key_positions [B, KV]`` (a window layer reads it): the position
+        # of the key each cache slot holds, where a slot is not its
+        # position (a continuation's slots lie behind the prompt's width,
+        # its positions behind the row's length); ``None`` = the slot.
+        # The scopes inside (``gqa.proj`` / ``.rope`` / ``.kernel`` /
+        # ``.gate`` / ``.out``) stand under the caller's own (``gqa`` in a
+        # decoder block, ``encoder.attention`` in the encoder).
         features = x.shape[-1]
         n_kv = self.n_kv_heads or self.n_heads
         head_dim = self.head_dim or features // self.n_heads
@@ -362,23 +467,49 @@ class MultiHeadAttention(nn.Module):
             name=name,
             **stored,
         )
-        q = dense((self.n_heads, head_dim), "q_proj")(x)
-        k = dense((n_kv, head_dim), "k_proj")(x)
-        v = dense((n_kv, head_dim), "v_proj")(x)
-        if self.qk_norm:
-            q = RMSNorm(epsilon=self.norm_eps, name="q_norm")(q)
-            k = RMSNorm(epsilon=self.norm_eps, name="k_norm")(k)
+        with jax.named_scope("gqa.proj"):
+            q = dense((self.n_heads, head_dim), "q_proj")(x)
+            k = dense((n_kv, head_dim), "k_proj")(x)
+            v = dense((n_kv, head_dim), "v_proj")(x)
+            if self.qk_norm:
+                q = RMSNorm(epsilon=self.norm_eps, name="q_norm")(q)
+                k = RMSNorm(epsilon=self.norm_eps, name="k_norm")(k)
+        if self.output_gate not in ("none", "softplus", "sigmoid"):
+            raise ValueError(f"unknown output_gate {self.output_gate!r}")
+        gate = None
+        if self.output_gate != "none":
+            # float32 at the highest matmul precision, as the router's: a
+            # sliver of the projections' cost, and one scalar scales a
+            # whole head
+            note_traced_path("gqa.output_gate")
+            with jax.named_scope("gqa.gate"):
+                gate_w = self.param("g_proj", fan_in_normal(features),
+                                    (features, self.n_heads), jnp.float32)
+                gate = jnp.dot(x.astype(jnp.float32), gate_w,
+                               precision=jax.lax.Precision.HIGHEST)
+                gate = (jax.nn.softplus(gate)
+                        if self.output_gate == "softplus"
+                        else jax.nn.sigmoid(gate))           # [B, S, H]
 
         if self.use_rope:
             if positions is None:
                 positions = jnp.broadcast_to(
                     jnp.arange(x.shape[1]), x.shape[:2]
                 )
-            cos, sin = rope_frequencies(
-                head_dim, self.max_positions, self.rope_theta
-            )
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
+            with jax.named_scope("gqa.rope"):
+                if self.yarn is not None:
+                    note_traced_path("rope.yarn")
+                if self.max_positions > ROPE_TABLE_POSITIONS:
+                    cos, sin = rope_at(positions, head_dim, self.rope_theta,
+                                       self.rotary_dim, self.yarn)
+                    at = None
+                else:
+                    cos, sin = rope_frequencies(
+                        head_dim, self.max_positions, self.rope_theta,
+                        self.rotary_dim, self.yarn)
+                    at = positions
+                q = apply_rope(q, cos, sin, at)
+                k = apply_rope(k, cos, sin, at)
 
         if packed is not None:
             note_traced_path("gqa.compact")
@@ -403,59 +534,90 @@ class MultiHeadAttention(nn.Module):
                     f"flash kernel and a cache view that carries it; {attention} "
                     "has none")
 
-        if paged:
-            if getattr(new_cache, "scale", None) != self.scale:
-                no_scale("this cache view")
-            out = new_cache.attend(q, mask)
-        elif self.attn_impl == "flash" and cache is None:
-            from music_analyst_tpu.ops.flash_attention import flash_attention
+        def no_window(attention: str):
+            if self.window:
+                raise ValueError(
+                    "a sliding window reaches the dense form, the flash "
+                    f"kernel and a cache view that carries it; {attention} "
+                    "masks none")
 
-            # The flash kernel expresses masking ONLY via flash_causal +
-            # lengths; an arbitrary `mask` array can't reach it and would
-            # be silently dropped — refuse outright.  Callers on the flash
-            # path pass mask=None and encode semantics in flash_causal /
-            # lengths (see LlamaBlock / DistilBert TransformerBlock).
-            if mask is not None:
-                raise ValueError(
-                    "attn_impl='flash' cannot apply a mask array; pass "
-                    "mask=None with lengths= (padding) and/or flash_causal "
-                    "set, or use attn_impl='dense' for arbitrary masks"
-                )
-            out = flash_attention(
-                q, k, v, lengths=lengths, causal=self.flash_causal,
-                q_segment_ids=segment_ids, scale=self.scale,
-            )
-        else:
-            if segment_ids is not None:
-                raise ValueError(
-                    "segment_ids is the flash path's masking vocabulary; "
-                    "dense callers build the block-diagonal mask array "
-                    "themselves (models/distilbert.py)"
-                )
-            if mask is None and lengths is not None and cache is None:
-                # Key padding described by `lengths` alone: the caller
-                # built no mask array because the shape is one the
-                # whole-row kernel takes (models/distilbert.py decides).
-                from music_analyst_tpu.ops.whole_row_attention import (
-                    whole_row_attention,
+        if self.window:
+            note_traced_path("gqa.window")
+        with jax.named_scope("gqa.kernel"):
+            if paged:
+                if getattr(new_cache, "scale", None) != self.scale:
+                    no_scale("this cache view")
+                if getattr(new_cache, "window", 0) != self.window:
+                    no_window("this cache view")
+                out = new_cache.attend(q, mask)
+            elif self.attn_impl == "flash" and cache is None:
+                from music_analyst_tpu.ops.flash_attention import (
+                    flash_attention,
                 )
 
-                no_scale("the whole-row kernel")
-                note_attention_path("whole_row")
-                out = whole_row_attention(q, k, v, lengths, mesh=self.mesh)
+                # The flash kernel expresses masking ONLY via flash_causal
+                # + lengths (+ window); an arbitrary `mask` array can't
+                # reach it and would be silently dropped — refuse outright.
+                # Callers on the flash path pass mask=None and encode
+                # semantics in flash_causal / lengths (see LlamaBlock /
+                # DistilBert TransformerBlock).
+                if mask is not None:
+                    raise ValueError(
+                        "attn_impl='flash' cannot apply a mask array; pass "
+                        "mask=None with lengths= (padding) and/or "
+                        "flash_causal set, or use attn_impl='dense' for "
+                        "arbitrary masks"
+                    )
+                out = flash_attention(
+                    q, k, v, lengths=lengths, causal=self.flash_causal,
+                    q_segment_ids=segment_ids, scale=self.scale,
+                    window=self.window,
+                )
             else:
-                note_attention_path("dense")
-                out = dot_product_attention(q, k, v, mask, self.scale)
+                if segment_ids is not None:
+                    raise ValueError(
+                        "segment_ids is the flash path's masking "
+                        "vocabulary; dense callers build the block-diagonal "
+                        "mask array themselves (models/distilbert.py)"
+                    )
+                if mask is None and lengths is not None and cache is None:
+                    # Key padding described by `lengths` alone: the caller
+                    # built no mask array because the shape is one the
+                    # whole-row kernel takes (models/distilbert.py decides).
+                    from music_analyst_tpu.ops.whole_row_attention import (
+                        whole_row_attention,
+                    )
+
+                    no_scale("the whole-row kernel")
+                    no_window("the whole-row kernel")
+                    note_attention_path("whole_row")
+                    out = whole_row_attention(q, k, v, lengths,
+                                              mesh=self.mesh)
+                else:
+                    if self.window:
+                        mask = window_mask(
+                            mask, self.window, q.shape[1], k.shape[1],
+                            positions if packed is None else None,
+                            key_positions)
+                        note_attention_path("window_dense")
+                    else:
+                        note_attention_path("dense")
+                    out = dot_product_attention(q, k, v, mask, self.scale)
         if packed is not None:
             out = packed.gather(out)[None]
-        out = dense_cls(
-            features=features,
-            axis=(-2, -1),
-            use_bias=self.use_bias,
-            dtype=self.dtype,
-            name="o_proj",
-            **stored,
-        )(out)
+        if gate is not None:
+            with jax.named_scope("gqa.gate"):
+                out = (out.astype(jnp.float32) * gate[..., None]
+                       ).astype(out.dtype)
+        with jax.named_scope("gqa.out"):
+            out = dense_cls(
+                features=features,
+                axis=(-2, -1),
+                use_bias=self.use_bias,
+                dtype=self.dtype,
+                name="o_proj",
+                **stored,
+            )(out)
         if cache is not None:
             return out, new_cache
         return out
@@ -525,6 +687,25 @@ def causal_mask(q_len: int, kv_len: int, offset) -> jax.Array:
     q_pos = jnp.arange(q_len)[:, None] + offset
     kv_pos = jnp.arange(kv_len)[None, :]
     return (kv_pos <= q_pos)[None, None, :, :]
+
+
+def window_mask(mask: Optional[jax.Array], window: int, q_len: int,
+                kv_len: int, positions: Optional[jax.Array] = None,
+                key_positions: Optional[jax.Array] = None) -> jax.Array:
+    """``mask`` (broadcastable ``[B, H, q_len, kv_len]``; a window layer's
+    caller always has one: a window alone is not causal) and the sliding
+    window ``key position > query position - window``.  ``positions [B,
+    q_len]`` are the queries' (``None`` = ``0 .. q_len - 1``, a prefill
+    from position 0); ``key_positions [B, kv_len]`` the position of the key
+    a slot holds (``None`` = the slot's index)."""
+    if mask is None:
+        raise ValueError(
+            "a sliding-window layer takes its causal mask from the caller")
+    q_pos = (jnp.arange(q_len)[None, :] if positions is None
+             else positions)[:, None, :, None]
+    k_pos = (jnp.arange(kv_len)[None, :] if key_positions is None
+             else key_positions)[:, None, None, :]
+    return mask & (k_pos > q_pos - window)
 
 
 def block_causal_mask(q_len: int, kv_len: int, block: int) -> jax.Array:

@@ -64,6 +64,26 @@ LYRICS_TRUNCATION = 4000
 
 
 @dataclasses.dataclass(frozen=True)
+class AttentionKind:
+    """What a grouped-query layer of one kind (``layer_types``' name for
+    it) has of its own where a model's attention layers differ: query
+    heads, a sliding window (0 = none), and its RoPE: ``rope_theta``, the
+    rotary part of the head (0 = all of it), YaRN's parameters (the
+    group's items, hashable; ``None`` = plain RoPE)."""
+
+    n_heads: int
+    window: int = 0
+    rope_theta: float = 10_000.0
+    rotary_dim: int = 0
+    yarn: Optional[tuple] = None
+
+
+# ``layer_types`` names of grouped-query layers whose widths are answered by
+# kind (``LlamaConfig.attention_kind``), beside "mamba" and "attention".
+ATTENTION_KINDS = ("full_attention", "sliding_attention")
+
+
+@dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 128_256
     dim: int = 4096
@@ -166,7 +186,9 @@ class LlamaConfig:
     experts_held: Optional[tuple] = None
     # The mixer a layer, as a published list (``"mamba"`` = a Mamba-2
     # state-space layer, models/mamba2.py: a recurrent state a row;
-    # ``"attention"`` = the ``attention`` kind); ``None`` = by
+    # ``"attention"`` = the ``attention`` kind; ``"full_attention"`` /
+    # ``"sliding_attention"`` = grouped-query layers whose heads, window
+    # and RoPE ``attention_kinds`` states by that name); ``None`` = by
     # ``mixer_period``.  The four widths below are Mamba-2's.
     layer_types: Optional[tuple] = None
     mamba_n_heads: int = 0
@@ -184,6 +206,20 @@ class LlamaConfig:
     logits_scaling: float = 1.0
     # The head is the embedding's transpose: no ``lm_head`` parameter.
     tie_embeddings: bool = False
+    # Grouped-query layers of different kinds in one model:
+    # ``((name, AttentionKind), ..)`` for the names ``layer_types`` uses
+    # (:meth:`attention_kind` answers a layer's; a layer whose name is not
+    # here has ``n_heads``, ``rope_theta``, no window, the whole head
+    # rotated).
+    attention_kinds: Optional[tuple] = None
+    # One gate a query head on grouped-query attention's output, from the
+    # layer's normed input (``models/layers.MultiHeadAttention``):
+    # "softplus", "sigmoid" or "none".
+    gqa_output_gate: str = "none"
+    # A gate on the shared expert's output (a family whose configuration
+    # names ``shared_expert_intermediate_size`` has one): not implemented;
+    # a configuration that states it is refused.
+    shared_expert_gate: bool = False
 
     def __post_init__(self):
         if self.attention not in ("gqa", "mla"):
@@ -209,17 +245,45 @@ class LlamaConfig:
                     self, name, tuple(int(n) for n in getattr(self, name)))
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            known = ("mamba", "attention") + ATTENTION_KINDS
             if (len(self.layer_types) != self.n_layers
-                    or set(self.layer_types) - {"mamba", "attention"}):
+                    or set(self.layer_types) - set(known)):
                 raise ValueError(
-                    "layer_types names n_layers layers, each mamba or "
-                    "attention")
-            if self.attention != "gqa" or min(
+                    "layer_types names n_layers layers, each one of "
+                    + ", ".join(known))
+            if self.attention != "gqa":
+                raise ValueError(
+                    "layer_types lists grouped-query layers and what stands "
+                    "between them: attention must be gqa")
+            if "mamba" in self.layer_types and min(
                     self.mamba_n_heads, self.mamba_head_dim,
                     self.mamba_d_state) < 1:
                 raise ValueError(
                     "layer_types puts Mamba-2 layers between grouped-query "
-                    "ones: attention must be gqa and the mamba widths set")
+                    "ones: the mamba widths must be set")
+        if self.attention_kinds is not None:
+            kinds = tuple((str(name), kind)
+                          for name, kind in self.attention_kinds)
+            object.__setattr__(self, "attention_kinds", kinds)
+            listed = set(self.layer_types or ())
+            for name, kind in kinds:
+                if name not in listed or not isinstance(kind, AttentionKind):
+                    raise ValueError(
+                        f"attention_kinds states {name!r}: an AttentionKind "
+                        "a name that layer_types uses")
+                if kind.n_heads % self.n_kv_heads or kind.window < 0:
+                    raise ValueError(
+                        f"attention kind {name!r}: {kind.n_heads} query "
+                        f"heads over {self.n_kv_heads} key/value heads, "
+                        f"window {kind.window}")
+        if self.gqa_output_gate not in ("none", "softplus", "sigmoid"):
+            raise ValueError(
+                f"unknown gqa_output_gate {self.gqa_output_gate!r}")
+        if self.shared_expert_gate:
+            raise ValueError(
+                "shared_expert_gate: a gate on the shared expert's output "
+                "is not implemented (models/moe.RoutedMoE adds the shared "
+                "experts ungated)")
         if self.tie_embeddings and self.weight_quant != "none":
             raise ValueError(
                 "weight_quant stores a head of its own: no tied embeddings")
@@ -265,6 +329,9 @@ class LlamaConfig:
         ``attention`` kind.  The one place that answers, from the list
         (``layer_types``) or the period (``mixer_period``)."""
         if self.layer_types is not None:
+            # "attention" and the kinds of ``ATTENTION_KINDS`` are all the
+            # ``attention`` kind's mixer; what differs between them is
+            # :meth:`attention_kind`'s to say
             return ("mamba" if self.layer_types[index] == "mamba"
                     else self.attention)
         if self.layer_ids is not None:
@@ -272,6 +339,30 @@ class LlamaConfig:
         if self.mixer_period and (index + 1) % self.mixer_period:
             return "kda"
         return self.attention
+
+    def layer_kind(self, index: int) -> str:
+        """Layer ``index``'s name in ``layer_types`` (its mixer's where the
+        layers are not listed)."""
+        return (self.layer_types[index] if self.layer_types is not None
+                else self.mixer(index))
+
+    def attention_kind(self, index: int) -> AttentionKind:
+        """The widths grouped-query layer ``index`` has of its own: its
+        kind's (``attention_kinds`` by the layer's name in ``layer_types``)
+        or, for a model whose attention layers are all alike, the
+        configuration's one ``n_heads`` and ``rope_theta``, no window, the
+        whole head rotated.  The one place that answers."""
+        if self.attention_kinds is not None:
+            for name, kind in self.attention_kinds:
+                if name == self.layer_types[index]:
+                    return kind
+        return AttentionKind(self.n_heads, rope_theta=self.rope_theta)
+
+    @property
+    def window_layers(self) -> int:
+        """Grouped-query layers with a sliding window."""
+        return sum(self.mixer(i) == "gqa" and self.attention_kind(i).window > 0
+                   for i in range(self.n_layers))
 
     def _layers_of(self, mixer: str) -> int:
         return sum(self.mixer(i) == mixer for i in range(self.n_layers))
@@ -291,12 +382,22 @@ class LlamaConfig:
         return self.kda_layers + self.ssm_layers > 0
 
     @property
+    def mixed_layers(self) -> bool:
+        """Whether the layers differ in kind: mixers on a recurrent state
+        between attention layers, or attention layers listed by kind.  A
+        scoring step of such a model hands back what its prefill left in
+        the caches of ``probe_rows`` (``score_labels_program``)."""
+        return self.recurrent_state or self.layer_types is not None
+
+    @property
     def compact_stream(self) -> bool:
         """Whether every block takes the compact token stream
-        (:func:`runs_compact`): the latent blocks, grouped-query blocks
-        between state-space layers, and the grouped-query blocks of a
-        block-diffusion prefill (its passes declare no lengths)."""
-        return (self.attention == "mla" or self.ssm_layers > 0
+        (:func:`runs_compact`): the latent blocks, the blocks of a model
+        whose layers are listed by kind (grouped-query blocks between
+        state-space layers, full and sliding-window grouped-query blocks),
+        and the grouped-query blocks of a block-diffusion prefill (its
+        passes declare no lengths)."""
+        return (self.attention == "mla" or self.layer_types is not None
                 or self.block_diffusion)
 
     @property
@@ -328,11 +429,13 @@ class LlamaConfig:
     @classmethod
     def from_hf_config(cls, hf: dict, **overrides) -> "LlamaConfig":
         """The decoder a published ``config.json`` of ``model_type:
-        deepseek_v3``, ``sdar_moe``, ``ling_hybrid`` or ``granitemoehybrid``
-        describes, key by key.  What this code cannot run is refused by
-        name, not approximated."""
+        deepseek_v3``, ``sdar_moe``, ``ling_hybrid``, ``granitemoehybrid``
+        or ``laguna`` describes, key by key.  What this code cannot run is
+        refused by name, not approximated."""
         if hf.get("model_type") == "sdar_moe":
             return cls._from_sdar_moe(hf, overrides)
+        if hf.get("model_type") == "laguna":
+            return cls._from_laguna(hf, overrides)
         if hf.get("model_type") == "granitemoehybrid":
             return cls._from_granitemoehybrid(hf, overrides)
         if hf.get("model_type") == "ling_hybrid":
@@ -541,6 +644,122 @@ class LlamaConfig:
         return cls(**fields)
 
     @classmethod
+    def _from_laguna(cls, hf: dict, overrides: dict) -> "LlamaConfig":
+        """``model_type: laguna``: every layer grouped-query attention over
+        ``num_key_value_heads`` heads of ``head_dim``, of the kind
+        ``layer_types`` names: a kind has its own count of query heads
+        (``num_attention_heads_per_layer``) and its own RoPE
+        (``rope_parameters[kind]``: ``default`` or ``yarn``, on
+        ``partial_rotary_factor`` of the head), and ``sliding_attention``
+        sees the last ``sliding_window`` keys; one gate a head on the
+        attention's output (``gating: "per-head"``); the leading
+        ``mlp_only_layers`` keep a dense SwiGLU of ``intermediate_size``,
+        the others route ``num_experts_per_tok`` of ``num_experts`` experts
+        of ``moe_intermediate_size`` (renormalised, times
+        ``moe_routed_scaling_factor``) beside shared experts of
+        ``shared_expert_intermediate_size``; untied head.
+
+        Four readings are of function and not of shape, and no key states
+        them: the caller's ``overrides`` (a preset's ``runtime`` block) do,
+        one field each: ``moe_router`` (how scores are made and chosen),
+        ``gqa_output_gate`` (the gate's nonlinearity), ``qk_norm`` (RMSNorm
+        a head on queries and keys before RoPE), ``shared_expert_gate``
+        (whether the shared expert's output is gated).  How many of the
+        experts this chip holds is the caller's to say too
+        (``experts_held``)."""
+        layers = hf["num_hidden_layers"]
+        kinds = list(hf.get("layer_types") or ())
+        heads_by_layer = list(hf.get("num_attention_heads_per_layer")
+                              or [hf["num_attention_heads"]] * layers)
+        gates = list(hf.get("gating_types") or ["per_head"] * layers)
+        dense = sorted(hf.get("mlp_only_layers") or ())
+        rope = hf.get("rope_parameters") or {}
+        width, shared = (hf["moe_intermediate_size"],
+                         hf["shared_expert_intermediate_size"])
+        heads_of = {kind: {h for h, k in zip(heads_by_layer, kinds)
+                           if k == kind} for kind in set(kinds)}
+        ffn_kinds = hf.get("mlp_layer_types") or (
+            ["dense"] * len(dense) + ["sparse"] * (layers - len(dense)))
+        unsupported = {
+            "attention_bias": bool(hf.get("attention_bias", False)),
+            "moe_router_logit_softcapping": bool(
+                hf.get("moe_router_logit_softcapping", 0)),
+            "moe_apply_router_weight_on_input": bool(
+                hf.get("moe_apply_router_weight_on_input", False)),
+            "decoder_sparse_step": hf.get("decoder_sparse_step", 1) != 1,
+            "mlp_only_layers": dense != list(range(len(dense))),
+            "mlp_layer_types": list(ffn_kinds) != (
+                ["dense"] * len(dense) + ["sparse"] * (layers - len(dense))),
+            "layer_types": (len(kinds) != layers
+                            or bool(set(kinds) - set(ATTENTION_KINDS))),
+            "gating": hf.get("gating") != "per-head",
+            "gating_types": (len(gates) != layers
+                             or bool(set(gates) - {"per_head"})),
+            "num_attention_heads_per_layer": (
+                len(heads_by_layer) != layers
+                or any(len(h) != 1 for h in heads_of.values())),
+            "rope_parameters": any(
+                (rope.get(kind) or {}).get("rope_type") not in (
+                    "default", "yarn") for kind in set(kinds)),
+            "sliding_window": ("sliding_attention" in kinds
+                               and not hf.get("sliding_window")),
+            "tie_word_embeddings": bool(hf.get("tie_word_embeddings", False)),
+            "shared_expert_intermediate_size": bool(shared % width),
+        }
+        bad = sorted(k for k, v in unsupported.items() if v)
+        if bad:
+            raise ValueError(
+                "this decoder does not implement the configuration's "
+                + ", ".join(f"{k}={hf.get(k)!r}" for k in bad)
+            )
+        readings = ("moe_router", "gqa_output_gate", "qk_norm",
+                    "shared_expert_gate")
+        missing = [name for name in readings if name not in overrides]
+        if missing:
+            raise ValueError(
+                "a laguna configuration states no " + ", ".join(missing)
+                + ": the preset's runtime block has to")
+        head_dim = hf["head_dim"]
+
+        def kind_of(name: str) -> AttentionKind:
+            group = rope[name]
+            rotary = int(head_dim * group.get("partial_rotary_factor", 1))
+            yarn = None
+            if group["rope_type"] == "yarn":
+                yarn = tuple(sorted(
+                    (key, value) for key, value in group.items()
+                    if key not in ("rope_type", "rope_theta",
+                                   "partial_rotary_factor")))
+            return AttentionKind(
+                n_heads=heads_of[name].pop(),
+                window=(hf["sliding_window"]
+                        if name == "sliding_attention" else 0),
+                rope_theta=float(group["rope_theta"]),
+                rotary_dim=0 if rotary == head_dim else rotary, yarn=yarn)
+
+        fields = dict(
+            vocab_size=hf["vocab_size"], dim=hf["hidden_size"],
+            n_layers=layers, n_heads=hf["num_attention_heads"],
+            n_kv_heads=hf["num_key_value_heads"], head_dim=head_dim,
+            hidden_dim=hf["intermediate_size"],
+            max_seq_len=hf["max_position_embeddings"],
+            rms_norm_eps=float(hf["rms_norm_eps"]),
+            layer_types=tuple(kinds),
+            attention_kinds=tuple(
+                (name, kind_of(name)) for name in sorted(set(kinds))),
+            first_k_dense_replace=len(dense),
+            n_experts=hf["num_experts"],
+            moe_top_k=hf["num_experts_per_tok"], moe_hidden_dim=width,
+            n_shared_experts=shared // width,
+            routed_scaling_factor=float(hf["moe_routed_scaling_factor"]),
+            norm_topk_prob=bool(hf["norm_topk_prob"]),
+            # every layer's own is its kind's; this is the first layer's
+            rope_theta=float(rope[kinds[0]]["rope_theta"]),
+        )
+        fields.update(overrides)
+        return cls(**fields)
+
+    @classmethod
     def _from_sdar_moe(cls, hf: dict, overrides: dict) -> "LlamaConfig":
         """``model_type: sdar_moe``: a Qwen3-MoE-shaped layer (grouped-query
         attention with QK-norm, a published ``head_dim``, every layer
@@ -549,6 +768,8 @@ class LlamaConfig:
         ``confidence_threshold``, ``mask_token_id``) are not in
         ``config.json``: the caller's ``overrides`` state them."""
         unsupported = {
+            # the kernel and the cache view take a window beside the block
+            # rule, but no test holds the two together to a reference
             "use_sliding_window": bool(hf.get("use_sliding_window", False)),
             "mlp_only_layers": bool(hf.get("mlp_only_layers")),
             "decoder_sparse_step": hf.get("decoder_sparse_step", 1) != 1,
@@ -632,6 +853,14 @@ LATENT_CACHE_REFUSAL = (
 )
 
 
+WINDOW_REFUSAL = (
+    "some of this model's attention layers see a sliding window of keys "
+    "(LlamaConfig.attention_kind: key position > query position - window); "
+    "the {runtime} runtime's slots or pages are attended whole: its kernel "
+    "masks by length alone and no page is released behind a window"
+)
+
+
 RECURRENT_STATE_REFUSAL = (
     "most of this model's layers carry a recurrent state a row "
     "(models/kda.RecurrentState or models/mamba2.SSMState: a float32 matrix "
@@ -653,7 +882,8 @@ class LlamaBlock(nn.Module):
                  segment_ids: Optional[jax.Array] = None,
                  prefill_lengths: Optional[jax.Array] = None,
                  prefill_capacity: Optional[int] = None,
-                 packed=None, row_lengths: Optional[jax.Array] = None):
+                 packed=None, row_lengths: Optional[jax.Array] = None,
+                 key_positions: Optional[jax.Array] = None):
         cfg = self.config
         if cfg.attention == "mla":
             return self._latent_block(x, mask, positions, cache,
@@ -675,12 +905,19 @@ class LlamaBlock(nn.Module):
                 "fold the segment mask into `mask` for the dense impl"
             )
         dtype = jnp.dtype(cfg.dtype)
+        # heads, window and RoPE are the layer's kind's (one kind in most
+        # models: the configuration's own ``n_heads`` and ``rope_theta``)
+        kind = cfg.attention_kind(self.layer_index)
         attn = MultiHeadAttention(
-            n_heads=cfg.n_heads,
+            n_heads=kind.n_heads,
             n_kv_heads=cfg.n_kv_heads,
             head_dim=cfg.attn_head_dim,
             use_rope=cfg.use_rope,
-            rope_theta=cfg.rope_theta,
+            rope_theta=kind.rope_theta,
+            window=kind.window,
+            rotary_dim=kind.rotary_dim,
+            yarn=kind.yarn,
+            output_gate=cfg.gqa_output_gate,
             max_positions=cfg.max_seq_len,
             dtype=dtype,
             attn_impl=cfg.attn_impl,
@@ -707,10 +944,11 @@ class LlamaBlock(nn.Module):
                 if view:
                     cache = BlockCausalPrefill(
                         cache, prefill_lengths.astype(jnp.int32), 1,
-                        scale=cfg.attention_scale or None)
+                        scale=cfg.attention_scale or None,
+                        window=kind.window)
                 attn_out, new_cache = attn(
                     h, mask=mask, positions=positions, cache=cache,
-                    packed=packed,
+                    packed=packed, key_positions=key_positions,
                 )
                 if view:
                     new_cache = new_cache.cache
@@ -912,6 +1150,7 @@ class LlamaModel(nn.Module):
         prefill_capacity: Optional[int] = None,    # static — see below
         with_head: bool = True,  # False: no logits (``None`` in their place)
         row_lengths: Optional[jax.Array] = None,   # [B] — see below
+        key_positions: Optional[jax.Array] = None,  # [B, KV] — see below
     ):
         # ``prefill_lengths`` is read by the blocks whose expert layers are
         # ``RoutedMoE`` (the latent blocks hand it to their attention too,
@@ -948,16 +1187,22 @@ class LlamaModel(nn.Module):
         # layers that carry a recurrent state (``models/kda.py``) where
         # ``prefill_lengths`` is withheld: how many of this call's tokens
         # exist a row (a mask cannot tell a state when to stop), a fact
-        # that promises nothing.  ``lengths`` keeps its one
+        # that promises nothing.  ``key_positions`` is read by
+        # sliding-window layers on a cache: the position of the key each
+        # slot holds where that is not the slot's index (a continuation
+        # behind a padded prompt); a window layer adds its own rule (``key
+        # position > query position - window``) to whatever ``mask``, the
+        # lengths or the cache view let a query see, so ``mask`` stays one
+        # array for all layers.  ``lengths`` keeps its one
         # meaning, the flash path's key padding:
         # CONTRACT: with cfg.attn_impl == "flash" (and no caches), the
         # `mask` argument is NOT applied — attention is causal + key-
-        # padding-by-`lengths` + optional same-segment (packed documents,
-        # ``segment_ids``; pair with per-segment-restarted ``positions``).
-        # Callers needing any other mask (sliding window, prefix-LM,
-        # cross-attention) must use the dense impl — where `mask` is
-        # arbitrary, so packed-causal is expressed there as
-        # ``causal & same-segment`` in the array; MultiHeadAttention
+        # padding-by-`lengths` + the layer's own sliding window + optional
+        # same-segment (packed documents, ``segment_ids``; pair with
+        # per-segment-restarted ``positions``).  Callers needing any other
+        # mask (prefix-LM, cross-attention) must use the dense impl —
+        # where `mask` is arbitrary, so packed-causal is expressed there
+        # as ``causal & same-segment`` in the array; MultiHeadAttention
         # raises if a mask array reaches the flash branch directly.
         cfg = self.config
         dtype = jnp.dtype(cfg.dtype)
@@ -985,7 +1230,7 @@ class LlamaModel(nn.Module):
                 x, mask, positions, cache_i, lengths,
                 segment_ids=segment_ids, prefill_lengths=prefill_lengths,
                 prefill_capacity=prefill_capacity, packed=packed,
-                row_lengths=row_lengths,
+                row_lengths=row_lengths, key_positions=key_positions,
             )
             if new_cache is not None:
                 new_caches.append(new_cache)
@@ -1290,14 +1535,14 @@ def init_params_by_layer(cfg: LlamaConfig, seed: int = 0):
     def program(module, *args):
         return jax.jit(lambda key: module.init(key, *args)["params"])
 
-    inits = {}  # (routed?, mixer) -> jitted init of that layer kind
+    inits = {}  # (routed?, the layer's kind) -> jitted init of that kind
     params = {}
     embed = nn.Embed(cfg.vocab_size, cfg.dim, dtype=dtype,
                      param_dtype=jnp.dtype(cfg.param_dtype))
     params["tok_embeddings"] = program(embed, positions)(
         jax.random.fold_in(root, 0))
     for i in range(cfg.n_layers):
-        kind = (cfg.routed_layer(i), cfg.mixer(i))
+        kind = (cfg.routed_layer(i), cfg.layer_kind(i))
         if kind not in inits:
             inits[kind] = program(LlamaBlock(cfg, i), x, mask, positions, None)
         params[f"layer_{i}"] = inits[kind](jax.random.fold_in(root, 1 + i))
@@ -1332,8 +1577,7 @@ def _prefill_capacity(config: LlamaConfig, mesh, prompt_lens,
     ``models/moe.compact_capacity`` that holds the real tokens, where the
     blocks read ``prefill_lengths`` (latent blocks, one device); ``None``
     where they are withheld or unread, so such a step has one program."""
-    if not (config.latent_cache or config.block_diffusion
-            or config.ssm_layers) or _partitioned(mesh):
+    if not config.compact_stream or _partitioned(mesh):
         return None
     from music_analyst_tpu.models.moe import compact_capacity
 
@@ -1377,6 +1621,20 @@ def runs_compact(config: LlamaConfig, shape, capacity) -> bool:
 MAX_LABEL_TOKENS = 8
 
 
+def _key_positions(config: LlamaConfig, prompt_lens, width: int,
+                   kv_len: int):
+    """``[B, kv_len]``: the position of the key each cache slot holds once
+    tokens are written behind a ``width``-wide prompt: slot ``j < width``
+    holds position ``j``, slot ``width + t`` position ``prompt_lens[row] +
+    t``.  ``None`` for a model without sliding-window layers, whose masks
+    say everything."""
+    if not config.window_layers:
+        return None
+    slots = jnp.arange(kv_len)[None, :]
+    return jnp.where(slots < width, slots,
+                     prompt_lens[:, None] + slots - width)
+
+
 def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
     """The jitted scoring step: one prompt prefill a row, then the
     teacher-forced label continuations on its cache (``profiled_jit``
@@ -1407,8 +1665,9 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
         ``[routed layers]`` of the prefill, for a model with routed
         experts; else empty; ``chosen_labels [3, layers, B, L, k]``: the
         experts the continuations' positions ran, ``-1`` at the last,
-        which ran none).  ``probe_rows [P]`` (a model with recurrent
-        state alone): the rows whose state after the prefill rides back
+        which ran none).  ``probe_rows [P]`` (a model whose layers
+        differ in kind, ``LlamaConfig.mixed_layers``): the rows whose
+        caches after the prefill ride back
         too (``stats["probe"]``: every KDA or Mamba-2 layer's ``state
         [layers, P, H, ., .]``, the Mamba-2 layers' ``conv`` tails, every
         latent layer's ``latents`` and ``rope_keys`` ``[layers, P, S, .]``,
@@ -1473,6 +1732,8 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
                 (logits2, _), sown2 = model.apply(
                     {"params": params}, lab[:, :-1], pos,
                     prompt_part | label_part, caches,
+                    key_positions=_key_positions(config, prompt_lens, S,
+                                                 kv_len),
                     mutable=["intermediates"],
                 )
                 rest_lp = jnp.take_along_axis(
@@ -1543,7 +1804,9 @@ def _probe(config: LlamaConfig, caches, rows, width: int) -> dict:
     for i, cache in enumerate(caches):
         by_kind.setdefault(config.mixer(i), []).append(cache)
     carried = by_kind.get("kda", []) + by_kind.get("mamba", [])
-    kept = {"state": jnp.stack([c.state[rows] for c in carried])}
+    kept = {}
+    if carried:
+        kept["state"] = jnp.stack([c.state[rows] for c in carried])
     if "mamba" in by_kind:
         kept["conv"] = jnp.stack([c.conv[rows] for c in by_kind["mamba"]])
     if "mla" in by_kind:
@@ -1568,6 +1831,8 @@ def decode_step_program(model: LlamaModel):
         # a recurrent state has no length: the first cache that has one
         kv_len = next(c.max_len for c in caches if hasattr(c, "max_len"))
         kv_pos = jnp.arange(kv_len)[None, None, None, :]
+        # a slot is its key's position here (``generate`` sets the caches'
+        # length to the row's), which is what a window layer counts from
         mask = kv_pos <= position[:, None, None, None]
         logits, caches = model.apply(
             {"params": params}, token, position[:, None], mask, caches
@@ -1634,6 +1899,7 @@ def generate_scan_program(model: LlamaModel, config: LlamaConfig,
             lg, caches = model.apply(
                 {"params": params}, token[:, None], pos[:, None],
                 step_mask, caches,
+                key_positions=_key_positions(config, prompt_lens, S, total),
             )
             nxt = jnp.argmax(lg[:, -1], axis=-1).astype(jnp.int32)
             done = done | (token == eos)
@@ -1841,10 +2107,10 @@ class LlamaZeroShotClassifier(ClassifierBackend):
 
             self.params = shard_params(self.params, mesh)
         self._label_ids, self._label_lens = _label_table(self.tokenizer)
-        # The rows of a step whose recurrent state rides back with the
-        # scores (``_score_labels``' ``probe_rows``: a model with KDA
-        # layers alone); whoever compares states with a reference sets the
-        # rows it sampled.  Values, not a program.
+        # The rows of a step whose caches after the prefill ride back
+        # with the scores (``_score_labels``' ``probe_rows``: a model whose
+        # layers differ in kind alone); whoever compares them with a
+        # reference sets the rows it sampled.  Values, not a program.
         self.probe_rows = np.arange(8, dtype=np.int32)
         self._score_labels = score_labels_program(
             self.model, self.config, mesh)
@@ -1857,11 +2123,14 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         """Why the continuous decode runtimes (``serving/
         decode_runtime.py``) cannot host this model, or ``None`` where
         they can: a latent cache, a step that yields a block
-        (``models/block_diffusion.py``'s own), or a recurrent state beside
-        the cache, whatever the cache's kind.  ``serve`` reads it to leave
-        the ``generate`` op off."""
+        (``models/block_diffusion.py``'s own), a recurrent state beside
+        the cache, whatever the cache's kind, or a sliding window their
+        kernels do not mask.  ``serve`` reads it to leave the ``generate``
+        op off."""
         if self.config.recurrent_state:
             return RECURRENT_STATE_REFUSAL
+        if self.config.window_layers:
+            return WINDOW_REFUSAL
         return LATENT_CACHE_REFUSAL if self.config.latent_cache else None
 
     @classmethod
@@ -1941,20 +2210,83 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         texts, prompt_ids, prompt_lens = prepared
         count_h2d_bytes([prompt_ids, prompt_lens])
         lens = prompt_lens.astype(np.int64)
-        # real prompt tokens, their causal (query, key) pairs, and the
-        # token slots the prefill's feed-forward layers run for them
+        # real prompt tokens, their causal (query, key) pairs, the token
+        # slots the prefill's feed-forward layers run for them, and, of a
+        # model whose attention layers differ in kind, the pairs by kind
         real = (int(lens.sum()), int((lens * (lens + 1) // 2).sum()),
                 _prefill_capacity(self.config, self.mesh, lens,
-                                  prompt_ids.shape))
+                                  prompt_ids.shape),
+                self._attention_counts(lens, prompt_ids.shape[1]))
         return (texts, jnp.asarray(prompt_ids), jnp.asarray(prompt_lens),
                 real)
+
+    def _attention_counts(self, lens, width: int) -> Optional[dict]:
+        """What the grouped-query layers of one scoring step attend to
+        where they differ in kind (``None`` for every other model), from
+        the rows' lengths on the host: layers with and without a window;
+        the real (query, key) pairs inside the mask of ONE layer of each
+        (``token_pairs_full``: causal; ``token_pairs_window``: of them
+        those inside the window) and of the label positions whose forward
+        is read (``label_pairs_*``); ``token_pairs``, their sum over the
+        layers; and ``token_pairs_tiles``, the pairs a query head of the
+        prefill computed, summed over the layers: whole tiles of the
+        kernel's grid (``ops/kv_cache.prefill_tile_pairs``), every pair of
+        the step where the masked form ran."""
+        cfg = self.config
+        if cfg.attention_kinds is None:
+            return None
+        from music_analyst_tpu.ops.kv_cache import prefill_tile_pairs
+
+        windows = [cfg.attention_kind(i).window for i in range(cfg.n_layers)
+                   if cfg.mixer(i) == "gqa"]
+        kernel = cfg.attn_impl == "flash" and not _partitioned(self.mesh)
+
+        def pairs(window: int) -> int:
+            causal = lens * (lens + 1) // 2
+            if not window:
+                return int(causal.sum())
+            w = window
+            return int(np.where(lens <= w, causal,
+                                w * (w + 1) // 2 + (lens - w) * w).sum())
+
+        def label_pairs(window: int) -> int:
+            # the label token at offset t behind a prompt of n tokens sees
+            # the prompt and the label's tokens up to its own
+            total = 0
+            for n_label in self._label_lens:
+                for t in range(max(int(n_label) - 1, 0)):
+                    seen = lens + t + 1
+                    total += int((np.minimum(seen, window) if window
+                                  else seen).sum())
+            return total
+
+        def tiles(window: int) -> int:
+            if kernel:
+                return prefill_tile_pairs(lens, width, window)
+            return len(lens) * width * (width + MAX_LABEL_TOKENS)
+
+        window = max(windows, default=0)
+        n_window = sum(w > 0 for w in windows)
+        n_full = len(windows) - n_window
+        counts = {
+            "attention_layers_full": n_full,
+            "attention_layers_window": n_window,
+            "token_pairs_full": pairs(0),
+            "token_pairs_window": pairs(window),
+            "label_pairs_full": label_pairs(0),
+            "label_pairs_window": label_pairs(window),
+        }
+        counts["token_pairs"] = (n_full * counts["token_pairs_full"]
+                                 + n_window * counts["token_pairs_window"])
+        counts["token_pairs_tiles"] = sum(tiles(w) for w in windows)
+        return counts
 
     def launch(self, transferred):
         """Dispatch the scoring program (JAX async dispatch: the handle
         holds device arrays, nothing blocks)."""
         texts, prompt_ids, prompt_lens, real = transferred
         extra = {}
-        if self.config.recurrent_state:
+        if self.config.mixed_layers:
             extra["probe_rows"] = jnp.asarray(
                 np.minimum(self.probe_rows, prompt_ids.shape[0] - 1))
         scores, stats = self._score_labels(
@@ -2006,7 +2338,7 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         from music_analyst_tpu.telemetry import get_telemetry
 
         tel = get_telemetry()
-        tokens_real, token_pairs, capacity = real
+        tokens_real, token_pairs, capacity, attention = real
         # a continuation runs the table's width less one: the positions
         # whose forward some label reads (``score_labels_program``)
         n_labels, label_width = self._label_ids.shape
@@ -2023,6 +2355,17 @@ class LlamaZeroShotClassifier(ClassifierBackend):
             slots = rows * width if capacity is None else capacity
             attrs.update(self._count_expert_load(stats, slots))
         cfg = self.config
+        if attention is not None:
+            # ``token_pairs`` of such a model is the sum over its layers,
+            # each by its kind's rule (``_attention_counts``)
+            attrs.update(attention)
+            tel.count("attention.full_tokens",
+                      tokens_real * attention["attention_layers_full"])
+            tel.count("attention.window_tokens",
+                      tokens_real * attention["attention_layers_window"])
+            tel.gauge("kv_cache_bytes", int(
+                rows * (width + MAX_LABEL_TOKENS) * cfg.n_layers
+                * 2 * 2 * cfg.n_kv_heads * cfg.attn_head_dim))
         if cfg.latent_cache:
             tel.gauge("latent_cache_bytes", int(
                 rows * (width + MAX_LABEL_TOKENS)

@@ -19,9 +19,13 @@ Grid ``(B, H, q_blocks, kv_blocks)``; the kv dimension is innermost and
 sequential ("arbitrary"), with the running state in VMEM scratch that
 persists across kv steps.  GQA maps query head ``h`` to kv head
 ``h // group`` in the BlockSpec index map — no ``jnp.repeat`` of K/V.
-Masking vocabulary: ``causal`` (with block skipping), per-row ``lengths``
-(key padding), and per-token ``segment_ids`` (block-diagonal, for packed
-batches) — all composable in one pass.
+Masking vocabulary: ``causal`` (with block skipping), a sliding ``window``
+behind the diagonal (with block skipping on that side too), per-row
+``lengths`` (key padding), ``block_causal`` (a block-diffusion prefill's
+rule) and per-token ``segment_ids`` (block-diagonal, for packed batches) —
+all composable in one pass.  :func:`tile_runs` is the one rule for which
+tiles of the grid compute: the kernel reads it, and so does whoever counts
+what the kernel computed (:func:`visited_pairs`).
 """
 
 from __future__ import annotations
@@ -48,6 +52,51 @@ def interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def tile_runs(qi, ki, block_q: int, block_kv: int, kv_len, causal: bool,
+              block_causal: int = 0, window: int = 0, q_off=0, kv_off=0):
+    """Whether tile ``(qi, ki)`` of the grid (queries ``q_off + qi *
+    block_q ..``, keys ``kv_off + ki * block_kv ..``, global positions)
+    computes, or is skipped because no pair in it can be inside the mask.
+    Arithmetic and comparisons alone: the kernel calls it on its program
+    ids, a host count on arrays of tile indices."""
+    if causal:
+        # Skip kv blocks whose every (offset-adjusted) position is above
+        # the diagonal: they can't contribute to the online softmax.  (A
+        # ``block_causal`` length divides both tile sizes, so the last
+        # query of a tile also ends its block and the rule is the same.)
+        run = kv_off + ki * block_kv <= q_off + qi * block_q + block_q - 1
+    else:
+        run = ki >= 0
+    if window:
+        # ... and those wholly behind the window of the tile's FIRST
+        # query (the furthest back any query of the tile sees): a key at
+        # ``k`` is seen by the query at ``q`` iff ``k > q - window``.
+        run = run & (kv_off + ki * block_kv + block_kv - 1
+                     > q_off + qi * block_q - window)
+    if block_causal:
+        # A block-causal prefill is self-attention of rows ``kv_len``
+        # long: key tiles at or past a row's length add nothing, and
+        # query tiles there are read by nobody (written as zeros).
+        run = run & (kv_off + ki * block_kv < kv_len) & (
+            q_off + qi * block_q < kv_len)
+    return run
+
+
+def visited_pairs(lengths, width: int, block_q: int, block_kv: int,
+                  window: int = 0, block_causal: int = 1) -> int:
+    """(query, key) pairs in the tiles a causal self-attention call of
+    ``width`` positions a row computes for rows ``lengths`` long (host
+    integers): the grid's tiles :func:`tile_runs` lets run, whole, for one
+    query head.  Against the real pairs inside the mask it says what the
+    kernel computes outside it."""
+    lengths = np.asarray(lengths, np.int64).reshape(-1, 1, 1)
+    qi = np.arange(width // block_q, dtype=np.int64)[None, :, None]
+    ki = np.arange(width // block_kv, dtype=np.int64)[None, None, :]
+    runs = tile_runs(qi, ki, block_q, block_kv, lengths, True, block_causal,
+                     window)
+    return int(np.sum(runs)) * block_q * block_kv
+
+
 def _flash_kernel(
     len_ref,  # SMEM [B] — kv valid length per batch row
     off_ref,  # SMEM [2] — (q_offset, kv_offset) global position offsets
@@ -60,6 +109,7 @@ def _flash_kernel(
     residuals: bool,
     segmented: bool,
     block_causal: int = 0,
+    window: int = 0,
 ):
     if segmented:
         # VMEM [1, bq, 1] / [1, 1, bkv] — per-token segment ids (block-
@@ -87,20 +137,8 @@ def _flash_kernel(
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    if causal:
-        # Skip kv blocks whose every (offset-adjusted) position is above
-        # the diagonal: they can't contribute to the online softmax.  (A
-        # ``block_causal`` length divides both tile sizes, so the last
-        # query of a tile also ends its block and the rule is the same.)
-        run = kv_off + ki * block_kv <= q_off + qi * block_q + block_q - 1
-    else:
-        run = ki >= 0
-    if block_causal:
-        # A block-causal prefill is self-attention of rows ``kv_len``
-        # long: key tiles at or past a row's length add nothing, and
-        # query tiles there are read by nobody (written as zeros).
-        run = run & (kv_off + ki * block_kv < kv_len) & (
-            q_off + qi * block_q < kv_len)
+    run = tile_runs(qi, ki, block_q, block_kv, kv_len, causal, block_causal,
+                    window, q_off, kv_off)
 
     @pl.when(run)
     def _compute():
@@ -129,6 +167,11 @@ def _flash_kernel(
                 q_pos = q_pos // block_causal * block_causal + (
                     block_causal - 1)
             valid = valid & (kv_pos <= q_pos)
+        if window:
+            q_at = q_off + qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, dimension=0
+            )
+            valid = valid & (kv_pos > q_at - window)
         if segmented:
             valid = valid & (qseg_ref[0] == kvseg_ref[0])  # [bq,1]==[1,bkv]
         s = jnp.where(valid, s, NEG_INF)
@@ -162,7 +205,7 @@ def _flash_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "block_q", "block_kv", "interpret",
-                     "residuals", "block_causal", "scale"),
+                     "residuals", "block_causal", "scale", "window"),
 )
 def _flash_call(
     q: jax.Array,       # [B, S, H, D]
@@ -179,6 +222,7 @@ def _flash_call(
     kv_seg: jax.Array | None = None,  # [B, KV]
     block_causal: int = 0,
     scale: float | None = None,
+    window: int = 0,
 ):
     B, S, H, D = q.shape
     KV = k.shape[1]
@@ -205,6 +249,7 @@ def _flash_call(
         residuals=residuals,
         segmented=segmented,
         block_causal=block_causal,
+        window=window,
     )
     qblock_spec = pl.BlockSpec(
         (1, 1, block_q, D),
@@ -310,8 +355,17 @@ def flash_attention(
     kv_segment_ids: jax.Array | None = None,
     block_causal: int = 0,
     scale: float | None = None,
+    window: int = 0,
 ):
     """Attention over ``[B, S, H, D]`` without materializing logits.
+
+    ``window`` = ``w`` > 0 adds a sliding window: the query at (global)
+    position ``i`` sees key ``j`` only where ``j > i - w`` (``w`` keys with
+    its own under ``causal``).  Key tiles wholly behind the window of a
+    query tile's first query are skipped as tiles above the diagonal are
+    (the compute; the index map does not see the runtime offsets, so the
+    fetch is not).  Under ``block_causal`` a query's window still counts
+    from its own position.
 
     ``scale`` multiplies the scores before the softmax: ``D ** -0.5``
     unless a configuration publishes its own (``None`` = that default, and
@@ -363,6 +417,8 @@ def flash_attention(
         raise ValueError(
             f"block_causal={block_causal} needs causal=True and a length "
             f"that divides the tiles ({block_q} x {block_kv})")
+    if window < 0:
+        raise ValueError(f"window={window} is not a number of keys")
     if lengths is None:
         # Lengths are *global* positions: with a kv_offset the local shard
         # covers [kv_offset, kv_offset + KV).
@@ -402,4 +458,5 @@ def flash_attention(
         block_kv, interpret, return_residuals, q_seg=q_seg, kv_seg=kv_seg,
         block_causal=block_causal,
         scale=None if scale is None else float(scale),
+        window=int(window),
     )

@@ -109,6 +109,23 @@ def block_causal_tile(n_queries: int) -> int:
     return 0
 
 
+def prefill_tile_pairs(lengths, width: int, window: int = 0) -> int:
+    """(query, key) pairs a query head of a causal prefill view
+    (:class:`BlockCausalPrefill` with ``block`` 1) computes for rows
+    ``lengths`` long at ``width`` positions a row (host integers): whole
+    tiles of the kernel's grid where the width admits the kernel
+    (``ops/flash_attention.visited_pairs`` at :func:`block_causal_tile`),
+    every pair of every row in the masked XLA form."""
+    import numpy as np
+
+    tile = block_causal_tile(width)
+    if not tile:
+        return int(np.size(lengths)) * width * width
+    from music_analyst_tpu.ops.flash_attention import visited_pairs
+
+    return visited_pairs(lengths, width, tile, tile, window)
+
+
 def _grouped_scores(q, k, scale):
     """``q [B, n, H, D]`` against ``k [B, L, Hkv, D]`` without repeating
     the key heads: ``[B, Hkv, G, n, L]`` float32."""
@@ -137,13 +154,19 @@ class BlockCausalPrefill:
 
     ``block`` 1 is the causal rule itself: the view of an autoregressive
     decoder's declared prefill (``models/llama.LlamaBlock``), rows of any
-    length.  ``scale`` multiplies the scores (``None`` = ``D ** -0.5``)."""
+    length.  ``scale`` multiplies the scores (``None`` = ``D ** -0.5``).
+    ``window`` > 0 is a sliding-window layer's prefill: a query sees the
+    ``window`` keys up to its own (``key > query - window``) of those the
+    rule above lets it see, in the kernel (attention path
+    ``window_causal``; tiles wholly behind the window skipped) and in the
+    masked form (``window_causal_dense``) alike."""
 
     cache: KVCache
     lengths: jax.Array  # [B] int32, a multiple of ``block`` a row
     block: int
     kernel: bool = True
     scale: float | None = None
+    window: int = 0
     k_new: jax.Array | None = None
     v_new: jax.Array | None = None
 
@@ -165,17 +188,21 @@ class BlockCausalPrefill:
         if tile:
             from music_analyst_tpu.ops.flash_attention import flash_attention
 
-            note_attention_path("block_causal")
+            note_attention_path(
+                "window_causal" if self.window else "block_causal")
             return flash_attention(
                 q, self.k_new, self.v_new, lengths=self.lengths,
                 causal=True, block_causal=self.block, block_q=tile,
-                block_kv=tile, scale=self.scale)
-        note_attention_path("block_causal_dense")
+                block_kv=tile, scale=self.scale, window=self.window)
+        note_attention_path(
+            "window_causal_dense" if self.window else "block_causal_dense")
         scores = _grouped_scores(
             q, self.k_new,
             q.shape[-1] ** -0.5 if self.scale is None else self.scale)
         pos = jnp.arange(n)
         seen = (pos[None, :] // self.block <= pos[:, None] // self.block)
+        if self.window:
+            seen = seen & (pos[None, :] > pos[:, None] - self.window)
         seen = seen[None] & (pos[None, None, :]
                              < self.lengths[:, None, None])    # [B, n, n]
         scores = jnp.where(seen[:, None, None], scores, NEG_INF)
@@ -228,7 +255,7 @@ class BlockPass:
 
 for _view, _data, _meta in (
     (BlockCausalPrefill, ["cache", "lengths", "k_new", "v_new"],
-     ["block", "kernel", "scale"]),
+     ["block", "kernel", "scale", "window"]),
     (BlockPass, ["cache", "filled", "k_new", "v_new"], ["commit"]),
 ):
     jax.tree_util.register_dataclass(_view, data_fields=_data,

@@ -167,7 +167,8 @@ def test_family_rows(model, family, buckets, weight_quant, env):
 def test_unknown_model_names_the_families():
     with pytest.raises(ValueError, match=(
             "unknown model 'bert': expected 'mock', 'distilbert\\*', "
-            "'llama\\*', 'granite\\*', 'kanana\\*', 'ling\\*' or 'sdar\\*'")):
+            "'llama\\*', 'granite\\*', 'kanana\\*', 'laguna\\*', 'ling\\*' or "
+            "'sdar\\*'")):
         seam.get_backend("bert")
 
 

@@ -20,7 +20,7 @@ def test_every_advertised_module_registers(monkeypatch):
     assert len(names) >= len(benchmarks._SUITE_MODULES)
     for expected in (
         "roofline", "flash_sweep", "mla_prefill", "kda_prefill", "ssd_prefill",
-        "generation", "coldstart",
+        "window_prefill", "generation", "coldstart",
         "ingest",
         "scaling", "joint", "llama_zeroshot", "sentiment_int8", "bucketing",
         "overlap", "streaming", "serving", "router", "slo", "crash",
@@ -31,7 +31,7 @@ def test_every_advertised_module_registers(monkeypatch):
 @pytest.mark.parametrize(
     "name",
     ["roofline", "flash_sweep", "mla_prefill", "kda_prefill", "ssd_prefill",
-     "generation", "ingest",
+     "window_prefill", "generation", "ingest",
      "joint", "llama_zeroshot", "sentiment_int8", "bucketing", "overlap",
      "streaming", "serving"],
 )

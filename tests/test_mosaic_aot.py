@@ -20,7 +20,9 @@ the rungs its compact prefill meets; the KDA prefill kernel at the hybrid
 cell's step (64 x 1,024, 32 heads of 128 | 128; compact and padded) and the
 whole scoring step of that cell; flash attention
 under the block-causal rule at the diffusion cell's prefill (32 x 1,024, 32
-query heads on 4 key heads of 128) and both programs of that cell's step.
+query heads on 4 key heads of 128) and both programs of that cell's step;
+flash attention with a sliding window at the window cell's prefill (32 x
+1,024, 72 and 48 query heads on 8 key heads of 128, window 512).
 One compile holds no kernel of ours: the grouped experts of a routed layer
 (``models/moe.grouped_experts``, XLA's own grouped matmul) at the decoder
 cells' compact token set, with 6 and with 8 choices a token, for what XLA
@@ -454,6 +456,44 @@ def test_block_causal_flash_attention_compiles_under_mosaic(
         ((rows, width, kv_heads, head_dim), jnp.bfloat16),
         ((rows, width, kv_heads, head_dim), jnp.bfloat16),
         ((rows,), jnp.int32))
+
+
+@pytest.mark.parametrize("heads,window", [(72, 512), (48, 0), (6, 8)],
+                         ids=["sliding-72", "full-48", "laguna-tiny"])
+def test_windowed_flash_attention_compiles_under_mosaic(
+    tpu_sharding, monkeypatch, heads, window
+):
+    """The window cell's prefill kernel: 32 rows x 1,024, 72 query heads (a
+    sliding layer's, window 512) and 48 (a full layer's) on 8 key/value
+    heads of 128, through the cache's causal view at the tile it takes
+    there; and ``laguna-tiny``'s heads at its smallest admitted width."""
+    from music_analyst_tpu.ops.kv_cache import (
+        BlockCausalPrefill,
+        KVCache,
+        block_causal_tile,
+    )
+
+    from music_analyst_tpu.ops import flash_attention as kernel_module
+
+    monkeypatch.setattr(kernel_module, "interpret_default", lambda: False)
+    rows, width, kv_heads, head_dim = (
+        (32, 1024, 8, 128) if heads > 6 else (8, 256, 2, 16))
+    assert block_causal_tile(width) in (256, 512)
+
+    def fn(q, k, v, lengths):
+        view = BlockCausalPrefill(
+            KVCache.zeros(rows, width + 8, kv_heads, head_dim), lengths, 1,
+            window=window).update(k, v)
+        return view.attend(q), view.cache.keys
+
+    compiled = _compile_for_tpu(
+        fn, tpu_sharding,
+        ((rows, width, heads, head_dim), jnp.bfloat16),
+        ((rows, width, kv_heads, head_dim), jnp.bfloat16),
+        ((rows, width, kv_heads, head_dim), jnp.bfloat16),
+        ((rows,), jnp.int32))
+    assert len(re.findall(r"%_flash_call[.\d]* = \S+ custom-call\(",
+                          compiled.as_text())) == 1
 
 
 @pytest.mark.parametrize("capacity", [12288, 16384])

@@ -15,7 +15,11 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from music_analyst_tpu.ops.kv_cache import KVCache
+from music_analyst_tpu.ops.kv_cache import (
+    KVCache,
+    grouped_scores,
+    grouped_values,
+)
 from music_analyst_tpu.profiling.compile import (
     note_attention_path,
     note_traced_path,
@@ -183,25 +187,29 @@ def dot_product_attention(
     mask: Optional[jax.Array] = None,
     scale: Optional[float] = None,
 ) -> jax.Array:
-    """Plain attention ``[B, S, H, D]`` with fp32 softmax accumulation.
+    """Plain attention ``[B, S, H, D]``: float32 scores out of the matmul,
+    float32 softmax, the result in ``q.dtype``.
 
-    Grouped-query support: when ``k``/``v`` carry fewer heads than ``q``,
-    KV heads are broadcast over the query-head groups (Llama-3 GQA).
-    ``scale`` multiplies the scores (``None`` = ``D ** -0.5``).
+    Grouped-query attention in place for every head ratio: ``k`` / ``v
+    [B, KV, Hkv, D]`` meet each group of ``G = H // Hkv`` query heads as
+    they are (``ops/kv_cache.grouped_scores`` / ``grouped_values``, traced
+    path ``gqa.grouped`` where ``Hkv < H``), never repeated to ``H`` heads;
+    ``G`` 1 is multi-head attention with a unit axis.  ``mask`` is
+    broadcastable ``[B, H|1, S, KV]``.  ``scale`` multiplies the scores
+    (``None`` = ``D ** -0.5``).
     """
-    n_q_heads = q.shape[2]
-    n_kv_heads = k.shape[2]
+    n_heads, n_kv = q.shape[2], k.shape[2]
+    if n_kv < n_heads:
+        note_traced_path("gqa.grouped")
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    if n_kv_heads != n_q_heads:
-        group = n_q_heads // n_kv_heads
-        k = jnp.repeat(k, group, axis=2)
-        v = jnp.repeat(v, group, axis=2)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+    scores = grouped_scores(q, k, scale)               # [B, Hkv, G, S, KV]
     if mask is not None:
-        logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+        mask = (mask[:, :, None] if mask.shape[1] == 1 else mask.reshape(
+            mask.shape[:1] + (n_kv, n_heads // n_kv) + mask.shape[2:]))
+        scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return grouped_values(probs, v, q.dtype).astype(q.dtype)
 
 
 class QuantDenseGeneral(nn.Module):

@@ -126,7 +126,13 @@ def prefill_tile_pairs(lengths, width: int, window: int = 0) -> int:
     return visited_pairs(lengths, width, tile, tile, window)
 
 
-def _grouped_scores(q, k, scale):
+# Grouped-query attention in place: each key/value head meets its group of
+# ``G = H // Hkv`` query heads as it is, never repeated to ``H`` heads
+# (query head ``h`` reads key head ``h // G``, as a repeat would give it).
+# The one definition of the masked form's two contractions: the views below
+# and ``models/layers.dot_product_attention`` (every other caller's) use it.
+
+def grouped_scores(q, k, scale):
     """``q [B, n, H, D]`` against ``k [B, L, Hkv, D]`` without repeating
     the key heads: ``[B, Hkv, G, n, L]`` float32."""
     B, n, H, D = q.shape
@@ -135,8 +141,9 @@ def _grouped_scores(q, k, scale):
                       preferred_element_type=jnp.float32) * scale
 
 
-def _grouped_values(p, v, dtype):
-    """``p [B, Hkv, G, n, L]`` over ``v [B, L, Hkv, D]``: ``[B, n, H, D]``."""
+def grouped_values(p, v, dtype):
+    """``p [B, Hkv, G, n, L]`` over ``v [B, L, Hkv, D]``: ``[B, n, H, D]``
+    float32 (``p`` cast to ``dtype`` first)."""
     out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(dtype), v,
                      preferred_element_type=jnp.float32)
     return out.reshape(out.shape[:2] + (-1, out.shape[-1]))
@@ -196,7 +203,7 @@ class BlockCausalPrefill:
                 block_kv=tile, scale=self.scale, window=self.window)
         note_attention_path(
             "window_causal_dense" if self.window else "block_causal_dense")
-        scores = _grouped_scores(
+        scores = grouped_scores(
             q, self.k_new,
             q.shape[-1] ** -0.5 if self.scale is None else self.scale)
         pos = jnp.arange(n)
@@ -206,8 +213,8 @@ class BlockCausalPrefill:
         seen = seen[None] & (pos[None, None, :]
                              < self.lengths[:, None, None])    # [B, n, n]
         scores = jnp.where(seen[:, None, None], scores, NEG_INF)
-        return _grouped_values(jax.nn.softmax(scores, axis=-1), self.v_new,
-                               q.dtype).astype(q.dtype)
+        return grouped_values(jax.nn.softmax(scores, axis=-1), self.v_new,
+                              q.dtype).astype(q.dtype)
 
 
 @dataclasses.dataclass
@@ -239,17 +246,17 @@ class BlockPass:
         note_attention_path("block_over_cache")
         scale = q.shape[-1] ** -0.5
         keys, values = self.cache.keys, self.cache.values
-        cached = _grouped_scores(q, keys, scale)           # [B,Hkv,G,n,L]
+        cached = grouped_scores(q, keys, scale)            # [B,Hkv,G,n,L]
         seen = (jnp.arange(keys.shape[1])[None, :]
                 < self.filled[:, None])[:, None, None, None, :]
         cached = jnp.where(seen, cached, NEG_INF)
-        own = _grouped_scores(q, self.k_new.astype(keys.dtype), scale)
+        own = grouped_scores(q, self.k_new.astype(keys.dtype), scale)
         probs = jax.nn.softmax(
             jnp.concatenate([cached, own], axis=-1), axis=-1)
         split = keys.shape[1]
-        out = (_grouped_values(probs[..., :split], values, q.dtype)
-               + _grouped_values(probs[..., split:],
-                                 self.v_new.astype(values.dtype), q.dtype))
+        out = (grouped_values(probs[..., :split], values, q.dtype)
+               + grouped_values(probs[..., split:],
+                                self.v_new.astype(values.dtype), q.dtype))
         return out.astype(q.dtype)
 
 

@@ -17,11 +17,11 @@ Two kernel bodies, chosen statically by backend
   one program over the whole batch; the in-kernel take-gather feeds the
   *verbatim* ops of the dense reference
   (``models/layers.dot_product_attention`` over the gathered view) —
-  same ``repeat``-broadcast GQA, same einsum subscripts, same cast/scale
-  order — so interpret-mode lowering is **bitwise** identical to the
-  retired gather path.  (A no-repeat grouped contraction is
-  mathematically equal but reassociates the head broadcast, and a
-  1-ulp logit difference flips greedy argmax near-ties.)
+  the same grouped contractions (``ops/kv_cache.grouped_scores`` /
+  ``grouped_values``), the same cast/scale order — so interpret-mode
+  lowering is **bitwise** identical to the retired gather path.  (Any
+  other form is mathematically equal but reassociates, and a 1-ulp
+  logit difference flips greedy argmax near-ties.)
 * **streaming body** (real TPU, compiled by Mosaic): grid ``(n_slots,
   pages_per_slot)``; the table rides in SMEM as a scalar-prefetch
   operand and the K/V page BlockSpecs index the pool *through* it, so
@@ -68,6 +68,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from music_analyst_tpu.ops.flash_attention import interpret_default
+from music_analyst_tpu.ops.kv_cache import grouped_scores, grouped_values
 
 # Masked logit value.  The exact body uses finfo.min to match the dense
 # reference bitwise; the streaming body's running max starts here and
@@ -108,15 +109,15 @@ def _exact_body(n, H, n_kv, D, P, pps, total, quantized, dtype):
     Bitwise-identical to dense attention over the gathered contiguous
     view (tests/test_paged_attention.py pins this at page sizes 8 and
     16): after the gather, the ops ARE ``dot_product_attention``'s —
-    ``repeat``-broadcast GQA, the same einsum subscripts, fp32 cast
-    before the ``D**-0.5`` scale, ``finfo.min`` masking, softmax cast
-    back to ``q.dtype``.  Any algebraic shortcut here (e.g. contracting
-    groups without the repeat) reassociates multiply-adds, and a 1-ulp
-    logit difference flips greedy argmax near-ties — the byte-identity
-    contract forbids it.
+    the same grouped contractions (``ops/kv_cache.grouped_scores`` /
+    ``grouped_values``: no repeat of the key heads, float32 scores out of
+    the matmul times ``D**-0.5``), ``finfo.min`` masking, the float32
+    softmax cast to ``q.dtype`` for the values.  Any other association of
+    the multiply-adds (repeating the key heads among them) can move a
+    logit by an ulp, and a 1-ulp logit difference flips greedy argmax
+    near-ties — the byte-identity contract forbids it.
     """
     span = pps * P
-    G = H // n_kv
     att_scale = D ** -0.5
 
     def body(table_ref, mask_ref, q_ref, kp_ref, vp_ref, *rest):
@@ -133,17 +134,13 @@ def _exact_body(n, H, n_kv, D, P, pps, total, quantized, dtype):
             v = _dequant(v, sv, dtype)
         k = k.reshape(n, span, n_kv, D)[:, :total]
         v = v.reshape(n, span, n_kv, D)[:, :total]
-        q = q_ref[:]
-        if n_kv != H:
-            k = jnp.repeat(k, G, axis=2)
-            v = jnp.repeat(v, G, axis=2)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-        s = s * att_scale
+        s = grouped_scores(q_ref[:], k, att_scale)     # [n, kv, G, 1, total]
         s = jnp.where(
-            mask_ref[:][:, None, None, :total], s, jnp.finfo(jnp.float32).min
+            mask_ref[:][:, None, None, None, :total], s,
+            jnp.finfo(jnp.float32).min,
         )
-        p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-        o_ref[:] = jnp.einsum("bhqk,bkhd->bqhd", p, v)
+        p = jax.nn.softmax(s, axis=-1)
+        o_ref[:] = grouped_values(p, v, dtype).astype(dtype)
 
     return body
 

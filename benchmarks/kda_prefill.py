@@ -5,14 +5,15 @@ forms, at the hybrid cell's step.
 states, with the prompt lengths of the benchmark's own corpus (as
 ``benchmarks/mla_prefill.py`` draws them): ``kernel_ms`` is one call of the
 Pallas kernel on the rows laid one behind the other in
-``models/moe.compact_capacity`` slots (what a compact prefill's KDA layer
-calls), ``kernel_padded_ms`` the same rows at ``[B, S]`` (a row a 1,024-slot
-stretch), ``xla_ms`` the chunked XLA form on the padded rows.  The errors
-are against the token-by-token recurrence in float32 on the same bfloat16
-operands, at log-decays drawn down to the lower bound of -5 a step (and, in
-``max_abs_error_repeated_keys``, with every key of a row nearly the same: the
-case the block-wise inverse exists for).  ``tflops`` counts ``6 * dk * dv`` a
-token a head, the recurrence's own operations (``perfbench/flops_ling.py``).
+``models/compact_stream.compact_capacity`` slots (what a compact prefill's
+KDA layer calls), ``kernel_padded_ms`` the same rows at ``[B, S]`` (a row
+a 1,024-slot stretch), ``xla_ms`` the chunked XLA form on the padded rows.
+The errors are against the token-by-token recurrence in float32 on the
+same bfloat16 operands, at log-decays drawn down to the lower bound of -5
+a step (and, in ``max_abs_error_repeated_keys``, with every key of a row
+nearly the same: the case the block-wise inverse exists for).  ``tflops``
+counts ``6 * dk * dv`` a token a head, the recurrence's own operations
+(``perfbench/flops_ling.py``).
 
     chiprun -- python3 bench.py --suite=kda_prefill
 """
@@ -53,7 +54,10 @@ def run() -> dict:
     import jax.numpy as jnp
 
     from benchmarks.mla_prefill import _prompt_lengths
-    from music_analyst_tpu.models.moe import RealPositions, compact_capacity
+    from music_analyst_tpu.models.compact_stream import (
+        RealPositions,
+        compact_capacity,
+    )
     from music_analyst_tpu.ops.kda_attention import (
         kda_chunked,
         kda_chunked_xla,
@@ -68,7 +72,7 @@ def run() -> dict:
         lens = _prompt_lengths(seq)[:rows]
     lens_d = jnp.asarray(lens, jnp.int32)
     valid = jnp.arange(seq)[None, :] < lens_d[:, None]
-    capacity = compact_capacity(int(lens.sum()), rows * seq)
+    capacity = compact_capacity(int(lens.sum()), rows, seq)
     packed = RealPositions.of(lens_d, seq, capacity)
 
     def flat(x):

@@ -1,19 +1,19 @@
 """The latent-attention prefill kernel against the blocked XLA form, at the
 decoder cell's step.
 
-Backs the per-kernel times of ``PERF.md`` (sections 5 and 6, PR 28): 32
-rows x 1,024 queries x 32 heads of 192 | 128 over the cache's 1,032-key
-buffer, bfloat16, with the prompt lengths of the benchmark's own corpus
-(``perfbench/corpus.py`` under ``perfbench/configs/kanana-2-30b-a3b.json``'s
-generator parameters, four batches of 32).  ``blocked`` is
-``models/mla.blocked_attention`` under the causal-and-padding mask;
-``kernel`` is ``ops/mla_prefill_attention.py`` at its own block, at the
-prompts' lengths and with every row full (the causal half alone);
-``packed`` is its packed form on the same rows laid one behind the other
-in ``models/moe.compact_capacity`` slots (PR 32: what the compact prefill
-calls), compared with the padded form on the real positions.
-``executed_share`` is the part of the ``S x S`` square the kernel's blocks
-cover, ``tflops`` counts those blocks' multiply-adds.
+Backs the per-kernel times of ``PERF.md`` (sections 5 and 6): 32 rows x
+1,024 queries x 32 heads of 192 | 128, bfloat16, with the prompt lengths
+of the benchmark's own corpus (``perfbench/corpus.py`` under
+``perfbench/configs/kanana-2-30b-a3b.json``'s generator parameters, four
+batches of 32).  ``blocked`` is ``models/mla.blocked_attention`` under the
+causal-and-padding mask over the cache's 1,032-key buffer; ``packed`` is
+``ops/mla_prefill_attention.py`` on the same rows laid one behind the other
+in ``models/compact_stream.compact_capacity`` slots (what the compact
+prefill calls); ``packed_full_rows`` the same kernel with every row full,
+at the step's own 32,768 slots (what a step of full rows calls).  Errors
+are against the blocked form on the real positions.  ``executed_share`` is
+the part of the ``S x S`` squares the kernel's tiles cover, ``tflops``
+counts those tiles' multiply-adds.
 
     chiprun -- python3 bench.py --suite=mla_prefill
 """
@@ -57,27 +57,22 @@ def _prompt_lengths(width: int, seed: int = 1) -> np.ndarray:
     return np.asarray(lens)
 
 
-def _executed_pairs(lens, seq: int, block: int) -> int:
-    """Query-key pairs inside the blocks the kernel runs."""
-    return sum(
-        (min((qi + 1) * block - 1, n - 1) // block + 1) * block * block
-        for n in np.asarray(lens) for qi in range(seq // block)
-        if qi * block < n)
-
-
 @suite("mla_prefill")
 def run() -> dict:
     import jax
     import jax.numpy as jnp
 
+    from music_analyst_tpu.models.compact_stream import (
+        RealPositions,
+        compact_capacity,
+    )
     from music_analyst_tpu.models.layers import causal_mask, padding_mask
     from music_analyst_tpu.models.mla import blocked_attention
-    from music_analyst_tpu.models.moe import RealPositions, compact_capacity
+    from music_analyst_tpu.ops.flash_attention import packed_visited_pairs
     from music_analyst_tpu.ops.mla_prefill_attention import (
-        mla_prefill_attention,
         mla_prefill_attention_packed,
-        prefill_block,
     )
+    from music_analyst_tpu.ops.packed_stream import stream_tile
 
     if smoke():
         rows, seq, heads, nope, rope, v_dim, n_batches = (
@@ -89,7 +84,6 @@ def run() -> dict:
         lens_all = _prompt_lengths(seq)
     keys = seq + 8                       # the cache's buffer: 8 label slots
     scale = (nope + rope) ** -0.5
-    block = prefill_block(seq)
     ks = jax.random.split(jax.random.key(0), 4)
     q_nope = jax.random.normal(ks[0], (rows, seq, heads, nope), jnp.bfloat16)
     q_rope = jax.random.normal(ks[1], (rows, seq, heads, rope), jnp.bfloat16)
@@ -108,18 +102,16 @@ def run() -> dict:
                                  kv[..., nope:], mask, scale, 128)
 
     flat = (q_nope.reshape(rows, seq, -1), q_rope.reshape(rows, seq, -1),
-            kv.reshape(rows, keys, -1), k_rope)
+            kv[:, :seq].reshape(rows, seq, -1), k_rope[:, :seq])
 
-    @jax.jit
-    def kernel(lens):
-        return mla_prefill_attention(*flat, lens, heads, scale)
-
-    # the packed form's operands: the same rows' real positions, gathered
+    # the packed form's operands: the rows' real positions, gathered
     # outside the timed call (in the model the projections write them so)
-    capacity = max(compact_capacity(int(np.asarray(b).sum()), rows * seq)
+    capacity = max(compact_capacity(int(np.asarray(b).sum()), rows, seq)
                    for b in batches)
     compact = [RealPositions.of(b, seq, capacity) for b in batches]
-    gathered = [tuple(c.gather(a[:, :seq]) for a in flat) for c in compact]
+    gathered = [tuple(c.gather(a) for a in flat) for c in compact]
+    whole = RealPositions.of(full, seq, rows * seq)
+    gathered_full = tuple(whole.gather(a) for a in flat)
 
     @jax.jit
     def packed(operands, lens):
@@ -137,19 +129,21 @@ def run() -> dict:
         go()
         return timed(go)[0] / len(lens_list) * 1e3
 
-    want = np.asarray(blocked(batches[0]), np.float32)
-    got = np.asarray(kernel(batches[0]), np.float32).reshape(want.shape)
-    lens0 = np.asarray(batches[0])
-    error = max(float(np.abs(got[b, :n] - want[b, :n]).max())
-                for b, n in enumerate(lens0))
-    got_packed = np.asarray(compact[0].put_back(
-        packed(gathered[0], batches[0])), np.float32).reshape(want.shape)
-    error_packed = max(float(np.abs(got_packed[b, :n] - got[b, :n]).max())
-                       for b, n in enumerate(lens0))
+    def error(stream, operands, lens):
+        """Largest distance from the blocked form on a real position."""
+        want = np.asarray(blocked(lens), np.float32)
+        got = np.asarray(stream.put_back(packed(operands, lens)),
+                         np.float32).reshape(want.shape)
+        return max(float(np.abs(got[b, :n] - want[b, :n]).max())
+                   for b, n in enumerate(np.asarray(lens)))
+
+    block = stream_tile(seq, capacity)
     flops_a_pair = heads * 2 * (nope + rope + v_dim)
-    pairs = float(np.mean([_executed_pairs(b, seq, block) for b in batches]))
-    pairs_full = _executed_pairs(full, seq, block)
-    kernel_ms, full_ms = ms(kernel, batches), ms(kernel, [full])
+    pairs = float(np.mean([packed_visited_pairs(b, capacity, seq, block)
+                           for b in batches]))
+    pairs_full = packed_visited_pairs(full, rows * seq, seq, block)
+    packed_ms = ms(packed, batches, gathered)
+    full_ms = ms(packed, [full], [gathered_full])
     return {
         "suite": "mla_prefill",
         "smoke": smoke(),
@@ -158,15 +152,15 @@ def run() -> dict:
                   "widths": f"{nope + rope}|{v_dim}", "block": block},
         "prompt_len_median": float(np.median(lens_all)),
         "blocked_ms": round(ms(blocked, batches), 3),
-        "kernel_ms": round(kernel_ms, 3),
-        "kernel_full_rows_ms": round(full_ms, 3),
-        "packed_ms": round(ms(packed, batches, gathered), 3),
+        "packed_ms": round(packed_ms, 3),
         "packed_capacity": capacity,
-        "packed_max_abs_error_against_kernel": error_packed,
+        "packed_full_rows_ms": round(full_ms, 3),
+        "packed_full_rows_capacity": rows * seq,
         "executed_share": round(pairs / (rows * seq * seq), 4),
-        "kernel_tflops": round(pairs * flops_a_pair / kernel_ms / 1e9, 2),
-        "kernel_full_rows_tflops": round(
+        "packed_tflops": round(pairs * flops_a_pair / packed_ms / 1e9, 2),
+        "packed_full_rows_tflops": round(
             pairs_full * flops_a_pair / full_ms / 1e9, 2),
-        "max_abs_error_real_positions": error,
-        "finite": bool(np.isfinite(got).all()),
+        "max_abs_error_real_positions": error(compact[0], gathered[0],
+                                              batches[0]),
+        "packed_full_rows_max_abs_error": error(whole, gathered_full, full),
     }

@@ -5,16 +5,17 @@ forms, at the state-space cell's step.
 operands, float32 states, with the prompt lengths of the benchmark's own
 corpus (as ``benchmarks/mla_prefill.py`` draws them): ``kernel_ms`` is one
 call of the Pallas kernel on the rows laid one behind the other in
-``models/moe.compact_capacity`` slots (what a compact prefill's Mamba-2 layer
-calls), at each chunk length of ``chunks`` (``ops/ssd_scan.CHUNK`` is the
-program's); ``kernel_padded_ms`` the same rows at ``[B, S]`` (a row a
-1,024-slot stretch), ``xla_ms`` the chunked XLA form on four of the padded
-rows (its ``[chunk, chunk, heads]`` decays are 0.5 GB a row block in
-float32).  The errors are against the token-by-token recurrence in float32
-on the same bfloat16 operands, with steps and decays drawn as the assumed
-initialisation gives them (``delta`` in ``[1e-3, 1e-1]``, ``A`` in ``[-16,
--1]``) and, in ``errors_fast_decay``, at ``delta A = -8`` a step on every
-head.  ``tflops`` counts ``perfbench/flops_granite.ssd_flops`` a token.
+``models/compact_stream.compact_capacity`` slots (what a compact prefill's
+Mamba-2 layer calls), at each chunk length of ``chunks``
+(``ops/ssd_scan.CHUNK`` is the program's); ``kernel_padded_ms`` the same
+rows at ``[B, S]`` (a row a 1,024-slot stretch), ``xla_ms`` the chunked
+XLA form on four of the padded rows (its ``[chunk, chunk, heads]`` decays
+are 0.5 GB a row block in float32).  The errors are against the
+token-by-token recurrence in float32 on the same bfloat16 operands, with
+steps and decays drawn as the assumed initialisation gives them (``delta``
+in ``[1e-3, 1e-1]``, ``A`` in ``[-16, -1]``) and, in ``errors_fast_decay``,
+at ``delta A = -8`` a step on every head.  ``tflops`` counts
+``perfbench/flops_granite.ssd_flops`` a token.
 
     chiprun -- python3 bench.py --suite=ssd_prefill
 """
@@ -53,7 +54,10 @@ def run() -> dict:
     import jax.numpy as jnp
 
     from benchmarks.mla_prefill import _prompt_lengths
-    from music_analyst_tpu.models.moe import RealPositions, compact_capacity
+    from music_analyst_tpu.models.compact_stream import (
+        RealPositions,
+        compact_capacity,
+    )
     from music_analyst_tpu.ops.ssd_scan import (
         CHUNK,
         ssd_chunked,
@@ -72,7 +76,7 @@ def run() -> dict:
     few = min(rows, 4)
     lens_d = jnp.asarray(lens, jnp.int32)
     valid = jnp.arange(seq)[None, :] < lens_d[:, None]
-    capacity = compact_capacity(int(lens.sum()), rows * seq)
+    capacity = compact_capacity(int(lens.sum()), rows, seq)
     packed = RealPositions.of(lens_d, seq, capacity)
 
     def flat(v):

@@ -39,7 +39,10 @@ def run() -> dict:
     import jax
     import jax.numpy as jnp
 
-    from music_analyst_tpu.models.moe import RealPositions, compact_capacity
+    from music_analyst_tpu.models.compact_stream import (
+        RealPositions,
+        compact_capacity,
+    )
     from music_analyst_tpu.ops.flash_attention import (
         flash_attention_packed,
         packed_visited_pairs,
@@ -70,7 +73,7 @@ def run() -> dict:
     few = min(rows, 4)
     lens_d = jnp.asarray(lens, jnp.int32)
     tile = block_causal_tile(seq)
-    capacity = compact_capacity(int(lens.sum()), rows * seq)
+    capacity = compact_capacity(int(lens.sum()), rows, seq)
     stream = RealPositions.of(lens_d, seq, capacity)
 
     def operands(heads, n_rows=rows, n_kv=kv_heads, width=seq):
@@ -171,7 +174,7 @@ def run() -> dict:
     # the block-diffusion prefill's rule at that cell's step
     d_rows, d_seq, d_heads, d_kv, whole = diffusion
     d_lens = jnp.asarray(whole, jnp.int32)
-    d_cap = compact_capacity(int(whole.sum()), d_rows * d_seq)
+    d_cap = compact_capacity(int(whole.sum()), d_rows, d_seq)
     d_stream = RealPositions.of(d_lens, d_seq, d_cap)
     d_args = operands(d_heads, d_rows, d_kv, d_seq)
     out["block_causal_ms"] = {"bs": round(ms(view(

@@ -21,10 +21,10 @@ between them, so that a trace names them apart:
   prompts' whole blocks under the block-causal mask
   (``ops/kv_cache.BlockCausalPrefill``), no logits (a diffusion prefill has
   no next token to read), the hidden state on the whole blocks' positions
-  alone from the embedding to the last layer (``models/llama.runs_compact``:
-  the compact token stream, ``models/moe.RealPositions``; queries, keys and
-  values put back at ``[B, S]`` for the view and the kernel); returns the
-  caches, zeros at and behind a row's whole blocks;
+  alone from the embedding to the last layer where
+  ``models/compact_stream.py`` says so (the compact token stream; keys and
+  values put back at ``[B, S]`` for the cache); returns the caches, zeros
+  at and behind a row's whole blocks;
 * :func:`diffusion_denoise_program` (``llama_diffusion_denoise``): takes
   the caches (donated) and runs every block on the device, a ``lax.scan``
   over blocks around a ``lax.while_loop`` over passes: no host round trip
@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from music_analyst_tpu.models.compact_stream import stream_slots
 from music_analyst_tpu.models.llama import (
     LlamaConfig,
     LlamaModel,
@@ -339,7 +340,8 @@ class BlockDiffusionClassifier(LlamaZeroShotClassifier):
         tel.count("diffusion.tokens_unmasked", unmasked)
         tel.count("decoder.tokens_real", prefilled + pass_positions)
         tel.count("decoder.tokens_computed",
-                  self._prefill_slots(rows, width, capacity) + pass_positions)
+                  stream_slots(cfg, (rows, width), capacity)
+                  + pass_positions)
         load = self._count_expert_load(
             stats, slots,
             pass_positions * cfg.moe_top_k * len(stats["expert_load_max"]))

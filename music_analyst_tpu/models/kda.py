@@ -215,8 +215,9 @@ class KimiDeltaAttention(nn.Module):
         ``state`` holds) and lets the kernel run; ``row_lengths [B]`` only
         says how many of this call's tokens exist a row (the rest leave the
         state alone) and promises nothing.  ``packed`` (a
-        ``models/moe.RealPositions`` of ``prefill_lengths``) says ``x [1,
-        C, D]`` and ``positions [1, C]`` are the compact token stream."""
+        ``models/compact_stream.RealPositions`` of ``prefill_lengths``) says
+        ``x [1, C, D]`` and ``positions [1, C]`` are the compact token
+        stream."""
         dim = x.shape[-1]
         heads, d = self.n_heads, self.head_dim
         width = heads * d

@@ -446,17 +446,17 @@ class MultiHeadAttention(nn.Module):
         packed=None,
         key_positions: Optional[jax.Array] = None,
     ):
-        # ``packed`` (a ``models/moe.RealPositions``): ``x [1, C, D]`` is
-        # the compact token stream of a ``[B, S]`` step (traced path
-        # ``gqa.compact``).  The projections, QK-norm, RoPE and the output
-        # gate run on it.  A cache view that attends on the stream itself
-        # (``ops/kv_cache.BlockCausalPrefill.attend_packed``, at a shape its
-        # ``packed_tile`` admits) takes queries, keys and values as they are
-        # and returns its result there; only keys and values are put back
-        # at their ``[B, S]`` places, for the cache.  Otherwise all three
-        # are put back for the cache and the attention this layer has
-        # (zeros at and behind a row's length), and the result is gathered
-        # onto the stream again before the gate and ``o_proj``.
+        # ``packed`` (a ``models/compact_stream.RealPositions``): ``x [1, C,
+        # D]`` is the compact token stream of a ``[B, S]`` step (traced
+        # path ``gqa.compact``).  The projections, QK-norm, RoPE and the
+        # output gate run on it.  A cache view that attends on the stream
+        # itself (``ops/kv_cache.BlockCausalPrefill.attend_packed``) takes
+        # queries, keys and values as they are and returns its result
+        # there; only keys and values are put back at their ``[B, S]``
+        # places, for the cache.  Otherwise all three are put back for the
+        # cache and the attention this layer has (zeros at and behind a
+        # row's length), and the result is gathered onto the stream again
+        # before the gate and ``o_proj``.
         # ``key_positions [B, KV]`` (a window layer reads it): the position
         # of the key each cache slot holds, where a slot is not its
         # position (a continuation's slots lie behind the prompt's width,
@@ -523,8 +523,7 @@ class MultiHeadAttention(nn.Module):
                 q = apply_rope(q, cos, sin, at)
                 k = apply_rope(k, cos, sin, at)
 
-        on_stream = (packed is not None and hasattr(cache, "attend_packed")
-                     and bool(cache.packed_tile(packed)))
+        on_stream = packed is not None and hasattr(cache, "attend_packed")
         if packed is not None:
             note_traced_path("gqa.compact")
             stream = (q[0], k[0], v[0])

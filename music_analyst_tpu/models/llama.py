@@ -34,6 +34,13 @@ import numpy as np
 from flax import linen as nn
 
 from music_analyst_tpu.models.backend import ClassifierBackend, preset_files
+from music_analyst_tpu.models.compact_stream import (
+    feed_forward_positions,
+    prefill_stream,
+    runs_compact,
+    step_capacity,
+    stream_slots,
+)
 from music_analyst_tpu.models.layers import (
     MultiHeadAttention,
     RMSNorm,
@@ -392,10 +399,11 @@ class LlamaConfig:
     @property
     def compact_stream(self) -> bool:
         """Whether every block takes the compact token stream
-        (:func:`runs_compact`): the latent blocks, the blocks of a model
-        whose layers are listed by kind (grouped-query blocks between
-        state-space layers, full and sliding-window grouped-query blocks),
-        and the grouped-query blocks of a block-diffusion prefill (its
+        (``models/compact_stream.runs_compact``): the latent blocks, the
+        blocks of a model whose layers are listed by kind (grouped-query
+        blocks between state-space layers, full and sliding-window
+        grouped-query blocks), and the grouped-query blocks of a
+        block-diffusion prefill (its
         passes declare no lengths)."""
         return (self.attention == "mla" or self.layer_types is not None
                 or self.block_diffusion)
@@ -1036,26 +1044,20 @@ class LlamaBlock(nn.Module):
         rest, with the router the configuration names.
 
         Where the caller's stream is the real positions' token set
-        (``packed``: ``h [1, C, D]``, every block under ``LlamaModel``'s
-        compact prefill, :func:`runs_compact`) it is taken and returned as
-        it is.  A prefill that declares its rows' lengths and a
-        ``prefill_capacity`` under the step's positions at a shape
-        ``runs_compact`` refuses (a width under 512, a slot count that is
-        not whole 256-slot blocks: the test presets' steps, an odd number
-        of rows at 1,024) still runs this half on the real positions
-        alone (``models/moe.RealPositions``: gathered into that many token
-        slots, the result put back at their places); positions at or
-        behind a row's length then receive zeros (the residual alone)."""
-        from music_analyst_tpu.models.moe import RealPositions, RoutedMoE
+        (``packed``: ``h [1, C, D]``) it is taken and returned as it is.
+        On a ``[B, S]`` stream this half alone may run on the real
+        positions (``models/compact_stream.feed_forward_positions``:
+        gathered into ``prefill_capacity`` token slots, the result put back
+        at their places); positions at or behind a row's length then
+        receive zeros (the residual alone)."""
+        from music_analyst_tpu.models.moe import RoutedMoE
 
         cfg = self.config
         dtype, param_dtype = jnp.dtype(cfg.dtype), jnp.dtype(cfg.param_dtype)
         compact = packed
-        if (packed is None and prefill_lengths is not None
-                and prefill_capacity is not None
-                and prefill_capacity < h.shape[0] * h.shape[1]):
-            compact = RealPositions.of(prefill_lengths, h.shape[1],
-                                       prefill_capacity)
+        if packed is None:
+            compact = feed_forward_positions(prefill_lengths, h.shape[1],
+                                             prefill_capacity)
         if cfg.routed_layer(self.layer_index):
             ffn = RoutedMoE(
                 cfg.n_experts, cfg.moe_hidden_dim, cfg.moe_top_k,
@@ -1152,38 +1154,22 @@ class LlamaModel(nn.Module):
         row_lengths: Optional[jax.Array] = None,   # [B] — see below
         key_positions: Optional[jax.Array] = None,  # [B, KV] — see below
     ):
-        # ``prefill_lengths`` is read by the blocks whose expert layers are
-        # ``RoutedMoE`` (the latent blocks hand it to their attention too,
-        # a grouped-query block's cache view knows the lengths itself)
-        # and is a promise about THIS call (models/mla.MLAttention): a
-        # causal prefill from position 0 on empty caches, ``mask`` = causal
-        # and key padding by these lengths, one device, and nothing reads
-        # a position at or behind its row's length.  The attention takes
-        # the kernel that reads the lengths in place of the mask.  With a
-        # ``prefill_capacity`` (static, ``models/moe.compact_capacity`` of
-        # these lengths: sum(lengths) <= capacity) under B*S the step runs
-        # on the real positions alone.  Where :func:`runs_compact` says so
-        # (latent blocks, KDA and state-space layers with their
-        # grouped-query layer, the block-diffusion prefill): the hidden
-        # state is ``[1, capacity, dim]`` from the embedding of the real
-        # positions' ids to the position the head reads (to the last block
-        # where there is no head), each row's real positions one behind
-        # the other (``models/moe.RealPositions``); norms, projections,
-        # QK-norm, RoPE, the feed-forward halves and the residual adds see
-        # nothing else.  The latent prefill kernel takes the stream as it
-        # is (its packed form) and ONLY the latent cache (``latents``,
-        # ``k_rope``) is put back at ``[B, S]``; a grouped-query layer
-        # puts queries, keys and values back at ``[B, S]`` for the cache
-        # view and the kernel it has and gathers the result onto the
-        # stream (``MultiHeadAttention(packed=...)``); the sown ``chosen``
-        # is put back either way; zeros at and behind a row's length.  A
-        # hidden state at a padding position does not exist; logits
-        # without ``last_position`` are the head's of zeros there.
-        # Anywhere else (a width or a slot count the kernels refuse): the
-        # feed-forward halves alone gather the real positions and put
-        # their result back.  Either way what the
-        # layers return at or behind a row's length is neither computed
-        # as the layer would nor defined.  ``row_lengths`` is read by the
+        # ``prefill_lengths`` is a promise about THIS call: a causal
+        # prefill from position 0 on empty caches, ``mask`` = causal and
+        # key padding by these lengths, one device, and nothing reads a
+        # position at or behind its row's length.  With it the step may run
+        # on the real positions alone, on ``prefill_capacity`` token slots
+        # (static, ``None`` = rows x width): which form it takes is
+        # ``models/compact_stream.py``'s answer.  On the compact stream
+        # (``prefill_stream``) the hidden state is ``[1, capacity, dim]``
+        # from the embedding of the real positions' ids to the position the
+        # head reads; every layer takes it as it is and puts back at ``[B,
+        # S]`` only what its cache holds, zeros at and behind a row's
+        # length; logits without ``last_position`` are the head's of zeros
+        # there.  Otherwise the feed-forward halves alone may gather the
+        # real positions (``feed_forward_positions``).  Either way what the
+        # layers return at or behind a row's length is neither computed as
+        # the layer would nor defined.  ``row_lengths`` is read by the
         # layers that carry a recurrent state (``models/kda.py``) where
         # ``prefill_lengths`` is withheld: how many of this call's tokens
         # exist a row (a mask cannot tell a state when to stop), a fact
@@ -1207,14 +1193,10 @@ class LlamaModel(nn.Module):
         cfg = self.config
         dtype = jnp.dtype(cfg.dtype)
         param_dtype = jnp.dtype(cfg.param_dtype)
-        packed = None
         with jax.named_scope("embed"):
-            if prefill_lengths is not None and runs_compact(
-                    cfg, token_ids.shape, prefill_capacity):
-                from music_analyst_tpu.models.moe import RealPositions
-
-                packed = RealPositions.of(
-                    prefill_lengths, token_ids.shape[1], prefill_capacity)
+            packed = prefill_stream(cfg, prefill_lengths,
+                                    token_ids.shape[1], prefill_capacity)
+            if packed is not None:
                 token_ids = packed.gather(token_ids)[None]
                 positions = packed.gather(positions)[None]
             embed = nn.Embed(cfg.vocab_size, cfg.dim, dtype=dtype,
@@ -1573,40 +1555,13 @@ def _prefill_lengths(mesh, prompt_lens):
 def _prefill_capacity(config: LlamaConfig, mesh, prompt_lens,
                       shape) -> Optional[int]:
     """The static ``prefill_capacity`` of a step of ``shape`` (rows,
-    width) whose rows have ``prompt_lens`` (host array): the rung of
-    ``models/moe.compact_capacity`` that holds the real tokens, where the
-    blocks read ``prefill_lengths`` (latent blocks, one device); ``None``
-    where they are withheld or unread, so such a step has one program."""
-    if not config.compact_stream or _partitioned(mesh):
+    width) whose rows have ``prompt_lens`` (host array):
+    ``models/compact_stream.step_capacity`` where the blocks read
+    ``prefill_lengths`` (one device); ``None`` under a mesh, where they
+    are withheld, so such a step has one program."""
+    if _partitioned(mesh):
         return None
-    from music_analyst_tpu.models.moe import compact_capacity
-
-    return compact_capacity(int(np.asarray(prompt_lens, np.int64).sum()),
-                            int(shape[0]) * int(shape[1]))
-
-
-def runs_compact(config: LlamaConfig, shape, capacity) -> bool:
-    """Whether a prefill of ``shape`` (rows, width) that declares its rows'
-    lengths and this ``prefill_capacity`` keeps its hidden state on the
-    compact token set from the embedding to the head (``LlamaModel``):
-    blocks that take the stream (``LlamaConfig.compact_stream``: latent
-    blocks, KDA layers among them or not, grouped-query blocks between
-    state-space layers, and the grouped-query blocks of a block-diffusion
-    prefill: every kernel finds a row at its own slot, and a grouped-query
-    layer puts queries, keys and values back at ``[B, S]`` for the cache
-    view and the kernel it has), fewer slots than positions, and a width
-    and a slot count the kernels take (whole 256-slot blocks of the packed
-    latent prefill, which are whole chunks of the KDA and the state-space
-    kernels too; the grouped-query layers keep the same rule, so a step
-    shape has one answer whatever its model).  The one place that decides
-    it, for the model and for whoever counts what a step computed."""
-    from music_analyst_tpu.ops.mla_prefill_attention import (
-        packed_prefill_block,
-    )
-
-    return (config.compact_stream and capacity is not None
-            and capacity < int(shape[0]) * int(shape[1])
-            and bool(packed_prefill_block(int(shape[1]), capacity)))
+    return step_capacity(config, prompt_lens, shape)
 
 
 # The decoder's three programs, built from ``(model, config, …)``: whoever
@@ -1657,9 +1612,9 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
 
         prompt_ids [B, S]; label_ids [3, L], ``L <= MAX_LABEL_TOKENS``;
         ``prefill_capacity`` (static)
-        the token slots the prefill's feed-forward layers run, from
-        ``models/moe.compact_capacity`` of these lengths (``None`` or
-        ``B * S``: every position).  Returns ``(scores [B, 3], stats)``:
+        the token slots the prefill runs, from
+        ``models/compact_stream.compact_capacity`` of these lengths
+        (``None``: ``B * S``).  Returns ``(scores [B, 3], stats)``:
         ``stats`` holds the small device-side reductions that ride back
         with the scores (``expert_load_max`` / ``expert_load_mean``
         ``[routed layers]`` of the prefill, for a model with routed
@@ -2234,8 +2189,8 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         computed, summed over the layers: whole tiles of the kernel's grid
         (``ops/kv_cache.prefill_tile_pairs``: the packed kernel's where the
         prefill ran on the compact stream of ``capacity`` slots,
-        :func:`runs_compact`), every pair of the step where the masked
-        form ran."""
+        ``models/compact_stream.runs_compact``), every pair of the step
+        where the masked form ran."""
         cfg = self.config
         if cfg.attention_kinds is None:
             return None
@@ -2319,13 +2274,6 @@ class LlamaZeroShotClassifier(ClassifierBackend):
                 labels.append(SUPPORTED_LABELS[int(idx)])
         return labels
 
-    def _prefill_slots(self, rows: int, width: int, capacity) -> int:
-        """Token slots a declared prefill of ``rows x width`` put through
-        the layers: its ``capacity`` where it ran on the compact token set
-        (:func:`runs_compact`), every position where it did not."""
-        return (capacity if runs_compact(self.config, (rows, width), capacity)
-                else rows * width)
-
     def _count_step(self, rows: int, width: int, real, stats) -> None:
         """What one scoring step computed, into the run's telemetry:
         ``decoder.tokens_real`` / ``decoder.tokens_computed`` (positions
@@ -2354,7 +2302,7 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         label_real = int(np.maximum(self._label_lens - 1, 0).sum())
         tel.count("decoder.tokens_real", tokens_real + rows * label_real)
         tel.count("decoder.tokens_computed",
-                  self._prefill_slots(rows, width, capacity)
+                  stream_slots(self.config, (rows, width), capacity)
                   + rows * label_run)
         attrs = dict(rows=rows, width=width, tokens_real=tokens_real,
                      token_pairs=token_pairs, label_positions=label_run,

@@ -12,13 +12,12 @@ Two forms of the same mathematics, chosen from the shapes:
 
 * **expanded** — expand the latents to per-head keys and values and run
   ordinary attention.  Cheapest when many queries share the expansion
-  (prefill).  A causal prefill from position 0 on one device, which its
-  caller declares by giving the rows' ``prefill_lengths``, runs as one
-  Pallas kernel (``ops/mla_prefill_attention.py``) that skips what lies
-  above the diagonal or past a row's length and keeps no score outside
-  VMEM; every other call takes :func:`blocked_attention`, the XLA form,
-  where the scores of one block of queries at a time are live, never
-  ``[B, H, S, S]``.
+  (prefill).  A prefill on the compact token stream
+  (``models/compact_stream.py`` decides who runs one) runs as one Pallas
+  kernel (``ops/mla_prefill_attention.py``) that skips what lies above the
+  diagonal or outside a row and keeps no score outside VMEM; every other
+  call takes :func:`blocked_attention`, the XLA form, where the scores of
+  one block of queries at a time are live, never ``[B, H, S, S]``.
 * **absorbed** — fold ``W_uk`` (the ``k_nope`` half of ``W_kvb``) into the
   query and ``W_uv`` (the ``v`` half) behind the weighted sum, so attention
   runs over the latents themselves:
@@ -49,9 +48,7 @@ from music_analyst_tpu.models.layers import (
     rope_frequencies,
 )
 from music_analyst_tpu.ops.mla_prefill_attention import (
-    mla_prefill_attention,
     mla_prefill_attention_packed,
-    prefill_block,
 )
 from music_analyst_tpu.profiling.compile import (
     note_attention_path,
@@ -138,13 +135,11 @@ def blocked_attention(q_nope, q_rope, k_nope, k_rope, v, mask, scale: float,
     queries: one block's float32 scores are live at a time, the whole row
     of keys is computed and ``mask`` decides afterwards.
 
-    The expanded form of every call the prefill kernel does not take:
-    a caller that gives no ``prefill_lengths`` (``MLAttention`` says what
-    they promise: ``init``, training, the layer tests, a continuation of
-    more than ``absorb_max_queries`` tokens on a filled cache, any forward
-    under a mesh, where XLA partitions this form and could not the
-    kernel's call), and with them a number of queries outside
-    ``ops/mla_prefill_attention.prefill_block`` (not whole kernel blocks,
+    The expanded form of every call not on the compact token stream:
+    ``init``, training, the layer tests, a continuation of more than
+    ``absorb_max_queries`` tokens on a filled cache, any forward under a
+    mesh (where XLA partitions this form and could not the kernel's call),
+    and a prefill at a width the kernel refuses (not whole 256-slot tiles,
     or fewer than two: prompt widths of 64 to 256).  The kernel's tests
     compare with it.
 
@@ -222,39 +217,28 @@ class MLAttention(nn.Module):
                  cache: Optional[LatentCache] = None,
                  prefill_lengths: Optional[jax.Array] = None,
                  packed=None):
-        """``prefill_lengths [B]`` is a promise only the caller can make
-        (``cache.length`` is traced, nothing here can check it): this call
-        is a causal prefill from position 0 (query ``i`` is key ``i``, on
-        an empty cache if any), ``mask`` is the causal rule and key padding
-        by these lengths, nothing reads the output at a padding position,
-        and the call is not partitioned over a mesh.  The expanded form may
-        then take the kernel that reads the lengths IN PLACE OF ``mask``
-        (:func:`prefill_block` decides from the number of queries).  A
-        continuation on a filled cache, a chunked prefill, any other mask,
-        training (the kernel has no gradient) and a meshed forward withhold
-        it, and ``mask`` is applied as given.
-
-        ``packed`` (a ``models/moe.RealPositions`` of these lengths, from
-        ``models/llama.LlamaModel`` alone) says ``x [1, C, dim]`` and
-        ``positions [1, C]`` are that compact token set, each row's real
-        positions one behind the other, and not ``[B, S, ...]``: the
-        projections, RoPE, the latent expansion, the kernel (its packed
-        form, which finds a row at its first slot) and ``o_proj`` run on
-        the ``C`` slots and the result is ``[1, C, dim]``.  Only what the
-        cache holds, ``latents`` and ``k_rope``, is put back at ``[B, S]``
-        (zeros at and behind a row's length); a hidden state at a padding
-        position does not exist."""
+        """``packed`` (a ``models/compact_stream.RealPositions`` of the
+        rows' ``prefill_lengths [B]``, from ``models/llama.LlamaModel``
+        alone) says this call is a causal prefill from position 0 (on an
+        empty cache if any) and ``x [1, C, dim]`` and ``positions [1, C]``
+        are that compact token set, each row's real positions one behind
+        the other, and not ``[B, S, ...]``: the projections, RoPE, the
+        latent expansion, the prefill kernel (which reads the lengths IN
+        PLACE OF ``mask`` and finds a row at its first slot) and ``o_proj``
+        run on the ``C`` slots and the result is ``[1, C, dim]``.  Only
+        what the cache holds, ``latents`` and ``k_rope``, is put back at
+        ``[B, S]`` (zeros at and behind a row's length); a hidden state at
+        a padding position does not exist.  Every other call applies
+        ``mask`` as given."""
         dim = x.shape[-1]
         batch, n_q = x.shape[:2]
         heads, nope, rope = (self.n_heads, self.qk_nope_head_dim,
                              self.qk_rope_head_dim)
         rank, v_dim = self.kv_lora_rank, self.v_head_dim
         scale = (nope + rope) ** -0.5
-        absorbed = (packed is None and cache is not None
+        flash = packed is not None
+        absorbed = (not flash and cache is not None
                     and n_q <= self.absorb_max_queries)
-        flash = packed is not None or (
-            not absorbed and prefill_lengths is not None
-            and bool(prefill_block(n_q)))
 
         x = x.astype(self.dtype)
         w_q = Kernel((dim, heads, nope + rope), dim, self.param_dtype,
@@ -273,8 +257,8 @@ class MLAttention(nn.Module):
         cos, sin = rope_frequencies(rope, self.max_positions, self.rope_theta)
         rotate = apply_rope_interleaved if self.rope_interleave else apply_rope
         if flash:
-            # The kernel reads ``[B, S, H*D]``, which is how XLA lays out a
-            # contraction with a 2-D weight; fed from ``[B, S, H, D]`` the
+            # The kernel reads ``[C, H*D]``, which is how XLA lays out a
+            # contraction with a 2-D weight; fed from ``[.., H, D]`` the
             # call gets a slice and three transposing copies a layer
             # (tests/test_mosaic_aot.py pins this).
             q_nope = x @ w_q[..., :nope].reshape(dim, heads * nope)
@@ -314,17 +298,11 @@ class MLAttention(nn.Module):
             note_traced_path("mla.expanded")
             kv = latents @ w_kvb.reshape(rank, heads * (nope + v_dim))
             q_rope = q_rope.reshape(batch, n_q, heads * rope)
-            if packed is not None:
-                note_traced_path("mla.compact")
-                note_attention_path("mla_flash_packed")
-                out = mla_prefill_attention_packed(
-                    q_nope[0], q_rope[0], kv[0], k_rope[0], prefill_lengths,
-                    packed.real.shape[1], heads, scale)
-            else:
-                note_attention_path("mla_flash")
-                out = mla_prefill_attention(
-                    q_nope, q_rope, kv, k_rope, prefill_lengths, heads,
-                    scale)
+            note_traced_path("mla.compact")
+            note_attention_path("mla_flash_packed")
+            out = mla_prefill_attention_packed(
+                q_nope[0], q_rope[0], kv[0], k_rope[0], prefill_lengths,
+                packed.real.shape[1], heads, scale)
             out = out.reshape(batch, n_q, heads, v_dim)
         else:
             note_traced_path("mla.expanded")

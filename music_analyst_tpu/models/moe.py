@@ -30,11 +30,13 @@ hosts — the multi-host regime ``parallel/distributed.py`` owns.
 from __future__ import annotations
 
 import math
-from typing import NamedTuple, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+
+from music_analyst_tpu.models.compact_stream import RealPositions
 
 
 def moe_capacity(tokens: int, top_k: int, n_experts: int,
@@ -251,68 +253,9 @@ def route_softmax_topk(logits: jax.Array, top_k: int,
 ROUTERS = ("sigmoid_noaux", "softmax_topk")
 
 
-COMPACT_RUNGS = 8
 # Tokens a pass of a layer that holds a share of its experts
 # (``RoutedMoE(experts_held=...)``) sorts to them at a time.
 HELD_CHUNK = 8192
-
-
-def compact_capacity(real: int, positions: int) -> int:
-    """Token slots a prefill's feed-forward layers run when ``real`` of
-    the step's ``positions`` (rows x width) lie inside a row's length: the
-    smallest multiple of an eighth of the step that holds them, so a step
-    shape has at most ``COMPACT_RUNGS`` compiled programs and a step of
-    full rows runs the uncompacted one (``capacity == positions``).  Host
-    arithmetic on the lengths the caller already has."""
-    rung = -(-positions // COMPACT_RUNGS)
-    return min(positions, rung * max(1, -(-real // rung)))
-
-
-class RealPositions(NamedTuple):
-    """The real positions (``pos < lengths[row]``) of a ``[B, S]`` step,
-    in row-major order, as slots ``0 .. N-1`` of a compact token set of a
-    static ``capacity >= N``; the ``capacity - N`` filler slots behind
-    them hold a copy of the last real position and are never read back."""
-
-    source: jax.Array  # [C] int32  flat position ``row * S + pos`` of a slot
-    valid: jax.Array   # [C] bool   the slot holds a real position
-    start: jax.Array   # [B] int32  a row's first slot
-    slot: jax.Array    # [B, S] int32  a real position's slot (else clamped)
-    real: jax.Array    # [B, S] bool   ``pos < lengths[row]``
-
-    @classmethod
-    def of(cls, lengths: jax.Array, width: int,
-           capacity: int) -> "RealPositions":
-        """From the rows' lengths by arithmetic: the rows' first slots are
-        the exclusive running sum of the lengths, a slot's row is the
-        number of rows that end at or before it, its position the rest.
-        The caller promises ``sum(lengths) <= capacity``."""
-        lengths = lengths.astype(jnp.int32)
-        ends = jnp.cumsum(lengths)
-        starts = ends - lengths
-        slots = jnp.arange(capacity, dtype=jnp.int32)
-        valid = slots < ends[-1]
-        last = jnp.maximum(ends[-1] - 1, 0)
-        held = jnp.where(valid, slots, last)
-        row = jnp.searchsorted(ends, held, side="right",
-                               method="compare_all").astype(jnp.int32)
-        row = jnp.minimum(row, lengths.shape[0] - 1)
-        pos = jnp.arange(width, dtype=jnp.int32)[None, :]
-        real = pos < lengths[:, None]
-        return cls(
-            source=row * width + (held - starts[row]), valid=valid,
-            start=starts,
-            slot=jnp.where(real, starts[:, None] + pos, 0), real=real)
-
-    def gather(self, x: jax.Array) -> jax.Array:
-        """``x [B, S, ...]`` at the slots' positions: ``[C, ...]``."""
-        return x.reshape((-1,) + x.shape[2:])[self.source]
-
-    def put_back(self, y: jax.Array) -> jax.Array:
-        """``y [C, ...]`` at its positions' places in ``[B, S, ...]``,
-        zeros at and behind every row's length."""
-        real = self.real.reshape(self.real.shape + (1,) * (y.ndim - 1))
-        return jnp.where(real, y[self.slot], jnp.zeros((), y.dtype))
 
 
 def _grouped_experts(xt, chosen, weights, gate_w, up_w, down_w,
@@ -439,9 +382,10 @@ class RoutedMoE(nn.Module):
     and positions at or behind a row's length are NOT computed; their
     output is zero and their ``chosen`` 0, not what the layer would give.
     With ``packed`` the caller's ``x [1, C, D]`` IS that token set (the
-    compact stream of ``models/llama.runs_compact``) and so is the result:
-    nothing is gathered or put back but the sown ``chosen``, and a filler's
-    routed output is zero (the shared experts' alone is its result).
+    compact stream of ``models/compact_stream.runs_compact``) and so is the
+    result: nothing is gathered or put back but the sown ``chosen``, and a
+    filler's routed output is zero (the shared experts' alone is its
+    result).
 
     ``experts_held = (first, count)`` says this chip holds experts ``first
     .. first + count - 1`` of the layer's ``n_experts`` (expert parallelism:

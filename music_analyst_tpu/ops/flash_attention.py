@@ -29,14 +29,14 @@ what the kernel computed (:func:`visited_pairs`).
 
 The packed form (:func:`flash_attention_packed`) is the causal prefill of
 rows laid one behind the other on a compact token set
-(``models/moe.RealPositions``), the latent prefill's packed kernel
-(``ops/mla_prefill_attention.py``) for grouped-query heads: ``q [C, H*D]``,
-``k``/``v [C, Hkv*D]`` read from the stream and ``[C, H*D]`` written back
-to it, the query heads of whole key/value groups in one grid step (a loop
+(``models/compact_stream.RealPositions``), the latent prefill's packed
+kernel (``ops/mla_prefill_attention.py``) for grouped-query heads: ``q [C,
+H*D]``, ``k``/``v [C, Hkv*D]`` read from the stream and ``[C, H*D]``
+written back to it, the query heads of whole key/value groups in one grid step (a loop
 inside the kernel; each key/value head read in place by its group;
 :func:`packed_kv_heads`), bfloat16 MXU operands with float32 statistics,
 and tiles above the diagonal, before the first row's start or wholly
-behind the window neither computed nor fetched.  :func:`packed_tile_runs`
+behind the window neither computed nor fetched.  :func:`packed_step_runs`
 is its one rule for which tiles compute, read by the kernel and by
 :func:`packed_visited_pairs`.
 """
@@ -499,7 +499,7 @@ def packed_steps(max_len: int, block: int, window: int = 0) -> int:
     return steps
 
 
-def packed_tile_runs(qi, step, first_key, total, block: int):
+def packed_step_runs(qi, step, first_key, total, block: int):
     """Whether step ``step`` of query block ``qi`` computes (key block
     ``first_key + step``): the block holds a real slot and the key block
     is not above the diagonal.  Key blocks before ``first_key`` are never
@@ -512,14 +512,14 @@ def packed_visited_pairs(lengths, capacity: int, max_len: int, block: int,
                          window: int = 0) -> int:
     """(query, key) pairs in the tiles the packed kernel computes for one
     query head, rows ``lengths`` long (host integers) in ``capacity``
-    slots: the grid's tiles :func:`packed_tile_runs` lets run, whole."""
+    slots: the grid's tiles :func:`packed_step_runs` lets run, whole."""
     lengths = np.asarray(lengths, np.int64)
     n_blocks = capacity // block
     _, _, _, first_key, total = packed_tables(lengths, n_blocks, block,
                                               window, np)
     qi = np.arange(n_blocks)[:, None]
     step = np.arange(packed_steps(max_len, block, window))[None, :]
-    runs = packed_tile_runs(qi, step, first_key[:, None], total[0], block)
+    runs = packed_step_runs(qi, step, first_key[:, None], total[0], block)
     return int(np.sum(runs)) * block * block
 
 
@@ -580,7 +580,7 @@ def _packed_flash_kernel(
 
         each(n_heads, turn)
 
-    @pl.when(packed_tile_runs(qi, step, first_key, total_ref[0], block))
+    @pl.when(packed_step_runs(qi, step, first_key, total_ref[0], block))
     def _compute():
         q_pos = qi * block + jax.lax.broadcasted_iota(
             jnp.int32, (block, block), 0)
@@ -762,12 +762,12 @@ def flash_attention_packed(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Causal self-attention of rows laid one behind the other on a compact
-    token set (``models/moe.RealPositions``): row ``b``'s ``lengths[b] <=
-    max_len`` positions are the slots from ``sum(lengths[:b])`` on, the
-    slots behind the last row are fillers.  ``q [C, H*D]``, ``k``/``v [C,
-    Hkv*D]`` (query head ``h`` reads key/value head ``h // (H // Hkv)``);
-    returns ``[C, H*D]``: every real slot's attention over its own row's
-    slots up to itself (``block_causal`` = ``n`` > 1: up to the end of its
+    token set (``models/compact_stream.RealPositions``): row ``b``'s
+    ``lengths[b] <= max_len`` positions are the slots from
+    ``sum(lengths[:b])`` on, the slots behind the last row are fillers.
+    ``q [C, H*D]``, ``k``/``v [C, Hkv*D]`` (query head ``h`` reads
+    key/value head ``h // (H // Hkv)``); returns ``[C, H*D]``: every real
+    slot's attention over its own row's slots up to itself (``block_causal`` = ``n`` > 1: up to the end of its
     block of ``n`` positions; rows are then whole blocks), of those the
     ``window`` up to its own where ``window`` > 0, with the scores times
     ``scale`` (``None`` = ``D ** -0.5``); finite values on fillers that share

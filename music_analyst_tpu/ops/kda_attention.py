@@ -22,7 +22,7 @@ a key channel ``g_t [d_k]`` in ``[lower_bound, 0)``::
   device operations carry ``_kda_`` in their names.  A head's state stays
   in VMEM across a row's chunks; state and accumulations are float32, MXU
   operands bfloat16.  It takes the compact token stream
-  (``models/moe.RealPositions``: row ``b`` lies in slots ``[start_b,
+  (``models/compact_stream.RealPositions``: row ``b`` lies in slots ``[start_b,
   end_b)``, rows dense, nothing aligned) and, with ``start_b = b * S``, the
   padded ``[B, S]`` form: one kernel, two tables of row bounds.
 

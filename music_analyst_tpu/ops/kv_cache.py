@@ -12,6 +12,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from music_analyst_tpu.ops.packed_stream import PACKED_TILE
+
 
 @dataclasses.dataclass
 class KVCache:
@@ -109,41 +111,24 @@ def block_causal_tile(n_queries: int) -> int:
     return 0
 
 
-# The packed kernel's tile (``ops/flash_attention.flash_attention_packed``):
-# the queries and keys of one grid step.
-PACKED_TILE = 256
-
-
-def packed_tile(width: int, capacity: int, block: int = 1) -> int:
-    """The packed kernel's tile for rows of up to ``width`` positions laid
-    one behind the other in ``capacity`` slots under the rule of ``block``,
-    0 = outside its regime (the view puts queries back at ``[B, S]``): whole
-    tiles of :data:`PACKED_TILE`, which a block divides.  The one place the
-    choice is written down."""
-    tile = PACKED_TILE
-    if width % tile or capacity % tile or tile % block:
-        return 0
-    return tile
-
-
 def prefill_tile_pairs(lengths, width: int, window: int = 0,
                        capacity: int | None = None) -> int:
     """(query, key) pairs a query head of a causal prefill view
     (:class:`BlockCausalPrefill` with ``block`` 1) computes for rows
     ``lengths`` long at ``width`` positions a row (host integers): whole
-    tiles of the kernel's grid where the width admits the kernel, of the
-    packed kernel's where the prefill ran on a compact stream of
-    ``capacity`` slots (``ops/flash_attention.packed_visited_pairs`` at
-    :func:`packed_tile`), of the ``[B, S]`` one's otherwise
-    (``ops/flash_attention.visited_pairs`` at :func:`block_causal_tile`),
-    every pair of every row in the masked XLA form."""
+    tiles of the packed kernel's grid where the prefill ran on a compact
+    stream of ``capacity`` slots (``ops/flash_attention.packed_visited_pairs``,
+    ``models/compact_stream.runs_compact``), of the ``[B, S]`` kernel's
+    where the width admits it (``ops/flash_attention.visited_pairs`` at
+    :func:`block_causal_tile`), every pair of every row in the masked XLA
+    form."""
     import numpy as np
 
-    tile = 0 if capacity is None else packed_tile(width, capacity)
-    if tile:
+    if capacity is not None:
         from music_analyst_tpu.ops.flash_attention import packed_visited_pairs
 
-        return packed_visited_pairs(lengths, capacity, width, tile, window)
+        return packed_visited_pairs(lengths, capacity, width, PACKED_TILE,
+                                    window)
     tile = block_causal_tile(width)
     if not tile:
         return int(np.size(lengths)) * width * width
@@ -194,8 +179,8 @@ class BlockCausalPrefill:
     ``window_causal``; tiles wholly behind the window skipped) and in the
     masked form (``window_causal_dense``) alike.
 
-    Where the layer's stream is a compact token set
-    (``models/moe.RealPositions``) at a shape :func:`packed_tile` admits,
+    Where the layer's stream is the compact token set
+    (``models/compact_stream.RealPositions``; ``runs_compact`` decides),
     :meth:`attend_packed` takes queries, keys and values from the stream as
     they are and returns the packed kernel's result there (attention paths
     ``window_causal_packed`` / ``block_causal_packed``); only the keys and
@@ -220,20 +205,11 @@ class BlockCausalPrefill:
             self, cache=KVCache(keys, values, self.lengths.astype(jnp.int32)),
             k_new=k_new, v_new=v_new)
 
-    def packed_tile(self, packed) -> int:
-        """The packed kernel's tile on the compact stream ``packed`` (a
-        ``models/moe.RealPositions``), 0 = none: the view takes queries
-        at ``[B, S]`` (:meth:`attend`)."""
-        if not self.kernel:
-            return 0
-        width, capacity = packed.real.shape[1], packed.source.shape[0]
-        return packed_tile(width, capacity, self.block)
-
     def attend_packed(self, q: jax.Array, k: jax.Array, v: jax.Array,
                       packed) -> jax.Array:
         """Attention on the compact stream: ``q [C, H, D]``, ``k``/``v [C,
         Hkv, D]`` the slots' own (what :meth:`update` put back at ``[B,
-        S]``), ``[C, H, D]`` back, at a shape :meth:`packed_tile` admits."""
+        S]``), ``[C, H, D]`` back."""
         from music_analyst_tpu.ops.flash_attention import (
             flash_attention_packed,
         )
@@ -253,7 +229,7 @@ class BlockCausalPrefill:
         out = flash_attention_packed(
             q.reshape(capacity, heads * dim), k.reshape(capacity, -1),
             v.reshape(capacity, -1), self.lengths, packed.real.shape[1],
-            heads, self.packed_tile(packed), block_causal=self.block,
+            heads, PACKED_TILE, block_causal=self.block,
             scale=self.scale, window=self.window)
         return out.reshape(capacity, heads, dim)
 

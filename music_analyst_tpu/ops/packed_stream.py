@@ -1,11 +1,12 @@
 """What the packed prefill kernels share: rows laid one behind the other on
-a compact token set (``models/moe.RealPositions``), read a query block at a
-time by the latent prefill's packed kernel
+a compact token set (``models/compact_stream.RealPositions``), read a query
+block at a time by the latent prefill's packed kernel
 (``ops/mla_prefill_attention.mla_prefill_attention_packed``) and the
 grouped-query one (``ops/flash_attention.flash_attention_packed``).
 
-:func:`packed_tables` are the five scalar-prefetched tables that tell a
-block's rows apart; :func:`last_live_block` the block both kernels' index
+:data:`PACKED_TILE` is their tile and :func:`stream_tile` the shapes they
+take; :func:`packed_tables` are the five scalar-prefetched tables that tell
+a block's rows apart; :func:`last_live_block` the block both kernels' index
 maps clamp to; :data:`MAX_FLOOR` and :func:`lanes` their running softmax
 statistics, kept ``[rows, 128]`` with every lane equal.
 """
@@ -13,6 +14,28 @@ statistics, kept ``[rows, 128]`` with every lane equal.
 from __future__ import annotations
 
 import jax.numpy as jnp
+
+# The queries and keys of one grid step.  On a v5e at 32 rows x 1,024 x 32
+# latent heads and the benchmark's prompt lengths (PERF.md): 128 -> 3.7 ms
+# a call, 256 -> 2.7, 512 -> 2.7 (a larger tile runs nearer the MXU's rate
+# and executes more of the masked square).
+PACKED_TILE = 256
+
+
+def stream_tile(width: int, capacity: int, block: int = 1) -> int:
+    """The packed kernels' tile for rows of up to ``width`` positions laid
+    one behind the other in ``capacity`` slots under the rule of ``block``
+    (1: causal), 0 = outside their regime: rows of whole tiles and at least
+    two of them (a single tile has nothing above the diagonal to skip), a
+    token set of whole tiles, and a block that divides the tile.  The one
+    place the regime is written down (``models/compact_stream.py`` reads
+    it)."""
+    tile = PACKED_TILE
+    if (width % tile or width < 2 * tile or capacity % tile
+            or tile % block):
+        return 0
+    return tile
+
 
 # Where a packed kernel's running max starts: above every masked score,
 # under every real one.  A query whose row starts inside a block meets key

@@ -23,10 +23,11 @@ head's ``A < 0`` is a constant::
 * :func:`ssd_chunked` — the same mathematics as one Pallas TPU kernel whose
   device operations carry ``_ssd_`` in their names.  A head's state stays in
   VMEM across a row's chunks; decays and state are float32, MXU operands
-  bfloat16.  It takes the compact token stream (``models/moe.RealPositions``:
-  row ``b`` lies in slots ``[start_b, end_b)``, rows dense, nothing aligned)
-  and, with ``start_b = b * S``, the padded ``[B, S]`` form: one kernel, two
-  tables of row bounds (``ops/kda_attention.py``'s scheme).
+  bfloat16.  It takes the compact token stream
+  (``models/compact_stream.RealPositions``: row ``b`` lies in slots
+  ``[start_b, end_b)``, rows dense, nothing aligned) and, with ``start_b =
+  b * S``, the padded ``[B, S]`` form: one kernel, two tables of row bounds
+  (``ops/kda_attention.py``'s scheme).
 
 The chunked form.  With ``a_t = delta_t A`` (the log-decay of a step, <= 0),
 ``G_i`` its running sum inside the chunk (inclusive), ``S_0`` the state on

@@ -45,10 +45,12 @@ from music_analyst_tpu.models.llama import (  # noqa: E402
     init_caches,
 )
 from music_analyst_tpu.models.mamba2 import Mamba2Mixer, SSMState  # noqa: E402
-from music_analyst_tpu.models.moe import (  # noqa: E402
+from music_analyst_tpu.models.compact_stream import (  # noqa: E402
     RealPositions,
-    RoutedMoE,
     compact_capacity,
+)
+from music_analyst_tpu.models.moe import (  # noqa: E402
+    RoutedMoE,
     route_softmax_topk,
 )
 from music_analyst_tpu.ops import ssd_scan  # noqa: E402
@@ -291,7 +293,7 @@ def test_kernel_is_the_recurrence_on_bfloat16_operands(case, form):
             starts + lens_d, valid.reshape(-1), heads, n_tok)
         got_y = got_y.reshape(rows, n_tok, heads, dim)
     else:
-        capacity = compact_capacity(int(lens.sum()), rows * n_tok)
+        capacity = compact_capacity(int(lens.sum()), rows, n_tok)
         assert capacity == 512
         packed = RealPositions.of(lens_d, n_tok, capacity)
         got_y, got_s = ssd_scan.ssd_chunked(
@@ -347,7 +349,7 @@ def test_convolution_and_state_restart_at_a_rows_first_slot(clf):
     cfg = clf.config
     rows, width = 4, 512
     lens = jnp.asarray([300, 41, 256, 101], jnp.int32)
-    capacity = compact_capacity(int(lens.sum()), rows * width)
+    capacity = compact_capacity(int(lens.sum()), rows, width)
     packed = RealPositions.of(lens, width, capacity)
     h = _hidden(rows, width, cfg.dim, 4)
     mixer = _mamba(cfg)
@@ -668,7 +670,7 @@ def test_prefill_and_label_passes_agree_with_the_full_forward(clf, longest):
     lyrics = _lyrics(1, 12, longest) + [""]
     probe = [0, 2, 3, 5, 7, 8, 11, 12]
     ids, lens, scores, stats, labels = _system(clf, lyrics, probe)
-    capacity = compact_capacity(int(lens.sum()), ids.size)
+    capacity = compact_capacity(int(lens.sum()), *ids.shape)
     assert llama.runs_compact(clf.config, ids.shape, capacity) == (
         longest == 400)
     judged = _judged(clf, ids, lens, stats)
@@ -751,7 +753,7 @@ def test_compact_stream_equals_the_padded_prefill_on_every_real_position(clf):
     mask = causal_mask(width, width + 8, 0) & (
         jnp.arange(width + 8)[None, None, None, :]
         < lens[:, None, None, None])
-    capacity = compact_capacity(int(lens.sum()), rows * width)
+    capacity = compact_capacity(int(lens.sum()), rows, width)
     assert capacity == 768 and llama.runs_compact(cfg, ids.shape, capacity)
 
     def forward(**kw):

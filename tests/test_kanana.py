@@ -12,6 +12,7 @@ is tight enough that int8 experts or a dropped shared expert fail it.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import json
 import os
@@ -37,9 +38,11 @@ from music_analyst_tpu.models.llama import (  # noqa: E402
     init_caches,
 )
 from music_analyst_tpu.models.mla import LatentCache, MLAttention  # noqa: E402
+from music_analyst_tpu.models.compact_stream import (  # noqa: E402
+    compact_capacity,
+)
 from music_analyst_tpu.models.moe import (  # noqa: E402
     SigmoidRoutedMoE,
-    compact_capacity,
     route_sigmoid_noaux,
 )
 
@@ -87,6 +90,18 @@ def _backend(name: str):
 @pytest.fixture(scope="module")
 def clf():
     return _backend("kanana-tiny")
+
+
+@pytest.fixture(scope="module")
+def clf32(clf):
+    """``clf``'s model computing in float32 (its programs take ``clf``'s
+    weights): the prefill kernel rounds its probabilities to the values'
+    dtype before P.V and divides after, the blocked form divides first,
+    and the padded layer reads its keys back from the cache where the
+    stream's reads them as computed, so only in float32, caches too, do
+    the two run the same arithmetic (summed in other orders)."""
+    return LlamaZeroShotClassifier(
+        config=dataclasses.replace(clf.config, dtype="float32"), seed=0)
 
 
 def _f32(tree):
@@ -384,7 +399,7 @@ def test_label_scores_through_the_latent_cache_match_reference(clf, scored):
     assert np.abs(got - free["scores"]).max() > diff.max()
     record = list(clf._score_labels.records.values())[-1]
     assert record.traced_paths["mla.expanded"] == 3      # the prefill
-    assert record.attention_paths == {"mla_flash": 3}    # as one kernel
+    assert record.attention_paths == {"mla_flash_packed": 3}  # one kernel
     assert record.traced_paths["mla.absorbed"] == 3      # label passes
     assert record.traced_paths["moe.grouped"] == 4       # 2 layers x 2
 
@@ -538,7 +553,7 @@ def test_compact_prefill_equals_the_full_one_on_every_real_position(
     chose, and through the scoring program the label scores."""
     ids, lens = _ragged_step(clf, lengths)
     rows, width = ids.shape
-    capacity = compact_capacity(int(lens.sum()), rows * width)
+    capacity = compact_capacity(int(lens.sum()), rows, width)
     assert capacity == {125: 128, 96: 96, 97: 128}[int(lens.sum())]
     positions = jnp.broadcast_to(jnp.arange(width), (rows, width))
     lens_j = jnp.asarray(lens)
@@ -602,20 +617,25 @@ _WIDE_STEPS = {"ragged": [300, 1, 256, 141],
 @pytest.mark.parametrize("lengths", list(_WIDE_STEPS.values()),
                          ids=list(_WIDE_STEPS))
 def test_compact_stream_equals_the_padded_prefill_on_every_real_position(
-        clf, lengths):
+        clf, clf32, lengths, monkeypatch):
     """At a width and a rung the packed prefill kernel takes, a prefill
     that declares a ``prefill_capacity`` keeps its hidden state on the
     compact token set from the embedding to the last norm
-    (``llama.runs_compact``).  Against the same call with lengths alone
-    (the padded kernel, every position through every layer): the head's
+    (``llama.runs_compact``).  Against the padded prefill (``runs_compact``
+    answering no: every position through every layer, the blocked form
+    under the mask), both in float32 (``clf32``, caches too): the head's
     logits at each row's last position, the cached ``latents`` and
     ``rope_keys`` and the chosen experts on every real position, zeros in
-    the cache behind a row's length; through the scoring program the label
-    scores, and the compile record names what ran; through
+    the cache behind a row's length.  As served
+    (bfloat16), with the capacity declared and without (the stream on the
+    step's rows x width slots): through the scoring program the label
+    scores, and the compile records name what ran; through
     ``generate_scan_program`` the same tokens."""
+    from music_analyst_tpu.models import compact_stream
+
     ids, lens = _ragged_step(clf, lengths, width=512)
     rows, width = ids.shape
-    capacity = compact_capacity(int(lens.sum()), rows * width)
+    capacity = compact_capacity(int(lens.sum()), rows, width)
     assert capacity == {698: 768, 1024: 1024}[int(lens.sum())]
     assert llama.runs_compact(clf.config, ids.shape, capacity)
     positions = jnp.broadcast_to(jnp.arange(width), (rows, width))
@@ -625,9 +645,9 @@ def test_compact_stream_equals_the_padded_prefill_on_every_real_position(
         kv < lens_j[:, None, None, None])
 
     def forward(**declared):
-        (logits, caches), sown = clf.model.apply(
+        (logits, caches), sown = clf32.model.apply(
             {"params": clf.params}, jnp.asarray(ids), positions, mask,
-            init_caches(clf.config, rows, width + 8),
+            init_caches(clf.config, rows, width + 8, jnp.float32),
             last_position=lens_j - 1, prefill_lengths=lens_j,
             mutable=["intermediates"], **declared)
         chosen = np.stack([
@@ -635,7 +655,9 @@ def test_compact_stream_equals_the_padded_prefill_on_every_real_position(
                        ["feed_forward_moe"]["chosen"][0]) for i in (1, 2)])
         return np.asarray(logits), caches, chosen
 
-    full, full_caches, full_chosen = forward()
+    with monkeypatch.context() as patched:
+        patched.setattr(compact_stream, "runs_compact", lambda *a: False)
+        full, full_caches, full_chosen = forward()
     got, caches, chosen = forward(prefill_capacity=capacity)
     real = np.arange(width)[None, :] < lens[:, None]
     assert got.shape == full.shape == (rows, 1, clf.config.vocab_size)
@@ -659,15 +681,12 @@ def test_compact_stream_equals_the_padded_prefill_on_every_real_position(
     scores, stats = clf._score_labels(
         clf.params, jnp.asarray(ids), lens_j, *labels,
         prefill_capacity=capacity)
-    record = list(clf._score_labels.records.values())[-1]
     assert np.abs(np.asarray(scores) - np.asarray(want)).max() < 0.03
-    assert record.attention_paths == {"mla_flash_packed": 3}
-    assert record.traced_paths["mla.compact"] == 3
-    assert record.traced_paths["moe.compact"] == 2
-    padded = [r for r in clf._score_labels.records.values()
-              if r.attention_paths == {"mla_flash": 3}]
-    assert padded and all(
-        "mla.compact" not in r.traced_paths for r in padded)
+    for key, record in clf._score_labels.records.items():
+        if f"int32[{rows}, {width}]" in key:    # both: the stream
+            assert record.attention_paths == {"mla_flash_packed": 3}
+            assert record.traced_paths["mla.compact"] == 3
+            assert record.traced_paths["moe.compact"] == 2
     assert (np.asarray(stats["expert_load_mean"]) * clf.config.n_experts
             ).tolist() == [lens.sum() * 2] * 2
 
@@ -681,40 +700,74 @@ def test_compact_stream_equals_the_padded_prefill_on_every_real_position(
 
 def test_who_keeps_the_padded_stream():
     """``runs_compact`` is decided by what the call shows: blocks that
-    take the stream, fewer slots than positions, a width and a slot count
-    the packed kernel takes.  Everyone else keeps the ``[B, S, dim]``
-    stream."""
+    take the stream, a width and a slot count the packed kernels take,
+    full rows included, and every rung of an odd number of rows at such a
+    width (``compact_capacity`` rounds it to whole tiles).  Everyone else
+    keeps the ``[B, S, dim]`` stream."""
     tiny = PRESETS["kanana-tiny"]()
     assert llama.runs_compact(tiny, (32, 1024), 12288)
     assert llama.runs_compact(tiny, (4, 512), 768)
     assert not llama.runs_compact(tiny, (32, 1024), None)        # a mesh
-    assert not llama.runs_compact(tiny, (32, 1024), 32 * 1024)   # full rows
+    assert llama.runs_compact(tiny, (32, 1024), 32 * 1024)       # full rows
     assert not llama.runs_compact(tiny, (4, 64), 128)     # blocked width
-    assert not llama.runs_compact(tiny, (13, 512), 832)   # not whole blocks
+    assert not llama.runs_compact(tiny, (4, 256), 512)    # one tile a row
+    assert not llama.runs_compact(tiny, (13, 512), 832)   # not whole tiles
+    assert all(llama.runs_compact(tiny, (13, 512),
+                                  compact_capacity(n, 13, 512))
+               for n in range(0, 13 * 512 + 1, 7))
     # grouped-query blocks alone: a block-diffusion prefill takes the
     # stream (tests/test_sdar.py), an autoregressive decoder does not
     assert llama.runs_compact(PRESETS["sdar-tiny"](), (32, 1024), 12288)
     assert not llama.runs_compact(LlamaConfig.tiny(), (32, 1024), 12288)
 
 
-def test_a_step_of_full_rows_runs_the_program_without_lengths(clf):
-    """The rung of a step whose rows are all full is the step itself, and
-    at that capacity the traced program is the uncompacted one, text for
-    text: the one a caller that declares no capacity, or no lengths at
-    all, compiles (at this width the prefill kernel takes no part)."""
-    ids, lens = _ragged_step(clf, [64, 64, 64, 64])
+@pytest.mark.parametrize("lengths", [[512] * 4, [512, 40, 300, 256] * 3 + [7]],
+                         ids=["full-rows", "13-rows"])
+def test_a_step_of_full_rows_runs_the_program_without_lengths(clf, clf32,
+                                                              lengths):
+    """A step that declares its rows' lengths runs the compact stream at a
+    kernel width whatever its rung: full rows (the rung is the step
+    itself, and a caller that declares no capacity compiles that program
+    text for text) and an odd number of rows (13 x 512, rungs of whole
+    tiles).  Its logits at every row's last position equal the padded
+    program's, the one a caller that declares no lengths compiles (the
+    blocked form under the mask), both in float32 (``clf32``, caches
+    too); and at a
+    narrow width the step of full rows is that padded program itself."""
+    ids, lens = _ragged_step(clf, lengths, width=512)
+    rows, width = ids.shape
+    capacity = compact_capacity(int(lens.sum()), rows, width)
+    assert capacity == ({4: 2048, 13: 4352}[rows])
+    assert llama.runs_compact(clf.config, ids.shape, capacity)
     args = (clf.params, jnp.asarray(ids), jnp.asarray(lens),
             jnp.asarray(clf._label_ids), jnp.asarray(clf._label_lens))
-    assert compact_capacity(int(lens.sum()), ids.size) == ids.size
 
     def text(**static):
         return clf._score_labels.lower(*args, **static).as_text()
 
-    assert text(prefill_capacity=ids.size) == text()
-    assert text(prefill_capacity=ids.size // 2) != text()
-    # the staged hooks hand the program that rung
-    assert clf.transfer(clf.prepare(["la"] * 2))[3][2] < 2 * 64
+    if rows * width == capacity:
+        assert text(prefill_capacity=capacity) == text()
+    positions = jnp.broadcast_to(jnp.arange(width), (rows, width))
+    lens_j = jnp.asarray(lens)
+    kv = jnp.arange(width + 8)[None, None, None, :]
+    mask = causal_mask(width, width + 8, 0) & (
+        kv < lens_j[:, None, None, None])
 
+    def logits(**declared):
+        return np.asarray(jax.jit(lambda p: clf32.model.apply(
+            {"params": p}, jnp.asarray(ids), positions, mask,
+            init_caches(clf.config, rows, width + 8, jnp.float32),
+            last_position=lens_j - 1, **declared)[0])(clf.params))
+
+    got = logits(prefill_lengths=lens_j, prefill_capacity=capacity)
+    want = logits()
+    assert got.shape == want.shape == (rows, 1, clf.config.vocab_size)
+    assert np.abs(got - want).max() < 0.05
+
+    # at a width the kernels refuse the rung of full rows is the step, and
+    # the traced program is the uncompacted one
+    ids, lens = _ragged_step(clf, [64, 64, 64, 64])
+    assert compact_capacity(int(lens.sum()), *ids.shape) == ids.size
     positions = jnp.broadcast_to(jnp.arange(64), (4, 64))
     mask = causal_mask(64, 64, 0)
 
@@ -725,6 +778,8 @@ def test_a_step_of_full_rows_runs_the_program_without_lengths(clf):
 
     assert block_text(prefill_lengths=jnp.asarray(lens),
                       prefill_capacity=ids.size) == block_text()
+    # the staged hooks hand the program a rung
+    assert clf.transfer(clf.prepare(["la"] * 2))[3][2] < 2 * 64
 
 
 def _traced_growth(fn):
@@ -749,7 +804,7 @@ def test_where_the_compact_path_must_and_must_not_engage(clf, path):
     no capacity trace ``moe.grouped`` without ``moe.compact``."""
     # a width no other test traces: a traced program is not traced again
     ids, lens = _ragged_step(clf, [48, 1, 23, 37], width=48)
-    assert compact_capacity(int(lens.sum()), ids.size) == 120
+    assert compact_capacity(int(lens.sum()), *ids.shape) == 120
     labels = (jnp.asarray(clf._label_ids), jnp.asarray(clf._label_lens))
     if path == "label_passes":
         # two routed layers: the prefill's two take it, the label passes'
@@ -830,7 +885,7 @@ def test_staged_hooks_equal_classify_batch_and_count_the_step(clf):
     # step's width and rung are ones the packed prefill takes), not the
     # step's rows x width, then the one position a label whose forward is
     # read (the word's; the table is two wide, not padded to 8)
-    capacity = compact_capacity(int(lens.sum()), rows * width)
+    capacity = compact_capacity(int(lens.sum()), rows, width)
     assert llama.runs_compact(clf.config, (rows, width), capacity)
     assert tel.counters["decoder.tokens_computed"] - before.get(
         "decoder.tokens_computed", 0) == 2 * (capacity + rows * 3 * 1)
@@ -952,7 +1007,7 @@ def test_a_step_counts_the_label_positions_it_ran(model, ran, read):
     def grew(name):
         return tel.counters.get(name, 0) - before.get(name, 0)
 
-    capacity = compact_capacity(int(lens.sum()), rows * width)
+    capacity = compact_capacity(int(lens.sum()), rows, width)
     through = (capacity if llama.runs_compact(
         served.config, (rows, width), capacity) else rows * width)
     assert grew("decoder.tokens_computed") == 2 * (through + rows * ran)
@@ -1031,7 +1086,7 @@ def test_sharded_equals_unsharded(clf):
     # heads), so the meshed program keeps the XLA form, which it splits
     assert ids.shape[1] == 512
     meshed = list(sharded._score_labels.records.values())[-1]
-    assert any(r.attention_paths == {"mla_flash": 3}
+    assert any(r.attention_paths == {"mla_flash_packed": 3}
                for r in clf._score_labels.records.values())
     assert meshed.attention_paths == {"mla_blocked": 3}
     assert meshed.traced_paths["mla.expanded"] == 3

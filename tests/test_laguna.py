@@ -44,9 +44,11 @@ from music_analyst_tpu.models.llama import (  # noqa: E402
     LlamaConfig,
     init_caches,
 )
+from music_analyst_tpu.models.compact_stream import (  # noqa: E402
+    compact_capacity,
+)
 from music_analyst_tpu.models.moe import (  # noqa: E402
     RoutedMoE,
-    compact_capacity,
     route_sigmoid_noaux,
 )
 from music_analyst_tpu.ops import flash_attention as fa  # noqa: E402
@@ -653,7 +655,7 @@ def test_compact_prefill_and_label_passes_agree_with_the_full_forward(
     masked behind its window), against one plain forward a label over
     prompt + label tokens."""
     ids, lens, scores, stats, labels, judged = compact_step
-    capacity = compact_capacity(int(lens.sum()), ids.size)
+    capacity = compact_capacity(int(lens.sum()), *ids.shape)
     assert ids.shape == (13, 512)
     assert llama.runs_compact(clf.config, ids.shape, capacity)
     _assert_within_tolerance(scores, judged, _held(judged, stats, lens))
@@ -665,7 +667,7 @@ def test_padded_prefill_agrees_with_the_full_forward(clf):
     the same way."""
     lyrics = _lyrics(2, 12, 60) + [""]
     ids, lens, scores, stats, _ = _system(clf, lyrics, PROBE)
-    capacity = compact_capacity(int(lens.sum()), ids.size)
+    capacity = compact_capacity(int(lens.sum()), *ids.shape)
     assert not llama.runs_compact(clf.config, ids.shape, capacity)
     judged = _judged(clf, ids, lens, stats)
     _assert_within_tolerance(scores, judged, _held(judged, stats, lens))

@@ -44,10 +44,12 @@ from music_analyst_tpu.models.llama import (  # noqa: E402
     init_caches,
 )
 from music_analyst_tpu.models.mla import LatentCache, MLAttention  # noqa: E402
-from music_analyst_tpu.models.moe import (  # noqa: E402
+from music_analyst_tpu.models.compact_stream import (  # noqa: E402
     RealPositions,
-    RoutedMoE,
     compact_capacity,
+)
+from music_analyst_tpu.models.moe import (  # noqa: E402
+    RoutedMoE,
     route_sigmoid_noaux,
 )
 from music_analyst_tpu.ops import kda_attention as kda_ops  # noqa: E402
@@ -633,7 +635,7 @@ def test_prefill_and_label_passes_agree_with_the_full_forward(clf, longest):
     lyrics = _lyrics(1, 12, longest) + [""]
     probe = [0, 2, 3, 5, 7, 8, 11, 12]
     ids, lens, scores, stats, labels = _system(clf, lyrics, probe)
-    capacity = compact_capacity(int(lens.sum()), ids.size)
+    capacity = compact_capacity(int(lens.sum()), *ids.shape)
     assert llama.runs_compact(clf.config, ids.shape, capacity) == (
         longest == 400)
     judged = _judged(clf, ids, lens, stats)
@@ -681,7 +683,7 @@ def test_compact_stream_equals_the_padded_prefill_on_every_real_position(clf):
     mask = causal_mask(width, width + 8, 0) & (
         jnp.arange(width + 8)[None, None, None, :]
         < lens[:, None, None, None])
-    capacity = compact_capacity(int(lens.sum()), rows * width)
+    capacity = compact_capacity(int(lens.sum()), rows, width)
     assert capacity == 768 and llama.runs_compact(cfg, ids.shape, capacity)
 
     def forward(**kw):

@@ -218,24 +218,48 @@ _WIDTH = 16
                               "one-short-of-full", "one-past-a-rung"])
 def test_compact_capacity_is_the_smallest_eighth_that_holds_the_tokens(
         lengths, rung):
-    from music_analyst_tpu.models.moe import compact_capacity
+    from music_analyst_tpu.models.compact_stream import compact_capacity
 
     positions = len(lengths) * _WIDTH
-    assert compact_capacity(sum(lengths), positions) == rung
+    assert compact_capacity(sum(lengths), len(lengths), _WIDTH) == rung
     assert sum(lengths) <= rung <= positions
     assert rung % (positions // 8) == 0
 
 
 def test_compact_capacity_never_passes_the_step_and_holds_an_empty_one():
-    from music_analyst_tpu.models.moe import compact_capacity
+    from music_analyst_tpu.models.compact_stream import compact_capacity
 
-    assert compact_capacity(0, 64) == 8        # one rung, never zero slots
-    assert compact_capacity(64, 64) == 64
-    assert compact_capacity(20, 20) == 20      # 20 positions: rungs of 3
-    assert compact_capacity(7, 20) == 9
-    assert {compact_capacity(n, 32 * 1024) for n in range(1, 32 * 1024 + 1,
+    assert compact_capacity(0, 1, 64) == 8     # one rung, never zero slots
+    assert compact_capacity(64, 1, 64) == 64
+    assert compact_capacity(20, 1, 20) == 20   # 20 positions: rungs of 3
+    assert compact_capacity(7, 1, 20) == 9
+    assert {compact_capacity(n, 32, 1024) for n in range(1, 32 * 1024 + 1,
                                                           97)} == {
         4096 * i for i in range(1, 9)}         # at most eight programs
+
+
+@pytest.mark.parametrize("rows,width,rungs", [
+    (16, 1024, [2048 * i for i in range(1, 9)]),
+    (32, 1024, [4096 * i for i in range(1, 9)]),
+    (64, 1024, [8192 * i for i in range(1, 9)]),
+    (13, 1024, [1792, 3328, 5120, 6656, 8448, 9984, 11776, 13312]),
+    (13, 512, [1024, 1792, 2560, 3328, 4352, 5120, 5888, 6656]),
+], ids=["16x1024", "32x1024", "64x1024", "13x1024", "13x512"])
+def test_compact_capacity_at_a_kernel_width_is_whole_tiles(rows, width,
+                                                           rungs):
+    """At a width the packed kernels take, every rung is whole 256-slot
+    tiles: the cells' steps (16, 32 and 64 rows of 1,024) keep their
+    eighths as they were, and an odd number of rows (a corpus job's last
+    batch) has each eighth rounded up to whole tiles, never past the
+    step's own positions; still at most eight programs, the last the
+    step's rows x width."""
+    from music_analyst_tpu.models.compact_stream import compact_capacity
+
+    got = sorted({compact_capacity(n, rows, width)
+                  for n in range(0, rows * width + 1, 13)}
+                 | {compact_capacity(rows * width, rows, width)})
+    assert got == rungs
+    assert all(rung % 256 == 0 for rung in got)
 
 
 @pytest.mark.parametrize("lengths,capacity", [
@@ -243,7 +267,7 @@ def test_compact_capacity_never_passes_the_step_and_holds_an_empty_one():
     ids=["ragged", "exact", "sparse", "a-rung-too-many"])
 def test_real_positions_index_is_row_major_and_its_own_inverse(
         lengths, capacity):
-    from music_analyst_tpu.models.moe import RealPositions
+    from music_analyst_tpu.models.compact_stream import RealPositions
 
     index = RealPositions.of(jnp.asarray(lengths, jnp.int16), _WIDTH,
                              capacity)
@@ -281,7 +305,7 @@ def test_compact_experts_equal_the_full_layer_on_every_real_position(
     the real positions alone give what the full layer gives there (a row
     of a grouped matmul depends on that row and its expert alone), the
     same experts chosen; padding receives zeros and is counted nowhere."""
-    from music_analyst_tpu.models.moe import RealPositions
+    from music_analyst_tpu.models.compact_stream import RealPositions
 
     layer = _routed(dtype)
     rows = len(lengths)
@@ -315,7 +339,7 @@ def test_compact_experts_equal_the_full_layer_on_every_real_position(
 def test_fillers_belong_to_no_expert_and_cannot_reach_a_real_position():
     """A filler's assignments sort behind the last group: poisoning what
     the filler slots hold changes no real position's result."""
-    from music_analyst_tpu.models.moe import RealPositions
+    from music_analyst_tpu.models.compact_stream import RealPositions
 
     layer = _routed(jnp.float32)
     lengths, capacity = _RAGGED
@@ -345,7 +369,7 @@ def test_packed_experts_take_and_return_the_token_set_itself(
     with the shared experts' output alone: it stays in the stream, and the
     prefill kernel needs it finite."""
     from music_analyst_tpu.models.layers import SwiGLU
-    from music_analyst_tpu.models.moe import RealPositions
+    from music_analyst_tpu.models.compact_stream import RealPositions
 
     layer = _routed(jnp.float32)
     rows, n_real = len(lengths), sum(lengths)

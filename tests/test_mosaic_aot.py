@@ -44,11 +44,9 @@ from jax.sharding import SingleDeviceSharding
 
 from music_analyst_tpu.ops.flash_attention import flash_attention
 from music_analyst_tpu.ops.mla_prefill_attention import (
-    mla_prefill_attention,
     mla_prefill_attention_packed,
-    packed_prefill_block,
-    prefill_block,
 )
+from music_analyst_tpu.ops.packed_stream import stream_tile
 from music_analyst_tpu.ops.paged_attention import (
     check_stream_geometry,
     paged_attention,
@@ -163,52 +161,26 @@ def test_whole_row_attention_compiles_under_mosaic(
 
 
 @pytest.mark.parametrize(
-    "rows,seq,heads,nope,rope,v_dim",
-    [
-        (32, 1024, 32, 128, 64, 128),   # kanana-2-30b-a3b, the cell's step
-        (4, 512, 32, 128, 64, 128),     # two blocks, the smallest admitted
-        (8, 512, 4, 16, 8, 16),         # kanana-tiny: heads inside a lane tile
-    ],
-)
-def test_mla_prefill_attention_compiles_under_mosaic(
-    tpu_sharding, rows, seq, heads, nope, rope, v_dim
-):
-    """The loop over heads with lane slices at traced offsets, and the
-    unrolled form where a head is narrower than a lane tile, inside the
-    VMEM the call asks for; keys are the cache's buffer, 8 past the
-    queries."""
-    assert prefill_block(seq)
-
-    def fn(q_nope, q_rope, kv, k_rope, lengths):
-        return mla_prefill_attention(
-            q_nope, q_rope, kv, k_rope, lengths, heads,
-            (nope + rope) ** -0.5, interpret=False)
-
-    _compile_for_tpu(
-        fn, tpu_sharding,
-        ((rows, seq, heads * nope), jnp.bfloat16),
-        ((rows, seq, heads * rope), jnp.bfloat16),
-        ((rows, seq + 8, heads * (nope + v_dim)), jnp.bfloat16),
-        ((rows, seq + 8, rope), jnp.bfloat16),
-        ((rows,), jnp.int32),
-    )
-
-
-@pytest.mark.parametrize(
     "capacity,rows,seq,heads,nope,rope,v_dim",
     [
         (12288, 32, 1024, 32, 128, 64, 128),  # the cell's usual rung
         (16384, 32, 1024, 32, 128, 64, 128),  # and the one above
+        (32768, 32, 1024, 32, 128, 64, 128),  # the cell's step, rows full
         (1024, 4, 512, 32, 128, 64, 128),     # the smallest admitted width
+        (2048, 4, 512, 32, 128, 64, 128),     # and its rows full
         (2048, 8, 512, 4, 16, 8, 16),         # kanana-tiny's widths
+        (4096, 8, 512, 4, 16, 8, 16),         # and its rows full
     ],
 )
 def test_packed_mla_prefill_attention_compiles_under_mosaic(
     tpu_sharding, capacity, rows, seq, heads, nope, rope, v_dim
 ):
-    """The packed form: ``[capacity, H*D]`` operands, five scalar-prefetched
-    tables, a loop over a block's rows with traced bounds."""
-    assert packed_prefill_block(seq, capacity)
+    """``[capacity, H*D]`` operands, five scalar-prefetched tables, a loop
+    over a block's rows with traced bounds; the loop over heads with lane
+    slices at traced offsets, and the unrolled form where a head is
+    narrower than a lane tile, inside the VMEM the call asks for; full
+    rows at the step's own ``rows x seq`` slots."""
+    assert stream_tile(seq, capacity)
 
     def fn(q_nope, q_rope, kv, k_rope, lengths):
         return mla_prefill_attention_packed(
@@ -235,14 +207,15 @@ def _opcode(program: str, name: str) -> str:
 def test_projections_feed_the_prefill_kernel_without_a_copy(
     tpu_sharding, monkeypatch
 ):
-    """One latent-attention layer at the published widths, compiled for a
-    v5e: the kernel's large operands, ``q_nope`` and the expanded ``[k_nope
-    | v]`` (nine tenths of what it reads), are the projections' own fusions
-    and its result reaches ``o_proj`` as a bitcast.  ``MLAttention`` writes
-    those projections as contractions with 2-D weights for this; from
-    ``[B, S, H, D]`` einsums each got a transposing copy.  The rotated
-    ``q_rope`` still comes through one (RoPE works on ``[B, S, H, D]``)."""
-    from music_analyst_tpu.models.layers import causal_mask
+    """One latent-attention layer at the published widths on the compact
+    stream of a step of full rows, compiled for a v5e: the kernel's large
+    operands, ``q_nope`` and the expanded ``[k_nope | v]`` (nine tenths of
+    what it reads), are the projections' own fusions and its result
+    reaches ``o_proj`` as a bitcast.  ``MLAttention`` writes those
+    projections as contractions with 2-D weights for this; from ``[.., H,
+    D]`` einsums each got a transposing copy.  The rotated ``q_rope``
+    still comes through one (RoPE works on ``[.., H, D]``)."""
+    from music_analyst_tpu.models.compact_stream import RealPositions
     from music_analyst_tpu.models.mla import LatentCache, MLAttention
     from music_analyst_tpu.ops import flash_attention
 
@@ -255,9 +228,12 @@ def test_projections_feed_the_prefill_kernel_without_a_copy(
     def forward(params, x, lens):
         cache = LatentCache.zeros(rows, seq + labels, rank, rope,
                                   jnp.bfloat16)
+        stream = RealPositions.of(lens, seq, rows * seq)
+        positions = jnp.broadcast_to(jnp.arange(seq), (rows, seq))
         return attention.apply(
-            params, x, causal_mask(seq, seq + labels, 0), None, cache,
-            prefill_lengths=lens)
+            params, stream.gather(x)[None], None,
+            stream.gather(positions)[None], cache, prefill_lengths=lens,
+            packed=stream)
 
     def placed(tree):
         return jax.tree_util.tree_map(
@@ -273,10 +249,11 @@ def test_projections_feed_the_prefill_kernel_without_a_copy(
     ).lower(lowering_platforms=("tpu",)).compile().as_text()
 
     (call,) = re.findall(
-        r"^\s*%(_prefill_call[.\d]*) = \S+ custom-call\(([^)]*)\)", program,
-        re.MULTILINE)
+        r"^\s*%(_packed_prefill_call[.\d]*) = \S+ custom-call\(([^)]*)\)",
+        program, re.MULTILINE)
     name, operands = call[0], re.findall(r"%([\w.-]+)", call[1])
-    lengths, q_nope, q_rope, kv, k_rope = operands
+    *tables, q_nope, q_rope, kv, k_rope = operands
+    assert len(tables) == 5
     assert _opcode(program, q_nope) == "fusion"
     assert _opcode(program, kv) == "fusion"
     assert _opcode(program, q_rope) in ("copy", "fusion")
@@ -373,9 +350,9 @@ def test_compact_scoring_step_keeps_the_kernel_fed_and_no_padded_position(
 ):
     """``llama_score_labels`` at the decoder cell's step (32 x 1,024, the
     published widths, abstract parameters) compiled for a v5e at the rungs
-    the cell's jobs meet (``models/moe.compact_capacity`` of about 10.3k
-    real tokens a step).  The prefill runs on ``capacity`` token slots
-    from the embedding to the last norm: nothing of the 196,608
+    the cell's jobs meet (``models/compact_stream.compact_capacity`` of
+    about 10.3k real tokens a step).  The prefill runs on ``capacity``
+    token slots from the embedding to the last norm: nothing of the 196,608
     assignments of the padded step (32 x 1,024 x 6) is left, in any
     array, and no array of the step's 32 x 1,024 positions wider than the
     latent cache's (``latents`` 512, ``k_rope`` 64).  The packed prefill
@@ -383,7 +360,7 @@ def test_compact_scoring_step_keeps_the_kernel_fed_and_no_padded_position(
     fusions in every one of the seven layers, with no copy, slice or
     transpose between, and its result reaches ``o_proj`` as a bitcast.
     The trace says which form it took; a program that declares no
-    capacity takes neither compact path."""
+    capacity takes the stream too, at the step's 32,768 slots."""
     from music_analyst_tpu.models import llama
     from music_analyst_tpu.ops import flash_attention
 
@@ -412,9 +389,8 @@ def test_compact_scoring_step_keeps_the_kernel_fed_and_no_padded_position(
     paths = _traced(lambda: traced.append(trace(prefill_capacity=capacity)))
     assert (paths["mla.compact"], paths["moe.compact"]) == (7, 6)
     if capacity == 12288:
-        padded = _traced(trace)       # lengths, no capacity
-        assert padded["mla.expanded"] == 7
-        assert not {"mla.compact", "moe.compact"} & set(padded)
+        full = _traced(trace)       # lengths, no capacity: rows x width
+        assert (full["mla.compact"], full["moe.compact"]) == (7, 6)
     compiled = traced[0].lower(lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
 
@@ -446,7 +422,6 @@ def test_compact_scoring_step_keeps_the_kernel_fed_and_no_padded_position(
         r"^\s*%(_packed_prefill_call[.\d]*) = \S+ custom-call\(([^)]*)\)",
         text, re.MULTILINE)
     assert len(calls) == config.n_layers
-    assert "%_prefill_call" not in text
     for name, operands in calls:
         *tables, q_nope, q_rope, kv, k_rope = re.findall(r"%([\w.-]+)",
                                                          operands)
@@ -538,10 +513,9 @@ def test_packed_flash_attention_compiles_under_mosaic(
     scalar-prefetched tables, feature-major operand blocks, and no scoped
     VMEM beyond the compiler's default (``packed_kv_heads``)."""
     from music_analyst_tpu.ops.flash_attention import flash_attention_packed
-    from music_analyst_tpu.ops.kv_cache import packed_tile
 
     width = 1024 if capacity > 1024 else 512
-    tile = packed_tile(width, capacity, block)
+    tile = stream_tile(width, capacity, block)
     assert tile
 
     def fn(q, k, v, lengths):
@@ -808,7 +782,7 @@ def test_mamba_layer_feeds_the_ssd_kernel_without_a_copy(tpu_sharding,
     out anew for the kernel, before it or behind it."""
     from music_analyst_tpu.models import llama
     from music_analyst_tpu.models.mamba2 import Mamba2Mixer, SSMState
-    from music_analyst_tpu.models.moe import RealPositions
+    from music_analyst_tpu.models.compact_stream import RealPositions
     from music_analyst_tpu.ops import flash_attention
 
     monkeypatch.setattr(flash_attention, "interpret_default", lambda: False)

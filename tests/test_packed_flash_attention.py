@@ -13,7 +13,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from music_analyst_tpu.models.moe import RealPositions, compact_capacity
+from music_analyst_tpu.models.compact_stream import (
+    RealPositions,
+    compact_capacity,
+)
 from music_analyst_tpu.ops import flash_attention
 from music_analyst_tpu.ops.flash_attention import (
     flash_attention_packed,
@@ -21,12 +24,11 @@ from music_analyst_tpu.ops.flash_attention import (
     packed_visited_pairs,
 )
 from music_analyst_tpu.ops.kv_cache import (
-    PACKED_TILE,
     BlockCausalPrefill,
     KVCache,
-    packed_tile,
     prefill_tile_pairs,
 )
+from music_analyst_tpu.ops.packed_stream import PACKED_TILE, stream_tile
 
 WIDTH, DIM, BLOCK = 32, 16, 8
 
@@ -151,7 +153,7 @@ TILE_CASES = {
 @pytest.mark.parametrize("case", list(TILE_CASES), ids=list(TILE_CASES))
 def test_the_tiles_the_packed_kernel_runs_are_the_tiles_a_mask_needs(case):
     """``packed_visited_pairs`` (the kernel's own rule,
-    ``packed_tile_runs``, on its own tables) counts exactly the tiles in
+    ``packed_step_runs``, on its own tables) counts exactly the tiles in
     which some real query sees some key: nothing the mask needs is
     skipped, nothing wholly outside it runs."""
     lengths, width, capacity, block, window, n = TILE_CASES[case]
@@ -166,8 +168,8 @@ def test_prefill_tile_pairs_counts_the_kernel_that_ran():
     tiles where the prefill ran on the compact stream, the ``[B, S]``
     kernel's where it did not."""
     lengths = _long_lyrics(16, 5)
-    capacity = compact_capacity(sum(lengths), 16 * 1024)
-    assert packed_tile(1024, capacity) == PACKED_TILE
+    capacity = compact_capacity(sum(lengths), 16, 1024)
+    assert stream_tile(1024, capacity) == PACKED_TILE
     for window in (0, 512):
         assert prefill_tile_pairs(lengths, 1024, window, capacity) == (
             packed_visited_pairs(lengths, capacity, 1024, PACKED_TILE,
@@ -177,12 +179,13 @@ def test_prefill_tile_pairs_counts_the_kernel_that_ran():
 
 
 def test_shapes_outside_the_regime_are_refused_not_served():
-    assert packed_tile(1024, 12288) == PACKED_TILE
-    assert packed_tile(1024, 12288, 4) == PACKED_TILE
-    # rows or slots that are not whole tiles; a block the tile cannot hold
-    assert [packed_tile(*shape) for shape in (
-        (1024 + 128, 12288), (1024, 12288 + 128), (1024, 12288, 3))
-            ] == [0, 0, 0]
+    assert stream_tile(1024, 12288) == PACKED_TILE
+    assert stream_tile(1024, 12288, 4) == PACKED_TILE
+    # rows or slots that are not whole tiles; rows of one tile; a block
+    # the tile cannot hold
+    assert [stream_tile(*shape) for shape in (
+        (1024 + 128, 12288), (1024, 12288 + 128), (256, 12288),
+        (1024, 12288, 3))] == [0, 0, 0, 0]
     q = jnp.zeros((64, 4 * DIM), jnp.bfloat16)
     kv = jnp.zeros((64, DIM), jnp.bfloat16)
     lens = jnp.asarray([32, 20], jnp.int32)
@@ -212,8 +215,7 @@ PACKED_PATHS = {
 @pytest.mark.parametrize("preset", list(PACKED_PATHS))
 def test_the_compact_prefill_records_the_packed_path(preset):
     """The compile record of a compact prefill names the packed paths, one
-    a grouped-query layer, and nothing of the ``[B, S]`` kernel's; the
-    same step without a capacity keeps the ``[B, S]`` paths."""
+    a grouped-query layer, and nothing of the ``[B, S]`` kernel's."""
     from music_analyst_tpu.engines.sentiment import get_backend
     from music_analyst_tpu.models import llama
 

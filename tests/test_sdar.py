@@ -444,9 +444,9 @@ def test_compact_prefill_is_bit_equal_on_the_real_positions(steps):
     args = (backend.params, jnp.asarray(ids), jnp.asarray(lens))
     full, full_stats = backend._prefill(*args, gen_blocks=4,
                                         prefill_capacity=None)
-    from music_analyst_tpu.models.moe import compact_capacity
+    from music_analyst_tpu.models.compact_stream import compact_capacity
 
-    capacity = compact_capacity(int(whole.sum()), rows * width)
+    capacity = compact_capacity(int(whole.sum()), rows, width)
     assert capacity < rows * width
     compact, stats = backend._prefill(*args, gen_blocks=4,
                                       prefill_capacity=capacity)
@@ -489,32 +489,35 @@ def clf32(clf):
 @pytest.mark.parametrize("lengths", list(_WIDE_STEPS.values()),
                          ids=list(_WIDE_STEPS))
 def test_compact_stream_equals_the_padded_prefill_on_every_whole_block(
-        clf32, lengths):
+        clf32, lengths, monkeypatch):
     """At a width and a rung ``llama.runs_compact`` admits, a prefill that
     declares a ``prefill_capacity`` keeps its hidden state on the compact
     token set through every block (projections, QK-norm, RoPE, norms,
     residual adds and experts on ``capacity`` slots; the packed kernel
     reads queries, keys and values on the stream and writes its result
     there, keys and values are put back at ``[B, S]`` for the cache).
-    Against the same call with the capacity withheld (every position
-    through every layer, the ``[B, S]`` kernel), both in float32
+    Against the padded prefill (``runs_compact`` answering no, the
+    capacity withheld: every position through every layer, the ``[B, S]``
+    kernel of the widths the packed one refuses), both in float32
     (``clf32``): the caches on ``[0, whole[row])`` (the first layer's to
     the bit, the others within one bfloat16 step of the stored value: the
     two kernels sum their tiles in other orders), zeros behind it, the
     chosen experts, and the tokens the block loop generates from either."""
-    from music_analyst_tpu.models import llama
-    from music_analyst_tpu.models.moe import compact_capacity
+    from music_analyst_tpu.models import compact_stream, llama
+    from music_analyst_tpu.models.compact_stream import compact_capacity
 
     clf = clf32
     ids, lens = _wide_step(clf, lengths)
     rows, width = ids.shape
     whole = lens // 4 * 4
     assert 0 in whole and width in whole
-    capacity = compact_capacity(int(whole.sum()), rows * width)
+    capacity = compact_capacity(int(whole.sum()), rows, width)
     assert capacity == {648: 768, 1024: 1024}[int(whole.sum())]
     assert llama.runs_compact(clf.config, ids.shape, capacity)
     args = (clf.params, jnp.asarray(ids), jnp.asarray(lens))
-    full, full_stats = clf._prefill(*args, gen_blocks=4)
+    with monkeypatch.context() as patched:
+        patched.setattr(compact_stream, "runs_compact", lambda *a: False)
+        full, full_stats = clf._prefill(*args, gen_blocks=4)
     compact, stats = clf._prefill(*args, gen_blocks=4,
                                   prefill_capacity=capacity)
     # both are the kernel's; the one that declared a capacity ran compact
@@ -585,16 +588,21 @@ def test_tokens_computed_counts_the_slots_the_prefill_ran(clf, words,
 
 
 # ``sha256(lowered.as_text())[:16]`` (a compile record's ``hlo_fingerprint``)
-# of the scoring program at 4 x 512 with lengths 300, 41, 256, 101, padded
-# and at 768 slots, as the tree before the block-diffusion prefill took the
-# stream lowered them (the two grouped-query ones: as PR 40's masked
-# attention in place lowers them; Granite's compact one as its attention
-# layer's packed flash kernel, PR 41, lowers it).  A PR that means to change
-# one of these programs replaces its pair here.
+# of the scoring program at 4 x 512 with lengths 300, 41, 256, 101, with no
+# capacity declared and at 768 slots.  The 768-slot programs are as the
+# tree before the block-diffusion prefill took the stream lowered them
+# (Granite's as its attention layer's packed flash kernel lowers it); the
+# grouped-query decoder's two are one program, as the masked attention
+# that reads each key/value head in place lowers it.  A prefill that declares its lengths and
+# no capacity runs the compact stream on rows x width slots
+# (``models/compact_stream.prefill_stream``): the first hash of the three
+# compact presets is their 2,048-slot program, which no longer holds the
+# padded latent kernel.  A PR that means to change one of these programs
+# replaces its pair here.
 _KEPT_PROGRAMS = {
-    "kanana-tiny": ("036fff5115d8d3d1", "0e77949cc3e2799c"),
-    "ling-tiny": ("87953e0cff93cbef", "5a49013a2523cef7"),
-    "granite-tiny": ("f4ab93187491a1c0", "35c7fa95bf5d088c"),
+    "kanana-tiny": ("f0d9b5b7793d6050", "0e77949cc3e2799c"),
+    "ling-tiny": ("e826f698ec00e369", "5a49013a2523cef7"),
+    "granite-tiny": ("9546c77954b07fea", "35c7fa95bf5d088c"),
     "llama3-tiny": ("665d91b43ea83077", "665d91b43ea83077"),
 }
 
@@ -618,7 +626,7 @@ def test_the_other_decoders_answer_and_lower_as_they_did(preset):
     assert llama.runs_compact(cfg, (4, 512), 768) == takes
     assert llama.runs_compact(cfg, (32, 1024), 12288) == takes
     assert not llama.runs_compact(cfg, (4, 512), None)       # a mesh
-    assert not llama.runs_compact(cfg, (4, 512), 4 * 512)    # full rows
+    assert llama.runs_compact(cfg, (4, 512), 4 * 512) == takes   # full rows
     assert not llama.runs_compact(cfg, (4, 64), 128)         # narrow
     extra = {}
     if cfg.recurrent_state:

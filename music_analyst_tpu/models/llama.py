@@ -86,7 +86,8 @@ class AttentionKind:
 
 
 # ``layer_types`` names of grouped-query layers whose widths are answered by
-# kind (``LlamaConfig.attention_kind``), beside "mamba" and "attention".
+# kind (``LlamaConfig.attention_kind``), beside "mamba", "mamba1" and
+# "attention".
 ATTENTION_KINDS = ("full_attention", "sliding_attention")
 
 
@@ -193,15 +194,20 @@ class LlamaConfig:
     experts_held: Optional[tuple] = None
     # The mixer a layer, as a published list (``"mamba"`` = a Mamba-2
     # state-space layer, models/mamba2.py: a recurrent state a row;
-    # ``"attention"`` = the ``attention`` kind; ``"full_attention"`` /
-    # ``"sliding_attention"`` = grouped-query layers whose heads, window
-    # and RoPE ``attention_kinds`` states by that name); ``None`` = by
-    # ``mixer_period``.  The four widths below are Mamba-2's.
+    # ``"mamba1"`` = a Mamba-1 selective-scan layer, models/mamba1.py: a
+    # state a channel; ``"attention"`` = the ``attention`` kind;
+    # ``"full_attention"`` / ``"sliding_attention"`` = grouped-query layers
+    # whose heads, window and RoPE ``attention_kinds`` states by that name);
+    # ``None`` = by ``mixer_period``.  Mamba-2's widths are the heads and
+    # their width; Mamba-1's the inner width and the step's rank; both have
+    # a state size and a convolution.
     layer_types: Optional[tuple] = None
     mamba_n_heads: int = 0
     mamba_head_dim: int = 0
     mamba_d_state: int = 0
     mamba_conv_kernel: int = 4
+    mamba_inner_dim: int = 0
+    mamba_dt_rank: int = 0
     # Grouped-query attention without rotary positions, and with a
     # published softmax scale (0 = ``head_dim ** -0.5``).
     use_rope: bool = True
@@ -252,7 +258,7 @@ class LlamaConfig:
                     self, name, tuple(int(n) for n in getattr(self, name)))
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
-            known = ("mamba", "attention") + ATTENTION_KINDS
+            known = ("mamba", "mamba1", "attention") + ATTENTION_KINDS
             if (len(self.layer_types) != self.n_layers
                     or set(self.layer_types) - set(known)):
                 raise ValueError(
@@ -268,6 +274,13 @@ class LlamaConfig:
                 raise ValueError(
                     "layer_types puts Mamba-2 layers between grouped-query "
                     "ones: the mamba widths must be set")
+            if "mamba1" in self.layer_types and min(
+                    self.mamba_inner_dim, self.mamba_dt_rank,
+                    self.mamba_d_state) < 1:
+                raise ValueError(
+                    "layer_types puts Mamba-1 layers between grouped-query "
+                    "ones: mamba_inner_dim, mamba_dt_rank and mamba_d_state "
+                    "must be set")
         if self.attention_kinds is not None:
             kinds = tuple((str(name), kind)
                           for name, kind in self.attention_kinds)
@@ -332,15 +345,15 @@ class LlamaConfig:
         return self.generation == "block_diffusion"
 
     def mixer(self, index: int) -> str:
-        """Layer ``index``'s mixer: ``"kda"``, ``"mamba"``, or the
-        ``attention`` kind.  The one place that answers, from the list
+        """Layer ``index``'s mixer: ``"kda"``, ``"mamba"``, ``"mamba1"``, or
+        the ``attention`` kind.  The one place that answers, from the list
         (``layer_types``) or the period (``mixer_period``)."""
         if self.layer_types is not None:
             # "attention" and the kinds of ``ATTENTION_KINDS`` are all the
             # ``attention`` kind's mixer; what differs between them is
             # :meth:`attention_kind`'s to say
-            return ("mamba" if self.layer_types[index] == "mamba"
-                    else self.attention)
+            kind = self.layer_types[index]
+            return kind if kind in ("mamba", "mamba1") else self.attention
         if self.layer_ids is not None:
             index = self.layer_ids[index]
         if self.mixer_period and (index + 1) % self.mixer_period:
@@ -383,10 +396,16 @@ class LlamaConfig:
         return self._layers_of("mamba")
 
     @property
+    def selective_layers(self) -> int:
+        """Mamba-1 layers."""
+        return self._layers_of("mamba1")
+
+    @property
     def recurrent_state(self) -> bool:
         """Whether some layer carries a recurrent state a row
-        (``models/kda.RecurrentState`` or ``models/mamba2.SSMState``)."""
-        return self.kda_layers + self.ssm_layers > 0
+        (``models/kda.RecurrentState``, ``models/mamba2.SSMState`` or
+        ``models/mamba1.SelectiveState``)."""
+        return self.kda_layers + self.ssm_layers + self.selective_layers > 0
 
     @property
     def mixed_layers(self) -> bool:
@@ -437,15 +456,17 @@ class LlamaConfig:
     @classmethod
     def from_hf_config(cls, hf: dict, **overrides) -> "LlamaConfig":
         """The decoder a published ``config.json`` of ``model_type:
-        deepseek_v3``, ``sdar_moe``, ``ling_hybrid``, ``granitemoehybrid``
-        or ``laguna`` describes, key by key.  What this code cannot run is
-        refused by name, not approximated."""
+        deepseek_v3``, ``sdar_moe``, ``ling_hybrid``, ``granitemoehybrid``,
+        ``laguna`` or ``jamba`` describes, key by key.  What this code cannot
+        run is refused by name, not approximated."""
         if hf.get("model_type") == "sdar_moe":
             return cls._from_sdar_moe(hf, overrides)
         if hf.get("model_type") == "laguna":
             return cls._from_laguna(hf, overrides)
         if hf.get("model_type") == "granitemoehybrid":
             return cls._from_granitemoehybrid(hf, overrides)
+        if hf.get("model_type") == "jamba":
+            return cls._from_jamba(hf, overrides)
         if hf.get("model_type") == "ling_hybrid":
             return cls._from_ling_hybrid(hf, overrides)
         unsupported = {
@@ -647,6 +668,57 @@ class LlamaConfig:
             n_experts=hf["num_local_experts"],
             moe_top_k=hf["num_experts_per_tok"],
             n_shared_experts=shared // width,
+        )
+        fields.update(overrides)
+        return cls(**fields)
+
+    @classmethod
+    def _from_jamba(cls, hf: dict, overrides: dict) -> "LlamaConfig":
+        """``model_type: jamba``: layer ``i`` is grouped-query attention
+        without positions where ``i % attn_layer_period ==
+        attn_layer_offset`` (``JambaConfig.layers_block_type``), else
+        Mamba-1 (inner width ``mamba_expand * hidden_size``, state
+        ``mamba_d_state``, step rank ``mamba_dt_rank``, ``"auto"`` =
+        ``ceil(hidden_size / 16)``); every layer's feed-forward half is
+        the dense SwiGLU of ``intermediate_size`` (``num_experts`` 1: the
+        expert layers of the routed variant are not this model's); the
+        head is the embedding's transpose where ``tie_word_embeddings``."""
+        rank = hf.get("mamba_dt_rank", "auto")
+        if rank == "auto":
+            rank = -(-hf["hidden_size"] // 16)
+        period = hf.get("attn_layer_period", 8)
+        offset = hf.get("attn_layer_offset", 4)
+        unsupported = {
+            "num_experts": hf.get("num_experts", 1) > 1,
+            "mamba_proj_bias": bool(hf.get("mamba_proj_bias", False)),
+            "mamba_conv_bias": not hf.get("mamba_conv_bias", True),
+            "hidden_act": hf.get("hidden_act", "silu") != "silu",
+            "sliding_window": hf.get("sliding_window") is not None,
+            "attn_layer_offset": not 0 <= offset < period,
+        }
+        bad = sorted(k for k, v in unsupported.items() if v)
+        if bad:
+            raise ValueError(
+                "this decoder does not implement the configuration's "
+                + ", ".join(f"{k}={hf.get(k)!r}" for k in bad)
+            )
+        layers = hf["num_hidden_layers"]
+        fields = dict(
+            vocab_size=hf["vocab_size"], dim=hf["hidden_size"],
+            n_layers=layers, n_heads=hf["num_attention_heads"],
+            n_kv_heads=hf["num_key_value_heads"],
+            hidden_dim=hf["intermediate_size"],
+            max_seq_len=hf["max_position_embeddings"],
+            rms_norm_eps=float(hf["rms_norm_eps"]),
+            layer_types=tuple(
+                "attention" if i % period == offset else "mamba1"
+                for i in range(layers)),
+            mamba_inner_dim=hf["mamba_expand"] * hf["hidden_size"],
+            mamba_dt_rank=int(rank),
+            mamba_d_state=hf["mamba_d_state"],
+            mamba_conv_kernel=hf["mamba_d_conv"],
+            use_rope=False,
+            tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
         )
         fields.update(overrides)
         return cls(**fields)
@@ -871,8 +943,9 @@ WINDOW_REFUSAL = (
 
 RECURRENT_STATE_REFUSAL = (
     "most of this model's layers carry a recurrent state a row "
-    "(models/kda.RecurrentState or models/mamba2.SSMState: a float32 matrix "
-    "a head and the convolutions' last inputs), not keys and values a "
+    "(models/kda.RecurrentState, models/mamba2.SSMState or "
+    "models/mamba1.SelectiveState: a float32 matrix a head or a channel and "
+    "the convolutions' last inputs), not keys and values a "
     "token; the {runtime} runtime has slots or pages of per-head keys and "
     "values only"
 )
@@ -897,7 +970,7 @@ class LlamaBlock(nn.Module):
             return self._latent_block(x, mask, positions, cache,
                                       prefill_lengths, prefill_capacity,
                                       segment_ids, packed, row_lengths)
-        if cfg.mixer(self.layer_index) == "mamba":
+        if cfg.mixer(self.layer_index) in ("mamba", "mamba1"):
             return self._state_space_block(
                 x, positions, cache, prefill_lengths, prefill_capacity,
                 segment_ids, packed, row_lengths)
@@ -978,7 +1051,8 @@ class LlamaBlock(nn.Module):
                 new_cache = None
         x = self._residual(x, attn_out)
         h = RMSNorm(epsilon=cfg.rms_norm_eps, name="ffn_norm")(x)
-        if cfg.n_experts > 0 and cfg.routed_experts:
+        if cfg.layer_types is not None or (
+                cfg.n_experts > 0 and cfg.routed_experts):
             return (self._residual(x, self._feed_forward(
                 h, prefill_lengths, prefill_capacity, packed)), new_cache)
         if cfg.n_experts > 0:
@@ -1012,21 +1086,30 @@ class LlamaBlock(nn.Module):
     def _state_space_block(self, x, positions, cache, prefill_lengths,
                            prefill_capacity, segment_ids, packed,
                            row_lengths):
-        """Pre-norm block whose mixer is Mamba-2 on a recurrent state in
-        the cache's place (``models/mamba2.py``), then
-        :meth:`_feed_forward`.  With ``packed`` the stream ``x [1, C, D]``
-        is the real positions' compact set from norm to residual."""
-        from music_analyst_tpu.models.mamba2 import Mamba2Mixer
-
+        """Pre-norm block whose mixer is Mamba-2 or Mamba-1 on a recurrent
+        state in the cache's place (``models/mamba2.py``,
+        ``models/mamba1.py``), then :meth:`_feed_forward`.  With ``packed``
+        the stream ``x [1, C, D]`` is the real positions' compact set from
+        norm to residual."""
         cfg = self.config
         if segment_ids is not None:
             raise ValueError("a state-space layer takes no segment_ids")
-        mixer = Mamba2Mixer(
-            n_heads=cfg.mamba_n_heads, head_dim=cfg.mamba_head_dim,
-            d_state=cfg.mamba_d_state, conv_kernel=cfg.mamba_conv_kernel,
-            norm_eps=cfg.rms_norm_eps, dtype=jnp.dtype(cfg.dtype),
-            param_dtype=jnp.dtype(cfg.param_dtype), name="attention",
-        )
+        kinds = dict(dtype=jnp.dtype(cfg.dtype),
+                     param_dtype=jnp.dtype(cfg.param_dtype), name="attention")
+        if cfg.mixer(self.layer_index) == "mamba1":
+            from music_analyst_tpu.models.mamba1 import Mamba1Mixer
+
+            mixer = Mamba1Mixer(
+                inner=cfg.mamba_inner_dim, d_state=cfg.mamba_d_state,
+                dt_rank=cfg.mamba_dt_rank, conv_kernel=cfg.mamba_conv_kernel,
+                norm_eps=cfg.rms_norm_eps, **kinds)
+        else:
+            from music_analyst_tpu.models.mamba2 import Mamba2Mixer
+
+            mixer = Mamba2Mixer(
+                n_heads=cfg.mamba_n_heads, head_dim=cfg.mamba_head_dim,
+                d_state=cfg.mamba_d_state, conv_kernel=cfg.mamba_conv_kernel,
+                norm_eps=cfg.rms_norm_eps, **kinds)
         h = RMSNorm(epsilon=cfg.rms_norm_eps, name="attention_norm")(x)
         mixed = mixer(h, positions, cache, prefill_lengths, row_lengths,
                       packed)
@@ -1269,9 +1352,10 @@ def init_caches(
     """One empty cache a layer, of the layer's mixer kind: a ``KVCache``
     (grouped-query attention), a ``LatentCache`` (latent attention) or,
     for a layer that carries a recurrent state, that state (a KDA layer's
-    ``RecurrentState``, a Mamba-2 layer's ``SSMState``), which does not grow
-    with ``max_len``.  The slot and paged decode runtimes hold the first
-    kind alone (``LlamaZeroShotClassifier.decode_runtime_refusal``)."""
+    ``RecurrentState``, a Mamba-2 layer's ``SSMState``, a Mamba-1 layer's
+    ``SelectiveState``), which does not grow with ``max_len``.  The slot
+    and paged decode runtimes hold the first kind alone
+    (``LlamaZeroShotClassifier.decode_runtime_refusal``)."""
 
     def empty(mixer: str):
         if mixer == "kda":
@@ -1285,6 +1369,12 @@ def init_caches(
             return SSMState.zeros(
                 batch, cfg.mamba_n_heads, cfg.mamba_head_dim,
                 cfg.mamba_d_state, cfg.mamba_conv_kernel, dtype)
+        if mixer == "mamba1":
+            from music_analyst_tpu.models.mamba1 import SelectiveState
+
+            return SelectiveState.zeros(
+                batch, cfg.mamba_inner_dim, cfg.mamba_d_state,
+                cfg.mamba_conv_kernel, dtype)
         if mixer == "mla":
             from music_analyst_tpu.models.mla import LatentCache
 
@@ -1624,11 +1714,15 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
         differ in kind, ``LlamaConfig.mixed_layers``): the rows whose
         caches after the prefill ride back
         too (``stats["probe"]``: every KDA or Mamba-2 layer's ``state
-        [layers, P, H, ., .]``, the Mamba-2 layers' ``conv`` tails, every
+        [layers, P, H, ., .]`` or Mamba-1 layer's ``[layers, P, I, N]``,
+        the Mamba layers' ``conv`` tails, every
         latent layer's ``latents`` and ``rope_keys`` ``[layers, P, S, .]``,
         every grouped-query layer's ``keys`` and ``values`` ``[layers, P,
-        S, Hkv, D]``), for whoever compares them with a reference; the
-        values choose rows, not a program.
+        S, Hkv, D]``, the logits at the prompt's last position ``[P,
+        V]``, and of a Mamba-1 model the first Mamba-1 layer's scan inputs
+        ``scan_x`` / ``scan_r`` / ``scan_b`` / ``scan_c [P, S, .]``), for
+        whoever compares them with a reference; the values choose rows,
+        not a program.
         """
         B, S = prompt_ids.shape
         n_labels, L = label_ids.shape
@@ -1663,6 +1757,8 @@ def score_labels_program(model: LlamaModel, config: LlamaConfig, mesh=None):
             stats = _routing_stats(sown, config)
             if probe_rows is not None:
                 stats["probe"] = _probe(config, caches, probe_rows, S)
+                stats["probe"]["logits"] = logits[probe_rows, 0]
+                stats["probe"].update(_scan_probe(sown, probe_rows))
             # Force every cache to report the true prompt length so
             # label positions line up even though the buffer was written
             # at 0..S.
@@ -1758,12 +1854,14 @@ def _probe(config: LlamaConfig, caches, rows, width: int) -> dict:
     by_kind = {}
     for i, cache in enumerate(caches):
         by_kind.setdefault(config.mixer(i), []).append(cache)
-    carried = by_kind.get("kda", []) + by_kind.get("mamba", [])
+    carried = [c for kind in ("kda", "mamba", "mamba1")
+               for c in by_kind.get(kind, [])]
+    tails = by_kind.get("mamba", []) + by_kind.get("mamba1", [])
     kept = {}
     if carried:
         kept["state"] = jnp.stack([c.state[rows] for c in carried])
-    if "mamba" in by_kind:
-        kept["conv"] = jnp.stack([c.conv[rows] for c in by_kind["mamba"]])
+    if tails:
+        kept["conv"] = jnp.stack([c.conv[rows] for c in tails])
     if "mla" in by_kind:
         kept["latents"] = jnp.stack(
             [c.latents[rows, :width] for c in by_kind["mla"]])
@@ -1775,6 +1873,23 @@ def _probe(config: LlamaConfig, caches, rows, width: int) -> dict:
         kept["values"] = jnp.stack(
             [c.values[rows, :width] for c in by_kind["gqa"]])
     return kept
+
+
+def _scan_probe(sown, rows) -> dict:
+    """What the first Mamba-1 layer's recurrence read for ``rows``
+    (``models/mamba1.Mamba1Mixer``'s ``scan_inputs``): ``scan_x``,
+    ``scan_r``, ``scan_b``, ``scan_c [P, S, .]``, zero past a row's
+    length; empty for a model without Mamba-1 layers."""
+    layers = sorted(
+        (int(key.rsplit("_", 1)[1]), layer["attention"]["scan_inputs"][0])
+        for key, layer in sown.get("intermediates", {}).items()
+        if "scan_inputs" in layer.get("attention", {}))
+    if not layers:
+        return {}
+    read = layers[0][1]
+    slot, real = read["slot"][rows], read["real"][rows]
+    return {f"scan_{name}": jnp.where(real[..., None], read[name][slot], 0)
+            for name in ("x", "r", "b", "c")}
 
 
 def decode_step_program(model: LlamaModel):
@@ -2307,7 +2422,7 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         attrs = dict(rows=rows, width=width, tokens_real=tokens_real,
                      token_pairs=token_pairs, label_positions=label_run,
                      label_positions_real=label_real)
-        if stats:
+        if "expert_load_max" in stats:
             slots = rows * width if capacity is None else capacity
             attrs.update(self._count_expert_load(stats, slots))
         cfg = self.config
@@ -2352,6 +2467,23 @@ class LlamaZeroShotClassifier(ClassifierBackend):
             tel.count("ssm.tokens", tokens_real * ssm)
             tel.count("ssm.state_steps", rows * label_run * ssm)
             attrs.update(ssm_layers=ssm, attention_layers=cfg.n_layers - ssm,
+                         state_bytes=state_bytes)
+        if cfg.selective_layers:
+            sel = cfg.selective_layers
+            inner = cfg.mamba_inner_dim
+            # a float32 entry a channel and state, and the convolution's
+            # last inputs
+            state_bytes = rows * sel * inner * (
+                4 * cfg.mamba_d_state + 2 * (cfg.mamba_conv_kernel - 1))
+            tel.gauge("recurrent_state_bytes", state_bytes)
+            tel.gauge("kv_cache_bytes", int(
+                rows * (width + MAX_LABEL_TOKENS) * (cfg.n_layers - sel)
+                * 2 * 2 * cfg.n_kv_heads * cfg.attn_head_dim))
+            tel.count("mamba1.tokens", tokens_real * sel)
+            tel.count("mamba1.state_steps", rows * label_run * sel)
+            attrs.update(mamba1_layers=sel,
+                         attention_layers=cfg.n_layers - sel,
+                         slots=stream_slots(cfg, (rows, width), capacity),
                          state_bytes=state_bytes)
         tel.current_span().set(**attrs)
 

@@ -25,7 +25,9 @@ flash attention with a sliding window at the window cell's prefill (32 x
 1,024, 72 and 48 query heads on 8 key heads of 128, window 512); its
 packed form on the compact token stream at the window and diffusion cells'
 steps, and the window cell's whole scoring step, whose five grouped-query
-layers feed it with no copy around the call.
+layers feed it with no copy around the call; the Mamba-1 selective scan at
+the Mamba-1 cell's step (64 x 1,024, 5,120 channels x 16 states) within the
+default scoped VMEM, and one Mamba-1 layer that feeds it with no copy.
 One compile holds no kernel of ours: the grouped experts of a routed layer
 (``models/moe.grouped_experts``, XLA's own grouped matmul) at the decoder
 cells' compact token set, with 6 and with 8 choices a token, for what XLA
@@ -837,3 +839,88 @@ def test_unservable_geometry_is_refused_by_name():
     check_stream_geometry(3, 128)   # odd KV heads are fine at full lanes
     with pytest.raises(ValueError, match="power-of-two"):
         check_stream_geometry(3, 64)
+
+
+@pytest.mark.parametrize("slots,rows,width,inner,state,rank", [
+    (24576, 64, 1024, 5120, 16, 160),  # the Mamba-1 cell's rung, compact
+    (768, 4, 512, 128, 16, 8),         # jamba-tiny's compact 512-wide step
+], ids=["jamba-compact", "jamba-tiny"])
+def test_selective_scan_compiles_under_mosaic_in_the_default_vmem(
+        tpu_sharding, slots, rows, width, inner, state, rank):
+    """The Mamba-1 prefill kernel (``ops/selective_scan.py``) at the Mamba-1
+    cell's step (64 rows of up to 1,024 slots, 5,120 channels x 16 states,
+    rank 160) and at the test size, asking for no scoped VMEM beyond the
+    compiler's default: nothing is held back from the rest of the
+    program."""
+    from music_analyst_tpu.ops.selective_scan import selective_scan_chunked
+
+    def fn(x, low, w_dt, b_dt, a_log, b, c, skip, starts, ends, valid):
+        return selective_scan_chunked(x, low, w_dt, b_dt, a_log, b, c, skip,
+                                      starts, ends, valid, width,
+                                      interpret=False)
+
+    compiled = _compile_for_tpu(
+        fn, tpu_sharding, ((slots, inner), jnp.bfloat16),
+        ((slots, rank), jnp.bfloat16), ((rank, inner), jnp.bfloat16),
+        ((inner,), jnp.float32), ((inner, state), jnp.float32),
+        ((slots, state), jnp.bfloat16), ((slots, state), jnp.bfloat16),
+        ((inner,), jnp.float32), ((rows,), jnp.int32), ((rows,), jnp.int32),
+        ((slots,), jnp.bool_))
+    text = compiled.as_text()
+    assert re.search(r"%_selective_scan_call[.\d]* = ", text)
+    # (the Mamba-2 kernel's 64 MiB reads "size":"67108864" here)
+    sizes = [int(s) for s in re.findall(r'"size":"(\d+)"', text)]
+    assert sizes and max(sizes) <= 16 * 2 ** 20, sizes
+
+
+def test_mamba1_layer_feeds_the_selective_scan_kernel_without_a_copy(
+        tpu_sharding, monkeypatch):
+    """One Mamba-1 mixer of ``ai21-jamba2-3b`` on the cell's compact stream
+    (24,576 slots of 64 rows, 1,024 wide, inner 5,120), compiled for a v5e:
+    one ``_selective_scan`` call, and no copy or transpose of a stream-sized
+    array anywhere in the layer: nothing is laid out anew for the kernel,
+    before it or behind it."""
+    from music_analyst_tpu.models import llama
+    from music_analyst_tpu.models.mamba1 import Mamba1Mixer, SelectiveState
+    from music_analyst_tpu.models.compact_stream import RealPositions
+    from music_analyst_tpu.ops import flash_attention
+
+    monkeypatch.setattr(flash_attention, "interpret_default", lambda: False)
+    cfg = llama.PRESETS["ai21-jamba2-3b"]()
+    rows, width, capacity = 64, 1024, 24576
+    mixer = Mamba1Mixer(
+        inner=cfg.mamba_inner_dim, d_state=cfg.mamba_d_state,
+        dt_rank=cfg.mamba_dt_rank, conv_kernel=cfg.mamba_conv_kernel,
+        norm_eps=cfg.rms_norm_eps, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=tpu_sharding), tree)
+
+    params = placed(jax.eval_shape(
+        mixer.init, jax.random.key(0), jnp.zeros((1, 8, cfg.dim),
+                                                 jnp.bfloat16)))
+
+    def fn(params, x, lens):
+        packed = RealPositions.of(lens, width, capacity)
+        positions = packed.gather(
+            jnp.broadcast_to(jnp.arange(width), (rows, width)))[None]
+        state = SelectiveState.zeros(rows, cfg.mamba_inner_dim,
+                                     cfg.mamba_d_state)
+        return mixer.apply(params, x, positions, state, lens, None, packed)
+
+    lowered = jax.jit(fn).trace(
+        params, placed(jnp.zeros((1, capacity, cfg.dim), jnp.bfloat16)),
+        placed(jnp.zeros((rows,), jnp.int32))).lower(
+            lowering_platforms=("tpu",))
+    text = lowered.compile().as_text()
+    calls = re.findall(
+        r"%_selective_scan_call[.\d]* = \([^=]*\) custom-call\(([^)]*)\)",
+        text)
+    assert len(calls) == 1
+    moved = re.findall(
+        rf"= \w+\[(?:1,)?{capacity},(?:2560|5120|10240)\]\S* "
+        r"(?:copy|transpose)\(", text[text.index("ENTRY"):])
+    assert not moved, moved

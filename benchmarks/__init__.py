@@ -29,6 +29,7 @@ _SUITE_MODULES = (
     "benchmarks.kda_prefill",
     "benchmarks.ssd_prefill",
     "benchmarks.window_prefill",
+    "benchmarks.grouped_matmul",
     "benchmarks.generation",
     "benchmarks.coldstart",
     "benchmarks.ingest",

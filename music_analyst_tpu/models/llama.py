@@ -1153,8 +1153,14 @@ class LlamaBlock(nn.Module):
                 experts_held=cfg.experts_held,
                 name="feed_forward_moe",
             )
+            # a prefill that declared its rows' lengths runs on one device
+            # (a mesh withholds them): there its grouped matmuls may take
+            # the kernel
+            one_device = prefill_lengths is not None or packed is not None
             if compact is not None:  # it puts the experts it chose back too
-                return ffn(h, compact, packed=packed is not None)
+                return ffn(h, compact, packed=packed is not None,
+                           one_device=one_device)
+            return ffn(h, one_device=one_device)
         else:
             ffn = SwiGLU(cfg.hidden_dim, dtype=dtype,
                          param_dtype=param_dtype, name="feed_forward")
@@ -2492,10 +2498,17 @@ class LlamaZeroShotClassifier(ClassifierBackend):
         """The ``moe.*`` counters of one step whose prefill ran its expert
         layers on ``slots`` token slots (``stats``: its routed layers'
         load), ``pass_assignments`` more having run uncompacted behind it;
-        returns what goes on the ``compute`` span."""
+        returns what goes on the ``compute`` span.  ``moe.rows_kernel``
+        counts the prefill's rows that went through the grouped matmul
+        kernel (``models/moe.grouped_kernel_tiles``: one device, a shape in
+        its regime), beside ``moe.rows_computed``."""
+        from music_analyst_tpu.models.moe import (
+            grouped_call_tokens,
+            grouped_kernel_tiles,
+        )
         from music_analyst_tpu.telemetry import get_telemetry
 
-        tel = get_telemetry()
+        tel, cfg = get_telemetry(), self.config
         load_max = np.asarray(stats["expert_load_max"], np.float64)
         load_mean = np.asarray(stats["expert_load_mean"], np.float64)
         # the load is the held experts' (all of them, but for a chip that
@@ -2515,9 +2528,13 @@ class LlamaZeroShotClassifier(ClassifierBackend):
             attrs.update(assignments=assignments, assignments_held=held,
                          label_assignments_held=held_labels)
         tel.count("moe.assignments", assignments + pass_assignments)
-        tel.count("moe.rows_computed",
-                  slots * self.config.moe_top_k * len(load_max)
-                  + pass_assignments)
+        prefill_rows = slots * cfg.moe_top_k * len(load_max)
+        tel.count("moe.rows_computed", prefill_rows + pass_assignments)
+        kernel = not _partitioned(self.mesh) and grouped_kernel_tiles(
+            grouped_call_tokens(slots, cfg.experts_held is not None),
+            cfg.moe_top_k, cfg.experts_held_count, cfg.dim,
+            cfg.moe_hidden_dim)
+        tel.count("moe.rows_kernel", prefill_rows if kernel else 0)
         tel.count("moe.expert_load_max", int(load_max.sum()))
         tel.count("moe.expert_load_mean", int(load_mean.sum()))
         return attrs

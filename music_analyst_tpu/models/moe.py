@@ -258,22 +258,57 @@ ROUTERS = ("sigmoid_noaux", "softmax_topk")
 HELD_CHUNK = 8192
 
 
+def grouped_call_tokens(tokens: int, share: bool) -> int:
+    """Tokens one grouped call of a routed layer runs over ``tokens`` token
+    slots: all of them, or where the layer holds a ``share`` of its experts
+    and they are more than :data:`HELD_CHUNK` (and whole stretches of it),
+    a stretch at a time."""
+    if share and tokens > HELD_CHUNK and tokens % HELD_CHUNK == 0:
+        return HELD_CHUNK
+    return tokens
+
+
+def grouped_kernel_tiles(tokens: int, top_k: int, held: int, dim: int,
+                         hidden: int):
+    """The grouped matmul kernel's tiles ``(swiglu, down)``
+    (``ops/grouped_matmul.kernel_tiles``) for one grouped call over
+    ``tokens`` tokens of ``top_k`` choices among ``held`` expert stacks of
+    ``dim x hidden``, or ``None`` where the call stays on
+    ``jax.lax.ragged_dot``: both projections take the kernel or neither.
+    The static shape alone decides (a prefill's calls have hundreds of
+    rows an expert, a label pass's, a diffusion block's and a decode
+    step's a few); whoever counts the rows the kernel took asks it too."""
+    from music_analyst_tpu.ops.grouped_matmul import kernel_tiles
+
+    rows = tokens * top_k
+    swiglu = kernel_tiles(rows, held, dim, hidden, 2)
+    down = kernel_tiles(rows, held, hidden, dim, 1)
+    return (swiglu, down) if swiglu and down else None
+
+
 def _grouped_experts(xt, chosen, weights, gate_w, up_w, down_w,
-                     partial: bool):
+                     partial: bool, one_device: bool):
     """``sum_k weights[t, k] * expert_{chosen[t, k]}(xt[t])`` for every
-    token, no capacity: assignments sorted by expert, one
-    ``jax.lax.ragged_dot`` a projection over the ragged groups, and the
-    results back in token order one choice at a time: ``k`` gathers of
-    the down projection's bfloat16 rows, each row converted to float32,
-    multiplied by its float32 weight and added in float32 in ONE fusion
-    behind the gathers (scopes ``moe.dispatch``, ``moe.matmul``,
-    ``moe.combine``, inside the caller's ``moe.experts``).  No ``[T, k, D]``
-    float32 array exists for any ``k``: it would be the step's largest by
-    far (2 GB at 24,576 slots x 8 x 2,560), and where ``k`` is no multiple
-    of the float32 tile's 8 rows the TPU pads it and lays it out anew
-    besides (6 to 8: 0.8 GB written and read a layer at 12,288 x 6 x
-    2,048).  ``xt [T, D]``, ``chosen``/``weights [T, k]``, expert stacks
-    ``[E, D, H]`` / ``[E, H, D]``.  Returns ``[T, D]`` float32.
+    token, no capacity: assignments sorted by expert, the grouped matmuls
+    over the ragged groups, and the results back in token order one choice
+    at a time: ``k`` gathers of the down projection's bfloat16 rows, each
+    row converted to float32, multiplied by its float32 weight and added
+    in float32 in ONE fusion behind the gathers (scopes ``moe.dispatch``,
+    ``moe.matmul``, ``moe.combine``, inside the caller's ``moe.experts``).
+    No ``[T, k, D]`` float32 array exists for any ``k``: it would be the
+    step's largest by far (2 GB at 24,576 slots x 8 x 2,560), and where
+    ``k`` is no multiple of the float32 tile's 8 rows the TPU pads it and
+    lays it out anew besides (6 to 8: 0.8 GB written and read a layer at
+    12,288 x 6 x 2,048).  ``xt [T, D]``, ``chosen``/``weights [T, k]``,
+    expert stacks ``[E, D, H]`` / ``[E, H, D]``.  Returns ``[T, D]``
+    float32.
+
+    The grouped matmuls: on ``one_device`` at a shape
+    :func:`grouped_kernel_tiles` admits, the Pallas kernel
+    (``ops/grouped_matmul.py``: gate and up and the SwiGLU in one call, the
+    down projection in another); otherwise one ``jax.lax.ragged_dot`` a
+    projection (under a mesh the kernel's call would be opaque to the
+    partitioner).
 
     A token whose ``chosen`` is the number of experts (one past the last)
     is a filler of a compact token set (:class:`RealPositions`): its
@@ -283,22 +318,34 @@ def _grouped_experts(xt, chosen, weights, gate_w, up_w, down_w,
     that one-past-the-last expert too (an expert another chip holds); they
     go where a filler's go and the sum masks them (``chosen < E``): each
     counts as exactly zero in its token's sum, whatever its row holds."""
+    from music_analyst_tpu.ops.grouped_matmul import (
+        grouped_matmul,
+        grouped_swiglu,
+    )
+
     T, D = xt.shape
+    E, _, H = gate_w.shape
     k = chosen.shape[-1]
+    tiles = one_device and grouped_kernel_tiles(T, k, E, D, H)
     with jax.named_scope("moe.dispatch"):
         flat_expert = chosen.reshape(T * k)
         order = jnp.argsort(flat_expert, stable=True)
-        group_sizes = jnp.zeros((gate_w.shape[0],), jnp.int32).at[
+        group_sizes = jnp.zeros((E,), jnp.int32).at[
             flat_expert].add(1, mode="drop")
         xs = xt[order // k]                                       # [A, D]
     with jax.named_scope("moe.matmul"):
-        gate = jax.lax.ragged_dot(xs, gate_w, group_sizes)
-        up = jax.lax.ragged_dot(xs, up_w, group_sizes)
-        ys = jax.lax.ragged_dot(nn.silu(gate) * up, down_w, group_sizes)
+        if tiles:
+            h = grouped_swiglu(xs, gate_w, up_w, group_sizes, tiles[0])
+            ys = grouped_matmul(h, down_w, group_sizes, tiles[1])
+        else:
+            gate = jax.lax.ragged_dot(xs, gate_w, group_sizes)
+            up = jax.lax.ragged_dot(xs, up_w, group_sizes)
+            ys = jax.lax.ragged_dot(nn.silu(gate) * up, down_w,
+                                    group_sizes)
     with jax.named_scope("moe.combine"):
         back = jnp.argsort(order).reshape(T, k)
         if partial:
-            here = chosen < gate_w.shape[0]
+            here = chosen < E
 
         def weighted(j):
             return (ys[back[:, j]].astype(jnp.float32)
@@ -313,7 +360,7 @@ def _grouped_experts(xt, chosen, weights, gate_w, up_w, down_w,
         return out
 
 
-def _batched_over_tokens(partial: bool):
+def _batched_over_tokens(partial: bool, one_device: bool):
     """:func:`_grouped_experts` with its batching rule: tokens are
     independent, so a batch of token sets is one larger set (``ragged_dot``
     has no batching rule over its token axis): the label continuations of
@@ -322,7 +369,7 @@ def _batched_over_tokens(partial: bool):
     @jax.custom_batching.custom_vmap
     def grouped_experts(xt, chosen, weights, gate_w, up_w, down_w):
         return _grouped_experts(xt, chosen, weights, gate_w, up_w, down_w,
-                                partial)
+                                partial, one_device)
 
     grouped = grouped_experts
 
@@ -347,11 +394,16 @@ def _batched_over_tokens(partial: bool):
     return grouped
 
 
-grouped_experts = _batched_over_tokens(partial=False)
+grouped_experts = _batched_over_tokens(partial=False, one_device=False)
 # The same over the experts THIS chip holds of a layer that has more
 # (``RoutedMoE(experts_held=...)``): ``chosen`` in local ids, an absent
 # expert's as one past the last.
-grouped_experts_held = _batched_over_tokens(partial=True)
+grouped_experts_held = _batched_over_tokens(partial=True, one_device=False)
+# Both on one device, where the grouped matmul kernel may run.
+grouped_experts_one_device = _batched_over_tokens(partial=False,
+                                                  one_device=True)
+grouped_experts_held_one_device = _batched_over_tokens(partial=True,
+                                                       one_device=True)
 
 
 class RoutedMoE(nn.Module):
@@ -367,11 +419,18 @@ class RoutedMoE(nn.Module):
     three projections is one grouped matrix multiplication over the ragged
     groups (``jax.lax.ragged_dot``: on the TPU XLA lowers it to its grouped
     matmul kernel, the work is ``top_k`` experts a token however uneven the
-    routing).  The results go back to token order one choice at a time
-    (``top_k`` gathers of bfloat16 rows) and are combined with the router's
-    weights in one fused float32 multiply-add: no ``[T, top_k, D]`` array
-    lies between (:func:`grouped_experts`).  ``n_shared`` shared experts
-    are one SwiGLU of width ``n_shared * hidden_dim``.
+    routing).  On ``one_device`` (a prefill that declared its rows'
+    lengths: a mesh withholds them, and the kernel's call would be opaque
+    to the partitioner) at a shape in its regime
+    (:func:`grouped_kernel_tiles`: a prefill's hundreds of rows an expert)
+    they run on the Pallas kernel of ``ops/grouped_matmul.py`` instead,
+    gate, up and the SwiGLU in one call (traced path
+    ``moe.grouped_kernel``).  The results go back to token order one
+    choice at a time (``top_k`` gathers of bfloat16 rows) and are combined
+    with the router's weights in one fused float32 multiply-add: no ``[T,
+    top_k, D]`` array lies between (:func:`grouped_experts`).
+    ``n_shared`` shared experts are one SwiGLU of width ``n_shared *
+    hidden_dim``.
 
     Given ``compact`` (a prefill that declared its rows' lengths:
     ``models/llama.LlamaBlock._feed_forward``), router, sort, grouped
@@ -421,7 +480,7 @@ class RoutedMoE(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array,
                  compact: Optional[RealPositions] = None,
-                 packed: bool = False) -> jax.Array:
+                 packed: bool = False, one_device: bool = False) -> jax.Array:
         from music_analyst_tpu.models.layers import SwiGLU, fan_in_normal
         from music_analyst_tpu.profiling.compile import note_traced_path
 
@@ -501,22 +560,31 @@ class RoutedMoE(nn.Module):
             stacks = (gate_w.astype(self.dtype), up_w.astype(self.dtype),
                       down_w.astype(self.dtype))
             n_tok = xt.shape[0]
-            if self.experts_held is None:
-                out = grouped_experts(xt, chosen, weights, *stacks)
-            elif n_tok > HELD_CHUNK and n_tok % HELD_CHUNK == 0:
+            share = self.experts_held is not None
+            call_tokens = grouped_call_tokens(n_tok, share)
+            if one_device and grouped_kernel_tiles(call_tokens, k, held, D,
+                                                   H):
+                note_traced_path("moe.grouped_kernel")
+            grouped = {
+                (False, False): grouped_experts,
+                (True, False): grouped_experts_held,
+                (False, True): grouped_experts_one_device,
+                (True, True): grouped_experts_held_one_device,
+            }[share, one_device]
+            if call_tokens < n_tok:
                 # Every assignment is sorted and gathered, held or not
                 # (how many are held is the router's to say, and no token
                 # is dropped), so the sorted copies are sized by all of
                 # them: a stretch of the tokens at a time.
                 def stretch(a):
-                    return a.reshape((-1, HELD_CHUNK) + a.shape[1:])
+                    return a.reshape((-1, call_tokens) + a.shape[1:])
 
                 out = jax.lax.map(
-                    lambda part: grouped_experts_held(*part, *stacks),
+                    lambda part: grouped(*part, *stacks),
                     (stretch(xt), stretch(chosen), stretch(weights)),
                 ).reshape(n_tok, D)
             else:
-                out = grouped_experts_held(xt, chosen, weights, *stacks)
+                out = grouped(xt, chosen, weights, *stacks)
             if packed:
                 # The fillers stay in the caller's stream, and their rows
                 # of the grouped matmuls belong to no group: undefined.

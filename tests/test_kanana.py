@@ -901,13 +901,17 @@ def test_staged_hooks_equal_classify_batch_and_count_the_step(clf):
     # two steps of one shape: the prefill's feed-forward halves ran on the
     # rung that holds the real tokens, every REAL position's assignments
     # are counted (top_k a routed layer) and the rows the grouped matmuls
-    # ran are the rung's, fillers included
+    # ran are the rung's, fillers included, none of them on the grouped
+    # matmul kernel (widths under a lane tile:
+    # models/moe.grouped_kernel_tiles)
     assert int(lens.sum()) <= capacity < rows * width
     assert span.attrs["moe_capacity"] == capacity
     grew = {name: tel.counters[name] - before.get(name, 0)
-            for name in ("moe.assignments", "moe.rows_computed")}
+            for name in ("moe.assignments", "moe.rows_computed",
+                         "moe.rows_kernel")}
     assert grew == {"moe.assignments": 2 * 2 * int(lens.sum()) * 2,
-                    "moe.rows_computed": 2 * 2 * capacity * 2}
+                    "moe.rows_computed": 2 * 2 * capacity * 2,
+                    "moe.rows_kernel": 0}
 
 
 # ------------------------------------------- the label continuations' width

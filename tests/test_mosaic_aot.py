@@ -28,7 +28,12 @@ steps, and the window cell's whole scoring step, whose five grouped-query
 layers feed it with no copy around the call; the Mamba-1 selective scan at
 the Mamba-1 cell's step (64 x 1,024, 5,120 channels x 16 states) within the
 default scoped VMEM, and one Mamba-1 layer that feeds it with no copy.
-One compile holds no kernel of ours: the grouped experts of a routed layer
+The grouped matmul kernel of the routed experts' prefill
+(``ops/grouped_matmul.py``) at each expert configuration's prefill call, in
+the default scoped VMEM, and in the decoder, diffusion, hybrid and window
+cells' programs in place of XLA's ``ragged-dot-none``, which the label
+passes and the diffusion block loop keep.  One compile holds no kernel of
+ours: the grouped experts of a routed layer in the form a mesh takes
 (``models/moe.grouped_experts``, XLA's own grouped matmul) at the decoder
 cells' compact token set, with 6 and with 8 choices a token, for what XLA
 puts between the kernels' result and the weighted sum.
@@ -300,12 +305,13 @@ def _largest_float32(program: str) -> int:
 def test_grouped_results_return_to_token_order_with_no_float32_copy(
     tpu_sharding, top_k
 ):
-    """``models/moe.grouped_experts`` at a routed layer of the decoder
-    cells' prefill (12,288 token slots, 128 experts of 2,048 x 768; 6
-    choices a token as ``kanana-2-30b-a3b``, 8 as ``sdar-30b-a3b-chat``)
-    compiled for a v5e.  The down projection's rows come back one choice
-    at a time, ``top_k`` bfloat16 gathers of ``[T, D]``, and one fusion
-    sums them in float32: no float32 array of ``T x top_k x D`` elements
+    """``models/moe.grouped_experts`` (the form a mesh takes: XLA's grouped
+    matmul) at a routed layer of the decoder cells' prefill (12,288 token
+    slots, 128 experts of 2,048 x 768; 6 choices a token as
+    ``kanana-2-30b-a3b``, 8 as ``sdar-30b-a3b-chat``) compiled for a
+    v5e.  The down projection's rows come back one choice at a time,
+    ``top_k`` bfloat16 gathers of ``[T, D]``, and one fusion sums them in
+    float32: no float32 array of ``T x top_k x D`` elements
     exists (at 6 choices the TPU padded it to 8 and laid it out anew,
     0.8 GB a layer), and the temporaries are the sorted rows in bfloat16
     twice over (the kernels' result and its gathered copy), 0.55 GB at 6
@@ -331,6 +337,58 @@ def test_grouped_results_return_to_token_order_with_no_float32_copy(
     sorted_rows = tokens * top_k * dim * 2      # bfloat16
     assert compiled.memory_analysis().temp_size_in_bytes < (
         2 * sorted_rows + 64 * 2 ** 20)
+
+
+@pytest.mark.parametrize("tokens,top_k,held,dim,hidden", [
+    (12288, 6, 128, 2048, 768),
+    (12288, 8, 128, 2048, 768),
+    (8192, 8, 128, 2560, 768),
+    (12288, 10, 36, 4096, 768),
+    (12288, 10, 128, 3072, 1024),
+], ids=["kanana-2-30b-a3b", "sdar-30b-a3b-chat", "ling-3.0-flash-vl",
+        "granite-4.0-h-small", "laguna-s-2.1"])
+def test_grouped_matmul_compiles_under_mosaic_at_each_prefill(
+        tpu_sharding, tokens, top_k, held, dim, hidden):
+    """The grouped matmul kernel (``ops/grouped_matmul.py``) at each expert
+    configuration's prefill call (the decoder and diffusion cells' 12,288
+    slots, the hybrid cell's stretch of 8,192, the state-space and window
+    cells' 12,288 of 10 choices over the experts a chip holds) at the tiles
+    ``models/moe.grouped_kernel_tiles`` gives it, inside the compiler's
+    default scoped VMEM: no instruction asks for more."""
+    from music_analyst_tpu.models.moe import grouped_kernel_tiles
+    from music_analyst_tpu.ops.grouped_matmul import (
+        grouped_matmul,
+        grouped_swiglu,
+    )
+
+    rows = tokens * top_k
+    swiglu_tiles, down_tiles = grouped_kernel_tiles(tokens, top_k, held,
+                                                    dim, hidden)
+
+    def layer(x, gate, up, down, sizes):
+        h = grouped_swiglu(x, gate, up, sizes, swiglu_tiles,
+                           interpret=False)
+        return grouped_matmul(h, down, sizes, down_tiles, interpret=False)
+
+    text = _compile_for_tpu(
+        layer, tpu_sharding, ((rows, dim), jnp.bfloat16),
+        ((held, dim, hidden), jnp.bfloat16),
+        ((held, dim, hidden), jnp.bfloat16),
+        ((held, hidden, dim), jnp.bfloat16), ((held,), jnp.int32),
+    ).as_text()
+    assert _grouped_kernel_calls(text) == {
+        f"bf16[{rows},{hidden}]": 1, f"bf16[{rows},{dim}]": 1}
+    assert '"scoped_memory_configs":[{' not in text
+
+
+def _grouped_kernel_calls(text):
+    """``{result shape: calls}`` of the grouped matmul kernel
+    (``ops/grouped_matmul.py``) in a compiled program's text."""
+    from collections import Counter
+
+    return dict(Counter(re.findall(
+        r"%_grouped_call[.\d]* = (\w+\[\d+,\d+\])\S* custom-call\(",
+        text)))
 
 
 def _traced(fn):
@@ -390,9 +448,11 @@ def test_compact_scoring_step_keeps_the_kernel_fed_and_no_padded_position(
     traced = []
     paths = _traced(lambda: traced.append(trace(prefill_capacity=capacity)))
     assert (paths["mla.compact"], paths["moe.compact"]) == (7, 6)
+    assert paths["moe.grouped_kernel"] == 6
     if capacity == 12288:
         full = _traced(trace)       # lengths, no capacity: rows x width
         assert (full["mla.compact"], full["moe.compact"]) == (7, 6)
+        assert full["moe.grouped_kernel"] == 6
     compiled = traced[0].lower(lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
 
@@ -407,12 +467,16 @@ def test_compact_scoring_step_keeps_the_kernel_fed_and_no_padded_position(
     assert max(int(a or b) for a, b in by_position) == 512
     shapes = set(re.findall(
         r"%ragged-dot-none[.\d]* = (\w+\[\d+,\d+\])", text))
-    # the prefill's grouped matmuls at capacity * top_k rows, the label
-    # continuations' at theirs: 3 x 32 rows x the ONE position whose
-    # forward is read (the label word's; not a table padded to 8)
+    # XLA's grouped matmuls are the label continuations' alone: 3 x 32 rows
+    # x the ONE position whose forward is read (the label word's; not a
+    # table padded to 8)
     assert shapes == {
-        f"bf16[{capacity * top_k},{n}]" for n in (768, 2048)} | {
         f"bf16[{3 * rows * top_k},{n}]" for n in (768, 2048)}, shapes
+    # the prefill's run on the grouped matmul kernel, at capacity * top_k
+    # rows: gate, up and the SwiGLU in one call, the down projection in
+    # another, in each of the six routed layers
+    assert _grouped_kernel_calls(text) == {
+        f"bf16[{capacity * top_k},{n}]": 6 for n in (768, 2048)}
     # the experts' results return to token order with no float32 copy of
     # the ``capacity x top_k`` sorted rows, in a fusion or out of one
     assert _largest_float32(text) < capacity * top_k * config.dim
@@ -626,9 +690,13 @@ def test_diffusion_step_compiles_for_the_chip_at_the_cells_shapes(
         params, ids, lens, gen_blocks=blocks, prefill_capacity=capacity,
     ).lower(lowering_platforms=("tpu",)).compile()
     text = prefill.as_text()
-    assert set(re.findall(
-        r"%ragged-dot-none[.\d]* = (\w+\[\d+,\d+\])", text)) == {
-        f"bf16[{capacity * top_k},{w}]" for w in (768, 2048)}
+    # the prefill's grouped matmuls run on the kernel, XLA's none (in six
+    # layers: the prefill has no head, so the last layer's feed-forward
+    # feeds nothing and is not run)
+    assert "ragged-dot-none" not in text
+    assert _grouped_kernel_calls(text) == {
+        f"bf16[{capacity * top_k},{w}]": config.n_layers - 1
+        for w in (768, 2048)}
     # one packed flash kernel a layer on the stream, q, k and v handed over
     # as bitcasts of their own fusions (the feature-major blocks it reads
     # are how XLA lays them out) and its result read in place
@@ -648,9 +716,11 @@ def test_diffusion_step_compiles_for_the_chip_at_the_cells_shapes(
         params, caches, ids, lens, gen_blocks=blocks,
     ).lower(lowering_platforms=("tpu",)).compile()
     text = denoise.as_text()
+    # the block loop's passes keep XLA's grouped matmul
     assert set(re.findall(
         r"%ragged-dot-none[.\d]* = (\w+\[\d+,\d+\])", text)) == {
         f"bf16[{rows * n * top_k},{w}]" for w in (768, 2048)}
+    assert not _grouped_kernel_calls(text)
     memory = denoise.memory_analysis()
     cache_bytes = (2 * config.n_layers * rows * (width + blocks * n)
                    * config.n_kv_heads * config.attn_head_dim * 2)
@@ -736,10 +806,14 @@ def test_hybrid_scoring_step_compiles_for_the_chip_at_the_cells_shape(
         r"%_packed_prefill_call[.\d]* = \S+ custom-call\(", text)) == 1
     shapes = set(re.findall(
         r"%ragged-dot-none[.\d]* = (\w+\[\d+,\d+\])", text))
-    # (a label's continuation at a time: 64 rows x the one position whose
-    # forward is read x 8 choices)
-    assert shapes == {f"bf16[{8192 * 8},{n}]" for n in (768, 2560)} | {
-        f"bf16[{rows * 8},{n}]" for n in (768, 2560)}, shapes
+    # XLA's grouped matmuls are the label passes' alone (a label's
+    # continuation at a time: 64 rows x the one position whose forward is
+    # read x 8 choices); the prefill's stretches run on the grouped matmul
+    # kernel, one call of each form in each routed layer's stretch loop
+    assert shapes == {f"bf16[{rows * 8},{n}]" for n in (768, 2560)}, shapes
+    assert paths["moe.grouped_kernel"] == 6
+    assert _grouped_kernel_calls(text) == {
+        f"bf16[{8192 * 8},{n}]": 6 for n in (768, 2560)}
     # a head's norms ride in the KDA kernel: no KDA layer holds the stream
     # as [slots, heads, 128] in float32 (0.4 GB and a re-tiling copy each)
     by_head = re.findall(
